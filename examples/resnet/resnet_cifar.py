@@ -153,7 +153,9 @@ def main_fun(args, ctx):
             jax.device_get(trainer.state.params), "resnet56_cifar",
             model_config={"dtype": args.dtype,
                           "blocks_per_stage": args.blocks_per_stage},
-            input_signature={"image": [None, HEIGHT, WIDTH, CHANNELS]})
+            input_signature={"image": [None, HEIGHT, WIDTH, CHANNELS]},
+            model=model,
+            extra_variables={"batch_stats": trainer.state.extra})
     return stats
 
 

@@ -209,7 +209,8 @@ def main_fun(args, ctx):
         if prof:
             prof.stop()
         _maybe_eval(args, ctx, mesh, model, trainer, size, in_dtype, stats)
-        _finish(args, ctx, trainer, ckpt, int(trainer.state.step), size)
+        _finish(args, ctx, model, trainer, ckpt, int(trainer.state.step),
+                size)
         return stats
 
     local_bs = mesh_mod.local_batch_size(mesh, args.batch_size)
@@ -248,7 +249,7 @@ def main_fun(args, ctx):
     stats = trainer.history.log_stats(
         loss=float(loss), accuracy=float(aux["accuracy"]))
     _maybe_eval(args, ctx, mesh, model, trainer, size, in_dtype, stats)
-    _finish(args, ctx, trainer, ckpt, step, size)
+    _finish(args, ctx, model, trainer, ckpt, step, size)
     return stats
 
 
@@ -306,9 +307,11 @@ def _evaluate(args, ctx, mesh, model, trainer, size, in_dtype):
     return trainer.evaluate(sharded, metric_fn)["accuracy"]
 
 
-def _finish(args, ctx, trainer, ckpt, step, size):
+def _finish(args, ctx, model, trainer, ckpt, step, size):
     """Final checkpoint + chief-only export (shared by the synthetic and
-    TFRecord-streaming paths)."""
+    TFRecord-streaming paths).  The export carries the BatchNorm running
+    statistics beside the params (the model cannot be applied without them)
+    and the serving fn as a StableHLO artifact."""
     import jax
 
     from tensorflowonspark_tpu import checkpoint
@@ -326,12 +329,12 @@ def _finish(args, ctx, trainer, ckpt, step, size):
             model_config={"num_classes": NUM_CLASSES, "dtype": args.dtype,
                           "blocks_per_stage": args.blocks_per_stage,
                           "stem": args.stem},
-            input_signature={"image": [None, size, size, 3]})
+            input_signature={"image": [None, size, size, 3]},
+            model=model,
+            extra_variables={"batch_stats": trainer.state.extra})
 
 
-def main(argv=None):
-    from tensorflowonspark_tpu import backend, cluster, device_info
-
+def build_parser():
     parser = argparse.ArgumentParser()
     parser.add_argument("--cluster_size", type=int, default=1)
     parser.add_argument("--batch_size", type=int, default=256,
@@ -386,7 +389,13 @@ def main(argv=None):
                              "throughput/MFU curves + eval accuracy)")
     parser.add_argument("--profile_steps", default=None)
     parser.add_argument("--profile_dir", default=None)
-    args, rem = parser.parse_known_args(argv)
+    return parser
+
+
+def main(argv=None):
+    from tensorflowonspark_tpu import backend, cluster, device_info
+
+    args, rem = build_parser().parse_known_args(argv)
     args.remaining_argv = rem
 
     b = backend.LocalBackend(args.cluster_size)

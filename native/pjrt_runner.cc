@@ -149,9 +149,8 @@ InputSpec ParseInput(const std::string& arg) {
 }
 
 // Client-create option, parsed from a repeatable `--create_option key=value`
-// flag.  Production plugins reject a bare PJRT_Client_Create: libtpu wants
-// ml_framework_name etc., and proxying plugins need their routing options
-// (topology, session_id, ...).  Value typing: an explicit `int:`/`str:`/
+// flag, for plugins whose PJRT_Client_Create wants options (libtpu accepts
+// a bare create).  Value typing: an explicit `int:`/`str:`/
 // `bool:`/`float:` prefix wins; otherwise all-digits (optional sign) is
 // kInt64, `true`/`false` is kBool, anything else a string.
 struct CreateOption {

@@ -43,7 +43,7 @@ import urllib.request
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+os.environ["JAX_PLATFORMS"] = "cpu"  # a CPU gate: several jax processes
 
 FAST_SECS = 0.001    # common batch production cost
 SLOW_SECS = 0.048    # every EVERY-th batch: the burst prefetch must absorb

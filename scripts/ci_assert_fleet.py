@@ -40,7 +40,7 @@ import urllib.request
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+os.environ["JAX_PLATFORMS"] = "cpu"  # a CPU gate: several jax processes
 
 BUDGET_SECS = 240.0
 N_CLIENTS = 6

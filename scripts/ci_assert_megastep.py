@@ -44,7 +44,7 @@ import tempfile
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+os.environ["JAX_PLATFORMS"] = "cpu"  # a CPU gate: several jax processes
 # Inherited by every executor: all dispatches in both phases run under the
 # transfer guard — a host round-trip on the grouped path fails the run.
 os.environ["TFOS_TRANSFER_GUARD"] = "disallow"

@@ -46,7 +46,7 @@ import urllib.request
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+os.environ["JAX_PLATFORMS"] = "cpu"  # a CPU gate: several jax processes
 
 N_SPLITS, PER_SPLIT = 16, 24
 SCRAPE_DEADLINE_SECS = 60.0

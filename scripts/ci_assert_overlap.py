@@ -30,7 +30,7 @@ import tempfile
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+os.environ["JAX_PLATFORMS"] = "cpu"  # a CPU gate: several jax processes
 # Inherited by the executor processes: every fit_feed dispatch in the node
 # fn runs under the h2d transfer guard (leg 1).
 os.environ["TFOS_TRANSFER_GUARD"] = "disallow"

@@ -52,7 +52,7 @@ import urllib.request
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+os.environ["JAX_PLATFORMS"] = "cpu"  # a CPU gate: several jax processes
 
 SLOW_SECS = 0.06         # injected per step on executor 0: ~6x its peers
 BASE_STEP_SECS = 0.012   # common per-step cost so peers have signal

@@ -43,7 +43,7 @@ import urllib.request
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+os.environ["JAX_PLATFORMS"] = "cpu"  # a CPU gate: several jax processes
 
 BUDGET_SECS = 90.0
 N_CLIENTS = 4

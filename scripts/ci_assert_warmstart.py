@@ -29,7 +29,7 @@ import urllib.request
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+os.environ["JAX_PLATFORMS"] = "cpu"  # a CPU gate: several jax processes
 
 N_ITEMS = 40   # 4 partitions of 10: the kill (after 5) always interrupts
                # executor 0 MID-partition, so its feed task fails its join

@@ -2,14 +2,13 @@
 
 One variant per fresh interpreter (XLA flags and libtpu knobs only apply
 at client creation; server-side compile state and HBM reset too), one
-output schema (``{"utc", ..., "rows": [...]}``), and the three
-guarantees the window playbook (scripts/bench_watch.py) depends on:
+output schema (``{"utc", ..., "rows": [...]}``), and three guarantees:
 
-- **persist-after-every-variant**: a tunnel flap mid-ladder keeps the
-  finished rows;
+- **persist-after-every-variant**: a killed ladder keeps the finished
+  rows;
 - **resume**: a re-run loads the prior artifact and skips variants that
-  already have an error-free row, so ladders complete across windows
-  none of which is long enough for the whole set;
+  already have an error-free row, so a ladder completes across runs none
+  of which is long enough for the whole set;
 - **fresh child files**: the per-variant scratch JSON is deleted before
   the child spawns and after the parent reads it — a stale file from an
   earlier run can never masquerade as this run's measurement.

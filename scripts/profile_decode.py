@@ -1,4 +1,4 @@
-"""Measure ImageNet JPEG decode throughput (VERDICT r3 item 3).
+"""Measure ImageNet JPEG decode throughput.
 
 Answers: at what rate can this host turn JPEG TFRecord shards into uint8
 224x224x3 training rows, per core and scaled across cores?  The 50%-MFU
@@ -159,7 +159,7 @@ def leg_predecoded(shards, px, store_px):
     once (offline cost, reported), then drain ``predecoded_reader`` through
     a FileFeed on ONE core — the hot-path rate a training worker would see.
     This is the extrapolation-free answer to the 8k img/s bar on hosts
-    whose cores can't sustain JPEG decode (VERDICT r4 item 4)."""
+    whose cores can't sustain JPEG decode."""
     import imagenet_input
 
     from tensorflowonspark_tpu import data as data_mod
@@ -202,7 +202,7 @@ def main():
     ap.add_argument("--rows", type=int, default=512)
     ap.add_argument("--image_px", type=int, default=224)
     ap.add_argument("--store_px", type=int, default=256)
-    # scaling curve to 16 procs by default (VERDICT r4 item 4); on a
+    # scaling curve to 16 procs by default; on a
     # 1-core host the tail of the curve measures IPC overhead only --
     # rows_per_sec_per_core is the honest cross-host number
     ap.add_argument("--pool_sizes", default="1,2,4,8,16")

@@ -26,7 +26,7 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+os.environ["JAX_PLATFORMS"] = "cpu"  # a CPU gate: several jax processes
 
 ASSEMBLY_COST_SECS = 0.004   # simulated host-side feature assembly per batch
 SAVE_LATENCY_SECS = 0.15     # simulated orbax serialization+write per save

@@ -19,11 +19,8 @@ from tensorflowonspark_tpu.parallel import mesh as mesh_mod
 
 
 def timed(fn, sync_value_fn, steps, per_step_sync=False):
-    # Sync = device->host READBACK, never block_until_ready: on remotely-
-    # attached backends block_until_ready returns before execution finishes
-    # (measured: a 4.4-TFLOP scan "done" in 0.1 ms), so a readback of a
-    # value data-dependent on the work is the only provable barrier (same
-    # rule as metrics.TimeHistory._sync).
+    # Sync = device->host readback of a value data-dependent on the work
+    # (same rule as metrics.TimeHistory._sync).
     out = None
     t0 = time.time()
     for _ in range(steps):
@@ -73,7 +70,9 @@ def main():
     from tensorflowonspark_tpu import metrics as metrics_mod
 
     flops = trainer.history.step_flops
-    peak = metrics_mod.peak_flops_per_device() or 197e12
+    peak = metrics_mod.peak_flops_per_device()
+    if peak is None:
+        raise SystemExit("no accelerator: this profile measures a chip")
     print("xla cost-analysis flops/step: %.3e (peak %.0fT)"
           % (flops or -1, peak / 1e12), flush=True)
 

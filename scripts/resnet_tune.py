@@ -1,10 +1,8 @@
 """ResNet-50 MFU tuning ladder: measure ms/step for targeted variants.
 
-Round-5 gap analysis (ROUND5.md): ResNet-50 bf16 bs256 K=20 runs at
-107.9 ms/step = 28.96% MFU while the same Trainer path sustains 82-87% of
-peak on plain matmuls — the gap is conv-mix efficiency, not dispatch, not
-data, not batch size (bs512 = exactly 2x bs256).  This script isolates the
-usual suspects one variant at a time, each in a FRESH subprocess (XLA flags
+Nothing here has been measured on this code (ROADMAP Queue 1 item 3: trace
+first, tune after).  The script isolates the usual suspects of a conv-mix
+efficiency gap one variant at a time, each in a FRESH subprocess (XLA flags
 and libtpu knobs only apply at client creation):
 
 - ``baseline``        exactly the bench leg's config (bs256, s2d, bf16
@@ -23,8 +21,7 @@ and libtpu knobs only apply at client creation):
                       single-chip; included to confirm that, not assume it)
 
 Timing discipline: every sample ends with a host readback data-dependent
-on the work (k_ladder.py lesson: ``block_until_ready`` does not span the
-dispatch chain on remotely-attached backends).
+on the work (same rule as ``metrics.TimeHistory._sync``).
 
 Usage:
     python scripts/resnet_tune.py                    # all variants

@@ -515,7 +515,8 @@ _COMPILE_OPTIONS_FILE = "compile_options.pb"
 def export_model(export_dir, params, model_name, model_config=None,
                  input_signature=None, model=None,
                  serialize_platforms=("cpu", "tpu"),
-                 embed_batch_size=None, embed_platform="tpu"):
+                 embed_batch_size=None, embed_platform="tpu",
+                 extra_variables=None):
     """Export params + model descriptor for serving.
 
     Call according to :func:`should_export` (chief-only convention,
@@ -536,9 +537,18 @@ def export_model(export_dir, params, model_name, model_config=None,
     fixed-batch StableHLO module (+ serialized compile options) for the
     native C++ PJRT runner (``native/pjrt_runner.cc``) — serving with no
     Python at all; ``embed_platform`` picks its single lowering target.
+
+    ``extra_variables``: the model's non-trainable collections
+    (``{"batch_stats": ...}`` for a BatchNorm model — the Trainer's
+    ``state.extra``).  The export then holds the whole variables dict and
+    serving applies it as such; without them such a model cannot be applied
+    at all.
     """
     import jax
     import orbax.checkpoint as ocp
+
+    if extra_variables:
+        params = dict(extra_variables, params=params)
 
     # Cross-process-sharded params (e.g. Trainer(param_sharding="fsdp") on
     # a multi-host mesh) are not fully addressable: device_get below would
@@ -567,13 +577,16 @@ def export_model(export_dir, params, model_name, model_config=None,
         "model_config": model_config or {},
         "input_signature": input_signature or {},
     }
+    if extra_variables:
+        descriptor["variables"] = sorted(params)
     if model is not None and input_signature and jax.process_index() == 0:
         from tensorflowonspark_tpu import serving
 
         try:
             blob, platforms = serving.serialize_apply(
                 model, jax.device_get(params), input_signature,
-                platforms=serialize_platforms)
+                platforms=serialize_platforms,
+                variables=bool(extra_variables))
             with open(os.path.join(export_dir, _STABLEHLO_FILE), "wb") as f:
                 f.write(blob)
             descriptor["stablehlo"] = {"file": _STABLEHLO_FILE,
@@ -586,7 +599,8 @@ def export_model(export_dir, params, model_name, model_config=None,
             try:
                 mlir, options, meta = serving.serialize_embedded(
                     model, jax.device_get(params), input_signature,
-                    batch_size=embed_batch_size, platform=embed_platform)
+                    batch_size=embed_batch_size, platform=embed_platform,
+                    variables=bool(extra_variables))
                 with open(os.path.join(export_dir, _EMBEDDED_MLIR_FILE),
                           "wb") as f:
                     f.write(mlir)

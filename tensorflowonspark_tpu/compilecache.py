@@ -10,7 +10,8 @@ amortization argument, arXiv:2101.12127), on two tiers:
 
 1. **Persistent compilation cache** (:func:`configure`): points JAX's
    ``jax_compilation_cache_dir`` at a cluster-shared directory resolved
-   from cluster config / :data:`CACHE_DIR_ENV`.  Every ``.compile()`` in
+   from :data:`JAX_CACHE_DIR_ENV` (wins when set) / cluster config /
+   :data:`CACHE_DIR_ENV`.  Every ``.compile()`` in
    the process — trainer steps, serving rungs, ``estimate_step_cost``'s
    canonical program — then reads/writes the disk cache, so a replacement
    node's compiles collapse to deserialization.  Hit/miss/saved-time
@@ -62,19 +63,23 @@ logger = logging.getLogger(__name__)
 #: path here so forked children (manager, feed tasks) inherit it.
 CACHE_DIR_ENV = "TFOS_COMPILE_CACHE_DIR"
 
+#: jax's own variable for the same directory.  Where it is set, it is the
+#: cache of every process of the program and :func:`configure` yields to it.
+JAX_CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
+
 #: bump when the artifact layout changes — old artifacts then read as
 #: fingerprint mismatches (clean JIT fallback), not crashes
-_FORMAT = 2
+_FORMAT = 3
 
 _SUFFIX = ".aotx"
 
 #: artifact layout: magic, one line of canonical-JSON fingerprint, then
-#: the pickled executable triple.  The JSON header is what load() checks
-#: — only a fingerprint-matched artifact ever reaches pickle.
-_MAGIC = b"TFOS-AOTX2\n"
+#: the pickled ``(payload, in_tree, out_tree, device_ids)``.  The JSON
+#: header is what load() checks — only a fingerprint-matched artifact ever
+#: reaches pickle.
+_MAGIC = b"TFOS-AOTX3\n"
 
-# jax monitoring event names the counters are derived from (stable across
-# the jax versions this repo supports; unknown names just never fire).
+# jax monitoring event names the counters are derived from
 _HIT_EVENT = "/jax/compilation_cache/cache_hits"
 _MISS_EVENT = "/jax/compilation_cache/cache_misses"
 _SAVED_EVENT = "/jax/compilation_cache/compile_time_saved_sec"
@@ -183,21 +188,16 @@ def _on_duration(event, duration=0.0, **kwargs):
 
 
 def _install_listeners():
-    """Subscribe the tallies to jax's monitoring events (idempotent).
-    Returns False on jax versions without the monitoring module — the
-    cache still works, the hit/miss counters just stay zero."""
+    """Subscribe the tallies to jax's monitoring events (idempotent)."""
     global _listeners_installed
     with _lock:
         if _listeners_installed:
-            return True
-        try:
-            from jax._src import monitoring
-        except ImportError:
-            return False
+            return
+        from jax import monitoring
+
         monitoring.register_event_listener(_on_event)
         monitoring.register_event_duration_secs_listener(_on_duration)
         _listeners_installed = True
-        return True
 
 
 def _register_stats_feed():
@@ -223,20 +223,24 @@ def configured_dir():
 def configure(cache_dir=None, register_feed=True):
     """Point JAX's persistent compilation cache at ``cache_dir``.
 
-    Resolution order: explicit argument, then :data:`CACHE_DIR_ENV`.
-    Returns the resolved (created) directory, or None when neither names
-    one — the whole compile plane is then inert, zero-cost.
+    Resolution order: JAX's own :data:`JAX_CACHE_DIR_ENV` (a cache placed
+    from outside the program wins — jax has already read it, and nothing
+    here sets another), then the explicit argument, then
+    :data:`CACHE_DIR_ENV`.  Returns the resolved (created) directory, or
+    None when nothing names one — the whole compile plane is then inert,
+    zero-cost.
 
-    Side effects on success: ``jax_compilation_cache_dir`` set, the
-    min-compile-time threshold dropped to 0 (CI/bench-scale programs
-    compile in milliseconds — the default 1s gate would exclude exactly
-    the compiles the warm-rejoin story needs cached), monitoring
-    listeners installed, the env var re-exported for forked children,
-    and (``register_feed=True``) :data:`stats` registered as a node
-    heartbeat feed.
+    Side effects on success: ``jax_compilation_cache_dir`` set (unless the
+    environment placed it), the min-compile-time threshold dropped to 0
+    (CI/bench-scale programs compile in milliseconds — the default 1s gate
+    would exclude exactly the compiles the warm-rejoin story needs cached),
+    monitoring listeners installed, the env var re-exported for forked
+    children, and (``register_feed=True``) :data:`stats` registered as a
+    node heartbeat feed.
     """
     global _configured_dir
-    cache_dir = cache_dir or os.environ.get(CACHE_DIR_ENV) or None
+    placed = os.environ.get(JAX_CACHE_DIR_ENV)
+    cache_dir = placed or cache_dir or os.environ.get(CACHE_DIR_ENV)
     if not cache_dir:
         return None
     cache_dir = os.path.abspath(cache_dir)
@@ -244,11 +248,9 @@ def configure(cache_dir=None, register_feed=True):
 
     import jax
 
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    try:
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    except Exception:  # pragma: no cover - knob renamed across versions
-        pass
+    if not placed:
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     _install_listeners()
     os.environ[CACHE_DIR_ENV] = cache_dir
     with _lock:
@@ -299,27 +301,18 @@ def fingerprint(avals=None, mesh=None, donate=(), extra=None):
     signature, or aval signature diverged before falling back to JIT.
     """
     import jax
+    import jaxlib
 
-    try:
-        import jaxlib.version as jaxlib_version_mod
-
-        jaxlib_version = jaxlib_version_mod.__version__
-    except Exception:  # pragma: no cover - stripped envs
-        jaxlib_version = "unknown"
     fp = {
         "format": _FORMAT,
         "jax": jax.__version__,
-        "jaxlib": jaxlib_version,
+        "jaxlib": jaxlib.__version__,
         "backend": jax.default_backend(),
         "device_count": jax.device_count(),
         "donate": tuple(donate),
     }
     if mesh is not None:
-        try:
-            fp["mesh"] = repr(tuple(zip(mesh.axis_names,
-                                        mesh.devices.shape)))
-        except Exception:
-            fp["mesh"] = repr(mesh)
+        fp["mesh"] = repr(tuple(zip(mesh.axis_names, mesh.devices.shape)))
     if avals is not None:
         fp["avals"] = _aval_signature(avals)
     if extra:
@@ -458,8 +451,9 @@ class AOTCache(object):
 
     Artifacts are ``<name>.aotx`` files: :data:`_MAGIC`, one line of
     canonical-JSON fingerprint, then the pickled
-    ``jax.experimental.serialize_executable`` triple
-    ``(payload, in_tree, out_tree)``, written atomically (tmp + rename)
+    ``jax.experimental.serialize_executable`` triple plus the ids of the
+    devices the program was compiled for
+    ``(payload, in_tree, out_tree, device_ids)``, written atomically (tmp + rename)
     so a killed writer can never leave a half artifact under a reader.
     Absent / mismatched / corrupt artifacts are all clean misses.
 
@@ -538,10 +532,15 @@ class AOTCache(object):
 
             import jax
 
-            payload, in_tree, out_tree = pickle.loads(blob[header_end + 1:])
+            payload, in_tree, out_tree, device_ids = pickle.loads(
+                blob[header_end + 1:])
+            # the program runs on the devices it was compiled for, not on
+            # every device of the backend (deserialize_and_load's default)
+            by_id = {d.id: d for d in jax.devices()}
             compiled = se.deserialize_and_load(
                 payload, in_tree, out_tree,
-                backend=jax.default_backend())
+                backend=jax.default_backend(),
+                execution_devices=[by_id[i] for i in device_ids])
         except Exception as e:
             stats.fallback += 1
             logger.warning("AOT artifact %s failed to load (%s: %s); "
@@ -569,8 +568,10 @@ class AOTCache(object):
             from jax.experimental import serialize_executable as se
 
             payload, in_tree, out_tree = se.serialize(compiled)
+            device_ids = [
+                d.id for d in compiled.runtime_executable().local_devices()]
             blob = (_MAGIC + _fp_canonical(fp).encode("utf-8") + b"\n"
-                    + pickle.dumps((payload, in_tree, out_tree),
+                    + pickle.dumps((payload, in_tree, out_tree, device_ids),
                                    protocol=pickle.HIGHEST_PROTOCOL))
         except Exception as e:
             logger.warning("AOT serialize of %s failed (%s: %s); "

@@ -127,6 +127,31 @@ def run_inference_native(export_dir, rows, plugin_path, input_mapping=None,
             yield row
 
 
+def _replica_report(server):
+    """What this replica serves with, for whoever launched it: the device
+    JAX gave the process, whether the export's own StableHLO artifact is the
+    serving program (and why not, when it is not), and what warming the
+    bucket ladder cost."""
+    import jax
+
+    from tensorflowonspark_tpu import compilecache
+
+    devices = jax.devices()
+    warm = server.warmup_report or {"buckets": []}
+    return {
+        "platform": devices[0].platform,
+        "device_kind": devices[0].device_kind,
+        "device_count": len(devices),
+        "from_stablehlo": server.from_stablehlo,
+        "stablehlo_fallback": server.stablehlo_fallback,
+        "warmup_secs": round(
+            sum(r["micros"] for r in warm["buckets"]) / 1e6, 3),
+        "compile_cache_dir": jax.config.jax_compilation_cache_dir,
+        "compile_cache_hit": compilecache.stats.cache_hit,
+        "compile_cache_miss": compilecache.stats.cache_miss,
+    }
+
+
 def serve_forever(args):
     """``--serve``: run one gateway replica until SIGTERM/SIGINT (the
     ``dataservice_worker.py`` lifecycle — print a ready line, wait on a
@@ -159,15 +184,16 @@ def serve_forever(args):
                     name, model_version, args.export_dir)
     elif not args.export_dir:
         raise SystemExit("--serve needs --export_dir or --registry/--model")
-    if args.warm_cache_dir:
-        # Warm-start compile plane: persistent XLA cache + serialized
-        # bucket-rung executables under one root, so a restarted replica
-        # reaches first prediction in seconds with compile_count == 0.
-        # register_feed=False: gateway beats merge the counters themselves
-        # (heartbeat_metrics), there is no node heartbeat here.
-        from tensorflowonspark_tpu import compilecache
+    # Warm-start compile plane: persistent XLA cache (placed by
+    # --warm-cache-dir or from outside by the environment; inert when
+    # nothing names one) + with --warm-cache-dir the serialized bucket-rung
+    # executables under the same root, so a restarted replica reaches first
+    # prediction in seconds with compile_count == 0.
+    # register_feed=False: gateway beats merge the counters themselves
+    # (heartbeat_metrics), there is no node heartbeat here.
+    from tensorflowonspark_tpu import compilecache
 
-        compilecache.configure(args.warm_cache_dir, register_feed=False)
+    compilecache.configure(args.warm_cache_dir, register_feed=False)
     server = serving.ModelServer(args.export_dir, args.max_batch,
                                  warm_cache_dir=args.warm_cache_dir)
     gw = gateway.GatewayServer(
@@ -181,6 +207,8 @@ def serve_forever(args):
     host, port = gw.start()
     print("serving replica {} ready on {}:{} (buckets {})".format(
         gw.replica_id, host, port, list(server.buckets)), flush=True)
+    print("serving replica {} report {}".format(
+        gw.replica_id, json.dumps(_replica_report(server))), flush=True)
     done = threading.Event()
     for sig in (signal.SIGTERM, signal.SIGINT):
         signal.signal(sig, lambda *_: done.set())

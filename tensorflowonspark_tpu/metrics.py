@@ -27,7 +27,6 @@ PEAK_FLOPS = {
     "tpu v5p": 459e12,
     "tpu v6 lite": 918e12,   # v6e / trillium
     "tpu v6e": 918e12,
-    "cpu": 1e11,             # nominal figure so tests exercise the math
 }
 
 # Peak HBM bytes/s per chip for roofline accounting — same keying rules as
@@ -45,7 +44,6 @@ PEAK_BYTES_PER_SEC = {
     "tpu v5p": 2765e9,
     "tpu v6 lite": 1640e9,   # v6e / trillium
     "tpu v6e": 1640e9,
-    "cpu": 5e10,             # nominal figure so tests exercise the math
 }
 
 
@@ -81,8 +79,10 @@ def mfu_from_step_time(step_flops, step_seconds):
     accountant (``train.Trainer``) and the bench scripts compute the same
     number from the same inputs.
     """
+    if not step_flops or not step_seconds or step_seconds <= 0:
+        return None
     peak = peak_flops_per_device()
-    if peak is None or not step_flops or not step_seconds or step_seconds <= 0:
+    if peak is None:
         return None
     return step_flops / peak / step_seconds
 
@@ -99,26 +99,31 @@ def compression_ratio(raw_bytes, wire_bytes):
     return raw_bytes / float(wire_bytes)
 
 
-def peak_flops_per_device():
+def _device_peak(table, table_name):
+    """The default device's row of a peak table.  A host CPU has no row and
+    gets None — a run without an accelerator reports no utilization at all.
+    An accelerator whose ``device_kind`` is missing from the table is an
+    error, not a default: a guessed peak is a wrong MFU."""
     import jax
 
-    kind = getattr(jax.devices()[0], "device_kind", "cpu").lower()
-    val = PEAK_FLOPS.get(kind)
-    if val is None:
-        logger.warning(
-            "unknown device kind %r; MFU will be reported as None", kind)
-    return val
+    device = jax.devices()[0]
+    kind = device.device_kind.lower()
+    if kind in table:
+        return table[kind]
+    if device.platform == "cpu":
+        return None
+    raise ValueError(
+        "device kind {!r} (platform {}) has no row in metrics.{}; add its "
+        "published peak there".format(device.device_kind, device.platform,
+                                      table_name))
+
+
+def peak_flops_per_device():
+    return _device_peak(PEAK_FLOPS, "PEAK_FLOPS")
 
 
 def peak_bytes_per_sec_per_device():
-    import jax
-
-    kind = getattr(jax.devices()[0], "device_kind", "cpu").lower()
-    val = PEAK_BYTES_PER_SEC.get(kind)
-    if val is None:
-        logger.warning(
-            "unknown device kind %r; roofline will be reported as None", kind)
-    return val
+    return _device_peak(PEAK_BYTES_PER_SEC, "PEAK_BYTES_PER_SEC")
 
 
 def estimate_step_flops(jitted_fn, *args, **kwargs):
@@ -147,8 +152,6 @@ def estimate_step_cost(jitted_fn, *args, **kwargs):
         compiled = jitted_fn.lower(*args, **kwargs).compile()
         compile_secs = time.perf_counter() - t0
         cost = compiled.cost_analysis()
-        if isinstance(cost, list):  # older jax returns [dict]
-            cost = cost[0]
         return {
             "flops": float(cost.get("flops", 0.0)) or None,
             "bytes_accessed": float(cost.get("bytes accessed", 0.0)) or None,
@@ -178,11 +181,13 @@ def roofline(step_flops, bytes_accessed, peak_flops=None, peak_bps=None):
     report; everything a measured step takes beyond it is starvation,
     drain, collective time, or device inefficiency.
     """
+    if not step_flops or not bytes_accessed:
+        return None
     if peak_flops is None:
         peak_flops = peak_flops_per_device()
     if peak_bps is None:
         peak_bps = peak_bytes_per_sec_per_device()
-    if not step_flops or not bytes_accessed or not peak_flops or not peak_bps:
+    if not peak_flops or not peak_bps:
         return None
     intensity = step_flops / bytes_accessed
     ridge = peak_flops / peak_bps
@@ -213,18 +218,17 @@ def device_memory_counters():
     ``{}``; ones that did (the trainer) get stats for free."""
     out = {}
     try:
-        jax = sys.modules.get("jax")
-        if jax is None:
-            return out
-        xb = sys.modules.get("jax._src.xla_bridge")
-        if xb is None or not getattr(xb, "_backends", None):
+        from tensorflowonspark_tpu import device_info
+
+        if not device_info.backends_initialized():
             return out  # no backend up yet; local_devices() would init one
+        jax = sys.modules["jax"]
 
         in_use, peak = 0, 0
         seen = False
         for dev in jax.local_devices():
-            stats = getattr(dev, "memory_stats", lambda: None)()
-            if not isinstance(stats, dict):
+            stats = dev.memory_stats()
+            if stats is None:  # the CPU backend keeps none
                 continue
             seen = True
             in_use = max(in_use, int(stats.get("bytes_in_use", 0)))
@@ -328,9 +332,8 @@ class TimeHistory(object):
     def _sync(value):
         """Force a device->host readback so the host clock reflects device
         completion; returns the host value (None when there was nothing to
-        sync on).  A readback (not just ``block_until_ready``): on
-        remotely-attached backends the transfer is the only barrier that
-        provably spans the full dispatch chain."""
+        sync on).  The value read back is also what the health counters and
+        the loss curve consume, so the boundary costs one transfer."""
         if value is None:
             return None
         import jax

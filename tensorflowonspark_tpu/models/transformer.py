@@ -41,7 +41,7 @@ class Attention(nn.Module):
         if self.attention == "flash":
             from tensorflowonspark_tpu.ops import flash_attention
 
-            out = flash_attention(q, k, v, causal=True)
+            out = flash_attention(q, k, v, causal=True, mesh=self.mesh)
         elif self.attention == "ring":
             assert self.mesh is not None, "ring attention needs a mesh"
             out = ring.ring_attention(q, k, v, self.mesh, causal=True)

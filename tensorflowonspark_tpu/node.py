@@ -380,11 +380,6 @@ class TPUNodeContext(object):
         coordinates the rendezvous distributed (SURVEY §2.5).  No-op for
         single-process clusters and for ps nodes.
         """
-        from tensorflowonspark_tpu.parallel import mesh as mesh_mod
-
-        # before ANY backend touch: env platform selection must win over
-        # plugin sitecustomize config rewrites (see enforce_env_platforms)
-        mesh_mod.enforce_env_platforms()
         if self.process_id is None or self.num_processes <= 1:
             return
         import jax
@@ -394,6 +389,21 @@ class TPUNodeContext(object):
             num_processes=self.num_processes,
             process_id=self.process_id,
         )
+        # The TPU runtime forms its world from its own view of the slice,
+        # not from this rendezvous.  Processes that each see only their own
+        # chips (several executors on one host, each pinned by
+        # ``device_info.pin_chips``) stay worlds of one, and a collective
+        # over "all" of them would wait for ever: refuse here instead.
+        if jax.process_count() != self.num_processes:
+            raise RuntimeError(
+                "the rendezvous joined {} processes but this process's jax "
+                "world has {} ({} device(s)): the executors do not form one "
+                "device world.  On one TPU host, run ONE executor that owns "
+                "all the chips; executors pinned to a chip each "
+                "(device_info.pin_chips) are independent one-chip worlds "
+                "and cannot train together".format(
+                    self.num_processes, jax.process_count(),
+                    jax.device_count()))
 
     def get_data_feed(self, train_mode=True, qname_in="input",
                       qname_out="output", input_mapping=None):
@@ -643,12 +653,14 @@ def run(fn, tf_args, cluster_meta, tensorboard=False, log_dir=None,
             tb_pid, tb_port = _start_tensorboard(log_dir or "tensorboard_logs")
 
         # Per-host jax.profiler server so TensorBoard's profile plugin can
-        # capture device traces on demand (SURVEY §5.1 TPU mapping).
+        # capture device traces on demand (SURVEY §5.1 TPU mapping).  Only
+        # its port is chosen here: ``jax.profiler.start_server`` creates the
+        # backend, and this shell must not own the chip — the process that
+        # runs the user fn starts the server (see wrapper_fn).
         profiler_port = 0
         if profiler and job_name in _JAX_JOBS:
-            from tensorflowonspark_tpu import profiler as profiler_mod
-
-            profiler_port = profiler_mod.start_server()
+            sock, profiler_port = _reserve_free_port()
+            sock.close()
 
         # Reserve the port this node contributes to the roster.  For process 0
         # it becomes the jax.distributed coordinator port (reference reserved
@@ -735,6 +747,10 @@ def run(fn, tf_args, cluster_meta, tensorboard=False, log_dir=None,
             from tensorflowonspark_tpu import compilecache
 
             compilecache.configure_from_meta(cluster_meta)
+            if profiler_port:
+                from tensorflowonspark_tpu import profiler as profiler_mod
+
+                profiler_mod.start_server_when_backend_is_up(profiler_port)
             if isinstance(args, list):
                 sys.argv = args
             fn(args, context)

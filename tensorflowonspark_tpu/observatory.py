@@ -201,16 +201,13 @@ def build_info():
 
     info = {"version": __version__,
             "python": "%d.%d.%d" % sys.version_info[:3]}
+    from tensorflowonspark_tpu import device_info
+
     jax = sys.modules.get("jax")
     if jax is not None:
-        info["jax"] = getattr(jax, "__version__", "unknown")
-        try:
-            from jax._src import xla_bridge
-            backends = getattr(xla_bridge, "_backends", None) or {}
-            if backends:
-                info["backend"] = ",".join(sorted(backends))
-        except Exception:
-            pass
+        info["jax"] = jax.__version__
+        if device_info.backends_initialized():
+            info["backend"] = jax.default_backend()
     return info
 
 

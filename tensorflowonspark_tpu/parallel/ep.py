@@ -115,8 +115,6 @@ def moe_ffn(x, params, mesh, num_experts, capacity_factor=1.25,
     from jax import lax
     from jax.sharding import PartitionSpec as P
 
-    from tensorflowonspark_tpu.parallel._compat import shard_map
-
     if batch_axes is None:
         batch_axes = (axis,)
     elif isinstance(batch_axes, str):
@@ -169,7 +167,7 @@ def moe_ffn(x, params, mesh, num_experts, capacity_factor=1.25,
         aux = num_experts * jnp.sum(fraction * mean_prob)
         return y, aux
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(batch_axes), P(), P(), P(axis), P(axis), P(axis),
                   P(axis)),

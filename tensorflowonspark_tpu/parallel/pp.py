@@ -69,8 +69,6 @@ def gpipe(stage_fn, stacked_params, microbatches, mesh, axis="pipe"):
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from tensorflowonspark_tpu.parallel._compat import shard_map
-
     n_stages = mesh.shape[axis]
     n_micro = microbatches.shape[0]
     ticks = n_micro + n_stages - 1
@@ -80,7 +78,7 @@ def gpipe(stage_fn, stacked_params, microbatches, mesh, axis="pipe"):
         return jax.vmap(lambda x: stage_fn(squeezed, x))(microbatches)
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(axis), P()), out_specs=P(),
         check_vma=False)
     def run(params, inputs):

@@ -27,8 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from tensorflowonspark_tpu.parallel._compat import pcast_varying, shard_map
-
 NEG_INF = -1e30  # large-negative instead of -inf: keeps exp() NaN-free
 
 
@@ -69,7 +67,7 @@ def _ring_shard_fn(q, k, v, axis_name, causal, scale, vary_axes):
     l = jnp.zeros((batch, sq, heads), dtype=jnp.float32)
     # The loop carry must be device-varying-typed from the start (shard_map
     # vma typing): the accumulators are per-shard state.
-    o, m, l = (pcast_varying(x, vary_axes) for x in (o, m, l))
+    o, m, l = (jax.lax.pcast(x, vary_axes, to="varying") for x in (o, m, l))
     q32 = q.astype(jnp.float32)
     perm = [(j, (j + 1) % axis_size) for j in range(axis_size)]
 
@@ -110,7 +108,7 @@ def ring_attention(q, k, v, mesh, seq_axis="seq", batch_axis="data",
     batch = batch_axis if batch_axis in mesh.axis_names else None
     spec = P(batch, seq_axis, None, None)
     vary_axes = tuple(a for a in (batch, seq_axis) if a is not None)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(_ring_shard_fn, axis_name=seq_axis,
                           causal=causal, scale=scale, vary_axes=vary_axes),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)
@@ -168,7 +166,7 @@ def ulysses_attention(q, k, v, mesh, seq_axis="seq", batch_axis="data",
         scale = 1.0 / math.sqrt(q.shape[-1])
     batch = batch_axis if batch_axis in mesh.axis_names else None
     spec = P(batch, seq_axis, None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(_ulysses_shard_fn, axis_name=seq_axis,
                           causal=causal, scale=scale, impl=impl),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,

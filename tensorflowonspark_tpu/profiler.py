@@ -9,13 +9,18 @@ The reference's tracing story is TensorBoard managed by the framework
 - :func:`start_server` — a per-host ``jax.profiler`` server so TensorBoard's
   profile plugin (or ``xprof``) can capture device traces on demand; the
   node runtime starts one per JAX-hosting node when ``cluster.run(...,
-  profiler=True)`` and publishes the port in the cluster roster.
+  profiler=True)`` — in the process that runs the user fn, once that
+  process has opened the device
+  (:func:`start_server_when_backend_is_up`) — and publishes the port in
+  the cluster roster.
 - :class:`StepProfiler` — programmatic trace capture over a step range,
   the ``--profile_steps start,stop`` behavior: call :meth:`on_step_end`
   once per step and the trace for [start, stop] lands in ``log_dir``.
 """
 
 import logging
+import threading
+import time
 
 logger = logging.getLogger(__name__)
 
@@ -52,6 +57,26 @@ def start_server(port=None):
     _server_state = "up"
     logger.info("jax profiler server listening on port %d", port)
     return port
+
+
+def start_server_when_backend_is_up(port, poll_secs=0.5):
+    """Start the profiler server on ``port`` once this process has created
+    its JAX backend — from a daemon thread, and never before:
+    ``jax.profiler.start_server`` creates the backend itself, which would
+    open the chip ahead of the user fn (before its ``pin_chips`` or
+    ``initialize_distributed``) or, worse, in a shell that is about to fork
+    it.  A process that never touches the device gets no server."""
+    from tensorflowonspark_tpu import device_info
+
+    def wait_then_start():
+        while not device_info.backends_initialized():
+            time.sleep(poll_secs)
+        start_server(port)
+
+    thread = threading.Thread(target=wait_then_start, daemon=True,
+                              name="profiler-server-start")
+    thread.start()
+    return thread
 
 
 def server_counters():

@@ -155,7 +155,10 @@ DEFAULT_CONFIG = {
     # node compiles cold and must not be re-flagged while warming up
     "replacement_grace_secs": 30.0,
     # subprocess argv for the scale-out families; None disables the
-    # family unless the wiring injects an actuator directly
+    # family unless the wiring injects an actuator directly.  A serving
+    # replica is one process for each chip: the argv (or its environment)
+    # must send the added replica to a chip no other process holds
+    # (docs/SERVING.md, "One replica process for each chip")
     "worker_spawn_argv": None,
     "serving_spawn_argv": None,
     # bounded in-memory action log + journal snapshot cadence

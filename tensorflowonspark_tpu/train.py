@@ -313,8 +313,16 @@ class Trainer(object):
         self._plain_core = train_step
         self._step_core = train_step if accum_steps == 1 else train_step_accum
         self._donate = (0,) if donate else ()
-        self._train_step = jax.jit(self._step_core,
-                                   donate_argnums=self._donate)
+        # Every step program hands the state back laid out as it came in.
+        # Left to itself the compiler picks the output layout: under an
+        # explicit ``param_sharding`` the requested layout was gone after
+        # one step, and the second call compiled a second program for the
+        # new one.
+        self._state_shardings = jax.tree_util.tree_map(
+            lambda x: x.sharding, self.state)
+        self._train_step = jax.jit(
+            self._step_core, donate_argnums=self._donate,
+            out_shardings=(self._state_shardings, None, None))
         self._multi_cache = {}  # k -> jitted k-step scan program
         # Warm-start compile plane (compilecache): the AOT executable
         # store, the per-program resolution memo (name -> deserialized /
@@ -631,8 +639,7 @@ class Trainer(object):
         """Jitted program running ``k`` train steps in ONE dispatch via
         ``lax.scan`` over a stacked group of batches (leaves shaped
         ``(k, batch, ...)``).  Amortizes per-step dispatch latency and lets
-        XLA overlap the scan iterations' host interactions — the difference
-        between single-digit and real MFU on remotely-attached backends.
+        XLA overlap the scan iterations' host interactions.
 
         The scan also reduces its window metrics ON DEVICE — per-step
         losses AND grad norms come out as the full vector plus O(1) means,
@@ -661,7 +668,9 @@ class Trainer(object):
                 # addressable) arrays
                 return state, (losses, losses[-1],
                                losses.mean(), gnorms.mean())
-            self._multi_cache[key] = jax.jit(multi, donate_argnums=donate)
+            self._multi_cache[key] = jax.jit(
+                multi, donate_argnums=donate,
+                out_shardings=(self._state_shardings, None))
         return self._multi_cache[key]
 
     def _get_repeat_step(self, k):
@@ -683,7 +692,8 @@ class Trainer(object):
                 return state, (losses, losses[-1],
                                losses.mean(), gnorms.mean())
             self._multi_cache[key] = jax.jit(
-                repeat, donate_argnums=self._donate)
+                repeat, donate_argnums=self._donate,
+                out_shardings=(self._state_shardings, None))
         return self._multi_cache[key]
 
     def set_aot_cache(self, cache):
@@ -906,9 +916,8 @@ class Trainer(object):
         else:
             call = lambda b, m: fn(self.state.params, b, m)
         # Accumulate ON DEVICE (jitted tree-add): a per-batch float() would
-        # block the host on every dispatch — lethal on remotely-attached
-        # backends where dispatch RTT dominates — and eager adds on multi-
-        # host jit outputs raise.  One sync at the very end.
+        # block the host on every dispatch, and eager adds on multi-host jit
+        # outputs raise.  One sync at the very end.
         totals = None
         weight_total = None
         for batch, mask in sharded_feed.batches(drain="all"):

@@ -355,7 +355,42 @@ class TestServingAOT:
 class TestConfigure:
     def test_inert_without_dir(self, monkeypatch):
         monkeypatch.delenv(compilecache.CACHE_DIR_ENV, raising=False)
+        monkeypatch.delenv(compilecache.JAX_CACHE_DIR_ENV, raising=False)
         assert compilecache.configure(None, register_feed=False) is None
+
+    @pytest.fixture
+    def config_updates(self, monkeypatch):
+        """Record ``jax.config.update`` calls instead of making them (the
+        suite's own jax config stays as it is) and restore the module's
+        record of the configured directory."""
+        updates = {}
+        monkeypatch.setattr(jax.config, "update", updates.__setitem__)
+        monkeypatch.setattr(compilecache, "_configured_dir", None)
+        monkeypatch.setenv(compilecache.CACHE_DIR_ENV, "")
+        return updates
+
+    def test_environment_variable_wins_over_the_argument(
+            self, monkeypatch, tmp_path, config_updates):
+        """A cache placed from outside (JAX_COMPILATION_CACHE_DIR) is the
+        cache of every process: ``cluster.run(compile_cache_dir=)`` and
+        TFOS_COMPILE_CACHE_DIR yield to it, and nothing sets another."""
+        placed = str(tmp_path / "placed")
+        monkeypatch.setenv(compilecache.JAX_CACHE_DIR_ENV, placed)
+        got = compilecache.configure(str(tmp_path / "from_cluster_run"),
+                                     register_feed=False)
+        assert got == placed and compilecache.configured_dir() == placed
+        assert "jax_compilation_cache_dir" not in config_updates
+        assert not (tmp_path / "from_cluster_run").exists()
+        # the same through the cluster's meta
+        assert compilecache.configure_from_meta(
+            {"compile_cache_dir": str(tmp_path / "meta")}) == placed
+
+    def test_argument_places_the_cache_when_the_environment_does_not(
+            self, monkeypatch, tmp_path, config_updates):
+        monkeypatch.delenv(compilecache.JAX_CACHE_DIR_ENV, raising=False)
+        want = str(tmp_path / "arg")
+        assert compilecache.configure(want, register_feed=False) == want
+        assert config_updates["jax_compilation_cache_dir"] == want
 
     def test_counters_snapshot_shape(self):
         snap = compilecache.stats.counters_snapshot()

@@ -124,7 +124,7 @@ class TestTrainFromRemoteStore:
 
     def test_mnist_trains_from_memory_store(self, mnist_shards):
         """End-to-end: the mnist model trains on shards living in a
-        non-local store (VERDICT r3 item 2's done-criterion)."""
+        non-local store."""
         import jax
         import jax.numpy as jnp
         import optax

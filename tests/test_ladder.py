@@ -1,7 +1,7 @@
 """Plumbing tests for the shared tuning-ladder runner (scripts/ladder.py).
 
-These guarantees are what bench_watch's resumable window playbook stands
-on, so they get direct coverage with a trivial child (no jax, no device):
+The tuning ladders (lm_tune / resnet_tune) stand on these guarantees, so
+they get direct coverage with a trivial child (no jax, no device):
 persist-after-every-variant, resume-skips-finished-variants, fresh child
 scratch files, and cwd-independent output paths.
 """

@@ -217,7 +217,7 @@ def _linear_loss(params, batch, mask):
 
 
 class TestRuntimeMfuAgreement:
-    def test_runtime_mfu_matches_bench_formula_within_5pct(self):
+    def test_runtime_mfu_matches_bench_formula_within_5pct(self, cpu_peaks):
         """The Trainer's runtime MFU gauge must agree with the bench's MFU
         computation (TimeHistory.mfu over a closed window) within 5% on a
         tiny jitted step — they share formula AND clock, so disagreement
@@ -269,7 +269,7 @@ class TestRuntimeMfuAgreement:
         assert cum == sorted(cum), "cumulative buckets must be monotone"
         assert cum[-1] <= snap["step_ms_count"]
 
-    def test_whole_run_mfu_same_ballpark(self):
+    def test_whole_run_mfu_same_ballpark(self, cpu_peaks):
         """build_stats' whole-run mfu (what bench.py publishes) and the
         runtime gauge's latest-window mfu measure the same steady loop —
         generous 2x band only to absorb CPU scheduler jitter."""

@@ -110,3 +110,52 @@ def test_ulysses_with_flash_inner(causal):
     got = ring.ulysses_attention(q, k, v, mesh, causal=causal, impl="flash")
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=2e-5, rtol=2e-5)
+
+
+def test_flash_on_a_mesh_maps_itself_per_shard():
+    """The compiler cannot partition a Mosaic kernel, so with ``mesh=`` the
+    op runs per shard (batch over data, heads over tensor): same values and
+    gradients as the reference."""
+    from tensorflowonspark_tpu.parallel import build_mesh
+
+    q, k, v = _qkv(batch=4, seq=64, heads=4, dim=16, seed=5)
+    mesh = build_mesh({"data": 2, "tensor": 2},
+                      devices=jax.devices()[:4])
+
+    def loss_flash(q, k, v):
+        o = flash_attention(q, k, v, causal=True, block_q=32, block_k=32,
+                            mesh=mesh)
+        return (o ** 2).sum(), o
+
+    def loss_ref(q, k, v):
+        o = ring.reference_attention(q, k, v, causal=True)
+        return (o ** 2).sum(), o
+
+    (_, got), g_flash = jax.jit(jax.value_and_grad(
+        loss_flash, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+    (_, want), g_ref = jax.value_and_grad(
+        loss_ref, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-5, rtol=2e-5)
+    for gf, gr, name in zip(g_flash, g_ref, "qkv"):
+        np.testing.assert_allclose(
+            np.asarray(gf), np.asarray(gr), atol=5e-4, rtol=5e-4,
+            err_msg="d{} mismatch".format(name))
+
+
+@pytest.mark.parametrize("platform, interpret", [("tpu", False),
+                                                 ("cpu", True)])
+def test_interpret_default_follows_the_platform(monkeypatch, platform,
+                                                interpret):
+    """A process whose platform is ``tpu`` never gets interpret mode
+    unasked; interpreting is for the CPU tests."""
+    import importlib
+
+    fa = importlib.import_module("tensorflowonspark_tpu.ops.flash_attention")
+
+    class Device:
+        device_kind = "whatever it says"
+
+    Device.platform = platform
+    monkeypatch.setattr(jax, "devices", lambda *a: [Device()])
+    assert fa._default_interpret() is interpret

@@ -85,3 +85,65 @@ def test_cluster_publishes_profiler_ports():
         c.shutdown(grace_secs=1)
     finally:
         b.stop()
+
+
+def test_server_start_waits_for_the_backend(monkeypatch):
+    """``jax.profiler.start_server`` creates the backend, so the node
+    runtime never calls it first: the start is deferred until the process
+    has opened the device itself."""
+    import time
+
+    import jax
+
+    from tensorflowonspark_tpu import device_info, profiler
+
+    started = []
+    monkeypatch.setattr(profiler, "_server_port", None)
+    monkeypatch.setattr(profiler, "_server_state", None)
+    monkeypatch.setattr(jax.profiler, "start_server", started.append)
+    up = [False]
+    monkeypatch.setattr(device_info, "backends_initialized", lambda: up[0])
+    thread = profiler.start_server_when_backend_is_up(4242, poll_secs=0.01)
+    time.sleep(0.1)
+    assert thread.is_alive() and started == []  # no backend yet: no server
+    up[0] = True
+    thread.join(timeout=5)
+    assert not thread.is_alive() and started == [4242]
+
+
+def test_profiler_does_not_open_the_device_in_the_executor_shell():
+    """SPARK mode forks the user fn from the executor shell; with
+    ``profiler=True`` the shell used to start the profiler server, which
+    created a backend there — on a chip, a second owner.  The fork must see
+    no backend, and the user fn's process gets the server."""
+    from tensorflowonspark_tpu import backend, cluster
+
+    def fn(args, ctx):
+        import time
+
+        from tensorflowonspark_tpu import device_info, profiler
+
+        inherited = device_info.backends_initialized()
+        import jax
+
+        jax.devices()
+        deadline = time.time() + 10
+        while profiler._server_port is None and time.time() < deadline:
+            time.sleep(0.05)
+        feed = ctx.get_data_feed(train_mode=False)
+        while not feed.should_stop():
+            rows = feed.next_batch(1)
+            feed.batch_results([(inherited, profiler._server_port)
+                                for _ in rows])
+
+    b = backend.LocalBackend(1)
+    try:
+        c = cluster.run(b, fn, {}, num_executors=1, profiler=True,
+                        input_mode=cluster.InputMode.SPARK)
+        (addr,) = c.profiler_addresses()
+        ((inherited, port),) = c.inference(backend.partition([0], 1))
+        assert inherited is False
+        assert port == int(addr.rsplit(":", 1)[1])
+        c.shutdown(grace_secs=1)
+    finally:
+        b.stop()

@@ -73,9 +73,9 @@ class TestRoofline:
         assert metrics_mod.roofline(1e9, 1e9, peak_flops=1e12,
                                     peak_bps=0) is None
 
-    def test_cpu_tables_feed_the_math(self):
-        # the nominal cpu entries exist precisely so CPU CI exercises this
-        assert metrics_mod.peak_bytes_per_sec_per_device() is not None
+    def test_device_tables_feed_the_math(self, cpu_peaks):
+        # peaks default to the device's rows of the tables
+        assert metrics_mod.peak_bytes_per_sec_per_device() == 5e10
         assert metrics_mod.roofline(1e6, 1e6) is not None
 
 

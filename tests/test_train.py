@@ -61,7 +61,7 @@ class TestTrainer:
 
 
 class TestMetrics:
-    def test_time_history_throughput(self):
+    def test_time_history_throughput(self, cpu_peaks):
         th = metrics_mod.TimeHistory(batch_size=32, log_steps=2,
                                      step_flops=1e6, num_devices=8)
         th.on_train_begin()
@@ -72,7 +72,39 @@ class TestMetrics:
         assert stats["global_steps"] == 6
         assert stats["avg_exp_per_second"] > 0
         assert stats["loss"] == 0.5
-        assert "mfu" in stats  # cpu has a nominal peak-flops entry
+        assert "mfu" in stats  # from the peaks this test passed in
+
+    def test_cpu_run_reports_no_utilization(self):
+        # no nominal CPU row: a run without an accelerator prints no MFU
+        assert "cpu" not in metrics_mod.PEAK_FLOPS
+        assert "cpu" not in metrics_mod.PEAK_BYTES_PER_SEC
+        assert metrics_mod.peak_flops_per_device() is None
+        assert metrics_mod.mfu_from_step_time(1e9, 0.01) is None
+        assert metrics_mod.roofline(1e6, 1e6) is None
+
+    @pytest.mark.parametrize("lookup", ["peak_flops_per_device",
+                                        "peak_bytes_per_sec_per_device"])
+    def test_unknown_accelerator_kind_raises(self, monkeypatch, lookup):
+        # an accelerator the table has no row for is an error, never a
+        # default and never a silent None
+        class Chip:
+            platform = "tpu"
+            device_kind = "TPU v9 imaginary"
+
+        monkeypatch.setattr(jax, "devices", lambda *a: [Chip()])
+        with pytest.raises(ValueError, match="TPU v9 imaginary"):
+            getattr(metrics_mod, lookup)()
+
+    def test_v5e_row_is_keyed_by_the_kind_the_chip_reports(self, monkeypatch):
+        # jax.devices()[0].device_kind on the attached v5e (chip_smoke.py
+        # prints it and fails if the lookup misses)
+        class Chip:
+            platform = "tpu"
+            device_kind = "TPU v5 lite"
+
+        monkeypatch.setattr(jax, "devices", lambda *a: [Chip()])
+        assert metrics_mod.peak_flops_per_device() == 197e12
+        assert metrics_mod.peak_bytes_per_sec_per_device() == 819e9
 
     def test_step_flops_from_cost_analysis(self):
         f = jax.jit(lambda a, b: a @ b)
@@ -106,7 +138,7 @@ class TestMetrics:
         assert "tpu v4" in metrics_mod.PEAK_FLOPS
         assert metrics_mod.PEAK_FLOPS.get("tpu v5 lite x") is None
 
-    def test_mfu_physically_possible_on_real_trainer(self):
+    def test_mfu_physically_possible_on_real_trainer(self, cpu_peaks):
         # Regression for >100%-MFU: window timing must sync on device
         # completion, so MFU from a real trainer run is always <= 1.
         mesh = build_mesh()
@@ -118,8 +150,7 @@ class TestMetrics:
             loss, _ = tr.step(_make_batch(mesh, seed=step))
         tr.history.on_train_end(loss)
         stats = tr.history.build_stats(loss=float(loss))
-        if "mfu" in stats:
-            assert 0.0 < stats["mfu"] <= 1.0, stats
+        assert 0.0 < stats["mfu"] <= 1.0, stats
         # per-window MFU too: recompute from the timestamp log
         log = tr.history.timestamp_log
         for (s0, t0), (s1, t1) in zip(log, log[1:]):
@@ -476,7 +507,7 @@ class _CaptureWriter:
 class TestPerStepLossCurve:
     def test_multi_step_writes_dense_loss_curve(self):
         """Under K-steps-per-dispatch, the TensorBoard loss curve must keep
-        PER-STEP density (VERDICT r3 weak #5): a K=4 group with log_steps=4
+        PER-STEP density: a K=4 group with log_steps=4
         yields four loss points at steps 1..4, matching the single-step
         trajectory, not one point per dispatch."""
         from tensorflowonspark_tpu.parallel import mesh as mesh_mod
@@ -537,9 +568,9 @@ class TestPerStepLossCurve:
 
 class TestEvaluateCacheKey:
     def test_fresh_closures_share_cache_under_key(self):
-        """evaluate(cache_key=...) dedups fresh metric closures (VERDICT r3
-        weak #4): two calls with different function objects but one key
-        compile once and agree."""
+        """evaluate(cache_key=...) dedups fresh metric closures: two calls
+        with different function objects but one key compile once and
+        agree."""
         from tensorflowonspark_tpu.parallel.infeed import ShardedFeed
 
         mesh = build_mesh()
@@ -571,3 +602,44 @@ class TestEvaluateCacheKey:
                          cache_key="mse")
         assert list(tr._eval_cache) == ["mse"]
         assert r1 == r2 and "mse" in r1
+
+
+def test_step_keeps_an_explicit_param_sharding():
+    """The state comes back from every step program laid out as it went in:
+    an explicit ``param_sharding`` survives training (it used to be gone
+    after one step, and the second call compiled a second program)."""
+    from tensorflowonspark_tpu import train as train_mod
+    from tensorflowonspark_tpu.parallel import mesh as mesh_mod, tp
+
+    mesh = build_mesh({"data": 2, "tensor": 2}, devices=jax.devices()[:4])
+
+    def loss(params, batch, mask):
+        h = jnp.tanh(batch["x"] @ params["w1"])
+        err = ((h @ params["w2"] - batch["y"]) ** 2).mean(-1) * mask
+        return err.sum() / jnp.maximum(mask.sum(), 1.0), {}
+
+    params = {"w1": jnp.ones((16, 32)) * 0.1, "w2": jnp.ones((32, 8)) * 0.1}
+    optimizer = optax.adam(1e-2)
+    abstract = jax.eval_shape(
+        lambda p: train_mod.TrainState(jnp.zeros((), jnp.int32), p,
+                                       optimizer.init(p)), params)
+    tr = Trainer(loss, params, optimizer, mesh=mesh, batch_size=8,
+                 param_sharding=tp.tp_param_shardings(abstract, mesh))
+
+    def specs(state):
+        return [str(x.sharding.spec)
+                for x in jax.tree_util.tree_leaves(state)]
+
+    before = specs(tr.state)
+    assert any("tensor" in spec for spec in before)
+    shard = mesh_mod.batch_sharding(mesh)
+    batch = {"x": jax.device_put(np.ones((8, 16), np.float32), shard),
+             "y": jax.device_put(np.ones((8, 8), np.float32), shard)}
+    tr.step(batch)
+    assert specs(tr.state) == before
+    scan = mesh_mod.scan_batch_sharding(mesh)
+    stack = {k: jax.device_put(np.stack([np.asarray(v)] * 2), scan)
+             for k, v in batch.items()}
+    tr.multi_step(stack, jax.device_put(np.ones((2, 8), np.float32), scan))
+    assert specs(tr.state) == before
+    assert tr._train_step._cache_size() == 1  # one program, not two

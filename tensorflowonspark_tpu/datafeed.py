@@ -13,15 +13,23 @@ into a sharded global batch (see :mod:`tensorflowonspark_tpu.parallel.infeed`).
 import logging
 import queue as _queue
 import threading
-import time
 
 import numpy as np
 
-from tensorflowonspark_tpu import fault, marker
+from tensorflowonspark_tpu import fault, marker, telemetry
 
 logger = logging.getLogger(__name__)
 
 _INTERRUPTED = object()  # internal next_batch abort marker (see interrupt())
+_BUFFERED = object()     # _pull() buffered a chunk; nothing to hand over yet
+
+#: The consumer's phases (see :class:`~tensorflowonspark_tpu.telemetry.PhaseClock`):
+#: ``away`` between two ``next_batch*`` calls (the caller's transform, the
+#: host-to-device put, a full prefetch queue), ``wait`` blocked on the empty
+#: queue, ``read`` the ring read with its decode (or an in-queue chunk's
+#: unpacking) and the chunk's ``task_done`` round trip, ``assemble`` slicing
+#: and concatenating columns.
+FEED_PHASES = ("away", "wait", "read", "assemble")
 
 
 def _rows_to_fields(rows):
@@ -163,6 +171,11 @@ class DataFeed(object):
         # tells an input-bound job from a compute-bound one).
         self.items_consumed = 0
         self.stall_secs = 0.0
+        # Where this feed's consumer thread spends its wall time, phase by
+        # phase (always on; ``feed_<phase>_us`` in counters_snapshot).  The
+        # switches sit in next_batch/next_batch_arrays and the helpers they
+        # call, nowhere else: terminate() may run in a signal handler.
+        self._clock = telemetry.PhaseClock(FEED_PHASES)
         # Set by interrupt(): unblocks a next_batch blocked on the queue so
         # another thread can take over queue consumption (the queue/ring is
         # single-consumer; see ShardedFeed.terminate).
@@ -198,6 +211,13 @@ class DataFeed(object):
         Returns a list of items, or a dict of per-tensor lists when
         ``input_mapping`` was provided.
         """
+        self._clock.switch("assemble")
+        try:
+            return self._next_batch(batch_size)
+        finally:
+            self._clock.switch("away")
+
+    def _next_batch(self, batch_size):
         logger.debug("requesting batch of %d items", batch_size)
         queue = self.mgr.get_queue(self.qname_in)
         tensors = ([] if self.input_tensors is None
@@ -209,27 +229,13 @@ class DataFeed(object):
                 self._buffer_idx += 1
                 from_queue = False
             else:
-                item = self._get_interruptible(queue)
+                item = self._pull(queue)
                 if item is _INTERRUPTED:
                     logger.info("next_batch: interrupted with %d items", count)
                     break
-                from_queue = True
-                if isinstance(item, marker.ShmChunk):
-                    # Payload took the native shm-ring fast path; the token
-                    # preserves ordering/join semantics (see marker.ShmChunk).
-                    item = self._ring_read(item)
-                elif isinstance(item, (marker.Chunk, marker.ColChunk)):
-                    self._note_transport("queue")
-                if isinstance(item, (marker.Chunk, marker.ColChunk)):
-                    # Buffer the chunk (item list or columnar); ack deferred
-                    # (see ctor).
-                    self._buffer = (item.items if isinstance(item, marker.Chunk)
-                                    else item)
-                    self._buffer_idx = 0
-                    self._chunk_q = queue
-                    if not self._buflen():
-                        self._ack_chunk()
+                if item is _BUFFERED:
                     continue
+                from_queue = True
             if item is None:
                 # End-of-feed: producers are done for good (reference 129-134).
                 logger.info("next_batch: end of feed")
@@ -258,7 +264,7 @@ class DataFeed(object):
                     # Ack only after the chunk's last item is safely batched:
                     # a crash on a malformed item above must leave the queue
                     # un-joined so the feeder's error-poll fires (see ctor).
-                    self._ack_chunk()
+                    self._ack_read()
         self.items_consumed += count
         self._fault.on_items(count)
         logger.debug("next_batch: returning %d items", count)
@@ -277,17 +283,48 @@ class DataFeed(object):
     def _get_interruptible(self, queue):
         """Blocking get that aborts (returning ``_INTERRUPTED``) once
         :meth:`interrupt` fires.  Short-timeout polling, not ``block=True``:
-        the proxy's blocking get cannot be cancelled from another thread."""
-        t0 = time.monotonic()
+        the proxy's blocking get cannot be cancelled from another thread.
+        Phase ``wait``; leaves the clock on ``read`` (see :meth:`_pull`)."""
+        t0 = self._clock.switch("wait")
         try:
-            while not self._interrupt.is_set():
-                try:
-                    return queue.get(block=True, timeout=self._poll_secs)
-                except _queue.Empty:
-                    continue
-            return _INTERRUPTED
+            with telemetry.annotation("feed/wait"):
+                while not self._interrupt.is_set():
+                    try:
+                        return queue.get(block=True, timeout=self._poll_secs)
+                    except _queue.Empty:
+                        continue
+                return _INTERRUPTED
         finally:
-            self.stall_secs += time.monotonic() - t0
+            self.stall_secs += (self._clock.switch("read") - t0) / 1e9
+
+    def _pull(self, queue):
+        """The next thing off the queue, accounted: blocked on the empty
+        queue (phase ``wait``), then a chunk's payload read into the buffer
+        (phase ``read``; the ack is deferred, see ctor), then back to
+        ``assemble``.  Returns ``_BUFFERED`` for a chunk, ``_INTERRUPTED``,
+        or the loose item / ``None`` / ``EndPartition`` for the caller."""
+        item = self._get_interruptible(queue)
+        try:
+            if item is _INTERRUPTED:
+                return item
+            with telemetry.annotation("feed/read"):
+                if isinstance(item, marker.ShmChunk):
+                    # Payload took the native shm-ring fast path; the token
+                    # preserves ordering/join semantics (see marker.ShmChunk).
+                    item = self._ring_read(item)
+                elif isinstance(item, (marker.Chunk, marker.ColChunk)):
+                    self._note_transport("queue")
+                if not isinstance(item, (marker.Chunk, marker.ColChunk)):
+                    return item
+                self._buffer = (item.items if isinstance(item, marker.Chunk)
+                                else item)
+                self._buffer_idx = 0
+                self._chunk_q = queue
+                if not self._buflen():
+                    self._ack_chunk()
+                return _BUFFERED
+        finally:
+            self._clock.switch("assemble")
 
     def interrupt(self):
         """Unblock a concurrent :meth:`next_batch` and make subsequent calls
@@ -301,6 +338,14 @@ class DataFeed(object):
         if self._chunk_q is not None:
             self._chunk_q.task_done()
             self._chunk_q = None
+
+    def _ack_read(self):
+        """:meth:`_ack_chunk` from inside ``next_batch*``: the ``task_done``
+        round trip to the manager belongs to phase ``read``."""
+        self._clock.switch("read")
+        with telemetry.annotation("feed/read"):
+            self._ack_chunk()
+        self._clock.switch("assemble")
 
     def _note_transport(self, fmt):
         self.wire_formats[fmt] = self.wire_formats.get(fmt, 0) + 1
@@ -371,6 +416,13 @@ class DataFeed(object):
         input_mapping), a sequence matching the field count (tuple rows), or
         a single dtype (single-value rows).
         """
+        self._clock.switch("assemble")
+        try:
+            return self._next_batch_arrays(batch_size, dtypes)
+        finally:
+            self._clock.switch("away")
+
+    def _next_batch_arrays(self, batch_size, dtypes):
         queue = self.mgr.get_queue(self.qname_in)
         parts = []       # per-part tuple of per-field array slices
         tuple_rows = None
@@ -396,23 +448,13 @@ class DataFeed(object):
                 count += take
                 self._buffer_idx += take
                 if self._buffer_idx >= buflen:
-                    self._ack_chunk()
+                    self._ack_read()
                 continue
-            item = self._get_interruptible(queue)
+            item = self._pull(queue)
             if item is _INTERRUPTED:
                 logger.info("next_batch_arrays: interrupted at %d rows", count)
                 break
-            if isinstance(item, marker.ShmChunk):
-                item = self._ring_read(item)
-            elif isinstance(item, (marker.Chunk, marker.ColChunk)):
-                self._note_transport("queue")
-            if isinstance(item, (marker.Chunk, marker.ColChunk)):
-                self._buffer = (item.items if isinstance(item, marker.Chunk)
-                                else item)
-                self._buffer_idx = 0
-                self._chunk_q = queue
-                if not self._buflen():
-                    self._ack_chunk()
+            if item is _BUFFERED:
                 continue
             if item is None:
                 logger.info("next_batch_arrays: end of feed")
@@ -448,15 +490,35 @@ class DataFeed(object):
         """Flat telemetry counters for heartbeat payloads.
 
         Schema: ``feed_items`` (rows delivered), ``feed_stall_secs`` (time
-        blocked on an empty queue), ``wire_<fmt>`` (chunks per transport —
+        blocked on an empty queue), ``feed_<phase>_us`` for each of
+        :data:`FEED_PHASES` (they sum to this feed's age), ``wire_<fmt>``
+        (chunks per transport —
         ``wire_colv1``/``wire_pickle``/``wire_queue``; data-service feeds
         additionally mint ``wire_colv1+<codec>`` keys for compressed
         streams plus the ``dataservice_cache_*`` / ``wire_compress_*``
-        vocabulary, see ``ServiceFeed.counters_snapshot``).
+        vocabulary, see ``ServiceFeed.counters_snapshot``), and what this
+        node's feed tasks published from the executor's process
+        (``feeder_<phase>_us``, ``feeder_items``, ``feeder_bytes``,
+        ``feeder_tasks``, ring writes: ``node._publish_feeder_metrics``).
+        Safe from any thread at any moment of a running feed.
         """
+        snap = self._own_counters()
+        try:
+            # one manager round trip, numbers only; a manager that cannot be
+            # asked costs the feeders' counters, never the snapshot
+            snap.update(telemetry.merge_counters(
+                [self.mgr.get("feeder_metrics")]))
+        except Exception:
+            pass
+        return snap
+
+    def _own_counters(self):
+        """This feed's counters alone, with no manager round trip (the
+        heartbeat provider's view: it merges the feeders' KV itself, once)."""
         snap = {"feed_items": self.items_consumed,
                 "feed_stall_secs": round(self.stall_secs, 6)}
-        for fmt, n in list(self.wire_formats.items()):
+        snap.update(self._clock.snapshot("feed_"))
+        for fmt, n in self.wire_formats.copy().items():
             snap["wire_{}".format(fmt)] = n
         return snap
 

@@ -43,9 +43,12 @@ def backends_initialized():
     """Whether this process has already created a JAX backend.  Imports
     nothing and creates nothing: callers are the places that must NOT be
     the first to touch the device (the executor shell before it forks the
-    user function, the heartbeat thread, :func:`pin_chips`)."""
+    user function, the heartbeat thread, :func:`pin_chips`).  Safe from a
+    thread that polls while another imports jax: a module that is still
+    being imported has created no backend."""
     xla_bridge = sys.modules.get("jax._src.xla_bridge")
-    return xla_bridge is not None and xla_bridge.backends_are_initialized()
+    probe = getattr(xla_bridge, "backends_are_initialized", None)
+    return probe is not None and probe()
 
 
 def device_summary():

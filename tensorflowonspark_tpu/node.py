@@ -21,6 +21,7 @@ Roles (cluster template job names, reference ``TFCluster.py:250-264``):
 capability parity even though TPU training is synchronous), ``'evaluator'``.
 """
 
+import itertools
 import json
 import logging
 import multiprocessing
@@ -73,6 +74,24 @@ def _register_feed(feed):
         if ref() is feed:
             return
     _feeds.append(weakref.ref(feed))
+
+
+#: The feeder's phases (see :class:`~tensorflowonspark_tpu.telemetry.PhaseClock`),
+#: switched in ``train``/``inference``'s task closures, ``_feed_blocks`` and
+#: ``_ChunkPutter``: ``between_tasks`` from the end of one feed task to the
+#: start of the next one's first pass (the driver's scheduling, the
+#: partition's way into this process and its unpickling, the task's own
+#: connect to the manager; current whenever no task runs), ``source`` pulling
+#: rows off the partition's iterator, ``pack_put`` the first pass of a block
+#: (columnar packing, framing, the ring write with its wait for room, the
+#: token on the queue), ``replay`` the same for every cached chunk of an
+#: epoch repeat, ``drain`` waiting for the consumer to empty the queue.
+FEEDER_PHASES = ("between_tasks", "source", "pack_put", "replay", "drain")
+
+
+#: qname -> this process's feeder clock.  It lives as long as the process: an
+#: executor runs many feed tasks, and the time between two of them is a phase.
+_feeder_clocks = {}
 
 
 # Live-knob application tallies, merged into the heartbeat counters so the
@@ -208,7 +227,11 @@ def _node_metrics_provider(mgr, qname="input"):
                 _feeds.remove(ref)
                 continue
             try:
-                parts.append(feed.counters_snapshot())
+                # a DataFeed's own counters: its public snapshot also
+                # fetches the feeders' KV, which is merged once, below,
+                # however many feeds this node has
+                parts.append(getattr(feed, "_own_counters",
+                                     feed.counters_snapshot)())
             except Exception:
                 pass
         try:
@@ -958,12 +981,14 @@ def train(cluster_info, cluster_meta, qname="input", feed_timeout=600,
             try:
                 with tracer.span("feed/partition", executor_id=executor_id,
                                  qname=qname):
-                    count = _feed_blocks(iterator, putter.put, chunk_size)
-                    for _ in range(num_epochs - 1):
-                        if mgr.get("state") in ("terminating", "stopped"):
-                            break
-                        count += putter.reput_cached()
-                    _publish_feeder_metrics(mgr, putter)
+                    with tracer.span("feed/first_pass"):
+                        count = _feed_blocks(iterator, putter, chunk_size)
+                    with tracer.span("feed/replay"):
+                        for _ in range(num_epochs - 1):
+                            if mgr.get("state") in ("terminating", "stopped"):
+                                break
+                            count += putter.reput_cached()
+                    putter.clock.switch("drain")
                     # Wait for the consumer to drain the queue, surfacing
                     # user-code errors and enforcing feed_timeout (reference
                     # TFSparkNode.py:407-418).  The deadline scales with
@@ -972,14 +997,15 @@ def train(cluster_info, cluster_meta, qname="input", feed_timeout=600,
                     # partition tasks each got their own timeout — a fixed
                     # deadline would spuriously kill healthy multi-epoch
                     # runs on the in-queue (no-shm-ring) path.
-                    _join_with_error_check(mgr, queue,
-                                           feed_timeout * max(num_epochs, 1),
-                                           "feeding",
-                                           executor_id=executor_id)
+                    with tracer.span("feed/drain"):
+                        _join_with_error_check(
+                            mgr, queue, feed_timeout * max(num_epochs, 1),
+                            "feeding", executor_id=executor_id)
             finally:
                 # The feeder's trace must survive a failed join too — the
                 # chaos timeline needs the feed span that the kill cut short.
                 tracer.flush()
+                _publish_feeder_metrics(mgr, putter)
             logger.info("fed %d items to %s queue", count, qname)
         # If the consumer began terminating while we fed, ask the driver to
         # stop scheduling feed partitions (reference TFSparkNode.py:422-434).
@@ -995,36 +1021,40 @@ def train(cluster_info, cluster_meta, qname="input", feed_timeout=600,
 
 
 def _publish_feeder_metrics(mgr, putter):
-    """Accumulate this feed task's counters into the node's manager KV
-    (``feeder_metrics``), where the consumer-side heartbeat provider picks
-    them up.  Feed tasks are serialized per executor, so read-modify-write
-    is race-free; any failure (dead manager mid-chaos) is swallowed —
-    metrics never outrank the feed itself."""
-    if not telemetry.get_tracer().enabled:
-        return
+    """End this feed task's account (the clock goes back to
+    ``between_tasks``) and add it to the node's manager KV
+    (``feeder_metrics``), where ``DataFeed.counters_snapshot`` and the
+    consumer-side heartbeat provider pick it up.  Always on.  One
+    publication a task, at its end, of a whole cycle (the gap before this
+    task, its phases, its rows), so that whatever a snapshot holds, times
+    and counts are of the same tasks.  Feed tasks are serialized per
+    executor, so read-modify-write is race-free, and a recycled worker
+    process neither double counts nor resets the total; any failure (dead
+    manager mid-chaos) is swallowed: the counters are dropped, never the
+    chunk or the task."""
+    putter.clock.switch("between_tasks")
     try:
-        prev = mgr.get("feeder_metrics")
         mgr.set("feeder_metrics", telemetry.merge_counters(
-            [prev if isinstance(prev, dict) else {},
-             putter.counters_delta()]))
+            [mgr.get("feeder_metrics"), putter.clock.delta("feeder_"),
+             putter.counters_delta(), {"feeder_tasks": 1}]))
     except Exception as e:
         logger.debug("feeder metrics publish failed: %s", e)
 
 
-def _feed_blocks(iterator, put, chunk_size):
-    """Batch an item iterator into ``chunk_size`` blocks through ``put``;
-    returns the item count (shared by the train and inference feeders)."""
+def _feed_blocks(iterator, putter, chunk_size):
+    """Batch an item iterator into ``chunk_size`` blocks through
+    ``putter.put``; returns the item count (shared by the train and
+    inference feeders).  Phase ``source`` while a block is pulled off the
+    iterator; ``put`` switches to ``pack_put``."""
     count = 0
-    block = []
-    for item in iterator:
-        block.append(item)
-        count += 1
-        if len(block) >= chunk_size:
-            put(block)
-            block = []
-    if block:
-        put(block)
-    return count
+    iterator = iter(iterator)
+    while True:
+        putter.clock.switch("source")
+        block = list(itertools.islice(iterator, chunk_size))
+        if not block:
+            return count
+        count += len(block)
+        putter.put(block)
 
 
 class _ChunkPutter(object):
@@ -1050,6 +1080,8 @@ class _ChunkPutter(object):
         self._queue = queue
         self._feed_timeout = feed_timeout
         self._cache = [] if cache else None
+        self.clock = _feeder_clocks.setdefault(
+            qname, telemetry.PhaseClock(FEEDER_PHASES))
         # Feeder-side telemetry tallies (always on; plain ints — see the
         # shmring.Ring counters for the rationale).  Published per feed task
         # to the node's manager KV so the consumer-side heartbeat can carry
@@ -1091,6 +1123,7 @@ class _ChunkPutter(object):
         return snap
 
     def put(self, block):
+        self.clock.switch("pack_put")
         chunk = marker.pack_columnar(block)
         n = len(block)
         if chunk is None:
@@ -1109,6 +1142,7 @@ class _ChunkPutter(object):
         """Re-send every cached chunk (one epoch); returns the item count."""
         import pickle
 
+        self.clock.switch("replay")
         total = 0
         for chunk, n, data in self._cache or ():
             if chunk is None:
@@ -1288,8 +1322,8 @@ def inference(cluster_info, cluster_meta, qname_in="input", qname_out="output",
         try:
             with tracer.span("feed/partition", executor_id=executor_id,
                              qname=qname_in, mode="inference"):
-                count = _feed_blocks(iterator, putter.put, chunk_size)
-                _publish_feeder_metrics(mgr, putter)
+                count = _feed_blocks(iterator, putter, chunk_size)
+                putter.clock.switch("drain")
                 # Signal end-of-partition so DataFeed can align result batches
                 # (reference TFSparkNode.py:469, marker.py).
                 queue_in.put(marker.EndPartition(), block=True)
@@ -1300,6 +1334,7 @@ def inference(cluster_info, cluster_meta, qname_in="input", qname_out="output",
                                        executor_id=executor_id)
         finally:
             tracer.flush()
+            _publish_feeder_metrics(mgr, putter)
 
         # Collect exactly `count` results: the 1:1 input/output contract
         # (reference TFSparkNode.py:491-500, TFNode.py:160-162).

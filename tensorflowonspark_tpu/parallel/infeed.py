@@ -316,7 +316,7 @@ class ShardedFeed(object):
         """Assemble this host's local batch as final columnar arrays;
         returns (arrays, count) or None when no usable rows remain."""
         start = time.perf_counter()
-        with telemetry.get_tracer().span("infeed/assemble"):
+        with telemetry.span("infeed/assemble"):
             local = self._next_local_inner()
         if local is not None:
             self._tally_assembly(start)
@@ -332,13 +332,15 @@ class ShardedFeed(object):
                 count = len(items)
             if count == 0:
                 return None
-            arrays = self.preprocess(items)
+            with telemetry.span("infeed/transform"):
+                arrays = self.preprocess(items)
         else:
             arrays, count = self.feed.next_batch_arrays(self.local_batch_size)
             if count == 0:
                 return None
             if self.transform is not None:
-                arrays = self.transform(arrays)
+                with telemetry.span("infeed/transform"):
+                    arrays = self.transform(arrays)
         if count < self.local_batch_size and not self.pad_final:
             # partial tail with padding disabled: drop it (documented)
             logger.info("dropping %d-row partial tail (pad_final=False)", count)
@@ -373,7 +375,7 @@ class ShardedFeed(object):
                 self._leaf_sharding(np.ndim(x)), x)
 
         start = time.perf_counter()
-        with telemetry.get_tracer().span("infeed/device_put", rows=count):
+        with telemetry.span("infeed/device_put", rows=count):
             batch = jax.tree_util.tree_map(put, local)
             mask = jax.make_array_from_process_local_data(
                 self._mask_sharding, mask)
@@ -651,10 +653,8 @@ class ShardedFeed(object):
         prefetch thread, overlapping the previous dispatch."""
         group = len(pending)
         start = time.perf_counter()
-        with telemetry.get_tracer().span("infeed/group_assemble",
-                                         group=group):
-            stack, masks = self._group_assembler_fn()(
-                [b for b, _ in pending], [m for _, m in pending])
+        stack, masks = self._group_assembler_fn()(
+            [b for b, _ in pending], [m for _, m in pending])
         us = int((time.perf_counter() - start) * 1e6)
         self._group_assemble_us += us
         if us > self._group_assemble_us_hwm:
@@ -723,7 +723,7 @@ class ShardedFeed(object):
                 pending.append(arrays)
                 if len(pending) >= group_k:
                     start = time.perf_counter()
-                    with telemetry.get_tracer().span("infeed/device_put",
+                    with telemetry.span("infeed/device_put",
                                                      group=group_k):
                         stack = jax.tree_util.tree_map(
                             lambda *cols: put_stack(cols), *pending)
@@ -760,12 +760,21 @@ class ShardedFeed(object):
         self._prefetch_buf = buf
 
         def _put(item):
-            while not stop.is_set():
-                try:
-                    buf.put(item, timeout=0.2)
-                    return True
-                except _queue.Full:
-                    continue
+            if stop.is_set():
+                return False
+            try:
+                buf.put_nowait(item)
+                return True
+            except _queue.Full:
+                pass
+            # the consumer is behind: this thread waits, the device does not
+            with telemetry.span("infeed/queue_full"):
+                while not stop.is_set():
+                    try:
+                        buf.put(item, timeout=0.2)
+                        return True
+                    except _queue.Full:
+                        continue
             return False
 
         def _producer():

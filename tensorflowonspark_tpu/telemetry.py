@@ -21,11 +21,23 @@ Three legs, all dependency-free:
    host-side time between dispatches — and ``infeed_batches`` /
    ``infeed_assembly_us`` / ``infeed_put_us`` (+``_hwm``) on the
    ShardedFeed — host assembly vs host->device transfer time, both off the
-   dispatch path when prefetch is on.
+   dispatch path when prefetch is on.  The feed cycle's wall time is kept
+   the same way by two phase clocks (:class:`PhaseClock`):
+   ``feeder_<phase>_us`` in the executor's feed tasks, ``feed_<phase>_us``
+   in the ``DataFeed``.  Every instant belongs to one phase, so the phases
+   sum to the wall time.
 3. **Hang flight recorder** — :meth:`Tracer.dump` writes all-thread
    stacktraces, the open span stack, counters, and caller-supplied state to
    ``<dir>/flight-<host>-<pid>.json``; triggered by SIGUSR1
    (:func:`install_sigusr1`) or programmatically when bring-up stalls.
+
+The module-level :func:`span` is what instrumented call sites use: the
+tracer's span when telemetry is on and, in a process that has already
+imported jax, a ``jax.profiler.TraceAnnotation`` named ``tfos/<name>`` as
+well, so the program's spans land in any profile of the chip-holding
+process on the device's clock.  It never imports jax itself.
+:func:`annotation` is the profiler's half alone, for regions entered once
+a chunk.
 
 Zero-cost-when-off: the module global defaults to :data:`NULL`, a null
 object whose methods are no-ops (the ``fault._NullInjector`` pattern), so
@@ -75,6 +87,9 @@ DEFAULT_CAPACITY = 16384
 #: analogue of ``dataservice/split_flow``.
 SERVING_REQUEST_FLOW = "serving/request_flow"
 
+#: the program's spans in a ``jax.profiler`` trace (see :func:`span`)
+SPAN_PREFIX = "tfos/"
+
 #: counter keys ending in one of these merge by ``max``; everything else sums
 _MAX_SUFFIXES = ("_hwm", "_max")
 
@@ -108,6 +123,61 @@ def merge_counters(snapshots):
             else:
                 out[key] = out.get(key, 0) + val
     return out
+
+
+class PhaseClock(object):
+    """Always-on account of one thread's wall time over a fixed set of
+    phases: every instant since construction belongs to exactly one phase,
+    so the phases sum to the clock's own wall time by construction.
+
+    The owning thread calls :meth:`switch` at each boundary (one clock read
+    and a few integer operations under an uncontended lock); any thread may
+    call :meth:`snapshot`.  The clock starts on the first of ``phases``.
+    Not for signal handlers (the lock is not reentrant).
+    """
+
+    def __init__(self, phases):
+        self.phases = tuple(phases)
+        self._index = {name: i for i, name in enumerate(self.phases)}
+        self._ns = [0] * len(self.phases)
+        self._told_us = [0] * len(self.phases)   # see delta()
+        self._current = 0
+        self._lock = threading.Lock()
+        self._last = time.monotonic_ns()
+
+    def switch(self, phase):
+        """Book the time since the last switch on the phase that was
+        current and make ``phase`` current; returns the instant
+        (``time.monotonic_ns()``) so the caller can reuse the clock read."""
+        i = self._index[phase]
+        with self._lock:
+            now = time.monotonic_ns()
+            self._ns[self._current] += now - self._last
+            self._last = now
+            self._current = i
+        return now
+
+    def _read_us(self):
+        with self._lock:
+            ns = list(self._ns)
+            ns[self._current] += time.monotonic_ns() - self._last
+        return [v // 1000 for v in ns]
+
+    def snapshot(self, prefix=""):
+        """``{prefix + phase + "_us": int}`` for every phase, the current
+        one including the time since the last switch."""
+        return {"{}{}_us".format(prefix, name): us
+                for name, us in zip(self.phases, self._read_us())}
+
+    def delta(self, prefix=""):
+        """Like :meth:`snapshot`, but only what was booked since the last
+        call of ``delta``: for an owner that adds its time to a total kept
+        elsewhere.  The deltas of a clock add up to its snapshot."""
+        now = self._read_us()
+        out = {"{}{}_us".format(prefix, name): us - told
+               for name, us, told in zip(self.phases, now, self._told_us)}
+        self._told_us = now
+        return out
 
 
 class _NullSpan(object):
@@ -459,6 +529,60 @@ _tracer_lock = threading.Lock()
 def get_tracer():
     """The process-global tracer (:data:`NULL` unless configured)."""
     return _tracer
+
+
+class _SpanPair(object):
+    """A tracer span and a profiler annotation entered as one."""
+
+    __slots__ = ("_outer", "_inner")
+
+    def __init__(self, outer, inner):
+        self._outer = outer
+        self._inner = inner
+
+    def __enter__(self):
+        self._outer.__enter__()
+        self._inner.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._inner.__exit__(*exc)
+        return self._outer.__exit__(*exc)
+
+
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation`` where this process has already
+    imported jax, else None.  Looked up in ``sys.modules`` only: a span
+    never imports jax (the executor shell must stay off it), and a
+    half-finished import on another thread reads as not imported."""
+    return getattr(sys.modules.get("jax.profiler"), "TraceAnnotation", None)
+
+
+def annotation(name):
+    """The profiler's half of :func:`span` alone, for a region that is
+    entered once a chunk: too many for the tracer's bounded buffer (its
+    lifecycle spans would be dropped), nothing to a profile."""
+    cls = _trace_annotation()
+    return _NULL_SPAN if cls is None else cls(SPAN_PREFIX + name)
+
+
+def span(name, **attrs):
+    """Context manager timing a region of the program.
+
+    The process-global tracer's span (``name`` and ``attrs`` as in the
+    Chrome JSON; a no-op while telemetry is off) and, when and only when
+    jax is already imported here, a ``jax.profiler.TraceAnnotation`` named
+    ``"tfos/" + name``: it costs well under a microsecond while no profile
+    runs and lands on the profiler's host plane, beside the device's
+    operations, while one does (``GET /profile``, ``StepProfiler``, any
+    ``jax.profiler`` capture), with no switch."""
+    own = _tracer.span(name, **attrs)
+    note = annotation(name)
+    if note is _NULL_SPAN:
+        return own
+    if own is _NULL_SPAN:
+        return note
+    return _SpanPair(own, note)
 
 
 def configure(enabled, out_dir=None, capacity=DEFAULT_CAPACITY):

@@ -359,7 +359,9 @@ class Trainer(object):
         self._goodput_ckpt_drain_us = 0
         self._goodput_recovery_us = 0
         self._last_drain_us = 0
-        self._step_ms_hist = {}      # bucket bound (ms) -> window steps
+        # bucket bound (ms) -> window steps; every key from construction, so
+        # a snapshot from another thread never meets a growing dict
+        self._step_ms_hist = dict.fromkeys(metrics_mod.STEP_MS_BUCKETS, 0)
         self._step_ms_count = 0      # steps covered by closed windows
         self._step_ms_sum_us = 0     # wall us covered by closed windows
         self._mfu_pct = None         # latest closed window's MFU, percent
@@ -1068,7 +1070,14 @@ class Trainer(object):
         # chain.  Duck-typed and optional — plain feeds have no flows.
         pop_flow = getattr(sharded_feed, "pop_dispatch_flow", None)
         prev_return = None
-        for kind, batch, mask in source:
+        source = iter(source)
+        while True:
+            # an explicit next(), so that the wait for a batch has a span
+            with telemetry.span("train/next_batch"):
+                item = next(source, None)
+            if item is None:
+                break
+            kind, batch, mask = item
             if self._rollback_req is not None:
                 # Remediator poison-step command: stop dispatching NOW —
                 # every further step trains on poisoned params.  Drain the
@@ -1077,8 +1086,6 @@ class Trainer(object):
                 token, self._rollback_req = self._rollback_req, None
                 if hasattr(sharded_feed, "terminate"):
                     sharded_feed.terminate()
-                tracer.instant("train/rollback_halt", step=steps_done,
-                               token=token)
                 raise fault_mod.PoisonRollback(step=steps_done, token=token)
             injector.on_step(steps_done)
             batch = injector.corrupt_batch(batch, steps_done)
@@ -1092,7 +1099,7 @@ class Trainer(object):
                 # iteration's on_steps hook was spent waiting on the feed.
                 self._goodput_infeed_starved_us += max(
                     0, gap_us - self._last_drain_us)
-            with tracer.span("train/dispatch", kind=kind), \
+            with telemetry.span("train/dispatch", kind=kind), \
                     _transfer_guard_ctx(guard_level):
                 if kind == "multi":
                     loss = self.multi_step(batch, mask,
@@ -1115,7 +1122,8 @@ class Trainer(object):
             last_loss = loss
             if on_steps is not None:
                 drain_t0 = time.perf_counter()
-                on_steps(steps_done)
+                with telemetry.span("train/on_steps"):
+                    on_steps(steps_done)
                 self._last_drain_us = int(
                     (time.perf_counter() - drain_t0) * 1e6)
                 self._goodput_ckpt_drain_us += self._last_drain_us

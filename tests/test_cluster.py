@@ -560,6 +560,21 @@ def test_backends_initialized_probe_exists_in_this_jax():
         device_info.pin_chips(0, 1)
 
 
+def test_backends_initialized_while_jax_is_being_imported(monkeypatch):
+    """A thread that polls (``profiler.start_server_when_backend_is_up``)
+    while the main thread imports jax meets ``jax._src.xla_bridge`` in
+    ``sys.modules`` before its body has run: no backend yet, and no
+    ``AttributeError`` that would end the poller."""
+    import sys
+    import types
+
+    from tensorflowonspark_tpu import device_info
+
+    monkeypatch.setitem(sys.modules, "jax._src.xla_bridge",
+                        types.ModuleType("jax._src.xla_bridge"))
+    assert device_info.backends_initialized() is False
+
+
 def _collect_feed_run(map_fun, rows, env, collect, chunk_size=6):
     """Spin one 2-executor SPARK-mode cluster under ``env``, train one epoch
     of ``rows`` through it, and return ``[collect(executor_dir), ...]`` plus

@@ -26,12 +26,49 @@ The backend contract (used by :mod:`~tensorflowonspark_tpu.cluster`):
 - one task slot per executor: a task occupies its executor until it returns,
   which is what lets the framework co-locate feed tasks with the long-running
   node process via the executor-id working-dir handshake (``util.py:66-75``).
+- ``look_ahead`` (``foreach_partition`` / ``foreach_partition_async``): an
+  internal argument that :meth:`cluster.TPUCluster.train` alone passes for
+  its list-of-partitions feed jobs.  It is not a user's to set; a backend
+  that has no use for it (:class:`SparkBackend`) accepts and ignores it.
+
+**LocalBackend's pipe protocol.**  The driver sends an executor
+``(job, task_id, ahead, pickled_fn, partition_items)`` and gets back
+``(job, task_id, ok, result_or_traceback)``; ``None`` shuts the executor
+down.  ``job`` is the driver's number for the ``JobHandle`` the task belongs
+to: two tasks may be outstanding on one pipe, so one reader a connection
+routes each reply to its own task.
+
+**One task of look-ahead for feed jobs.**  An executor runs its tasks
+strictly one at a time, in the order they were dispatched, but a job that
+asked for look-ahead may have one task *waiting* in an executor while
+another of the same job *runs* there: the waiting task's message (for a
+feed job, the next partition: 100 MB in the benchmark's cell) is sent,
+received and unpickled by the executor's receiver thread while the running
+task feeds, instead of after it.  A free executor is always preferred, and
+one that is busy with another job's task may come free: nothing is sent
+ahead until every live executor works for this job.  There is never more
+than one waiting task an executor, and never one behind a task of another
+job.  The cost is one more partition resident in the executor while such a
+job runs (the one in hand, the one waiting).  What happens to a waiting
+task when things go wrong:
+
+- the task before it fails: the waiting task is not run; the executor
+  answers ``task skipped: job cancelled after an earlier task failure``
+  (retryable, so a supervised ``train`` re-feeds both partitions once);
+- the executor dies: running and waiting task are both reported
+  ``executor N died ...`` (retryable);
+- the executor is fenced (:meth:`LocalBackend.exclude`): nothing more is
+  sent ahead to it; a task already waiting runs, as one in flight does;
+- ``stop()``: the waiting task is dropped with the executor.
 """
 
+import collections
+import itertools
 import logging
 import os
 import queue as _queue
 import shutil
+import socket
 import tempfile
 import threading
 import time
@@ -166,14 +203,66 @@ class JobHandle(object):
 # LocalBackend: executor worker process main loop
 # ---------------------------------------------------------------------------
 
+#: The reply to a task that waited in an executor while the task before it,
+#: of the same job, failed there (matched by ``fault.RETRYABLE_PATTERNS``;
+#: the dispatcher answers the job's unsent tasks with the same words).
+TASK_SKIPPED = "task skipped: job cancelled after an earlier task failure"
+
+#: How the task that this executor process is running came in: ``(ahead,
+#: ready)``, see :func:`task_handover`.  Written by ``_executor_main`` alone.
+_handover = (False, False)
+
+
+def task_handover():
+    """``(ahead, ready)`` for the task this executor process is running:
+    ``ahead`` if its message had begun to arrive before the task before it
+    returned (the look-ahead engaged), ``ready`` if it was wholly unpickled
+    by then (the hand-over was hidden completely).  ``(False, False)``
+    outside a :class:`LocalBackend` executor and for an executor's first
+    task."""
+    return _handover
+
+
+def _receive_tasks(conn, inbox, closing):
+    """Executor's receiver thread: read and unpickle the driver's messages
+    while the main thread runs a task; stamp when each began to arrive and
+    when it was whole.  ``inbox`` holds at most one waiting task; at the
+    shutdown (``closing``) a task still waiting there is dropped, not run."""
+    while True:
+        try:
+            conn.poll(None)  # the first bytes of the next message
+            began = time.monotonic()
+            msg = conn.recv()
+        except (EOFError, OSError):
+            msg = None
+        except Exception as e:
+            # a message that does not unpickle ends the executor, as it
+            # always did: in its turn, after the task that is running
+            msg = e
+        if msg is None:  # backend shutdown, or the driver is gone
+            closing.set()
+            try:
+                inbox.put_nowait(None)  # wake a main thread that waits
+            except _queue.Full:
+                pass  # it will see ``closing`` when it takes what waits
+            return
+        inbox.put((msg, began, time.monotonic()))
+        if isinstance(msg, Exception):
+            return
+
+
 def _executor_main(executor_index, workdir, conn, env_overrides):
     """Long-lived executor process: apply env, chdir, serve tasks over a pipe.
 
-    Tasks arrive as ``(task_id, pickled_fn, partition_items)``; results return
-    as ``(task_id, ok, result_or_traceback)``.  Environment overrides are
-    applied *before* any task runs so that e.g. ``JAX_PLATFORMS`` is set before
-    the first ``import jax`` in user code.
+    Tasks arrive as ``(job, task_id, ahead, pickled_fn, partition_items)``;
+    results return as ``(job, task_id, ok, result_or_traceback)``.  A
+    receiver thread reads and unpickles the messages, so a task sent ahead
+    arrives while the one before it runs; this thread runs them one at a
+    time, in arrival order.  Environment overrides are applied *before* any
+    task runs so that e.g. ``JAX_PLATFORMS`` is set before the first
+    ``import jax`` in user code.
     """
+    global _handover
     os.environ.update(env_overrides or {})
     os.makedirs(workdir, exist_ok=True)
     os.chdir(workdir)
@@ -187,23 +276,62 @@ def _executor_main(executor_index, workdir, conn, env_overrides):
     # file doesn't exist until a node's start task writes it — so target
     # executor-loss faults via ``env_per_executor`` instead.
     injector = fault.from_env()
+    inbox = _queue.Queue(maxsize=1)
+    closing = _threading.Event()
+    _threading.Thread(target=_receive_tasks, args=(conn, inbox, closing),
+                      name="executor-{}-recv".format(executor_index),
+                      daemon=True).start()
+    failed_job = None  # the job whose task last failed here
+    returned = None  # when the task before returned
     while True:
-        try:
-            msg = conn.recv()
-        except EOFError:
+        arrival = inbox.get()
+        if arrival is None or closing.is_set():  # backend shutdown
             break
-        if msg is None:  # backend shutdown
-            break
-        task_id, fn_bytes, items = msg
+        msg, began, whole = arrival
+        if isinstance(msg, Exception):
+            raise msg
+        job, task_id, ahead, fn_bytes, items = msg
+        del arrival
+        if ahead and job == failed_job:
+            # it waited here behind a task of its job that failed: not run
+            returned = time.monotonic()
+            conn.send((job, task_id, False, TASK_SKIPPED))
+            continue
+        _handover = ((began < returned, whole < returned)
+                     if returned is not None else (False, False))
         try:
             fn = cloudpickle.loads(fn_bytes)
             result = fn(iter(items))
             if result is not None and not isinstance(result, (list, tuple)):
                 result = list(result)  # drain generators inside the executor
-            conn.send((task_id, True, result))
+            returned = time.monotonic()
+            conn.send((job, task_id, True, result))
         except Exception:
-            conn.send((task_id, False, traceback.format_exc()))
+            returned = time.monotonic()
+            failed_job = job
+            conn.send((job, task_id, False, traceback.format_exc()))
+        # the partition goes now, not when the next one has arrived
+        del msg, fn_bytes, items
+        fn = result = None
         injector.on_task()  # kill_after_tasks: die AFTER serving N tasks
+
+
+class _Task(object):
+    """Driver-side record of one task in flight on a :class:`LocalBackend`
+    executor."""
+
+    def __init__(self, job, task_id, fn_bytes, items, handle):
+        self.job = job  # the driver's number for ``handle``'s job
+        self.task_id = task_id
+        self.fn_bytes = fn_bytes
+        self.items = items
+        self.handle = handle
+        self.ahead = False  # sent to wait behind a running task of its job
+        self.after = None  # the ``sent`` of the task before it on the pipe
+        self.sent = threading.Event()  # its message has left (or never will)
+        # from the connection's reader: True once the reply has reached
+        # ``handle``, False if the pipe ended first
+        self.answered = _queue.SimpleQueue()
 
 
 class LocalBackend(object):
@@ -237,7 +365,16 @@ class LocalBackend(object):
         self._base_env = dict(env or {})
         self._procs = []
         self._conns = []
-        self._free = _queue.Queue()
+        self._send_locks = []  # one a connection: a message goes out whole
+        self._readers = []  # one reply-routing thread a connection
+        # Scheduling state, all under ``_cv``: the executors that are free,
+        # oldest first, and each executor's tasks in flight in dispatch
+        # order (none: free or parked; one: running; two: one running, one
+        # waiting behind it — look-ahead).
+        self._cv = threading.Condition()
+        self._idle = collections.deque()
+        self._inflight = []
+        self._job_ids = itertools.count()
         self._stopped = False
         self._excluded = set()  # executor indices fenced off from scheduling
         self._lock = threading.Lock()  # guards _procs/_conns growth
@@ -247,7 +384,7 @@ class LocalBackend(object):
             if env_per_executor:
                 overrides.update(env_per_executor[i] or {})
             self._spawn_executor(i, overrides)
-            self._free.put(i)
+            self._idle.append(i)
 
     def _spawn_executor(self, i, overrides):
         parent_conn, child_conn = self._ctx.Pipe()
@@ -265,47 +402,164 @@ class LocalBackend(object):
         child_conn.close()
         self._procs.append(proc)
         self._conns.append(parent_conn)
+        self._send_locks.append(threading.Lock())
+        self._inflight.append([])
+        reader = threading.Thread(
+            target=self._route_replies, args=(i,),
+            name="executor-{}-replies".format(i), daemon=True)
+        reader.start()
+        self._readers.append(reader)
 
     # -- scheduling -------------------------------------------------------
 
-    def _run_one(self, executor_index, task_id, fn_bytes, items, handle):
+    def _route_replies(self, executor_index):
+        """One reader a connection: two tasks may be outstanding on it, so
+        each ``(job, task_id, ok, payload)`` goes to the ``JobHandle`` of the
+        task it answers — from this one thread, so that a job hears of a
+        failure before it hears of the task skipped behind it.  Ends when
+        the pipe does (the executor's exit, or :meth:`_hang_up`), telling
+        the tasks still in flight that no reply will come."""
         conn = self._conns[executor_index]
+        while True:
+            try:
+                reply = conn.recv()
+            except (EOFError, OSError):
+                reply = None
+            with self._cv:
+                tasks = list(self._inflight[executor_index])
+            for task in tasks:
+                if reply is None:
+                    task.answered.put(False)
+                elif (task.job, task.task_id) == reply[:2]:
+                    task.handle._task_done(task.task_id, *reply[2:])
+                    task.answered.put(True)
+                    break
+            if reply is None:
+                return
+
+    def _hang_up(self, executor_index):
+        """Shut the driver's end of a dead (or stopped) executor's pipe.  The
+        pipe is a socket pair, and children of the executor may still hold
+        its other end: without this, a sender blocked on a message that
+        nobody will read and the connection's reader would wait forever."""
         try:
-            conn.send((task_id, fn_bytes, items))
-            # recv with a LIVENESS poll, not a bare recv: an executor whose
+            sock = socket.socket(
+                fileno=os.dup(self._conns[executor_index].fileno()))
+        except (OSError, ValueError):
+            return  # already closed
+        try:
+            sock.shutdown(socket.SHUT_RDWR)
+        except OSError:
+            pass
+        finally:
+            sock.close()
+
+    def _place(self, task, executor_index):
+        """Put ``task`` behind whatever ``executor_index`` has in flight
+        (call with ``_cv`` held)."""
+        queue = self._inflight[executor_index]
+        task.after = queue[-1].sent if queue else None
+        queue.append(task)
+
+    def _assign(self, task, look_ahead):
+        """Block until an executor can take ``task``; returns its index, or
+        None when none will (backend stopped; every executor dead or
+        excluded; a look-ahead job that failed meanwhile).  A free executor
+        first.  For a job that asked for look-ahead, else, an executor that
+        is running one task of the job and has nothing waiting
+        (``task.ahead``) — but only once every live executor works for this
+        job: one that is busy with another job's task (a start task about to
+        return, say) may come free, and a free executor is preferred, so the
+        task waits for one as it always did."""
+        with self._cv:
+            while not self._stopped:
+                if look_ahead and task.handle.error is not None:
+                    return None  # cancelled while it waited its turn
+                while self._idle:
+                    i = self._idle.popleft()
+                    if (i not in self._excluded
+                            and self._procs[i].is_alive()):
+                        self._place(task, i)
+                        return i
+                    # else: drop the stale slot
+                live = self._live_executors()
+                if not live:
+                    return None  # no executor can ever serve this task
+                if look_ahead:
+                    queues = [self._inflight[i] for i in live]
+                    if all(q and q[0].job == task.job for q in queues):
+                        for i, queue in zip(live, queues):
+                            if len(queue) == 1:
+                                task.ahead = True
+                                self._place(task, i)
+                                return i
+                # Poll instead of waiting forever: a dead or excluded
+                # executor never comes back, and nothing notifies of it.
+                self._cv.wait(1.0)
+        return None
+
+    def _run_one(self, executor_index, task):
+        conn = self._conns[executor_index]
+        proc = self._procs[executor_index]
+        handle, task_id = task.handle, task.task_id
+        try:
+            try:
+                # messages leave in dispatch order
+                while task.after is not None and not task.after.wait(1.0):
+                    if not proc.is_alive():
+                        raise EOFError("executor process died")
+                with self._send_locks[executor_index]:
+                    conn.send((task.job, task_id, task.ahead, task.fn_bytes,
+                               task.items))
+            finally:
+                task.items = None
+                task.sent.set()
+            # wait with a LIVENESS poll, not a bare get: an executor whose
             # task spawned children (every node runtime forks a manager
             # server) leaves those children holding a dup of the pipe fd,
             # so a SIGKILLed executor never EOFs the pipe — the job would
             # wedge forever instead of failing (observed: vanished-executor
             # shutdown hang).
-            while not conn.poll(1.0):
-                if not self._procs[executor_index].is_alive():
-                    if conn.poll(0.5):
-                        break  # final response raced with process exit
-                    raise EOFError("executor process died")
-            rid, ok, payload = conn.recv()
-            assert rid == task_id
-            handle._task_done(task_id, ok, payload)
+            answered = None
+            while answered is None:
+                try:
+                    answered = task.answered.get(timeout=1.0)
+                except _queue.Empty:
+                    if not proc.is_alive():
+                        try:  # final response raced with process exit
+                            answered = task.answered.get(timeout=0.5)
+                        except _queue.Empty:
+                            answered = False
+            if not answered:  # says the poll, or the reader at the pipe's end
+                raise EOFError("executor process died")
         except (EOFError, OSError):
+            # whoever else still sends to or reads from this executor
+            # (a task waiting behind this one) must not block on it
+            self._hang_up(executor_index)
             if self._stopped:
                 return
             handle._task_done(
                 task_id,
                 False,
                 "executor {} died while running task {} (exitcode={})".format(
-                    executor_index, task_id, self._procs[executor_index].exitcode
+                    executor_index, task_id, proc.exitcode
                 ),
             )
         finally:
-            if (self._procs[executor_index].is_alive()
-                    and executor_index not in self._excluded):
-                self._free.put(executor_index)
+            with self._cv:
+                queue = self._inflight[executor_index]
+                queue.remove(task)
+                if (not queue and proc.is_alive()
+                        and executor_index not in self._excluded):
+                    self._idle.append(executor_index)
+                self._cv.notify_all()
 
     def exclude(self, executor_index):
         """Fence an executor off from future scheduling (liveness monitor:
         its node process died, so tasks landing there would feed a corpse).
-        In-flight tasks finish/fail on their own; the slot is simply never
-        returned to the free pool."""
+        In-flight tasks finish/fail on their own (a task waiting there behind
+        a running one too); the slot is simply never returned to the free
+        pool, and no task is sent ahead to it."""
         if 0 <= executor_index < self.num_executors:
             self._excluded.add(executor_index)
             logger.warning("executor %d excluded from scheduling", executor_index)
@@ -343,10 +597,13 @@ class LocalBackend(object):
         task finishes, the executor joins the free pool for ordinary
         scheduling (``_run_one``'s finally)."""
         handle = JobHandle(1)
-        fn_bytes = cloudpickle.dumps(fn)
+        task = _Task(next(self._job_ids), 0, cloudpickle.dumps(fn),
+                     list(items), handle)
+        with self._cv:
+            self._place(task, executor_index)
         t = threading.Thread(
             target=self._run_one,
-            args=(executor_index, 0, fn_bytes, list(items), handle),
+            args=(executor_index, task),
             name="task-on-{}".format(executor_index),
             daemon=True,
         )
@@ -357,64 +614,54 @@ class LocalBackend(object):
         return [i for i, p in enumerate(self._procs)
                 if p.is_alive() and i not in self._excluded]
 
-    def foreach_partition_async(self, partitions, fn):
-        """Dispatch ``fn(iter(partition))`` per partition onto free executors."""
+    def foreach_partition_async(self, partitions, fn, look_ahead=False):
+        """Dispatch ``fn(iter(partition))`` per partition onto free executors.
+
+        ``look_ahead`` is internal (``cluster.train``'s list-of-partitions
+        feed jobs pass it, nothing else does): with no executor free, the
+        job's next task is sent to wait in an executor that is running one
+        of its tasks, so that the partition travels and is unpickled while
+        the one before it is fed (see the module docstring).  It costs one
+        more partition resident in that executor."""
         handle = JobHandle(len(partitions))
         fn_bytes = cloudpickle.dumps(fn)
+        job = next(self._job_ids)
 
         def _dispatch():
-            threads = []
             for task_id, items in enumerate(partitions):
                 if handle.error is not None:
                     # Job-level cancel: a sibling task already failed, so
                     # don't keep feeding the failed job's remaining tasks to
                     # executors (wait() has raised; stop() may be imminent).
                     # In-flight tasks finish on their own.
-                    handle._task_done(
-                        task_id, False,
-                        "task skipped: job cancelled after an earlier task "
-                        "failure")
+                    handle._task_done(task_id, False, TASK_SKIPPED)
                     continue
-                # Poll the free queue instead of blocking forever: a dead or
-                # excluded executor's slot never returns, so a bare get()
-                # would starve the dispatcher once nodes start dying.
-                executor_index = None
-                while executor_index is None:
-                    try:
-                        executor_index = self._free.get(timeout=1.0)
-                    except _queue.Empty:
-                        if self._stopped:
-                            break
-                        if not self._live_executors():
-                            break  # no executor can ever serve this task
-                        continue
-                    if (executor_index in self._excluded
-                            or not self._procs[executor_index].is_alive()):
-                        executor_index = None  # drop the stale slot token
+                task = _Task(job, task_id, fn_bytes, list(items), handle)
+                executor_index = self._assign(task, look_ahead)
                 if executor_index is None:
-                    handle._task_done(
-                        task_id, False,
-                        "backend stopped" if self._stopped else
-                        "task {} unschedulable: no live executors remain "
-                        "(all died or were excluded)".format(task_id))
+                    if self._stopped:
+                        why = "backend stopped"
+                    elif look_ahead and handle.error is not None:
+                        why = TASK_SKIPPED
+                    else:
+                        why = ("task {} unschedulable: no live executors "
+                               "remain (all died or were excluded)"
+                               .format(task_id))
+                    handle._task_done(task_id, False, why)
                     continue
-                if self._stopped:
-                    handle._task_done(task_id, False, "backend stopped")
-                    continue
-                t = threading.Thread(
+                threading.Thread(
                     target=self._run_one,
-                    args=(executor_index, task_id, fn_bytes, list(items), handle),
+                    args=(executor_index, task),
                     name="task-{}".format(task_id),
                     daemon=True,
-                )
-                t.start()
-                threads.append(t)
+                ).start()
 
         threading.Thread(target=_dispatch, name="job-dispatch", daemon=True).start()
         return handle
 
-    def foreach_partition(self, partitions, fn, timeout=None):
-        self.foreach_partition_async(partitions, fn).wait(timeout)
+    def foreach_partition(self, partitions, fn, timeout=None,
+                          look_ahead=False):
+        self.foreach_partition_async(partitions, fn, look_ahead).wait(timeout)
 
     def map_partitions(self, partitions, fn, timeout=None):
         """Run ``fn`` per partition and return the list of per-partition results."""
@@ -428,11 +675,19 @@ class LocalBackend(object):
             conns = list(self._conns)
             procs = list(self._procs)
         _live_backends.discard(self)
-        for conn in conns:
+        with self._cv:
+            self._cv.notify_all()  # a dispatcher waiting for an executor
+        for conn, lock in zip(conns, self._send_locks):
+            # behind a message that is leaving, never into the middle of it;
+            # a sender stuck on a dead executor is not waited for
+            if not lock.acquire(timeout=2):
+                continue
             try:
                 conn.send(None)
             except OSError:
                 pass
+            finally:
+                lock.release()
         for proc in procs:
             proc.join(timeout=5)
             if proc.is_alive():
@@ -441,6 +696,12 @@ class LocalBackend(object):
                 if proc.is_alive():
                     proc.kill()
                     proc.join(timeout=1)
+        # The executors are gone; their children may still hold the pipes'
+        # other ends, so end the readers (and any sender) from this side.
+        for i in range(len(conns)):
+            self._hang_up(i)
+        for reader in list(self._readers):
+            reader.join(timeout=2)
         if self._owns_root:
             shutil.rmtree(self.workdir_root, ignore_errors=True)
 
@@ -495,7 +756,9 @@ class SparkBackend(object):
         flat = [item for part in partitions for item in part]
         return self.sc.parallelize(flat, len(partitions))
 
-    def foreach_partition_async(self, partitions, fn):
+    def foreach_partition_async(self, partitions, fn, look_ahead=False):
+        # look_ahead: LocalBackend's; here the hand-over of a partition to
+        # the Python worker is Spark's own
         rdd = self._to_rdd(partitions)
         handle = JobHandle(rdd.getNumPartitions())
         # uuid, not id(): a freed handle's address can be reused, and a
@@ -550,7 +813,8 @@ class SparkBackend(object):
                 logger.debug("statusTracker poll failed", exc_info=True)
             time.sleep(1)
 
-    def foreach_partition(self, partitions, fn, timeout=None):
+    def foreach_partition(self, partitions, fn, timeout=None,
+                          look_ahead=False):
         self.foreach_partition_async(partitions, fn).wait(timeout)
 
     def map_partitions(self, partitions, fn, timeout=None):

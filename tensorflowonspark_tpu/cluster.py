@@ -177,13 +177,18 @@ class TPUCluster(object):
         that died without one — e.g. OOM-killed, surfaced as the feeder's
         feed_timeout) is latched into ``tf_status`` so a later
         ``shutdown()`` still exits non-zero (reference ``tf_status``
-        error propagation, ``TFCluster.py:177-181``)."""
+        error propagation, ``TFCluster.py:177-181``).
+
+        A feed job of many partitions asks the backend for one task of
+        look-ahead (``backend.py``): the next partition travels into the
+        executor while this one is fed."""
         try:
             if retry_policy is not None:
                 self._dispatch_with_retry(partitions, fn, retry_policy,
                                           fn_factory)
             else:
-                self.backend.foreach_partition(partitions, fn)
+                self.backend.foreach_partition(partitions, fn,
+                                               look_ahead=True)
         except Exception as e:
             self._latch_error(e)
             raise
@@ -231,7 +236,7 @@ class TPUCluster(object):
             logger.info("backend %s has no per-task outcome visibility; "
                         "dispatching unsupervised",
                         type(self.backend).__name__)
-            self.backend.foreach_partition(partitions, fn)
+            self.backend.foreach_partition(partitions, fn, look_ahead=True)
             return
         tracer = telemetry_mod.get_tracer()
         parts = list(partitions)
@@ -240,7 +245,7 @@ class TPUCluster(object):
             with tracer.span("cluster/dispatch", attempt=attempt + 1,
                              partitions=len(pending)):
                 handle = self.backend.foreach_partition_async(
-                    [parts[i] for i in pending], fn)
+                    [parts[i] for i in pending], fn, look_ahead=True)
                 handle.wait_settled()
                 failed = handle.failed_tasks()
             if not failed:

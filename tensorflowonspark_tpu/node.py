@@ -35,8 +35,8 @@ import time
 import traceback
 import weakref
 
-from tensorflowonspark_tpu import (fault, manager, marker, reservation,
-                                   telemetry, util)
+from tensorflowonspark_tpu import (backend, fault, manager, marker,
+                                   reservation, telemetry, util)
 
 logger = logging.getLogger(__name__)
 
@@ -1033,10 +1033,15 @@ def _publish_feeder_metrics(mgr, putter):
     manager mid-chaos) is swallowed: the counters are dropped, never the
     chunk or the task."""
     putter.clock.switch("between_tasks")
+    # how this task's partition came in (LocalBackend's look-ahead): zeros
+    # under Spark and for jobs that did not ask
+    ahead, ready = backend.task_handover()
     try:
         mgr.set("feeder_metrics", telemetry.merge_counters(
             [mgr.get("feeder_metrics"), putter.clock.delta("feeder_"),
-             putter.counters_delta(), {"feeder_tasks": 1}]))
+             putter.counters_delta(),
+             {"feeder_tasks": 1, "feeder_tasks_ahead": int(ahead),
+              "feeder_tasks_ready": int(ready)}]))
     except Exception as e:
         logger.debug("feeder metrics publish failed: %s", e)
 

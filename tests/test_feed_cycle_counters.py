@@ -98,10 +98,15 @@ def test_feed_cycle_counters_through_a_cluster():
     # the heartbeat still carries nothing
     assert report["heartbeat"] is None
     for key in FEEDER_US + FEED_US + ["feeder_items", "feeder_bytes",
-                                      "feeder_tasks"]:
+                                      "feeder_tasks", "feeder_tasks_ahead",
+                                      "feeder_tasks_ready"]:
         assert isinstance(snap[key], int) and snap[key] >= 0, key
     assert snap["feeder_items"] == rows * epochs * calls
     assert snap["feeder_tasks"] == parts * calls
+    # the second partition of every call was sent ahead of the first's end;
+    # the first of a call never is (the call before it had returned)
+    assert snap["feeder_tasks_ahead"] == (parts - 1) * calls
+    assert snap["feeder_tasks_ready"] <= snap["feeder_tasks_ahead"]
     # every task drains (it polls every 0.1 s) and publishes its whole
     # cycle at its end
     assert snap["feeder_drain_us"] >= parts * calls * 50000
@@ -113,6 +118,48 @@ def test_feed_cycle_counters_through_a_cluster():
     assert abs(snap["feed_wait_us"] / 1e6 - snap["feed_stall_secs"]) < 0.01
     assert report["hammer"]["errors"] == []
     assert report["hammer"]["snapshots"] > 0
+
+
+def _consume_and_snapshot(args, ctx):
+    import json
+
+    feed = ctx.get_data_feed()
+    while not feed.should_stop():
+        feed.next_batch_arrays(args["batch"])
+    with open("snapshot.json", "w") as f:
+        json.dump(feed.counters_snapshot(), f)
+
+
+@pytest.mark.parametrize("streaming, ahead", [(False, 1), (True, 0)])
+def test_look_ahead_counters_of_a_two_partition_train(streaming, ahead):
+    """A list of two partitions: the second is sent ahead (``feeder_tasks``
+    2, ``feeder_tasks_ahead`` 1).  The same two from an iterator are two
+    jobs of one partition: nothing to send ahead.  The four phases still sum
+    to the clock's wall time: no more than this test's own."""
+    data = [(np.full((4,), i, np.float32), i) for i in range(32)]
+    parts = backend.partition(data, 2)
+    b = backend.LocalBackend(1)
+    try:
+        c = cluster.run(b, _consume_and_snapshot, {"batch": 8},
+                        num_executors=1, input_mode=InputMode.SPARK)
+        t0 = time.monotonic()
+        c.train(iter(parts) if streaming else parts, chunk_size=4)
+        wall_us = (time.monotonic() - t0) * 1e6
+        c.shutdown(grace_secs=1)
+        with open(os.path.join(b.workdir_root, "executor-0",
+                               "snapshot.json")) as f:
+            snap = json.load(f)
+    finally:
+        b.stop()
+    assert snap["feed_items"] == snap["feeder_items"] == 32
+    assert snap["feeder_tasks"] == 2
+    assert snap["feeder_tasks_ahead"] == ahead
+    assert 0 <= snap["feeder_tasks_ready"] <= ahead
+    # published at each task's end: the gap before the first task (the
+    # process's life until then) is in it, the time after the last is not
+    assert all(snap[k] >= 0 for k in FEEDER_US)
+    in_tasks = sum(snap[k] for k in FEEDER_US) - snap["feeder_between_tasks_us"]
+    assert 0 < in_tasks <= wall_us, (snap, wall_us)
 
 
 # ---------------------------------------------------------------------------
@@ -192,12 +239,16 @@ def test_feeder_phases_sum_to_the_feeders_wall_time(harness):
     assert abs(sum(total.values()) - wall_us) < 1000, (total, wall_us)
     assert published["feeder_items"] == 3 * 48
     assert published["feeder_tasks"] == 3
+    # no LocalBackend executor runs these tasks: nothing came in ahead
+    assert published["feeder_tasks_ahead"] == 0
+    assert published["feeder_tasks_ready"] == 0
     assert total["feeder_between_tasks_us"] >= 3 * 20000
     assert total["feeder_drain_us"] > 0
     for key in ("feeder_source_us", "feeder_pack_put_us", "feeder_replay_us"):
         assert total[key] > 0, key
     snap = feed.counters_snapshot()
-    for key in FEEDER_US + FEED_US:
+    for key in FEEDER_US + FEED_US + ["feeder_tasks_ahead",
+                                      "feeder_tasks_ready"]:
         assert key in snap, key
     harness.finish(feed)
     assert harness.errors == []

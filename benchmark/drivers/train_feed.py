@@ -366,7 +366,7 @@ def _train(args, ctx, report):
         "counters0": window["counters0"], "counters1": window["counters1"]}
     report["process_compiles"] = watch.mark()
     report["model"] = {"flops_per_example": flops_example,
-                       "batch_size": batch}
+                       "batch_size": batch, "kernels": flops.kernels(cfg)}
     print("perfbench: window {:.3f} s, {} steps of {}, {:.2f} examples/s/chip,"
           " model FLOP utilisation {:.2f}%".format(
               span_s, steps, batch, steps * batch / span_s / len(jax.devices()),
@@ -428,10 +428,15 @@ def run(args, workdir):
     deadline = time.time() + args.deadline_secs
     b = backend.LocalBackend(1)
     try:
+        # stopping a profile of the SPARK-fed process takes 33-37 s, and the
+        # whole process has been silent for longer than the liveness deadline
+        # (5 s x 3) meanwhile: a traced run is not fenced for the profiler's
+        # stall.  An untraced run keeps the program's default
+        liveness = {"heartbeat_misses": 10 ** 6} if args.trace else {}
         c = cluster.run(
             b, main_fun, args, num_executors=1,
             input_mode=(cluster.InputMode.SPARK if spark
-                        else cluster.InputMode.FILES))
+                        else cluster.InputMode.FILES), **liveness)
         if spark:
             while not os.path.exists(args.result_path) and not c.server.done:
                 if time.time() > deadline:

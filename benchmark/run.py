@@ -164,7 +164,9 @@ def main(argv=None):
 
     from benchmark import correctness
 
-    correct, _ = correctness.verdict(report, limits)
+    said = []
+    correct, rows = correctness.verdict(report, limits, out=said.append)
+    print("\n".join(said), flush=True)
 
     device = {"platform": report["device"]["platform"],
               "kind": report["device"]["kind"],
@@ -182,7 +184,16 @@ def main(argv=None):
     else:
         result["metrics"] = metrics_of(manifest, cell, "end_to_end", report)
     result["device"] = device
+    # each number compared beside its limit: last in the line, and again as
+    # the last lines of standard error (what the driver's record keeps of a
+    # run that is not correct)
+    result["compared"] = dict(
+        {name: {"value": value if value is not None and value == value
+                and abs(value) != float("inf") else None, "limit": limit}
+         for name, value, limit, _ in rows},
+        problems={"value": len(report.get("problems", [])), "limit": 0})
     print(json.dumps(result), flush=True)
+    print("\n".join(said), file=sys.stderr, flush=True)
     return 0
 
 

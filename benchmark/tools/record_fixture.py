@@ -21,13 +21,14 @@ from benchmark import trace_reduce  # noqa: E402
 
 def dump_head(planes, path, seconds):
     """Write the head of a trace as a fixture (gzipped JSON of ``load``'s
-    plain data): device events and the benchmark's host spans that start in
-    the first ``seconds`` of the window, names cut short, the window span
-    clipped to that head."""
+    plain data): device events and the host spans (the benchmark's and the
+    program's) that start in the first ``seconds`` of the window, names cut
+    short, the window span clipped to that head, and of each device plane's
+    ``origins`` those of the events kept."""
     import gzip
     import json
 
-    window = [(s, e) for n, s, e in trace_reduce.host_spans(planes)
+    window = [(s, e) for n, s, e, _ in trace_reduce.host_spans(planes)
               if n == trace_reduce.SPAN_PREFIX + "window"]
     lo = window[0][0] if window else min(
         ev[1] for p in planes for ln in p["lines"] for ev in ln["events"])
@@ -35,20 +36,24 @@ def dump_head(planes, path, seconds):
     out = []
     for plane in planes:
         device = plane["name"].startswith("/device:")
-        lines = []
+        where, kept, lines = plane.get("origins") or {}, {}, []
         for line in plane["lines"]:
             events = []
             for name, start, dur in line["events"]:
                 if name == trace_reduce.SPAN_PREFIX + "window":
                     events.append([name, lo, hi - lo])
                 elif lo <= start < hi and (
-                        device or name.startswith(trace_reduce.SPAN_PREFIX)):
-                    events.append([trace_reduce.short_name(name, 64), start,
-                                   min(dur, hi - start)])
+                        device or name.startswith(
+                            trace_reduce.SPAN_PREFIXES)):
+                    short = trace_reduce.short_name(name, 64)
+                    events.append([short, start, min(dur, hi - start)])
+                    if name in where:
+                        kept[short] = where[name]
             if events:
                 lines.append({"name": line["name"], "events": events})
         if lines:
-            out.append({"name": plane["name"], "lines": lines})
+            out.append({"name": plane["name"], "lines": lines,
+                        "origins": kept})
     with gzip.open(path, "wt") as f:
         json.dump(out, f)
 

@@ -3,12 +3,12 @@
 not a number), their ``per_layer`` entries held against the manifest's
 contract, and the tiny rehearsals printing them in the cells that list them.
 
-The entries wait in ``benchmark/layer_metrics/feed_cycle.per_layer.json``: a
-new metric has to be named by ``BENCHMARK.json`` and by the tiny manifest at
-once (``test_tiny_manifest_covers_the_real_one``), and the tiny manifest is
-not a file that the PR which wrote the readers could change.  So every test
-here runs on a manifest with the entries appended in memory, as the PR that
-appends them on disk will leave it."""
+The entries are listed in ``benchmark/layer_metrics/feed_cycle.per_layer.json``
+and, since PR 27, named by ``BENCHMARK.json`` as they stand and by the
+rehearsal manifest in their tiny form (its first fragment,
+``tiny/manifest.d/00_feed_cycle.json``); a metric has to be named by both at
+once (``test_tiny_manifest_covers_the_real_one``), and the tests here hold
+both to the list."""
 
 import copy
 import json
@@ -27,7 +27,6 @@ from _tiny import ROOT
 sys.path.insert(0, os.path.join(ROOT, "benchmark"))
 from run import load_reader  # noqa: E402  (benchmark/run.py: never imports jax)
 
-MANIFEST = os.path.join(_tiny.TINY, "manifest.json")
 ENTRIES = _tiny.load(ROOT, "benchmark", "layer_metrics",
                      "feed_cycle.per_layer.json")
 FEED_METRICS = ["feeder_ship_ms_per_krow", "feeder_pack_put_ms_per_krow",
@@ -72,14 +71,6 @@ READS = {
 }
 
 
-def _appended(manifest, entries):
-    """``manifest`` with those of ``entries`` it does not name yet at the
-    end of its ``per_layer``."""
-    named = {m["name"] for m in manifest["per_layer"]}
-    manifest["per_layer"] += [e for e in entries if e["name"] not in named]
-    return manifest
-
-
 def _tiny_entries():
     return [dict(e, workloads=[t for w in e["workloads"]
                                for t in ENTRIES["tiny_cells"][w]])
@@ -88,7 +79,7 @@ def _tiny_entries():
 
 @pytest.fixture(scope="module")
 def manifest():
-    return _appended(_tiny.load(ROOT, "BENCHMARK.json"), ENTRIES["per_layer"])
+    return _tiny.load(ROOT, "BENCHMARK.json")
 
 
 def _reader(manifest, name):
@@ -187,7 +178,7 @@ def test_entries_keep_the_manifests_contract(which):
     """What ``test_benchmark_manifest.py`` asks of a ``per_layer`` entry,
     asked of these against the manifest they are meant for."""
     if which == "tiny":
-        on_disk, entries = _tiny.load(MANIFEST), _tiny_entries()
+        on_disk, entries = _tiny.manifest(), _tiny_entries()
     else:
         on_disk, entries = _tiny.load(ROOT, "BENCHMARK.json"), \
             ENTRIES["per_layer"]
@@ -211,20 +202,23 @@ def test_both_manifests_name_the_seven_or_neither_does():
     manifest and the rehearsals of the tiny one read the same readers."""
     names = {e["name"] for e in ENTRIES["per_layer"]}
     real = {m["name"] for m in _tiny.load(ROOT, "BENCHMARK.json")["per_layer"]}
-    tiny = {m["name"] for m in _tiny.load(MANIFEST)["per_layer"]}
+    tiny = {m["name"] for m in _tiny.manifest()["per_layer"]}
     assert names & real == names & tiny
     assert names & real in (set(), names)
 
 
 @pytest.fixture(scope="module")
 def own_manifest(tmp_path_factory):
-    """The tiny manifest with the entries appended and its two training
-    cells under names of this file's own.  ``run.py`` keeps a cell's work under
+    """The merged tiny manifest (its first fragment names the seven) with
+    its two training cells under names of this file's own.  ``run.py`` keeps a cell's work under
     ``.perfbench_work/<cell name>`` and ``test_benchmark_run.py`` rehearses
     the same cells: under xdist the two files run at once and would empty
     each other's directory."""
     own = tmp_path_factory.mktemp("feed_cycle")
-    manifest = _appended(_tiny.load(MANIFEST), _tiny_entries())
+    manifest = _tiny.manifest()
+    named = {m["name"]: m for m in manifest["per_layer"]}
+    for entry in _tiny_entries():
+        assert named[entry["name"]] == entry
     names = {"resnet_tiny_spark": "resnet_tiny_spark_fc",
              "gpt2_tiny_files": "gpt2_tiny_files_fc"}
     manifest["workloads"] = [dict(w, name=names[w["name"]])
@@ -289,5 +283,5 @@ def test_tiny_files_rehearsal_prints_h2d_and_none_of_the_feed_ones(
 def test_untraced_line_keeps_its_shape(own_manifest):
     result = _rehearse(own_manifest, "resnet_tiny_spark", 0)
     assert set(result["metrics"]) == {"train_examples_per_s", "setup_s"}
-    assert set(result) == {"correct", "attempted", "failed", "metrics",
-                           "device"}
+    assert list(result) == ["correct", "attempted", "failed", "metrics",
+                            "device", "compared"]

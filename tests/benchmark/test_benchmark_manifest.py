@@ -1,10 +1,13 @@
 """BENCHMARK.json against the contract, and against the files it names."""
 
+import copy
+import json
 import os
 import re
 
 import pytest
 
+import _tiny
 from _tiny import ROOT, load
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
@@ -108,14 +111,95 @@ def test_metrics(manifest):
     assert layered == cells
 
 
+def _families(manifest):
+    """{(reference, adapter)} of a manifest's configurations."""
+    out = set()
+    for entry in manifest["configs"]:
+        cfg = load(ROOT, entry["file"])
+        out.add((cfg["reference"], cfg["adapter"]))
+    return out
+
+
 def test_tiny_manifest_covers_the_real_one(manifest):
-    """The rehearsal manifest runs the same drivers and metric readers, and
-    those of the cells kept for later (serving, the mesh)."""
-    tiny = load(ROOT, "tests", "benchmark", "tiny", "manifest.json")
+    """The rehearsal manifest (the base file with its fragments merged in)
+    runs the same drivers and metric readers, and those of the cells kept
+    for later (serving, the mesh); and every family of ``BENCHMARK.json``
+    has a tiny cell, so that it can be rehearsed on the CPU."""
+    tiny = _tiny.manifest()
     assert {m["name"] for m in tiny["per_layer"]} >= \
         {m["name"] for m in manifest["per_layer"]}
     assert {m["name"] for m in tiny["end_to_end"]} >= \
         {m["name"] for m in manifest["end_to_end"]}
+    used = {w["config"] for w in tiny["workloads"]}
+    assert used == {c["name"] for c in tiny["configs"]}
+    assert _families(tiny) >= _families(manifest)
+
+
+def test_every_family_brings_its_files(manifest):
+    """A configuration's ``reference`` and ``adapter`` are names of files:
+    the plain reference, the adapter and the count, each found by that name
+    alone (no table anywhere lists the families)."""
+    for reference, adapter in _families(manifest) | _families(
+            _tiny.manifest()):
+        for kind, name in (("references", reference), ("adapters", adapter),
+                           ("counts", reference)):
+            assert os.path.exists(os.path.join(
+                ROOT, "benchmark", kind, name + ".py")), (kind, name)
+
+
+BASE = {"configs": [{"name": "c"}], "workloads": [{"name": "w"}],
+        "end_to_end": [{"name": "e", "workloads": ["w"]},
+                       {"name": "setup_s"}],
+        "per_layer": [{"name": "p", "workloads": ["w"]}]}
+
+
+def _base():
+    return copy.deepcopy(BASE)
+
+
+def test_a_fragment_appends_entries_and_cells():
+    merged = _tiny.merge_fragment(_base(), {
+        "what": "a new family's tiny cell",
+        "configs": [{"name": "c2"}], "workloads": [{"name": "w2"}],
+        "per_layer": [{"name": "p2", "workloads": ["w2"]}],
+        "append_workloads": {"e": ["w2"], "p": ["w2", "w"]}})
+    assert [c["name"] for c in merged["configs"]] == ["c", "c2"]
+    assert [w["name"] for w in merged["workloads"]] == ["w", "w2"]
+    assert [m["name"] for m in merged["per_layer"]] == ["p", "p2"]
+    assert merged["end_to_end"][0]["workloads"] == ["w", "w2"]
+    assert merged["per_layer"][0]["workloads"] == ["w", "w2"]   # no doubles
+
+
+@pytest.mark.parametrize("fragment", [
+    {"per_layer": [{"name": "p"}]},                   # a name that is there
+    {"workloads": [{"name": "w2"}, {"name": "w2"}]},  # twice in one fragment
+    {"append_workloads": {"nobody": ["w"]}},          # no such metric
+    {"append_workloads": {"setup_s": ["w"]}},         # a metric of every cell
+    {"paths": ["elsewhere"]},                         # not a fragment's to set
+])
+def test_a_fragment_that_would_edit_is_refused(fragment):
+    with pytest.raises(ValueError):
+        _tiny.merge_fragment(_base(), fragment)
+
+
+def test_fragments_merge_in_name_order(tmp_path):
+    """``manifest.d/*.json`` in name order over the base file; the merged
+    file is what ``run.py --manifest`` is given."""
+    tiny = tmp_path / "tiny"
+    (tiny / "manifest.d").mkdir(parents=True)
+    (tiny / "manifest.json").write_text(json.dumps(_base()))
+    (tiny / "manifest.d" / "20_cell.json").write_text(json.dumps(
+        {"workloads": [{"name": "w2"}], "append_workloads": {"p2": ["w2"]}}))
+    (tiny / "manifest.d" / "10_metric.json").write_text(json.dumps(
+        {"per_layer": [{"name": "p2", "workloads": ["w"]}]}))
+    merged = _tiny.manifest(str(tiny))
+    assert merged["per_layer"][1] == {"name": "p2", "workloads": ["w", "w2"]}
+    assert load(_tiny.manifest_path(tmp_path, str(tiny))) == merged
+    # the tree's own: the base file names none of the feed cycle's seven,
+    # the first fragment brings them
+    base = {m["name"] for m in load(_tiny.TINY, "manifest.json")["per_layer"]}
+    merged = {m["name"] for m in _tiny.manifest()["per_layer"]}
+    assert "feeder_ship_ms_per_krow" in merged - base
 
 
 def test_peaks_have_their_source():

@@ -12,10 +12,13 @@ import pytest
 import _tiny
 from _tiny import ROOT
 
-MANIFEST = os.path.join(_tiny.TINY, "manifest.json")
+@pytest.fixture(scope="module")
+def manifest(tmp_path_factory):
+    """The merged rehearsal manifest, written where ``run.py`` can read it."""
+    return _tiny.manifest_path(tmp_path_factory.mktemp("manifest"))
 
 
-def _run(workload, trace, rehearse=True, seed=5, devices=1):
+def _run(manifest, workload, trace, rehearse=True, seed=5, devices=1):
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.pop("PERFBENCH_REHEARSAL_PLATFORM", None)
     if rehearse:
@@ -26,7 +29,7 @@ def _run(workload, trace, rehearse=True, seed=5, devices=1):
                             % devices)
     return subprocess.run(
         [sys.executable, os.path.join(ROOT, "benchmark", "run.py"),
-         "--manifest", MANIFEST, "--workload", workload, "--seed", str(seed),
+         "--manifest", manifest, "--workload", workload, "--seed", str(seed),
          "--seconds", "2", "--trace", str(trace)],
         cwd=ROOT, env=env, stdout=subprocess.PIPE, stderr=subprocess.PIPE,
         text=True, timeout=600)
@@ -46,8 +49,8 @@ def _run(workload, trace, rehearse=True, seed=5, devices=1):
     # the traffic mix's ``mesh`` layout, on four forced host devices
     ("gpt2_tiny_mesh4", 0, {"train_examples_per_s", "setup_s"}),
 ])
-def test_rehearsal_prints_the_result_line(workload, trace, metrics):
-    done = _run(workload, trace, seed=2147483659 + trace,
+def test_rehearsal_prints_the_result_line(manifest, workload, trace, metrics):
+    done = _run(manifest, workload, trace, seed=2147483659 + trace,
                 devices=4 if workload.endswith("mesh4") else 1)
     assert done.returncode == 0, done.stderr[-3000:]
     result = json.loads(done.stdout.strip().splitlines()[-1])
@@ -68,11 +71,20 @@ def test_rehearsal_prints_the_result_line(workload, trace, metrics):
         # the tiny cells have limits: the whole comparison ran and held
         assert result["correct"] is True, done.stdout[-2000:]
         assert "compared grad_rel_diff" in done.stdout
+        # every number compared beside its limit: last in the line, and the
+        # last lines of standard error
+        assert list(result)[-1] == "compared"
+        held = result["compared"]
+        assert held["problems"] == {"value": 0, "limit": 0}
+        assert 0 <= held["grad_rel_diff"]["value"] <= \
+            held["grad_rel_diff"]["limit"]
+        assert "compared grad_rel_diff" in "\n".join(
+            done.stderr.strip().splitlines()[-8:])
 
 
-def test_without_a_chip_there_is_no_result():
+def test_without_a_chip_there_is_no_result(manifest):
     """The real path: no rehearsal switch, JAX finds only the CPU."""
-    done = _run("gpt2_tiny_files", 0, rehearse=False)
+    done = _run(manifest, "gpt2_tiny_files", 0, rehearse=False)
     assert done.returncode not in (0, None)
     assert "needs platform 'tpu'" in done.stderr
     assert not any(line.startswith("{") for line in done.stdout.splitlines())
@@ -99,8 +111,7 @@ def _drive(config, seed, directory):
 
     cfg = _tiny.config(config)
     traffic = _tiny.load(_tiny.TINY, "traffic", "token_files_tiny.json")
-    cell = {"gpt2_tiny": "gpt2_tiny_files",
-            "resnet_tiny": "resnet_tiny_spark"}[config]
+    cell = _tiny.training_cells()[config]
     limits = _tiny.load(_tiny.TINY, "correctness", cell + ".json")["limits"]
     adapter = importlib.import_module("benchmark.adapters." + cfg["adapter"])
     args = types.SimpleNamespace(

@@ -14,15 +14,169 @@ is the long-context showcase of the TPU-native design: the same module runs
 Everything is static-shaped and bf16-friendly; the attention choice only
 swaps the core contraction, so checkpoints are interchangeable between modes
 (e.g. train with ring on a pod, serve with full on one chip).
+
+**One decoder, a pattern of layers.**  ``TransformerLM`` is built from a
+:class:`DecoderSpec`: the vocabulary, the position table, the final norm and
+one :class:`LayerSpec` a layer (norm kind and epsilon, operator kind,
+feed-forward kind, position kind, and their widths), read from a
+configuration:
+
+- :func:`gpt2_spec`: what the constructor's own fields describe when no spec
+  is given: LayerNorm, learned positions, fused-qkv multi-head attention and
+  a GELU MLP (or the top-1 Switch ``MoEMlp``) in every layer; its parameter
+  tree (``block_i/LayerNorm_0``, ``Attention_0/qkv``, ``Dense_0``,
+  ``Dense_1``, ``pos_embed``, ``embed``) and arithmetic are the GPT-2
+  decoder's as they always were;
+- :func:`lfm2_moe_spec` (registered as ``lfm2_moe``): the LFM2 mixture
+  family's ``config.json``: RMSNorm, no position table, ``layer_types``
+  choosing a gated short convolution (:class:`ShortConv`) or grouped-query
+  attention with per-head q/k RMSNorm and RoPE for each layer,
+  ``num_dense_layers`` leading SwiGLU feed-forwards and then
+  :class:`TopKExperts` (top-k of E by sigmoid scores with a selection bias,
+  nothing dropped, told which experts it holds).
+
+Scopes a device trace can be read by (``jax.named_scope`` under the flax
+module names): ``block_i/short_conv``, ``block_i/attention/flash``,
+``block_i/moe/route`` (router, top-k, sort), ``moe/dispatch`` (gather),
+``moe/experts`` (the grouped products), ``moe/combine`` (scale, gather back).
 """
 
-from typing import Optional
+import dataclasses
+from typing import Optional, Tuple
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 
 from tensorflowonspark_tpu.models import register_model
 from tensorflowonspark_tpu.parallel import ring
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerSpec:
+    """One decoder layer, ``x + op(norm(x))`` then ``x + ff(norm(x))``: its
+    kinds, and the widths they need."""
+
+    op: str = "attention"          # attention | conv (gated short convolution)
+    ff: str = "gelu"               # gelu | switch (top-1, capacity: MoEMlp)
+    #                                | swiglu | experts (top-k: TopKExperts)
+    norm: str = "layernorm"        # layernorm | rmsnorm
+    norm_eps: float = 1e-6
+    positions: str = "learned"     # learned (a table added to the embedding:
+    #                                nothing in the layer) | rope (on q and k)
+    num_heads: int = 8
+    head_dim: int = 64
+    # None: fused qkv projection with biases, as many KV heads as query heads
+    # (GPT-2's); a number: separate q/k/v/o projections without biases,
+    # query head i reading KV head i // (num_heads // num_kv_heads)
+    num_kv_heads: Optional[int] = None
+    qk_norm: bool = False          # per-head RMSNorm on q and on k
+    rope_theta: float = 10000.0
+    flash_block: int = 128         # q and k block of attention="flash"
+    conv_kernel: int = 3
+    ff_size: int = 0               # the dense feed-forward's width
+    num_experts: int = 8           # the router's outputs
+    experts_per_token: int = 1
+    expert_size: int = 0           # one expert's width (ff="experts")
+    # (first, count): the contiguous range of the router's experts whose
+    # weights this layer holds (one chip's share under expert parallelism);
+    # None holds them all
+    held_experts: Optional[Tuple[int, int]] = None
+    norm_topk: bool = True
+    routed_scaling: float = 1.0
+    capacity_factor: float = 1.25  # ff="switch"
+
+
+@dataclasses.dataclass(frozen=True)
+class DecoderSpec:
+    """The whole decoder: embedding, optional position table, the layers,
+    the final norm, a read-out tied to the embedding."""
+
+    vocab_size: int
+    hidden_size: int
+    layers: Tuple[LayerSpec, ...]
+    learned_positions: int = 0     # rows of the position table; 0 = none
+    norm: str = "layernorm"        # the final norm
+    norm_eps: float = 1e-6
+
+
+def gpt2_layer(num_heads, head_dim, mlp="dense", mlp_ratio=4, num_experts=8,
+               capacity_factor=1.25):
+    """One layer of the GPT-2 decoder: LayerNorm, fused-qkv multi-head
+    attention under learned positions, a GELU MLP (``mlp="moe"``: the top-1
+    Switch layer)."""
+    return LayerSpec(
+        op="attention", ff="switch" if mlp == "moe" else "gelu",
+        norm="layernorm", norm_eps=1e-6, positions="learned",
+        num_heads=num_heads, head_dim=head_dim,
+        ff_size=num_heads * head_dim * mlp_ratio, num_experts=num_experts,
+        capacity_factor=capacity_factor)
+
+
+def gpt2_spec(vocab_size, num_layers, num_heads, head_dim, max_seq_len,
+              **layer):
+    """The GPT-2 decoder that ``TransformerLM``'s own fields describe
+    (``layer``: :func:`gpt2_layer`'s options)."""
+    return DecoderSpec(
+        vocab_size=vocab_size, hidden_size=num_heads * head_dim,
+        layers=(gpt2_layer(num_heads, head_dim, **layer),) * num_layers,
+        learned_positions=max_seq_len)
+
+
+def lfm2_moe_spec(config):
+    """:class:`DecoderSpec` of an LFM2 mixture ``config.json`` (a dict with
+    the source's keys: ``layer_types``, ``num_dense_layers``, ``conv_L_cache``,
+    ``num_experts_per_tok``, ...).  ``num_experts`` is the router's width;
+    ``held_experts`` (``[first, count]``, optional) the experts this program
+    holds of each expert layer; ``flash_block`` (optional) the attention
+    kernel's block."""
+    held = config.get("held_experts")
+    common = dict(
+        norm="rmsnorm", norm_eps=config["norm_eps"], positions="rope",
+        num_heads=config["num_attention_heads"],
+        head_dim=config["hidden_size"] // config["num_attention_heads"],
+        num_kv_heads=config["num_key_value_heads"], qk_norm=True,
+        rope_theta=float(config["rope_theta"]),
+        flash_block=config.get("flash_block", 512),
+        conv_kernel=config["conv_L_cache"],
+        ff_size=config["intermediate_size"],
+        num_experts=config["num_experts"],
+        experts_per_token=config["num_experts_per_tok"],
+        expert_size=config["moe_intermediate_size"],
+        held_experts=tuple(held) if held else None,
+        norm_topk=config.get("norm_topk_prob", True),
+        routed_scaling=float(config.get("routed_scaling_factor", 1.0)))
+    kinds = {"conv": "conv", "full_attention": "attention"}
+    layers = tuple(
+        LayerSpec(op=kinds[kind],
+                  ff="swiglu" if i < config["num_dense_layers"] else "experts",
+                  **common)
+        for i, kind in enumerate(config["layer_types"]))
+    if len(layers) != config["num_hidden_layers"]:
+        raise ValueError("{} layer_types for num_hidden_layers {}".format(
+            len(layers), config["num_hidden_layers"]))
+    return DecoderSpec(vocab_size=config["vocab_size"],
+                       hidden_size=config["hidden_size"], layers=layers,
+                       norm="rmsnorm", norm_eps=config["norm_eps"])
+
+
+def _norm(kind, eps, dtype):
+    if kind == "rmsnorm":
+        return nn.RMSNorm(epsilon=eps, dtype=dtype)
+    return nn.LayerNorm(epsilon=eps, dtype=dtype)
+
+
+def rope(x, theta):
+    """Rotary positions 0..S-1 on ``x [B, S, H, D]``, rotate-half pairing
+    (dimension i with i + D/2), angles in float32."""
+    half = x.shape[-1] // 2
+    inv = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    angle = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * inv[None]
+    cos, sin = jnp.cos(angle)[None, :, None], jnp.sin(angle)[None, :, None]
+    x1, x2 = x[..., :half].astype(jnp.float32), x[..., half:].astype(
+        jnp.float32)
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1).astype(x.dtype)
 
 
 class Attention(nn.Module):
@@ -31,27 +185,147 @@ class Attention(nn.Module):
     attention: str = "full"   # full | flash | ring | ulysses
     mesh: Optional[object] = None
     dtype: jnp.dtype = jnp.float32
+    # grouped-query form (see LayerSpec.num_kv_heads); None = GPT-2's
+    num_kv_heads: Optional[int] = None
+    qk_norm: bool = False
+    norm_eps: float = 1e-6
+    rope_theta: Optional[float] = None
+    flash_block: int = 128
 
     @nn.compact
     def __call__(self, x):
         features = self.num_heads * self.head_dim
-        qkv = nn.DenseGeneral((3, self.num_heads, self.head_dim),
-                              dtype=self.dtype, name="qkv")(x)
-        q, k, v = (qkv[:, :, i] for i in range(3))
+        if self.num_kv_heads is None:
+            qkv = nn.DenseGeneral((3, self.num_heads, self.head_dim),
+                                  dtype=self.dtype, name="qkv")(x)
+            q, k, v = (qkv[:, :, i] for i in range(3))
+        else:
+            q, k, v = (
+                nn.DenseGeneral((heads, self.head_dim), use_bias=False,
+                                dtype=self.dtype, name=name)(x)
+                for name, heads in (("q", self.num_heads),
+                                    ("k", self.num_kv_heads),
+                                    ("v", self.num_kv_heads)))
+        if self.qk_norm:
+            q = nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype,
+                           name="q_norm")(q)
+            k = nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype,
+                           name="k_norm")(k)
+        if self.rope_theta is not None:
+            q, k = rope(q, self.rope_theta), rope(k, self.rope_theta)
         if self.attention == "flash":
             from tensorflowonspark_tpu.ops import flash_attention
 
-            out = flash_attention(q, k, v, causal=True, mesh=self.mesh)
-        elif self.attention == "ring":
-            assert self.mesh is not None, "ring attention needs a mesh"
-            out = ring.ring_attention(q, k, v, self.mesh, causal=True)
-        elif self.attention == "ulysses":
-            assert self.mesh is not None, "ulysses attention needs a mesh"
-            out = ring.ulysses_attention(q, k, v, self.mesh, causal=True)
+            with jax.named_scope("flash"):
+                out = flash_attention(q, k, v, causal=True, mesh=self.mesh,
+                                      block_q=self.flash_block,
+                                      block_k=self.flash_block)
         else:
-            out = ring.reference_attention(q, k, v, causal=True)
+            group = self.num_heads // k.shape[2]
+            if group > 1:   # the contractions below want a KV head each
+                k, v = (jnp.repeat(t, group, axis=2) for t in (k, v))
+            if self.attention == "ring":
+                assert self.mesh is not None, "ring attention needs a mesh"
+                out = ring.ring_attention(q, k, v, self.mesh, causal=True)
+            elif self.attention == "ulysses":
+                assert self.mesh is not None, "ulysses attention needs a mesh"
+                out = ring.ulysses_attention(q, k, v, self.mesh, causal=True)
+            else:
+                out = ring.reference_attention(q, k, v, causal=True)
         out = out.reshape(out.shape[0], out.shape[1], features)
-        return nn.Dense(x.shape[-1], dtype=self.dtype, name="proj")(out)
+        return nn.Dense(x.shape[-1], use_bias=self.num_kv_heads is None,
+                        dtype=self.dtype, name="proj")(out)
+
+
+class ShortConv(nn.Module):
+    """Gated short convolution (the LFM2 family's token mixer): ``[B, C, u]
+    = split3(x W_in)``, ``z = B * u``, ``c_t = sum_j w_j * z_{t-j}``
+    (depthwise, causal, ``kernel`` taps a channel, zeros before the
+    sequence), ``y = (C * c) W_out``; no biases."""
+
+    kernel: int = 3
+    dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        d, seq = x.shape[-1], x.shape[1]
+        gate_b, gate_c, u = jnp.split(
+            nn.Dense(3 * d, use_bias=False, dtype=self.dtype,
+                     name="in_proj")(x), 3, axis=-1)
+        taps = self.param("conv", nn.initializers.normal(0.02),
+                          (self.kernel, d)).astype(self.dtype)
+        z = gate_b * u
+        padded = jnp.pad(z, ((0, 0), (self.kernel - 1, 0), (0, 0)))
+        conv = sum(taps[j] * padded[:, self.kernel - 1 - j:
+                                    self.kernel - 1 - j + seq]
+                   for j in range(self.kernel))
+        return nn.Dense(d, use_bias=False, dtype=self.dtype,
+                        name="out_proj")(gate_c * conv)
+
+
+class SwiGLU(nn.Module):
+    """``(silu(x W_1) * (x W_3)) W_2``, no biases."""
+
+    hidden: int
+    dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        dense = lambda n, name: nn.Dense(  # noqa: E731
+            n, use_bias=False, dtype=self.dtype, name=name)
+        return dense(x.shape[-1], "w2")(
+            nn.silu(dense(self.hidden, "w1")(x)) * dense(self.hidden, "w3")(x))
+
+
+class TopKExperts(nn.Module):
+    """Top-k mixture of SwiGLU experts without dropped tokens
+    (:func:`~tensorflowonspark_tpu.parallel.ep.route_topk`,
+    :func:`~tensorflowonspark_tpu.parallel.ep.experts_ffn`): the router
+    scores all ``num_experts`` by a sigmoid, ``expert_bias`` enters the
+    choice of the ``experts_per_token`` only, the chosen scores are
+    renormalised (``norm_topk``) and scaled.
+
+    ``held = (first, count)`` says which of the router's experts this layer
+    holds (``w1``/``w3 [count, D, F]``, ``w2 [count, F, D]``; None: all).  It
+    routes over all of them and returns its own experts' part of the sum;
+    what the absent experts would add is left out, which is one chip's part
+    of an expert-parallel layer before the exchange (there is none here).
+
+    The token-slot counts of the call are sown under
+    ``intermediates/moe_counts`` (``slots_total``, ``slots_local``,
+    ``expert_load_max``, ``expert_load_mean``); ``loss_fn`` adds them up
+    over the expert layers into ``aux["moe_counts"]``."""
+
+    num_experts: int
+    experts_per_token: int
+    hidden: int
+    held: Optional[Tuple[int, int]] = None
+    norm_topk: bool = True
+    routed_scaling: float = 1.0
+    dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        from tensorflowonspark_tpu.parallel import ep as ep_mod
+
+        batch, seq, d_model = x.shape
+        first, count = self.held or (0, self.num_experts)
+        init = nn.initializers.lecun_normal()
+        router = self.param("router", init, (d_model, self.num_experts))
+        bias = self.param("expert_bias", nn.initializers.zeros,
+                          (self.num_experts,))
+        w1 = self.param("w1", init, (count, d_model, self.hidden))
+        w3 = self.param("w3", init, (count, d_model, self.hidden))
+        w2 = self.param("w2", init, (count, self.hidden, d_model))
+        tokens = x.reshape(batch * seq, d_model)
+        with jax.named_scope("route"):
+            sel, weights = ep_mod.route_topk(
+                tokens, router, bias, self.experts_per_token,
+                norm_topk=self.norm_topk, scaling=self.routed_scaling)
+        y, load = ep_mod.experts_ffn(tokens, sel, weights, w1, w3, w2, first,
+                                     dtype=self.dtype)
+        self.sow("intermediates", "moe_counts", load)
+        return y.reshape(batch, seq, d_model)
 
 
 class _RouterParams(nn.Module):
@@ -194,8 +468,8 @@ class MoEMlp(nn.Module):
 
 
 class Block(nn.Module):
-    num_heads: int
-    head_dim: int
+    num_heads: int = 8
+    head_dim: int = 64
     mlp_ratio: int = 4
     attention: str = "full"
     mlp: str = "dense"        # dense | moe
@@ -205,22 +479,49 @@ class Block(nn.Module):
     mesh: Optional[object] = None
     ep_batch_axes: Optional[tuple] = None
     dtype: jnp.dtype = jnp.float32
+    # the layer's description; None: a GPT-2 layer of the fields above
+    spec: Optional[LayerSpec] = None
 
     @nn.compact
     def __call__(self, x):
-        h = nn.LayerNorm(dtype=self.dtype)(x)
-        x = x + Attention(self.num_heads, self.head_dim, self.attention,
-                          self.mesh, self.dtype)(h)
-        h = nn.LayerNorm(dtype=self.dtype)(x)
-        if self.mlp == "moe":
-            h = MoEMlp(num_experts=self.num_experts,
-                       mlp_ratio=self.mlp_ratio,
-                       capacity_factor=self.capacity_factor,
+        spec = self.spec or gpt2_layer(
+            self.num_heads, self.head_dim, mlp=self.mlp,
+            mlp_ratio=self.mlp_ratio, num_experts=self.num_experts,
+            capacity_factor=self.capacity_factor)
+        h = _norm(spec.norm, spec.norm_eps, self.dtype)(x)
+        if spec.op == "conv":
+            h = ShortConv(spec.conv_kernel, self.dtype, name="short_conv")(h)
+        else:
+            # the fused form keeps flax's own name (Attention_0: checkpoints
+            # of the GPT-2 decoder), the grouped-query form is "attention"
+            h = Attention(
+                spec.num_heads, spec.head_dim, self.attention, self.mesh,
+                self.dtype, num_kv_heads=spec.num_kv_heads,
+                qk_norm=spec.qk_norm, norm_eps=spec.norm_eps,
+                rope_theta=(spec.rope_theta if spec.positions == "rope"
+                            else None),
+                flash_block=spec.flash_block,
+                name=None if spec.num_kv_heads is None else "attention")(h)
+        x = x + h
+        h = _norm(spec.norm, spec.norm_eps, self.dtype)(x)
+        if spec.ff == "switch":
+            h = MoEMlp(num_experts=spec.num_experts,
+                       mlp_ratio=spec.ff_size // x.shape[-1],
+                       capacity_factor=spec.capacity_factor,
                        ep_mode=self.ep_mode, mesh=self.mesh,
                        ep_batch_axes=self.ep_batch_axes,
                        dtype=self.dtype, name="moe")(h)
+        elif spec.ff == "experts":
+            h = TopKExperts(
+                num_experts=spec.num_experts,
+                experts_per_token=spec.experts_per_token,
+                hidden=spec.expert_size, held=spec.held_experts,
+                norm_topk=spec.norm_topk, routed_scaling=spec.routed_scaling,
+                dtype=self.dtype, name="moe")(h)
+        elif spec.ff == "swiglu":
+            h = SwiGLU(spec.ff_size, self.dtype, name="mlp")(h)
         else:
-            h = nn.Dense(x.shape[-1] * self.mlp_ratio, dtype=self.dtype)(h)
+            h = nn.Dense(spec.ff_size, dtype=self.dtype)(h)
             h = nn.gelu(h)
             h = nn.Dense(x.shape[-1], dtype=self.dtype)(h)
         return x + h
@@ -241,30 +542,36 @@ class TransformerLM(nn.Module):
     ep_batch_axes: Optional[tuple] = None
     remat: bool = False
     dtype: jnp.dtype = jnp.float32
+    # the decoder's description; None: the GPT-2 decoder that vocab_size,
+    # num_layers, num_heads, head_dim, max_seq_len, mlp, num_experts and
+    # capacity_factor describe (gpt2_spec)
+    spec: Optional[DecoderSpec] = None
 
     @nn.compact
     def __call__(self, tokens):
-        d_model = self.num_heads * self.head_dim
-        x = nn.Embed(self.vocab_size, d_model, dtype=self.dtype,
+        spec = self.spec or gpt2_spec(
+            self.vocab_size, self.num_layers, self.num_heads, self.head_dim,
+            self.max_seq_len, mlp=self.mlp, num_experts=self.num_experts,
+            capacity_factor=self.capacity_factor)
+        x = nn.Embed(spec.vocab_size, spec.hidden_size, dtype=self.dtype,
                      name="embed")(tokens)
-        pos = nn.Embed(self.max_seq_len, d_model, dtype=self.dtype,
-                       name="pos_embed")(jnp.arange(tokens.shape[1]))
-        x = x + pos[None]
+        if spec.learned_positions:
+            pos = nn.Embed(spec.learned_positions, spec.hidden_size,
+                           dtype=self.dtype,
+                           name="pos_embed")(jnp.arange(tokens.shape[1]))
+            x = x + pos[None]
         # remat trades FLOPs for HBM: each block's activations (incl. the
         # full-attention S x S probs the backward pass would otherwise
         # keep per layer) are recomputed during backprop instead of
         # stored — the standard TPU recipe for configs whose stored
         # activations exceed HBM (e.g. d2048 x 16L x b16 full attention).
         block_cls = nn.remat(Block) if self.remat else Block
-        for i in range(self.num_layers):
-            x = block_cls(self.num_heads, self.head_dim,
-                          attention=self.attention, mlp=self.mlp,
-                          num_experts=self.num_experts,
-                          capacity_factor=self.capacity_factor,
-                          ep_mode=self.ep_mode, mesh=self.mesh,
-                          ep_batch_axes=self.ep_batch_axes,
-                          dtype=self.dtype, name="block_%d" % i)(x)
-        x = nn.LayerNorm(dtype=self.dtype)(x)
+        for i, layer in enumerate(spec.layers):
+            x = block_cls(attention=self.attention, ep_mode=self.ep_mode,
+                          mesh=self.mesh, ep_batch_axes=self.ep_batch_axes,
+                          dtype=self.dtype, spec=layer,
+                          name="block_%d" % i)(x)
+        x = _norm(spec.norm, spec.norm_eps, self.dtype)(x)
         # weight-tied readout keeps the big vocab matmul on the MXU once
         embed = self.variables["params"]["embed"]["embedding"]
         return (x @ embed.T.astype(self.dtype)).astype(jnp.float32)
@@ -285,22 +592,53 @@ def build_transformer(vocab_size=32000, num_layers=4, num_heads=8,
                          remat=remat, dtype=jnp.dtype(dtype))
 
 
+@register_model("lfm2_moe")
+def build_lfm2_moe(config, attention="flash", mesh=None, remat=False,
+                   dtype="float32"):
+    """The one decoder under an LFM2 mixture ``config.json`` (see
+    :func:`lfm2_moe_spec`); ``attention`` picks the contraction as for
+    ``transformer_lm`` (grouped KV heads reach ``flash`` as they are and are
+    repeated for the others)."""
+    return TransformerLM(spec=lfm2_moe_spec(config), attention=attention,
+                         mesh=mesh, remat=remat, dtype=jnp.dtype(dtype))
+
+
+def _sown(tree, name):
+    """Every value sown under ``name`` anywhere in the intermediates tree."""
+    found = []
+    if isinstance(tree, dict):
+        for key, val in tree.items():
+            if key == name:
+                found.extend(val if isinstance(val, (tuple, list)) else (val,))
+            else:
+                found.extend(_sown(val, name))
+    return found
+
+
 def _sum_moe_aux(tree):
     """Sum every ``moe_aux_loss`` sown anywhere in the intermediates tree;
     None when the model has no MoE layers."""
-    total, found = 0.0, False
-    if isinstance(tree, dict):
-        for key, val in tree.items():
-            if key == "moe_aux_loss":
-                for v in (val if isinstance(val, (tuple, list)) else (val,)):
-                    total = total + v
-                    found = True
-            else:
-                sub = _sum_moe_aux(val)
-                if sub is not None:
-                    total = total + sub
-                    found = True
-    return total if found else None
+    found = _sown(tree, "moe_aux_loss")
+    return sum(found) if found else None
+
+
+def _sum_moe_counts(tree):
+    """Every ``moe_counts`` sown anywhere in the intermediates tree (one dict
+    an expert layer), added up under the names of ``train.Trainer``'s
+    counters: ``moe_slots_total`` and ``moe_slots_local`` as they are, the
+    heaviest and the mean held expert's count as ``*_sum`` over the layers,
+    and ``moe_layers_steps`` how many layers there were; None without expert
+    layers."""
+    found = _sown(tree, "moe_counts")
+    if not found:
+        return None
+    return {"moe_slots_total": sum(c["slots_total"] for c in found),
+            "moe_slots_local": sum(c["slots_local"] for c in found),
+            "moe_expert_load_max_sum": sum(c["expert_load_max"]
+                                           for c in found),
+            "moe_expert_load_mean_sum": sum(c["expert_load_mean"]
+                                            for c in found),
+            "moe_layers_steps": jnp.asarray(len(found), jnp.int32)}
 
 
 def loss_fn(model, moe_aux_weight=0.01):
@@ -313,7 +651,10 @@ def loss_fn(model, moe_aux_weight=0.01):
 
     MoE models' sown load-balance auxiliaries are folded in with weight
     ``moe_aux_weight`` (Switch Transformer's alpha=0.01 default) and
-    reported via ``aux["moe_aux_loss"]``.
+    reported via ``aux["moe_aux_loss"]``.  ``TopKExperts`` layers have no
+    auxiliary loss; their token-slot counts of the step come out as
+    ``aux["moe_counts"]`` (device scalars; ``train.Trainer`` adds them up
+    into its ``moe_*`` counters without a host sync).
     """
     import optax
 
@@ -327,10 +668,14 @@ def loss_fn(model, moe_aux_weight=0.01):
         ce = (ce * pos_mask[None]).sum(axis=-1) / pos_mask.sum()
         ce = (ce * mask).sum() / jnp.maximum(mask.sum(), 1.0)
         aux = {}
-        lb = _sum_moe_aux(dict(state.get("intermediates", {})))
+        sown = dict(state.get("intermediates", {}))
+        lb = _sum_moe_aux(sown)
         if lb is not None:
             aux["moe_aux_loss"] = lb
             ce = ce + moe_aux_weight * lb
+        counts = _sum_moe_counts(sown)
+        if counts is not None:
+            aux["moe_counts"] = counts
         return ce, aux
 
     return loss

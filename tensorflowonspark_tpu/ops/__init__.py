@@ -2,8 +2,11 @@
 
 The compute path is jax/XLA; these kernels cover the few ops where
 hand-scheduling VMEM traffic beats XLA's fusion — attention first
-(:mod:`~tensorflowonspark_tpu.ops.flash_attention`).  Every kernel runs in
-pallas interpret mode off-TPU, so the suite validates them on the CPU mesh.
+(:mod:`~tensorflowonspark_tpu.ops.flash_attention`), then the grouped matrix
+product of an expert layer (:mod:`~tensorflowonspark_tpu.ops.grouped_matmul`).
+Every kernel runs in pallas interpret mode off-TPU, so the suite validates
+them on the CPU mesh.
 """
 
 from tensorflowonspark_tpu.ops.flash_attention import flash_attention  # noqa: F401
+from tensorflowonspark_tpu.ops.grouped_matmul import grouped_matmul  # noqa: F401

@@ -18,10 +18,29 @@ against the reference attention, values and grads), so
 ``attention="flash"`` is portable; on TPU they compile to Mosaic
 (``tests/test_chip_compile.py`` compiles them for a described v5e).
 
+Grouped-query attention: K and V may carry fewer heads than Q
+(``heads % kv_heads == 0``).  They stay ``[batch*kv_heads, S, D]``; the block
+index maps send query head ``h`` to KV head ``h // group`` in the forward and
+dQ kernels, and the dK/dV kernel's inner grid dimension runs over the
+``group`` query heads of a KV head as well as over the q blocks, so their
+contributions are summed in the scratch accumulators (nothing is repeated in
+HBM).
+
+Precision: matrix products take their operands in the inputs' dtype (bf16
+inputs -> bf16 operands on the MXU, float32 inputs -> float32 as before) and
+accumulate in float32; scores, softmax statistics and the accumulators are
+float32 always.
+
+Causal: tiles wholly above the diagonal are neither computed nor fetched (the
+index maps clamp to the last tile a row block needs, and a block whose index
+does not change is not copied again); the mask itself is applied on the tiles
+the diagonal crosses only.
+
 Layout contract: ``[batch, seq, heads, dim]`` like
 :mod:`~tensorflowonspark_tpu.parallel.ring`; blocks default to 128 (MXU
-tile) and the sequence length must divide by the block size (pad upstream
-— model code here keeps S a power of two).
+tile) and the sequence length must divide by the block size: a
+``ValueError`` names both where it does not (pad upstream — model code here
+keeps S a power of two).
 """
 
 import functools
@@ -39,6 +58,54 @@ def _default_interpret():
     from tensorflowonspark_tpu.device_info import is_tpu_device
 
     return not is_tpu_device()
+
+
+def _dot(a, b, contract):
+    """``a`` x ``b`` over ``contract`` with float32 accumulation; operands as
+    they come (the caller casts them to the inputs' dtype)."""
+    return jax.lax.dot_general(a, b, (contract, ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _scores(q_ref, k_ref, scale, masked, q_block_id, k_block_id, block_q,
+            block_k):
+    """float32 ``q k^T * scale`` of one (q block, k block) tile, the causal
+    mask applied where ``masked`` (a tile the diagonal crosses)."""
+    s = _dot(q_ref[0], k_ref[0], ((1,), (1,))) * scale       # [BQ, BK]
+    if masked:
+        rows = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+                + q_block_id * block_q)
+        cols = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+                + k_block_id * block_k)
+        s = jnp.where(rows >= cols, s, NEG_INF)
+    return s
+
+
+def _when_needed(causal, qi, kk, block_q, block_k, compute):
+    """Run ``compute(masked)`` for the tile (qi, kk): always when not
+    causal; when causal only for tiles that reach the diagonal or lie below
+    it, masked only where the diagonal crosses the tile.  A tile wholly
+    above the diagonal contributes p=0 / alpha=1 (exactly nothing)."""
+    from jax.experimental import pallas as pl
+
+    if not causal:
+        compute(False)
+        return
+    needed = qi * block_q + block_q - 1 >= kk * block_k
+    crossed = kk * block_k + block_k - 1 > qi * block_q
+    pl.when(jnp.logical_and(needed, crossed))(lambda: compute(True))
+    pl.when(jnp.logical_and(needed, jnp.logical_not(crossed)))(
+        lambda: compute(False))
+
+
+def _last_k_block(i, block_q, block_k):
+    """The last k block a causal q block ``i`` needs."""
+    return (i * block_q + block_q - 1) // block_k
+
+
+def _first_q_block(kk, block_q, block_k):
+    """The first q block a causal k block ``kk`` is seen by."""
+    return (kk * block_k) // block_q
 
 
 # ---------------------------------------------------------------------------
@@ -60,36 +127,19 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    def _compute():
-        q = q_ref[0].astype(jnp.float32) * scale      # [BQ, D]
-        k = k_ref[0].astype(jnp.float32)              # [BK, D]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        if causal:
-            rows = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-                    + qi * block_q)
-            cols = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-                    + kk * block_k)
-            s = jnp.where(rows >= cols, s, NEG_INF)
-
+    def _compute(masked):
+        s = _scores(q_ref, k_ref, scale, masked, qi, kk, block_q, block_k)
         m_prev = m_scr[:]                              # [BQ, 1]
         m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)                         # [BQ, BK]
         alpha = jnp.exp(m_prev - m_new)                # [BQ, 1]
         l_scr[:] = l_scr[:] * alpha + p.sum(axis=-1, keepdims=True)
-        v = v_ref[0].astype(jnp.float32)               # [BK, D]
-        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        v = v_ref[0]                                   # [BK, D]
+        acc_scr[:] = acc_scr[:] * alpha + _dot(
+            p.astype(v.dtype), v, ((1,), (0,)))
         m_scr[:] = m_new
 
-    if causal:
-        # Skip tiles entirely above the diagonal: a fully-masked tile
-        # contributes p=0 / alpha=1 (exactly no-op), so predicating it off
-        # halves the causal kernel's MXU work.
-        pl.when(qi * block_q + block_q - 1 >= kk * block_k)(_compute)
-    else:
-        _compute()
+    _when_needed(causal, qi, kk, block_q, block_k, _compute)
 
     @pl.when(kk == n_k - 1)
     def _emit():
@@ -98,11 +148,23 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
         lse_ref[0] = m_scr[:] + jnp.log(l)
 
 
-def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret):
-    """Returns ``(out [bh, seq, d], logsumexp [bh, seq, 1])``.  The softmax
-    statistics keep a trailing unit dim: the TPU lowering wants a block's
-    last two dims divisible by (8, 128) or equal to the array's, and a
-    ``(1, block_q)`` row block of a ``[bh, seq]`` array is neither."""
+def _kv_maps(causal, block_q, block_k, group):
+    """Block index maps of K and V on a (q head, q block, k block) grid:
+    query head ``b`` reads KV head ``b // group``; a causal q block never
+    moves past the last k block it needs."""
+    def kv(b, i, kk):
+        if causal:
+            kk = jnp.minimum(kk, _last_k_block(i, block_q, block_k))
+        return (b // group, kk, 0)
+    return kv
+
+
+def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret, group=1):
+    """Returns ``(out [bh, seq, d], logsumexp [bh, seq, 1])``; ``k`` and
+    ``v`` are ``[bh // group, seq, d]``.  The softmax statistics keep a
+    trailing unit dim: the TPU lowering wants a block's last two dims
+    divisible by (8, 128) or equal to the array's, and a ``(1, block_q)``
+    row block of a ``[bh, seq]`` array is neither."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -112,13 +174,14 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret):
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, block_q=block_q,
         block_k=block_k, n_k=n_k)
+    kv = _kv_maps(causal, block_q, block_k, group)
     out, lse = pl.pallas_call(
         kernel,
         grid=(bh, n_q, n_k),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, kk: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, kk: (b, kk, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, kk: (b, kk, 0)),
+            pl.BlockSpec((1, block_k, d), kv),
+            pl.BlockSpec((1, block_k, d), kv),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, kk: (b, i, 0)),
@@ -142,22 +205,6 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret):
 # backward
 # ---------------------------------------------------------------------------
 
-def _recompute_p(q_ref, k_ref, lse_ref, scale, causal, q_block_id, k_block_id,
-                 block_q, block_k):
-    """exp(q k^T * scale - L) for one (q block, k block) tile."""
-    q = q_ref[0].astype(jnp.float32) * scale
-    k = k_ref[0].astype(jnp.float32)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    if causal:
-        rows = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-                + q_block_id * block_q)
-        cols = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-                + k_block_id * block_k)
-        s = jnp.where(rows >= cols, s, NEG_INF)
-    return jnp.exp(s - lse_ref[0])                     # [BQ, BK]
-
-
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                    dq_scr, *, scale, causal, block_q, block_k, n_k):
     from jax.experimental import pallas as pl
@@ -169,23 +216,16 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    def _compute():
-        p = _recompute_p(q_ref, k_ref, lse_ref, scale, causal,
-                         qi, kk, block_q, block_k)
-        do = do_ref[0].astype(jnp.float32)             # [BQ, D]
-        v = v_ref[0].astype(jnp.float32)               # [BK, D]
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0])                   # [BQ, BK]
-        k = k_ref[0].astype(jnp.float32)
-        dq_scr[:] += scale * jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    def _compute(masked):
+        # p = exp(q k^T * scale - L), recomputed from the saved logsumexp
+        p = jnp.exp(_scores(q_ref, k_ref, scale, masked, qi, kk, block_q,
+                            block_k) - lse_ref[0])
+        dp = _dot(do_ref[0], v_ref[0], ((1,), (1,)))   # [BQ, BK]
+        ds = p * (dp - delta_ref[0])
+        k = k_ref[0]
+        dq_scr[:] += scale * _dot(ds.astype(k.dtype), k, ((1,), (0,)))
 
-    if causal:
-        pl.when(qi * block_q + block_q - 1 >= kk * block_k)(_compute)
-    else:
-        _compute()
+    _when_needed(causal, qi, kk, block_q, block_k, _compute)
 
     @pl.when(kk == n_k - 1)
     def _emit():
@@ -194,39 +234,33 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, dk_scr, dv_scr,
-                    *, scale, causal, block_q, block_k, n_q):
+                    *, scale, causal, block_q, block_k, n_q, group):
     from jax.experimental import pallas as pl
 
-    qi = pl.program_id(2)
+    # the inner grid dimension runs over the KV head's ``group`` query heads
+    # and, for each, over the q blocks: one accumulation for all of them
+    j = pl.program_id(2)
+    qi = j % n_q
     kk = pl.program_id(1)  # read outside pl.when bodies (interpret mode)
 
-    @pl.when(qi == 0)
+    @pl.when(j == 0)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    def _compute():
-        p = _recompute_p(q_ref, k_ref, lse_ref, scale, causal,
-                         qi, kk, block_q, block_k)
-        do = do_ref[0].astype(jnp.float32)             # [BQ, D]
-        dv_scr[:] += jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        v = v_ref[0].astype(jnp.float32)               # [BK, D]
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0])                   # [BQ, BK]
-        q = q_ref[0].astype(jnp.float32)
-        dk_scr[:] += scale * jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    def _compute(masked):
+        p = jnp.exp(_scores(q_ref, k_ref, scale, masked, qi, kk, block_q,
+                            block_k) - lse_ref[0])
+        do = do_ref[0]                                 # [BQ, D]
+        dv_scr[:] += _dot(p.astype(do.dtype), do, ((0,), (0,)))
+        dp = _dot(do, v_ref[0], ((1,), (1,)))          # [BQ, BK]
+        ds = p * (dp - delta_ref[0])
+        q = q_ref[0]
+        dk_scr[:] += scale * _dot(ds.astype(q.dtype), q, ((0,), (0,)))
 
-    if causal:
-        pl.when(qi * block_q + block_q - 1 >= kk * block_k)(_compute)
-    else:
-        _compute()
+    _when_needed(causal, qi, kk, block_q, block_k, _compute)
 
-    @pl.when(qi == n_q - 1)
+    @pl.when(j == group * n_q - 1)
     def _emit():
         dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
@@ -240,21 +274,22 @@ def _bwd_delta(out, g):
 
 
 def _flash_bwd_dq(q, k, v, g, lse, delta, scale, causal, block_q, block_k,
-                  interpret):
+                  interpret, group=1):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     bh, s_len, d = q.shape
     n_q = s_len // block_q
     n_k = s_len // block_k
+    kv = _kv_maps(causal, block_q, block_k, group)
     return pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k, n_k=n_k),
         grid=(bh, n_q, n_k),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, kk: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, kk: (b, kk, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, kk: (b, kk, 0)),
+            pl.BlockSpec((1, block_k, d), kv),
+            pl.BlockSpec((1, block_k, d), kv),
             pl.BlockSpec((1, block_q, d), lambda b, i, kk: (b, i, 0)),
             pl.BlockSpec((1, block_q, 1), lambda b, i, kk: (b, i, 0)),
             pl.BlockSpec((1, block_q, 1), lambda b, i, kk: (b, i, 0)),
@@ -267,28 +302,39 @@ def _flash_bwd_dq(q, k, v, g, lse, delta, scale, causal, block_q, block_k,
 
 
 def _flash_bwd_dkv(q, k, v, g, lse, delta, scale, causal, block_q, block_k,
-                   interpret):
+                   interpret, group=1):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    bh, s_len, d = q.shape
+    bh_kv, s_len, d = k.shape
     n_q = s_len // block_q
     n_k = s_len // block_k
+
+    def rows(b, kk, j):
+        """Block index of a per-query-head array: the ``j // n_q``-th query
+        head of KV head ``b``, q block ``j % n_q`` (causal: never before the
+        first q block that sees k block ``kk``)."""
+        i = j % n_q
+        if causal:
+            i = jnp.maximum(i, _first_q_block(kk, block_q, block_k))
+        return (b * group + j // n_q, i, 0)
+
     return pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k, n_q=n_q),
-        grid=(bh, n_k, n_q),
+                          block_q=block_q, block_k=block_k, n_q=n_q,
+                          group=group),
+        grid=(bh_kv, n_k, group * n_q),
         in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, kk, i: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, kk, i: (b, kk, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, kk, i: (b, kk, 0)),
-            pl.BlockSpec((1, block_q, d), lambda b, kk, i: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, kk, i: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, kk, i: (b, i, 0)),
+            pl.BlockSpec((1, block_q, d), rows),
+            pl.BlockSpec((1, block_k, d), lambda b, kk, j: (b, kk, 0)),
+            pl.BlockSpec((1, block_k, d), lambda b, kk, j: (b, kk, 0)),
+            pl.BlockSpec((1, block_q, d), rows),
+            pl.BlockSpec((1, block_q, 1), rows),
+            pl.BlockSpec((1, block_q, 1), rows),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda b, kk, i: (b, kk, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, kk, i: (b, kk, 0)),
+            pl.BlockSpec((1, block_k, d), lambda b, kk, j: (b, kk, 0)),
+            pl.BlockSpec((1, block_k, d), lambda b, kk, j: (b, kk, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct(k.shape, k.dtype),
@@ -302,13 +348,13 @@ def _flash_bwd_dkv(q, k, v, g, lse, delta, scale, causal, block_q, block_k,
     )(q, k, v, g, lse, delta)
 
 
-def _flash_bwd(res, g, scale, causal, block_q, block_k, interpret):
+def _flash_bwd(res, g, scale, causal, block_q, block_k, interpret, group):
     q, k, v, out, lse = res
     delta = _bwd_delta(out, g)
     dq = _flash_bwd_dq(q, k, v, g, lse, delta, scale, causal, block_q,
-                       block_k, interpret)
+                       block_k, interpret, group)
     dk, dv = _flash_bwd_dkv(q, k, v, g, lse, delta, scale, causal, block_q,
-                            block_k, interpret)
+                            block_k, interpret, group)
     return dq, dk, dv
 
 
@@ -316,19 +362,23 @@ def _flash_bwd(res, g, scale, causal, block_q, block_k, interpret):
 # public op
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash(q, k, v, causal, block_q, block_k, interpret, scale):
-    out, _ = _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash(q, k, v, causal, block_q, block_k, interpret, scale, group):
+    out, _ = _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret,
+                        group)
     return out
 
 
-def _flash_vjp_fwd(q, k, v, causal, block_q, block_k, interpret, scale):
-    out, lse = _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret)
+def _flash_vjp_fwd(q, k, v, causal, block_q, block_k, interpret, scale,
+                   group):
+    out, lse = _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret,
+                          group)
     return out, (q, k, v, out, lse)
 
 
-def _flash_vjp_bwd(causal, block_q, block_k, interpret, scale, res, g):
-    return _flash_bwd(res, g, scale, causal, block_q, block_k, interpret)
+def _flash_vjp_bwd(causal, block_q, block_k, interpret, scale, group, res, g):
+    return _flash_bwd(res, g, scale, causal, block_q, block_k, interpret,
+                      group)
 
 
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
@@ -336,22 +386,31 @@ _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 def flash_attention(q, k, v, causal=True, block_q=128, block_k=128,
                     interpret=None, scale=None, mesh=None):
-    """Memory-linear attention over ``[batch, seq, heads, dim]`` inputs.
+    """Memory-linear attention over ``[batch, seq, heads, dim]`` inputs;
+    ``k`` and ``v`` may carry fewer heads than ``q`` (grouped-query
+    attention: query head ``h`` reads KV head ``h // (heads // kv_heads)``).
 
     Differentiable (custom FlashAttention-2 backward kernels); softmax
     statistics live in fp32 regardless of input dtype.  ``block_q/k``
     default to the 128 MXU tile and are clamped to the sequence length;
-    ``seq`` must divide by the clamped blocks.  ``interpret`` defaults to
-    True off-TPU so the same kernel runs (slowly) everywhere.
+    ``seq`` must divide by the clamped blocks (``ValueError`` otherwise).
+    ``interpret`` defaults to True off-TPU so the same kernel runs (slowly)
+    everywhere.
 
     ``mesh``: the compiler cannot partition a Mosaic kernel, so under a
     multi-device mesh the call is mapped per shard here — batch over the
     mesh's ``data``/``fsdp`` axes, heads over ``tensor`` (attention is
-    independent per batch row and head, so no collective is needed).
+    independent per batch row and head, so no collective is needed; with
+    grouped KV heads both head counts must divide by the ``tensor`` axis).
     """
     if interpret is None:
         interpret = _default_interpret()
     batch, s_len, heads, dim = q.shape
+    kv_heads = k.shape[2]
+    if heads % kv_heads or v.shape[2] != kv_heads:
+        raise ValueError(
+            "{} query heads do not divide into {} / {} key / value heads"
+            .format(heads, kv_heads, v.shape[2]))
     if scale is None:
         scale = 1.0 / (dim ** 0.5)
     if mesh is not None and mesh.size > 1:
@@ -368,13 +427,15 @@ def flash_attention(q, k, v, causal=True, block_q=128, block_k=128,
                              out_specs=spec, check_vma=False)(q, k, v)
     block_q = min(block_q, s_len)
     block_k = min(block_k, s_len)
-    assert s_len % block_q == 0 and s_len % block_k == 0, (
-        "seq len {} must divide by blocks ({}, {})".format(
-            s_len, block_q, block_k))
+    if s_len % block_q or s_len % block_k:
+        raise ValueError(
+            "sequence length {} does not divide by the block sizes (q {}, "
+            "k {}): pad the sequence upstream or pass blocks that divide it"
+            .format(s_len, block_q, block_k))
 
     def fold(x):
-        return x.transpose(0, 2, 1, 3).reshape(batch * heads, s_len, dim)
+        return x.transpose(0, 2, 1, 3).reshape(-1, s_len, dim)
 
     out = _flash(fold(q), fold(k), fold(v), causal, block_q, block_k,
-                 interpret, scale)
+                 interpret, scale, heads // kv_heads)
     return out.reshape(batch, heads, s_len, dim).transpose(0, 2, 1, 3)

@@ -1,14 +1,37 @@
-"""Expert parallelism: MoE FFN over the ``expert`` mesh axis.
+"""Expert parallelism: MoE FFN over the ``expert`` mesh axis, and the two
+routers of :mod:`~tensorflowonspark_tpu.models.transformer`.
 
-The last of the classic parallelism modes to get an explicit implementation
-(SURVEY §2.4; the reference ships none of them — like :mod:`.tp`/:mod:`.pp`
-this is capability beyond parity).  Two complementary paths, numerically
-identical:
+**Two routers.**
+
+- :func:`_route`: grouped **top-1** Switch routing by softmax with a
+  **capacity** (``capacity_factor * S / E`` slots an expert and batch row);
+  tokens over capacity are dropped and ride the residual; dispatch and
+  combine are dense one-hot tensors ``[G, S, E, C]`` (einsums on the MXU, no
+  gathers).  ``MoEMlp`` and :func:`moe_ffn` use it.
+- :func:`route_topk` with :func:`sort_pairs` and :func:`experts_ffn`:
+  **top-k of E by sigmoid scores with a selection bias**, weights
+  renormalised over the k chosen, **no capacity and no token dropped at any
+  imbalance**.  Dispatch is a sort of the (token, slot) pairs by expert, the
+  experts' SwiGLU products are grouped matrix products
+  (:mod:`~tensorflowonspark_tpu.ops.grouped_matmul`) over the stacked weights
+  of the experts **held here** (a contiguous range of the E the router
+  knows), and the pairs whose expert lives elsewhere contribute nothing: the layer returns its own
+  experts' part of the sum, which is what one chip of an expert-parallel
+  deployment computes before the exchange.  Buffers have the static
+  worst-case size (every pair routed here); the grouped products' work
+  follows the pairs that are.  ``TopKExperts`` uses it.  Nothing here stands
+  in for the absent chips: on one chip there is no exchange.
+
+**Two ways over the mesh** for the capacity router's layer (the last of the
+classic parallelism modes to get an explicit implementation, SURVEY §2.4;
+the reference ships none of them — like :mod:`.tp`/:mod:`.pp` this is
+capability beyond parity), numerically identical:
 
 1. **GSPMD** (:func:`ep_param_shardings`): shard the expert-stacked
    ``[E, ...]`` weights of :class:`~tensorflowonspark_tpu.models.transformer.MoEMlp`
-   over ``expert`` and let XLA partition the dense dispatch/combine einsums —
-   the all-to-alls fall out of the partitioner.  Zero model changes.
+   (and ``TopKExperts``' ``w1``/``w3``/``w2``) over ``expert`` and let XLA
+   partition the dense dispatch/combine einsums — the all-to-alls fall out
+   of the partitioner.  Zero model changes.
 
 2. **shard_map** (:func:`moe_ffn`): the DeepSpeed-MoE/GShard schedule written
    explicitly — tokens (groups) sharded over ``expert``, expert weights
@@ -34,9 +57,10 @@ import re
 
 logger = logging.getLogger(__name__)
 
-# Expert-stacked parameter leaves of models.transformer.MoEMlp: leading dim
-# is the expert dim for all four.
-MOE_PARAM_RE = re.compile(r"(^|/)moe/(w1|w2|b1|b2)$")
+# Expert-stacked parameter leaves of models.transformer.MoEMlp (w1, w2 and
+# their biases) and TopKExperts (w1, w3, w2): the leading dim is the expert
+# dim for all of them.
+MOE_PARAM_RE = re.compile(r"(^|/)moe/(w1|w2|w3|b1|b2)$")
 
 
 def ep_param_shardings(params, mesh, axis="expert", pattern=MOE_PARAM_RE):
@@ -80,6 +104,125 @@ def _route(x, router_kernel, router_bias, num_experts, capacity):
     fraction = expert_onehot.astype(jnp.float32).mean(axis=(0, 1))
     mean_prob = probs.mean(axis=(0, 1))
     return dispatch, expert_prob, (fraction, mean_prob)
+
+
+def route_topk(x, router_kernel, expert_bias, experts_per_token,
+               norm_topk=True, scaling=1.0):
+    """Top-k routing by sigmoid scores with a selection bias.
+
+    ``x [T, D]``, ``router_kernel [D, E]``, ``expert_bias [E]`` ->
+    ``(sel [T, k] int32, weights [T, k] float32)``: ``s = sigmoid(x W_r)``
+    in float32 at full precision (a selection must not flip with the compute
+    dtype or the TPU's default one-pass products), ``sel = top_k(s + b)``
+    (the bias enters the selection only and gets no gradient), ``weights =
+    s[sel]``, divided by their sum (+1e-6) where ``norm_topk``, times
+    ``scaling``."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    scores = jax.nn.sigmoid(jnp.matmul(
+        x.astype(jnp.float32), router_kernel.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST))                    # [T, E]
+    _, sel = lax.top_k(
+        scores + lax.stop_gradient(expert_bias.astype(jnp.float32)),
+        experts_per_token)
+    weights = jnp.take_along_axis(scores, sel, axis=-1)
+    if norm_topk:
+        weights = weights / (weights.sum(axis=-1, keepdims=True) + 1e-6)
+    return sel.astype(jnp.int32), weights * scaling
+
+
+def sort_pairs(sel, first, count):
+    """The (token, slot) pairs of ``sel [T, k]`` in the order the grouped
+    products want: pairs whose expert is one of the ``count`` held from
+    ``first`` on, expert by expert, then every other pair.
+
+    Returns ``(order [P], inverse [P], group_sizes [count], n_local)`` with
+    ``P = T * k``: ``order[i]`` is the pair (``token * k + slot``) at sorted
+    position ``i``, ``inverse`` its inverse permutation, ``group_sizes`` the
+    pairs of each held expert, ``n_local`` their sum."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    flat = sel.reshape(-1)
+    here = (flat >= first) & (flat < first + count)
+    key = jnp.where(here, flat - first, count)
+    iota = lax.iota(jnp.int32, flat.shape[0])
+    _, order = lax.sort((key, iota), num_keys=1)             # stable
+    _, inverse = lax.sort((order, iota), num_keys=1)
+    group_sizes = (key[:, None] == jnp.arange(count, dtype=jnp.int32)).sum(
+        axis=0, dtype=jnp.int32)
+    return order, inverse, group_sizes, group_sizes.sum()
+
+
+def _gather_rows(x, src, back):
+    """``out[i] = x[src[i]]`` for a ``src`` that names every row of ``x``
+    exactly ``rep`` times, ``back [rows, rep]`` the positions that name each
+    row.  The backward pass is the gather ``sum_j g[back[:, j]]``, not the
+    scatter-add that differentiating the take would give."""
+    import jax
+
+    @jax.custom_vjp
+    def gather(x, src, back):
+        return x[src]
+
+    def fwd(x, src, back):
+        return x[src], back
+
+    def bwd(back, g):
+        return g[back].sum(axis=1).astype(g.dtype), None, None
+
+    gather.defvjp(fwd, bwd)
+    return gather(x, src, back)
+
+
+def experts_ffn(x, sel, weights, w1, w3, w2, first, dtype=None):
+    """The held experts' part of a top-k SwiGLU expert layer.
+
+    ``x [T, D]`` tokens, ``sel``/``weights [T, k]`` from :func:`route_topk`,
+    ``w1``/``w3 [H, D, F]`` and ``w2 [H, F, D]`` the stacked weights of the
+    ``H`` experts held here, experts ``first .. first + H - 1`` of the
+    router's.  Returns ``(y [T, D], load)``: ``y = sum over the token's
+    slots whose expert is held of weight * SwiGLU_e(x)``, and ``load``, the
+    token-slot counts of this call (int32 scalars ``slots_total``,
+    ``slots_local``, ``expert_load_max``; float32 ``expert_load_mean``).
+
+    No pair is dropped: the sorted buffer holds all ``T * k`` pairs (the
+    worst case, every pair routed here), the pairs of held experts first;
+    the three grouped products
+    (:func:`~tensorflowonspark_tpu.ops.grouped_matmul.grouped_matmul`: pallas
+    kernels on a TPU, ``jax.lax.ragged_dot`` elsewhere) cover exactly those,
+    and the rows behind them are masked to zero on the way in and out."""
+    import jax
+    import jax.numpy as jnp
+
+    from tensorflowonspark_tpu.ops.grouped_matmul import grouped_matmul
+
+    dtype = dtype or x.dtype
+    tokens, k = sel.shape
+    held = w1.shape[0]
+    with jax.named_scope("route"):
+        order, inverse, group_sizes, n_local = sort_pairs(sel, first, held)
+    valid = (jnp.arange(tokens * k, dtype=jnp.int32) < n_local)[:, None]
+    with jax.named_scope("dispatch"):
+        xs = _gather_rows(x.astype(dtype), order // k,
+                          inverse.reshape(tokens, k))
+        xs = jnp.where(valid, xs, jnp.zeros((), dtype))
+    with jax.named_scope("experts"):
+        h = jax.nn.silu(grouped_matmul(xs, w1.astype(dtype), group_sizes))
+        h = h * grouped_matmul(xs, w3.astype(dtype), group_sizes)
+        ys = grouped_matmul(h, w2.astype(dtype), group_sizes)
+    with jax.named_scope("combine"):
+        ys = jnp.where(valid, ys, jnp.zeros((), dtype))
+        pairs = _gather_rows(ys, inverse, order[:, None])    # pair order
+        y = (pairs.reshape(tokens, k, -1).astype(jnp.float32)
+             * weights[..., None]).sum(axis=1).astype(dtype)
+    load = {"slots_total": jnp.asarray(tokens * k, jnp.int32),
+            "slots_local": n_local,
+            "expert_load_max": group_sizes.max(),
+            "expert_load_mean": n_local.astype(jnp.float32) / held}
+    return y, load
 
 
 def moe_ffn(x, params, mesh, num_experts, capacity_factor=1.25,

@@ -14,6 +14,7 @@ import dataclasses
 import logging
 import math
 import os
+import threading
 import time
 from typing import Any, Callable, Optional
 
@@ -405,6 +406,34 @@ class Trainer(object):
         self._steps_per_call_gauge = 0
         self._steps_per_call_hwm = 0
         self._steps_per_call_req = None
+        # The router's load (models with TopKExperts layers): each step's
+        # ``aux["moe_counts"]`` waits here as device scalars until the device
+        # has produced them; ``_fold_moe`` adds the finished ones into host
+        # totals and never waits, so neither the step loop nor a heartbeat's
+        # counters_snapshot syncs on a step in flight.
+        self._moe_pending = []
+        self._moe_totals = {}
+        self._moe_lock = threading.Lock()
+
+    def _note_moe(self, aux):
+        counts = aux.get("moe_counts") if isinstance(aux, dict) else None
+        if counts is None:
+            return
+        with self._moe_lock:
+            self._moe_pending.append(counts)
+        if len(self._moe_pending) > 8:
+            self._fold_moe()
+
+    def _fold_moe(self):
+        """Add the counts of every step the device has finished to the host
+        totals, oldest first; stops at the first step still in flight."""
+        with self._moe_lock:
+            while self._moe_pending and all(
+                    v.is_ready() for v in self._moe_pending[0].values()):
+                for key, val in jax.device_get(
+                        self._moe_pending.pop(0)).items():
+                    self._moe_totals[key] = self._moe_totals.get(
+                        key, 0) + val.item()
 
     def counters_snapshot(self):
         """Flat overlap + goodput counters for heartbeat payloads /
@@ -437,7 +466,16 @@ class Trainer(object):
         The observatory renders them as ``tfos_attrib_*`` gauges.  Plus
         ``train_compile_us_max`` (lower+compile wall time of the canonical
         step) and ``train_step_bytes_max`` (cost-analysis bytes accessed
-        per step) when known."""
+        per step) when known.
+
+        The router's load, for a model with ``TopKExperts`` layers only
+        (summed over the steps the device has finished; a step in flight
+        is counted by a later snapshot): ``moe_slots_total`` (token, slot)
+        pairs routed, ``moe_slots_local`` of them to experts held here,
+        ``moe_expert_load_max_sum`` / ``moe_expert_load_mean_sum`` the
+        heaviest and the mean held expert's pairs summed over layers and
+        steps (their ratio is the imbalance the grouped products see),
+        ``moe_layers_steps`` the expert-layer calls counted."""
         snap = {
             "dispatch_count": self._dispatch_count,
             "dispatch_gap_us": self._dispatch_gap_us,
@@ -491,6 +529,9 @@ class Trainer(object):
                 snap["train_grad_norm_max"] = round(self._health_grad, 6)
         if self._rollbacks:
             snap["train_rollbacks_total"] = self._rollbacks
+        if self._moe_pending or self._moe_totals:
+            self._fold_moe()
+            snap.update(self._moe_totals)   # the loss names them moe_*
         attrib = self.attribution_report()
         if attrib:
             for name, pct in attrib.items():
@@ -963,6 +1004,7 @@ class Trainer(object):
         # (multi_step buffers its scan's on-device grad-norm mean the same
         # way).
         aux, self._health_grad_norm = packed
+        self._note_moe(aux)
         self._steps_per_call_gauge = 1
         self._steps_per_call_hwm = max(self._steps_per_call_hwm, 1)
         self._steps_total += 1

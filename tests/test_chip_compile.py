@@ -132,3 +132,76 @@ def test_sharded_flash_compiles_for_v5e_2x2(topo, monkeypatch):
                                                impl="flash"),
         xs, xs, xs)
     assert "tpu_custom_call" in text and "all-to-all" in text
+
+
+def test_lfm2_moe_step_compiles_and_fits_v5e(topo, monkeypatch):
+    """The whole training step of ``lfm2_8b_a1b_ep4`` (the benchmark's
+    configuration: published widths, the layer pattern, 8 of 32 experts,
+    batch and 8,192-token rows as the file says, bf16 compute, remat per
+    block, Adam) compiles for one described v5e chip, with the grouped-query
+    flash kernels and the grouped expert products (pallas kernels) in it, and XLA's memory
+    analysis of it (arguments + outputs - aliased + temporaries) fits the
+    15.75 GiB a v5e offers.  The numbers are in the configuration's
+    ``assumed.batch_size``."""
+    import json
+    import sys
+
+    import optax
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmark.adapters import lfm2_moe as adapter
+    from tensorflowonspark_tpu.models import get_model, transformer
+
+    monkeypatch.setattr(fa, "_default_interpret", lambda: False)
+    monkeypatch.setattr(
+        importlib.import_module("tensorflowonspark_tpu.ops.grouped_matmul"),
+        "_default_impl", lambda: "pallas")
+    with open(os.path.join(root, "benchmark", "configs",
+                           "lfm2_8b_a1b_ep4.json")) as f:
+        cfg = json.load(f)
+    config = adapter.program_config(cfg)
+    model = get_model("lfm2_moe", config=config, attention=cfg["attention"],
+                      remat=cfg["remat"], dtype=cfg["dtype"])
+    # parameters never depend on the attention kind: shape them without
+    # tracing the kernel for the CPU
+    shapes = jax.eval_shape(
+        get_model("lfm2_moe", config=config, attention="full").init,
+        jax.random.PRNGKey(0), jnp.zeros((1, 128), jnp.int32))["params"]
+    assert sum(x.size for x in jax.tree_util.tree_leaves(shapes)) \
+        == 507_820_288
+    optimizer = optax.adam(cfg["optimizer"]["learning_rate"])
+    loss = transformer.loss_fn(model)
+
+    def step(params, opt_state, batch, mask):
+        (value, aux), grads = jax.value_and_grad(loss, has_aux=True)(
+            params, batch, mask)
+        updates, opt_state = optimizer.update(grads, opt_state, params)
+        return (optax.apply_updates(params, updates), opt_state, value,
+                (aux, optax.global_norm(grads)))
+
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def described(tree):
+        return jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one),
+            tree)
+
+    batch = cfg["batch_size"]
+    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
+        described(shapes), described(jax.eval_shape(optimizer.init, shapes)),
+        {"tokens": jax.ShapeDtypeStruct((batch, cfg["seq_len"]), jnp.int32,
+                                        sharding=one)},
+        jax.ShapeDtypeStruct((batch,), jnp.float32, sharding=one)).compile()
+    memory = compiled.memory_analysis()
+    needed = (memory.argument_size_in_bytes + memory.output_size_in_bytes
+              - memory.alias_size_in_bytes + memory.temp_size_in_bytes)
+    assert needed <= 15.75 * 2 ** 30, needed
+    text = compiled.as_text()
+    # 4 expert layers x 3 grouped products x (forward, recomputed forward,
+    # two gradients), and the flash kernels (forward twice, dQ, dK/dV): all
+    # pallas kernels that carry their scope, none of XLA's nameless
+    # ragged-dot calls
+    assert "ragged-dot" not in text
+    assert text.count("tpu_custom_call") >= 48 + 4

@@ -75,8 +75,50 @@ class TestFlashAttention:
 
     def test_seq_divisibility_enforced(self):
         q, k, v = _qkv(seq=48)
-        with pytest.raises(AssertionError, match="divide"):
+        with pytest.raises(ValueError, match="8.*divide.*32"):
             flash_attention(q, k, v, block_q=32, block_k=32)
+
+
+@pytest.mark.parametrize("blocks", [(32, 32), (64, 32), (32, 64)],
+                         ids=["q32k32", "q64k32", "q32k64"])
+@pytest.mark.parametrize("causal", [False, True])
+def test_grouped_query_flash_matches_reference(causal, blocks):
+    """8 query heads over 2 KV heads: values and the three gradients against
+    the reference contraction with each KV head repeated for its group (the
+    reference's dK/dV then sum over the group by the chain rule); block
+    sizes that differ exercise the causal index maps' clamping."""
+    keys = jax.random.split(jax.random.PRNGKey(11), 3)
+    q = jax.random.normal(keys[0], (2, 128, 8, 16))
+    k = jax.random.normal(keys[1], (2, 128, 2, 16))
+    v = jax.random.normal(keys[2], (2, 128, 2, 16))
+
+    def flash(q, k, v):
+        o = flash_attention(q, k, v, causal=causal, block_q=blocks[0],
+                            block_k=blocks[1])
+        return (o ** 2).sum(), o
+
+    def ref(q, k, v):
+        o = ring.reference_attention(q, jnp.repeat(k, 4, axis=2),
+                                     jnp.repeat(v, 4, axis=2), causal=causal)
+        return (o ** 2).sum(), o
+
+    (_, got), g_flash = jax.value_and_grad(
+        flash, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    (_, want), g_ref = jax.value_and_grad(
+        ref, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-5, rtol=2e-5)
+    for gf, gr, name in zip(g_flash, g_ref, "qkv"):
+        assert gf.shape == gr.shape
+        np.testing.assert_allclose(
+            np.asarray(gf), np.asarray(gr), atol=5e-4, rtol=5e-4,
+            err_msg="d{} mismatch".format(name))
+
+
+def test_flash_refuses_head_counts_that_do_not_group():
+    q, k, v = _qkv(heads=6)
+    with pytest.raises(ValueError, match="6 query heads"):
+        flash_attention(q, k[:, :, :4], v[:, :, :4])
 
 
 def test_transformer_flash_mode_matches_full():
@@ -159,3 +201,44 @@ def test_interpret_default_follows_the_platform(monkeypatch, platform,
     Device.platform = platform
     monkeypatch.setattr(jax, "devices", lambda *a: [Device()])
     assert fa._default_interpret() is interpret
+
+
+@pytest.mark.parametrize("sizes", [[40, 0, 100, 37], [0, 0, 0, 256],
+                                   [64, 64, 64, 64]],
+                         ids=["uneven", "one_group", "even"])
+def test_grouped_matmul_kernels_match_ragged_dot(sizes):
+    """The pallas grouped product (forward, and both gradients' kernels) in
+    interpret mode against ``jax.lax.ragged_dot``: an empty group, every row
+    in one group, and rows behind the last group, which the kernels leave
+    unwritten and the caller masks."""
+    from tensorflowonspark_tpu.ops import grouped_matmul
+
+    keys = jax.random.split(jax.random.PRNGKey(7), 2)
+    lhs = jax.random.normal(keys[0], (256, 128))
+    rhs = 0.1 * jax.random.normal(keys[1], (4, 128, 256))
+    group_sizes = jnp.asarray(sizes, jnp.int32)
+    valid = (jnp.arange(256) < group_sizes.sum())[:, None]
+
+    def run(impl):
+        def loss(lhs, rhs):
+            out = grouped_matmul(jnp.where(valid, lhs, 0.0), rhs, group_sizes,
+                                 impl=impl, interpret=True)
+            out = jnp.where(valid, out, 0.0)
+            return (out ** 2).sum(), out
+        return jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)(lhs, rhs)
+
+    (_, want), g_want = run("xla")
+    (_, got), g_got = run("pallas")
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=1e-4, rtol=1e-4)
+    for a, b, name in zip(g_got, g_want, ("lhs", "rhs")):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-3,
+                                   rtol=1e-3, err_msg="d" + name)
+
+
+def test_grouped_matmul_refuses_an_unknown_impl():
+    from tensorflowonspark_tpu.ops import grouped_matmul
+
+    with pytest.raises(ValueError, match="impl"):
+        grouped_matmul(jnp.zeros((8, 8)), jnp.zeros((1, 8, 8)),
+                       jnp.asarray([8], jnp.int32), impl="cuda")

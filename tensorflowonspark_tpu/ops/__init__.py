@@ -3,10 +3,14 @@
 The compute path is jax/XLA; these kernels cover the few ops where
 hand-scheduling VMEM traffic beats XLA's fusion — attention first
 (:mod:`~tensorflowonspark_tpu.ops.flash_attention`), then the grouped matrix
-product of an expert layer (:mod:`~tensorflowonspark_tpu.ops.grouped_matmul`).
+product of an expert layer (:mod:`~tensorflowonspark_tpu.ops.grouped_matmul`)
+and the row movement around it, which stops at the rows routed here
+(:mod:`~tensorflowonspark_tpu.ops.routed_rows`).
 Every kernel runs in pallas interpret mode off-TPU, so the suite validates
 them on the CPU mesh.
 """
 
 from tensorflowonspark_tpu.ops.flash_attention import flash_attention  # noqa: F401
 from tensorflowonspark_tpu.ops.grouped_matmul import grouped_matmul  # noqa: F401
+from tensorflowonspark_tpu.ops.routed_rows import (  # noqa: F401
+    gather_rows, gather_sum_rows)

@@ -9,7 +9,8 @@ the static worst-case number of rows; the kernels visit only the row tiles
 that lie inside a group (a scalar-prefetched tile -> group map; a tile that
 straddles a boundary is visited once a group, masked), so the work follows
 ``sum(group_sizes)``, not the buffer.  **Rows behind the last group are not
-written**: the caller masks them.
+written** and, where a product sums over rows (the gradient of ``rhs``), not
+read: the expert layer leaves them as they are and reads none of them.
 
 The kernels are the ``megablox`` grouped products that ship with jax
 (``jax.experimental.pallas.ops.tpu.megablox``: ``gmm`` for the forward
@@ -102,7 +103,7 @@ def grouped_matmul(lhs, rhs, group_sizes, impl=None, interpret=False):
     """``lhs [m, k]``, ``rhs [groups, k, n]``, ``group_sizes [groups]`` int32
     with ``sum <= m`` -> ``[m, n]`` in ``lhs``'s dtype: row ``i`` of group
     ``g`` (the groups lie one after another from row 0) is ``lhs[i] @
-    rhs[g]``.  Rows behind the last group are unspecified: mask them.
+    rhs[g]``.  Rows behind the last group are unspecified: do not read them.
     Differentiable in ``lhs`` and ``rhs``.
 
     ``impl``: ``"pallas"`` (the kernels; ``interpret=True`` off the TPU) or

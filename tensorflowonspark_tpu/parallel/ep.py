@@ -18,8 +18,10 @@ routers of :mod:`~tensorflowonspark_tpu.models.transformer`.
   knows), and the pairs whose expert lives elsewhere contribute nothing: the layer returns its own
   experts' part of the sum, which is what one chip of an expert-parallel
   deployment computes before the exchange.  Buffers have the static
-  worst-case size (every pair routed here); the grouped products' work
-  follows the pairs that are.  ``TopKExperts`` uses it.  Nothing here stands
+  worst-case size (every pair routed here); the work of the grouped products
+  and of the row movement around them
+  (:mod:`~tensorflowonspark_tpu.ops.routed_rows`) follows the pairs that
+  are.  ``TopKExperts`` uses it.  Nothing here stands
   in for the absent chips: on one chip there is no exchange.
 
 **Two ways over the mesh** for the capacity router's layer (the last of the
@@ -156,25 +158,70 @@ def sort_pairs(sel, first, count):
     return order, inverse, group_sizes, group_sizes.sum()
 
 
-def _gather_rows(x, src, back):
-    """``out[i] = x[src[i]]`` for a ``src`` that names every row of ``x``
-    exactly ``rep`` times, ``back [rows, rep]`` the positions that name each
-    row.  The backward pass is the gather ``sum_j g[back[:, j]]``, not the
-    scatter-add that differentiating the take would give."""
+def _dispatch(x, src, idx, n_local):
+    """Tokens into expert order: ``xs[i] = x[src[i]]`` for ``i < n_local``
+    (``src [P]`` the token of each sorted position, ``idx [T, k]`` the sorted
+    position of each (token, slot) pair); rows from ``n_local`` on are
+    unspecified.  The backward pass is the gather-and-sum ``d_x[t] = sum_j
+    [idx[t, j] < n_local] g[idx[t, j]]``, not the scatter-add that
+    differentiating a take would give."""
     import jax
 
+    from tensorflowonspark_tpu.ops.routed_rows import (gather_rows,
+                                                        gather_sum_rows)
+
     @jax.custom_vjp
-    def gather(x, src, back):
-        return x[src]
+    def dispatch(x, src, idx, n_local):
+        return gather_rows(x, src, n_local)
 
-    def fwd(x, src, back):
-        return x[src], back
+    def fwd(x, src, idx, n_local):
+        return dispatch(x, src, idx, n_local), (idx, n_local)
 
-    def bwd(back, g):
-        return g[back].sum(axis=1).astype(g.dtype), None, None
+    def bwd(residual, g):
+        idx, n_local = residual
+        return gather_sum_rows(g, idx, n_local), None, None, None
 
-    gather.defvjp(fwd, bwd)
-    return gather(x, src, back)
+    dispatch.defvjp(fwd, bwd)
+    return dispatch(x, src, idx, n_local)
+
+
+def _combine(ys, weights, order, idx, n_local):
+    """Experts' rows back to token order: ``y[t] = sum_j [idx[t, j] <
+    n_local] weights[t, j] * ys[idx[t, j]]`` in float32, written once; rows
+    of ``ys`` from ``n_local`` on are not read.  The backward pass gathers
+    ``dy`` into expert order once, scaled by the pair's weight for ``d_ys``
+    (unspecified from ``n_local`` on) and dotted with ``ys`` for the weights'
+    gradient (0 for a pair routed elsewhere)."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    from tensorflowonspark_tpu.ops.routed_rows import (gather_rows,
+                                                        gather_sum_rows)
+
+    @jax.custom_vjp
+    def combine(ys, weights, order, idx, n_local):
+        return gather_sum_rows(ys, idx, n_local, weights=weights)
+
+    def fwd(ys, weights, order, idx, n_local):
+        return (combine(ys, weights, order, idx, n_local),
+                (ys, weights, order, idx, n_local))
+
+    def bwd(residual, dy):
+        ys, weights, order, idx, n_local = residual
+        # one float a pair moves into expert order, and back, as the payload
+        # of a sort by the pair's position there (0.2 ms for 131,072 pairs
+        # on a v5e; XLA's gather of as many scalars takes 1.2)
+        _, scale = lax.sort((idx.reshape(-1), weights.reshape(-1)),
+                            num_keys=1)
+        d_ys, dots = gather_rows(dy, order // idx.shape[1], n_local,
+                                 scale=scale, dot_with=ys)
+        _, dots = lax.sort((order, dots), num_keys=1)
+        d_weights = jnp.where(idx < n_local, dots.reshape(idx.shape), 0.0)
+        return d_ys, d_weights.astype(weights.dtype), None, None, None
+
+    combine.defvjp(fwd, bwd)
+    return combine(ys, weights, order, idx, n_local)
 
 
 def experts_ffn(x, sel, weights, w1, w3, w2, first, dtype=None):
@@ -188,12 +235,20 @@ def experts_ffn(x, sel, weights, w1, w3, w2, first, dtype=None):
     token-slot counts of this call (int32 scalars ``slots_total``,
     ``slots_local``, ``expert_load_max``; float32 ``expert_load_mean``).
 
-    No pair is dropped: the sorted buffer holds all ``T * k`` pairs (the
-    worst case, every pair routed here), the pairs of held experts first;
-    the three grouped products
+    No pair is dropped: the sorted buffers hold all ``T * k`` pairs (the
+    worst case, every pair routed here), the ``n_local`` pairs of held
+    experts first.  **Nothing reads a sorted buffer behind ``n_local``, in
+    either direction, so the rows there may hold anything** (the kernels
+    never write them, and nothing masks them): the row movement
+    (:mod:`~tensorflowonspark_tpu.ops.routed_rows`: pallas kernels on a TPU
+    that fetch a row only where its sorted position lies in front of
+    ``n_local``, XLA's take with a mask elsewhere) stops there by
+    construction, the three grouped products
     (:func:`~tensorflowonspark_tpu.ops.grouped_matmul.grouped_matmul`: pallas
-    kernels on a TPU, ``jax.lax.ragged_dot`` elsewhere) cover exactly those,
-    and the rows behind them are masked to zero on the way in and out."""
+    kernels on a TPU, ``jax.lax.ragged_dot`` elsewhere) by their tile map,
+    and what lies between them works row by row.  ``slots_local /
+    slots_total`` is therefore the share of the rows that is fetched, and 1
+    minus it the share that is skipped."""
     import jax
     import jax.numpy as jnp
 
@@ -204,20 +259,15 @@ def experts_ffn(x, sel, weights, w1, w3, w2, first, dtype=None):
     held = w1.shape[0]
     with jax.named_scope("route"):
         order, inverse, group_sizes, n_local = sort_pairs(sel, first, held)
-    valid = (jnp.arange(tokens * k, dtype=jnp.int32) < n_local)[:, None]
+    idx = inverse.reshape(tokens, k)
     with jax.named_scope("dispatch"):
-        xs = _gather_rows(x.astype(dtype), order // k,
-                          inverse.reshape(tokens, k))
-        xs = jnp.where(valid, xs, jnp.zeros((), dtype))
+        xs = _dispatch(x.astype(dtype), order // k, idx, n_local)
     with jax.named_scope("experts"):
         h = jax.nn.silu(grouped_matmul(xs, w1.astype(dtype), group_sizes))
         h = h * grouped_matmul(xs, w3.astype(dtype), group_sizes)
         ys = grouped_matmul(h, w2.astype(dtype), group_sizes)
     with jax.named_scope("combine"):
-        ys = jnp.where(valid, ys, jnp.zeros((), dtype))
-        pairs = _gather_rows(ys, inverse, order[:, None])    # pair order
-        y = (pairs.reshape(tokens, k, -1).astype(jnp.float32)
-             * weights[..., None]).sum(axis=1).astype(dtype)
+        y = _combine(ys, weights, order, idx, n_local)
     load = {"slots_total": jnp.asarray(tokens * k, jnp.int32),
             "slots_local": n_local,
             "expert_load_max": group_sizes.max(),

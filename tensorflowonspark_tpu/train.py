@@ -471,7 +471,9 @@ class Trainer(object):
         The router's load, for a model with ``TopKExperts`` layers only
         (summed over the steps the device has finished; a step in flight
         is counted by a later snapshot): ``moe_slots_total`` (token, slot)
-        pairs routed, ``moe_slots_local`` of them to experts held here,
+        pairs routed, ``moe_slots_local`` of them to experts held here
+        (their ratio is the share of the sorted buffers' rows that the expert
+        layer's kernels fetch, 1 minus it the share they skip),
         ``moe_expert_load_max_sum`` / ``moe_expert_load_mean_sum`` the
         heaviest and the mean held expert's pairs summed over layers and
         steps (their ratio is the imbalance the grouped products see),

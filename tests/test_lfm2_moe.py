@@ -66,7 +66,21 @@ def _reference_layer(w, x, held=(0, 8)):
                       for row in x])
 
 
-def test_the_shares_add_up_to_the_whole_layer():
+@pytest.fixture(params=["xla", "kernels"])
+def row_path(request, monkeypatch):
+    """Both paths of the layer's row movement (``ops/routed_rows``): XLA's
+    take, which is what a CPU process gets, and the kernels a TPU process
+    gets, here in interpret mode."""
+    import importlib
+
+    if request.param == "kernels":
+        monkeypatch.setattr(
+            importlib.import_module("tensorflowonspark_tpu.ops.routed_rows"),
+            "_default_impl", lambda: ("pallas", True))
+    return request.param
+
+
+def test_the_shares_add_up_to_the_whole_layer(row_path):
     """8 experts in 4 shares of 2: the four partial sums that the chips of an
     expert-parallel layer compute equal the uncut reference's whole layer,
     and every (token, slot) pair is counted by exactly one share."""
@@ -83,7 +97,7 @@ def test_the_shares_add_up_to_the_whole_layer():
         atol=2e-5, rtol=2e-5)
 
 
-def test_nothing_is_dropped_when_every_token_goes_to_one_expert():
+def test_nothing_is_dropped_when_every_token_goes_to_one_expert(row_path):
     """A selection bias that sends every token's first slot to expert 3: the
     share holding experts 3 and 4 gets all 120 tokens in one group (a
     capacity of 1.25 S / E would keep 18) and gives the reference's answer,
@@ -103,7 +117,7 @@ def test_nothing_is_dropped_when_every_token_goes_to_one_expert():
                                atol=1e-4, rtol=1e-4)
 
 
-def test_a_share_that_nothing_is_routed_to_gives_zero():
+def test_a_share_that_nothing_is_routed_to_gives_zero(row_path):
     bias = np.zeros(8, np.float32)
     bias[:2] = 10.0        # both slots of every token go to experts 0 and 1
     w, x = _layer_weights(2, bias)

@@ -242,3 +242,180 @@ def test_grouped_matmul_refuses_an_unknown_impl():
     with pytest.raises(ValueError, match="impl"):
         grouped_matmul(jnp.zeros((8, 8)), jnp.zeros((1, 8, 8)),
                        jnp.asarray([8], jnp.int32), impl="cuda")
+
+
+# the row movement of the expert layer: 64 tokens, 4 slots, rows of 256
+ROWS_T, ROWS_K, ROWS_D = 64, 4, 256
+ROWS_P = ROWS_T * ROWS_K
+
+
+def _routed(dtype, seed=11):
+    """Tokens, experts' rows, weights and a sorting of the pairs (a random
+    permutation: the kernels take any), with cotangents for both outputs."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    order = jax.random.permutation(ks[0], ROWS_P).astype(jnp.int32)
+    return {"x": jax.random.normal(ks[1], (ROWS_T, ROWS_D)).astype(dtype),
+            "ys": jax.random.normal(ks[2], (ROWS_P, ROWS_D)).astype(dtype),
+            "weights": jax.random.uniform(ks[3], (ROWS_T, ROWS_K)),
+            "order": order,
+            "idx": jnp.argsort(order).astype(jnp.int32).reshape(
+                ROWS_T, ROWS_K),
+            "c_xs": jax.random.normal(ks[4], (ROWS_P, ROWS_D)),
+            "c_y": jax.random.normal(ks[5], (ROWS_T, ROWS_D))}
+
+
+@pytest.fixture
+def row_kernels(monkeypatch):
+    """The kernels of ``ops/routed_rows`` in interpret mode as the default
+    path, four row tiles in a sorted buffer and four token tiles."""
+    import importlib
+
+    rr = importlib.import_module("tensorflowonspark_tpu.ops.routed_rows")
+    monkeypatch.setattr(rr, "_default_impl", lambda: ("pallas", True))
+    monkeypatch.setattr(rr, "GATHER_TILE", 64)
+    monkeypatch.setattr(rr, "SUM_TILE", 16)
+    return rr
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("n_local", [0, 64, 100, ROWS_P],
+                         ids=["none", "one_tile", "mid_tile", "all"])
+def test_routed_rows_kernels_match_the_plain_formulation(row_kernels, n_local,
+                                                         dtype):
+    """Dispatch and combine through the kernels (interpret mode) against
+    ``x[src]``, a mask and a weighted sum differentiated by jax: values, and
+    the gradients of ``x``, ``ys`` and ``weights``, with nothing, one tile
+    exactly, a tile and a part, and every row in front of ``n_local``.  The
+    rows behind it are compared nowhere: they are unspecified."""
+    from tensorflowonspark_tpu.parallel import ep
+
+    a = _routed(dtype)
+    n = jnp.int32(n_local)
+    valid = (jnp.arange(ROWS_P) < n_local)[:, None]
+
+    def f32(v):
+        return np.asarray(v, np.float32)
+
+    def plain_dispatch(x):
+        return jnp.where(valid, x[a["order"] // ROWS_K], 0)
+
+    def plain_combine(ys, weights):
+        rows = ys[a["idx"]].astype(jnp.float32) * weights[..., None]
+        return jnp.where((a["idx"] < n_local)[..., None], rows, 0.0).sum(
+            axis=1).astype(dtype)
+
+    def kernel_dispatch(x):
+        xs = ep._dispatch(x, a["order"] // ROWS_K, a["idx"], n)
+        return jnp.where(valid, xs, 0)
+
+    def kernel_combine(ys, weights):
+        return ep._combine(ys, weights, a["order"], a["idx"], n)
+
+    def loss(fn, c):
+        return lambda *v: (fn(*v).astype(jnp.float32) * c).sum()
+
+    tol = dict(atol=1e-5, rtol=1e-5) if dtype == jnp.float32 \
+        else dict(atol=2e-2, rtol=2e-2)
+    np.testing.assert_array_equal(f32(kernel_dispatch(a["x"])),
+                                  f32(plain_dispatch(a["x"])))
+    np.testing.assert_allclose(
+        f32(jax.grad(loss(kernel_dispatch, a["c_xs"]))(a["x"])),
+        f32(jax.grad(loss(plain_dispatch, a["c_xs"]))(a["x"])), **tol)
+    np.testing.assert_allclose(
+        f32(kernel_combine(a["ys"], a["weights"])),
+        f32(plain_combine(a["ys"], a["weights"])), **tol)
+    got = jax.grad(loss(kernel_combine, a["c_y"]), argnums=(0, 1))(
+        a["ys"], a["weights"])
+    want = jax.grad(loss(plain_combine, a["c_y"]), argnums=(0, 1))(
+        a["ys"], a["weights"])
+    np.testing.assert_allclose(f32(jnp.where(valid, got[0], 0)),
+                               f32(want[0]), **tol)
+    np.testing.assert_allclose(f32(got[1]), f32(want[1]), atol=1e-3,
+                               rtol=1e-2 if dtype == jnp.bfloat16 else 1e-4)
+
+
+def test_nothing_reads_a_sorted_buffer_behind_n_local(row_kernels,
+                                                      monkeypatch):
+    """``experts_ffn`` with every kernel in interpret mode and the rows
+    behind ``n_local`` of every sorted buffer set to NaN, forward and
+    backward (the tokens in expert order, the grouped products' inputs and
+    outputs, and their cotangents): the layer's output and the gradients of
+    tokens, weights and expert weights are finite and equal to the XLA
+    path's, which is never poisoned."""
+    import importlib
+
+    from tensorflowonspark_tpu.parallel import ep
+
+    gm = importlib.import_module("tensorflowonspark_tpu.ops.grouped_matmul")
+    real = gm.grouped_matmul
+
+    @jax.custom_vjp
+    def poison(rows, n):
+        return jnp.where((jnp.arange(rows.shape[0]) < n)[:, None], rows,
+                         jnp.nan)
+
+    poison.defvjp(lambda rows, n: (poison(rows, n), n),
+                  lambda n, g: (poison(g, n), None))
+
+    calls = []
+
+    def poisoned(lhs, rhs, group_sizes):
+        calls.append(lhs.shape)
+        n = group_sizes.sum()
+        return poison(real(poison(lhs, n), rhs, group_sizes, impl="pallas",
+                           interpret=True), n)
+
+    tokens, k, d, f, held = 128, 2, 128, 128, 3
+    ks = jax.random.split(jax.random.PRNGKey(5), 6)
+    x = jax.random.normal(ks[0], (tokens, d))
+    sel = jax.random.randint(ks[1], (tokens, k), 0, 8, jnp.int32)
+    weights = jax.random.uniform(ks[2], (tokens, k))
+    ws = [0.1 * jax.random.normal(key, shape) for key, shape in zip(
+        ks[3:], [(held, d, f), (held, d, f), (held, f, d)])]
+
+    def loss(x, weights, *ws):
+        y, load = ep.experts_ffn(x, sel, weights, *ws, first=2)
+        return (y ** 2).sum(), (y, load["slots_local"])
+
+    run = jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4), has_aux=True)
+    monkeypatch.setattr(gm, "grouped_matmul", poisoned)
+    (_, (y, n_local)), grads = run(x, weights, *ws)
+    monkeypatch.undo()
+    assert len(calls) == 3
+    (_, (y_want, _)), grads_want = run(x, weights, *ws)
+    assert 0 < int(n_local) < tokens * k and int(n_local) % 64
+    for got, want, name in zip((y,) + grads, (y_want,) + grads_want,
+                               ("y", "d_x", "d_weights", "d_w1", "d_w3",
+                                "d_w2")):
+        assert np.isfinite(np.asarray(got)).all(), name
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=1e-3, rtol=1e-3, err_msg=name)
+
+
+def test_gather_sum_rows_takes_a_slot_count_that_is_no_power_of_two(
+        row_kernels):
+    """Three slots a token: the scalar copy of the positions is padded to
+    four a token and the pad is never visited."""
+    ks = jax.random.split(jax.random.PRNGKey(2), 3)
+    ys = jax.random.normal(ks[0], (96, 128))
+    idx = jax.random.permutation(ks[1], 96).astype(jnp.int32).reshape(32, 3)
+    weights = jax.random.uniform(ks[2], (32, 3))
+    want = row_kernels.gather_sum_rows(ys, idx, 50, weights=weights,
+                                       impl="xla")
+    got = row_kernels.gather_sum_rows(
+        jnp.where((jnp.arange(96) < 50)[:, None], ys, jnp.nan), idx, 50,
+        weights=weights)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5,
+                               rtol=1e-5)
+
+
+def test_routed_rows_refuse_an_unknown_impl():
+    from tensorflowonspark_tpu.ops import gather_rows, gather_sum_rows
+
+    with pytest.raises(ValueError, match="impl"):
+        gather_rows(jnp.zeros((8, 8)), jnp.zeros((8,), jnp.int32), 8,
+                    impl="cuda")
+    with pytest.raises(ValueError, match="impl"):
+        gather_sum_rows(jnp.zeros((8, 8)), jnp.zeros((4, 2), jnp.int32), 8,
+                        impl="cuda")

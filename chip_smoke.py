@@ -757,9 +757,9 @@ def _read_result(path):
 
 def _run_cluster(main_fun, args, input_mode, partitions=None, num_epochs=1,
                  num_executors=1):
-    """Run ``main_fun`` on a LocalBackend cluster, as ``bench.py`` and the
-    examples do; returns once the chip-holding process has left its result
-    and the cluster is down."""
+    """Run ``main_fun`` on a LocalBackend cluster, as the examples do;
+    returns once the chip-holding process has left its result and the
+    cluster is down."""
     from tensorflowonspark_tpu import backend, cluster
 
     b = backend.LocalBackend(num_executors)

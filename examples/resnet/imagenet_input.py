@@ -311,7 +311,7 @@ def predecoded_reader(train=True, image_size=224, store_px=256, seed=0,
     - ``device_crop=False``: crop/flip as host uint8 slicing; rows are
       ``{"image": (S,S,3)}``.  Simple, but the strided crop copy costs
       ~0.2 ms/row — ~3.5k rows/s/core at the batch assembler.
-    - ``device_crop=True`` (the 8k-rows/s path, docs/PERF.md round 5):
+    - ``device_crop=True``:
       pixels ship UNTOUCHED as the full contiguous ``store_px`` row (the
       host's only per-pixel work is the contiguous batch memcpy) plus
       sampled ``cropx/cropy/flip`` ints; the crop happens on device via

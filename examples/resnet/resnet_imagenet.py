@@ -1,7 +1,7 @@
 """ResNet-50 v1.5 / ImageNet distributed training (reference
 ``examples/resnet/resnet_imagenet_main.py``).
 
-The BASELINE.md second headline workload: ResNet-50 with ImageNet scale
+ResNet-50 with ImageNet scale
 constants (1,281,167 train images, 90 epochs, batch 256 — reference
 ``imagenet_preprocessing.py:46-49``, ``resnet_imagenet_main.py:271``),
 piecewise LR decay with linear warmup (reference

@@ -115,8 +115,17 @@ def main_fun(args, ctx):
                 jax.device_put(np.ones((args.batch_size,), np.float32),
                                mask_sharding))
 
-    flops = metrics_mod.estimate_step_flops(
-        step_fn, params, opt_state, *next_batch())
+    # A step's model FLOPs from shapes, per device: 6 for each matmul
+    # parameter and token (12 d^2 a layer, d V for the read-out) plus the
+    # causal half of the attention products.  An MoE model's active share
+    # follows its router: it states no count and reports no MFU.
+    flops = None
+    if args.mlp == "dense":
+        d = args.num_heads * args.head_dim
+        macs = args.seq_len * (args.num_layers * 12 * d * d
+                               + d * args.vocab_size)
+        macs += args.num_layers * args.seq_len * (args.seq_len + 1) * d
+        flops = 6 * macs * args.batch_size / mesh.size
     history = metrics_mod.TimeHistory(args.batch_size,
                                       log_steps=args.log_steps,
                                       step_flops=flops)

@@ -10,8 +10,8 @@
 # jax.distributed worlds.
 #
 # Usage:
-#   ./run_tests.sh            # full suite (~27 min on 8 CPU cores; 258
-#                             # tests incl. all example-CLI integration runs)
+#   ./run_tests.sh            # full suite (~27 min on 8 CPU cores, incl.
+#                             # all example-CLI integration runs)
 #   ./run_tests.sh -m 'not slow'   # fast subset, ~5 min — every framework
 #                                  # module; 'slow' marks the example/cluster
 #                                  # integration runs (each boots multi-
@@ -36,9 +36,9 @@ python -m pytest tests/ -q --durations=10 "$@" || rc=$?
 # prove the observatory answers live: /metrics + /status scrapeable
 # mid-run with the MFU/goodput accountant, counters monotone, and trace
 # flow events linking a data-service split to a consumer-side dispatch,
-# then prove the device plane explains itself: attribution gauges on
-# /metrics summing to ~100%, a mid-run GET /profile collecting every
-# node's device trace to the driver, and analyze_profile.py merging them
+# then prove the device plane hands over its traces: a mid-run GET
+# /profile collecting every node's device trace to the driver, and
+# analyze_profile.py merging them
 # with the host traces into one Perfetto timeline, and finally prove the
 # watchtower catches an injected straggler and an injected NaN loss live
 # (correctly attributed on /alerts, /metrics, /status and as trace

@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""Merge a profile capture into ONE Perfetto timeline + attribution table.
+"""Merge a profile capture into ONE Perfetto timeline.
 
 Input: a ``profiles/<capture_id>/`` directory produced by the observatory's
 ``GET /profile`` trigger (see ``tensorflowonspark_tpu/profiling.py``) —
@@ -11,8 +11,7 @@ Output: one Chrome-trace JSON loadable in Perfetto / chrome://tracing with
 the device planes and the host spans on the same wall-clock-µs timeline
 (both sides already share the convention: XPlane lines stamp nanoseconds
 since the UNIX epoch, telemetry stamps ``time.time() * 1e6`` — see
-``telemetry.wall_time_us``), plus the step-time attribution table printed
-from the manifest's metrics snapshot.
+``telemetry.wall_time_us``).
 
 The ``.xplane.pb`` decoder is a minimal pure-Python protobuf wire-format
 reader (varint / length-delimited), dependency-free by design: this repo
@@ -225,21 +224,6 @@ def request_flow_summary(events):
     return {"ids": len(pids_by_id), "cross_pid": cross}
 
 
-def attribution_rows(manifest):
-    """``attrib_*_pct_max`` gauges from the manifest's aggregate metrics ->
-    ``[(bucket, pct), ...]`` in report order (empty when absent)."""
-    agg = ((manifest.get("metrics") or {}).get("aggregate")) or {}
-    rows = []
-    for key in sorted(agg):
-        if key.startswith("attrib_") and key.endswith("_pct_max"):
-            bucket = key[len("attrib_"):-len("_pct_max")]
-            rows.append((bucket, float(agg[key])))
-    order = ("device_compute", "collective", "infeed_starved", "ckpt_drain",
-             "unattributed")
-    rows.sort(key=lambda r: (order.index(r[0]) if r[0] in order else 99))
-    return rows
-
-
 def main(argv=None):
     ap = argparse.ArgumentParser(
         description="merge a profile capture into one Perfetto timeline")
@@ -252,8 +236,7 @@ def main(argv=None):
                          "<capture_dir>/merged_timeline.json)")
     args = ap.parse_args(argv)
 
-    payload, manifest, notes = merge_capture(args.capture_dir,
-                                             args.telemetry_dir)
+    payload, _, notes = merge_capture(args.capture_dir, args.telemetry_dir)
     out = args.out or os.path.join(args.capture_dir, "merged_timeline.json")
     with open(out, "w") as f:
         json.dump(payload, f)
@@ -261,20 +244,6 @@ def main(argv=None):
         print(note)
     print("merged timeline: %s (%d events) — load it in ui.perfetto.dev"
           % (out, len(payload["traceEvents"])))
-
-    rows = attribution_rows(manifest)
-    if rows:
-        # each node's buckets sum to 100%; the aggregate takes the per-
-        # bucket MAX across nodes (the _max merge rule), so the total can
-        # exceed 100% on a skewed cluster — that skew is itself signal
-        print("\nstep-time attribution (per-bucket max across nodes):")
-        for bucket, pct in rows:
-            print("  %-16s %6.2f%%  %s" % (bucket, pct,
-                                           "#" * int(round(pct / 2))))
-        print("  %-16s %6.2f%%" % ("total", sum(p for _, p in rows)))
-    else:
-        print("\nno attrib_* gauges in the manifest (train long enough for "
-              "a metrics window to close before triggering the capture)")
     return 0
 
 

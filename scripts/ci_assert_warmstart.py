@@ -5,12 +5,12 @@ Boots a real 2-node in-process cluster with a cluster-shared compile cache
 process mid-run, and asserts the replacement rejoins on the warm path:
 
 1. every node trains a real (tiny, CPU) jitted step, so
-   ``train_compile_us_max`` measures each node's actual compile debt,
+   ``compilecache.stats`` counts each node's actual compile debt: what
+   resolving its step program cost (``compile_cache_aot_compile_us`` +
+   ``compile_cache_aot_load_us``),
 2. the replacement's step program resolves to verdict ``loaded`` — it
    deserialized a fingerprint-matched executable and NEVER traced,
-3. the replacement's ``train_compile_us_max`` is a small fraction of the
-   cold nodes' (the canonical-program estimate rides the persistent disk
-   cache),
+3. the replacement's compile debt is a small fraction of the cold nodes',
 4. ``tfos_compile_cache_hit_total`` is nonzero on a live ``/metrics``
    scrape (the counters ride heartbeats into the observatory),
 5. every fed element is accounted for exactly once (the elastic-recovery
@@ -35,9 +35,6 @@ N_ITEMS = 40   # 4 partitions of 10: the kill (after 5) always interrupts
                # executor 0 MID-partition, so its feed task fails its join
                # and the partition is re-fed wholesale (exactly-once math)
 WARM_FRACTION = 3      # replacement compile debt must be <= cold / this
-                       # (measured ~4.4x on CI-class CPU; the canonical-
-                       # program estimate still pays tracing, only XLA
-                       # compilation rides the persistent cache)
 SCRAPE_DEADLINE_SECS = 30.0
 
 
@@ -70,13 +67,14 @@ def _node_fn(args, ctx):
     mask = jnp.ones((4,), jnp.float32)
 
     def report(total):
+        cache = compilecache.stats.counters_snapshot()
         doc = {
             "executor_id": ctx.executor_id,
             "total": int(total),
-            "train_compile_us": int(trainer.counters_snapshot().get(
-                "train_compile_us_max", 0)),
+            "compile_debt_us": (cache["compile_cache_aot_compile_us"]
+                                + cache["compile_cache_aot_load_us"]),
             "verdicts": dict(trainer._aot_verdicts),
-            "cache": compilecache.stats.counters_snapshot(),
+            "cache": cache,
         }
         tmp = "report.json.tmp"
         with open(tmp, "w") as f:
@@ -174,15 +172,15 @@ def main():
                 with open(path) as f:
                     reports[i] = json.load(f)
         print("per-node reports:", {
-            i: {"total": r["total"], "compile_us": r["train_compile_us"],
+            i: {"total": r["total"], "compile_us": r["compile_debt_us"],
                 "verdicts": r["verdicts"]}
             for i, r in sorted(reports.items())})
         assert 2 in reports, \
             "replacement wrote no report: {}".format(sorted(reports))
-        cold_us = max(reports[i]["train_compile_us"]
+        cold_us = max(reports[i]["compile_debt_us"]
                       for i in (0, 1) if i in reports)
         warm = reports[2]
-        warm_us = warm["train_compile_us"]
+        warm_us = warm["compile_debt_us"]
         assert warm["verdicts"].get("step") == "loaded", \
             "replacement retraced its step program: {}".format(
                 warm["verdicts"])

@@ -1,8 +1,8 @@
 """Stage-by-stage microbenchmark of the SPARK-mode data plane.
 
 Times each hop a feed row takes (serialization, queue/ring IPC, batch
-assembly, driver pipe ship) in isolation for the MNIST workload shape —
-the numbers behind docs/PERF.md.  Run on any host:
+assembly, driver pipe ship) in isolation for the MNIST workload shape:
+host counts of the box it runs on, no device rate.  Run on any host:
 
     python scripts/profile_feed.py
 """

@@ -11,7 +11,7 @@ latency, and reports the dispatch-gap counters for:
 - ``overlapped`` — prefetch=2 (transfer in the prefetch thread) + async
   saves: the shipped defaults.
 
-The numbers land in docs/PERF.md (round 8).  Pure stdlib + repo deps; CPU
+Host counts of a CPU box, no device rate.  Pure stdlib + repo deps; CPU
 only; ~10 s.  Usage::
 
     python scripts/profile_overlap.py [--steps 60]

@@ -696,9 +696,11 @@ def run(cluster_backend, map_fun, tf_args, num_executors=None, num_ps=0,
         JAX's persistent compilation cache at this cluster-shared
         directory before touching any backend, so an elastic replacement
         node (which re-runs the same start closure) rejoins by
-        deserializing instead of recompiling — ``train_compile_us_max``
-        collapses from seconds to milliseconds and
-        ``tfos_compile_cache_hit`` counts the saves on ``/metrics``.
+        deserializing instead of recompiling — its compile debt
+        (``compile_cache_aot_compile_us`` + ``compile_cache_aot_load_us``
+        of :data:`compilecache.stats`) collapses from seconds to
+        milliseconds and ``tfos_compile_cache_hit`` counts the saves on
+        ``/metrics``.
         Falls back to the ``TFOS_COMPILE_CACHE_DIR`` env var; None with
         no env leaves the compile plane off.
     """

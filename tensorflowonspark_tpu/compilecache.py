@@ -4,19 +4,18 @@ AOT executables.
 The reference framework restarts a failed TF node cheaply because graph
 construction is fast; the jax_graft equivalent pays a full XLA recompile of
 every jitted step (and every serving bucket rung) on each elastic
-replacement, gateway restart, and bench leg.  This module makes that a
-one-time cost shared across runs and replicas (the tf.data fixed-cost
-amortization argument, arXiv:2101.12127), on two tiers:
+replacement and gateway restart.  This module makes that a one-time cost
+shared across runs and replicas (the tf.data fixed-cost amortization
+argument, arXiv:2101.12127), on two tiers:
 
 1. **Persistent compilation cache** (:func:`configure`): points JAX's
    ``jax_compilation_cache_dir`` at a cluster-shared directory resolved
    from :data:`JAX_CACHE_DIR_ENV` (wins when set) / cluster config /
-   :data:`CACHE_DIR_ENV`.  Every ``.compile()`` in
-   the process — trainer steps, serving rungs, ``estimate_step_cost``'s
-   canonical program — then reads/writes the disk cache, so a replacement
-   node's compiles collapse to deserialization.  Hit/miss/saved-time
-   counters are derived from jax's monitoring events and ride heartbeats
-   into the observatory as ``tfos_compile_cache_*``.
+   :data:`CACHE_DIR_ENV`.  Every ``.compile()`` in the process (trainer
+   steps, serving rungs) then reads/writes the disk cache, so a
+   replacement node's compiles collapse to deserialization.
+   Hit/miss/saved-time counters are derived from jax's monitoring events
+   and ride heartbeats into the observatory as ``tfos_compile_cache_*``.
 
 2. **AOT executable store** (:class:`AOTCache`): explicit
    ``jax.experimental.serialize_executable`` round trips, keyed by a
@@ -232,7 +231,7 @@ def configure(cache_dir=None, register_feed=True):
 
     Side effects on success: ``jax_compilation_cache_dir`` set (unless the
     environment placed it), the min-compile-time threshold dropped to 0
-    (CI/bench-scale programs compile in milliseconds — the default 1s gate
+    (CI-scale programs compile in milliseconds — the default 1s gate
     would exclude exactly the compiles the warm-rejoin story needs cached),
     monitoring listeners installed, the env var re-exported for forked
     children, and (``register_feed=True``) :data:`stats` registered as a

@@ -160,9 +160,8 @@ class DataFeed(object):
         self._chunk_q = None
         # Transport observability: {format: chunks seen} — wire.WIRE_COLV1
         # for zero-copy framed ring records, wire.WIRE_PICKLE for pickled
-        # ring records, "queue" for in-queue chunks.  The bench feedplane
-        # leg publishes this so a throughput number always names the wire
-        # format that produced it.
+        # ring records, "queue" for in-queue chunks: a throughput number
+        # can always name the wire format that produced it.
         self.wire_formats = {}
         # More always-on feed-plane tallies (plain numbers; snapshotted into
         # heartbeat payloads by the node runtime — see counters_snapshot):

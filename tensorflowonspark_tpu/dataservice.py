@@ -290,7 +290,7 @@ class _Job(object):
         self.assigned[split] = (worker_id, consumer_id)
         if self.mode == SHARD_DYNAMIC:
             # tallied for EVERY dynamic hand-out, affinity knob on or off,
-            # so the A/B bench can compare hit rates between the two
+            # so an A/B run can compare hit rates between the two
             self.affinity_total += 1
             if (worker_caches
                     and self.splits[split] in

@@ -8,8 +8,8 @@ training side already proved:
   ``max_wait_ms`` since the oldest queued request, whichever first).  The
   batch pads up to the :func:`serving.bucket_ladder` rung, and
   :meth:`ModelServer.warmup` AOT-compiles every rung at load time, so no
-  request ever pays a compile (``serving_compiles`` stays flat under load
-  — the ``train_compile_us`` convention).
+  request ever pays a compile (``serving_compiles`` stays flat under
+  load).
 * **admission control** — a *bounded* queue.  At ``max_queue`` pending
   requests new arrivals are shed immediately with a typed
   :class:`OverloadError` (code ``overload``); a request whose deadline
@@ -693,10 +693,9 @@ class GatewayServer(object):
     # -- metrics ------------------------------------------------------------
 
     def heartbeat_metrics(self):
-        """Flat counter/gauge dict piggybacked on each roster beat (and
-        polled directly by the bench leg).  Key suffixes follow the
-        observatory contract: ``_hwm``/``_max`` render as gauges, the rest
-        as monotonic counters."""
+        """Flat counter/gauge dict piggybacked on each roster beat.  Key
+        suffixes follow the observatory contract: ``_hwm``/``_max`` render
+        as gauges, the rest as monotonic counters."""
         with self._metrics_lock:
             lat = sorted(self._lat_us)
             depth_hwm = self._queue_depth_hwm

@@ -3,8 +3,9 @@
 First-class equivalent of the reference's ``TimeHistory`` callback and
 ``build_stats`` summary (reference ``examples/resnet/common.py:177-245``):
 per-N-step wall-clock logging, ``avg_exp_per_second``, and final stats —
-plus MFU (model FLOPs utilization), which the BASELINE targets are defined
-in terms of (BASELINE.md: >=50% MFU on v5e-16).
+plus MFU (model FLOPs utilization) where the model's owner states a step's
+FLOPs: that count over the device's published peak over device-synced step
+time.  Nothing here derives a count from a compiled program.
 """
 
 import json
@@ -27,23 +28,6 @@ PEAK_FLOPS = {
     "tpu v5p": 459e12,
     "tpu v6 lite": 918e12,   # v6e / trillium
     "tpu v6e": 918e12,
-}
-
-# Peak HBM bytes/s per chip for roofline accounting — same keying rules as
-# PEAK_FLOPS (full lowercased ``device_kind``, exact match).  Together the
-# two tables define the ridge point peak_flops/peak_bw: a step fn whose
-# arithmetic intensity (flops / bytes accessed) sits below it is
-# memory-bound and its honest ceiling is bw * intensity, not peak flops.
-PEAK_BYTES_PER_SEC = {
-    "tpu v2": 700e9,
-    "tpu v3": 900e9,
-    "tpu v4": 1228e9,
-    "tpu v5 lite": 819e9,
-    "tpu v5e": 819e9,
-    "tpu v5": 2765e9,        # v5p
-    "tpu v5p": 2765e9,
-    "tpu v6 lite": 1640e9,   # v6e / trillium
-    "tpu v6e": 1640e9,
 }
 
 
@@ -76,8 +60,8 @@ def mfu_from_step_time(step_flops, step_seconds):
 
     The exact formula :meth:`TimeHistory.mfu` applies (per-device FLOPs over
     per-device peak over step seconds) — exposed standalone so the runtime
-    accountant (``train.Trainer``) and the bench scripts compute the same
-    number from the same inputs.
+    accountant (``train.Trainer``) computes the same number from the same
+    inputs.
     """
     if not step_flops or not step_seconds or step_seconds <= 0:
         return None
@@ -90,10 +74,8 @@ def mfu_from_step_time(step_flops, step_seconds):
 def compression_ratio(raw_bytes, wire_bytes):
     """Wire compression ratio ``raw / wire`` (> 1 when the codec saved
     bytes; 1.0 when nothing compressed or either side is unknown, so
-    gauges and bench stats never divide by zero).  The one definition
-    shared by ``ServiceFeed.counters_snapshot``, the bench
-    ``dataservice_cached_epoch`` leg, and ``profile_feed.py`` — the same
-    single-formula contract as :func:`mfu_from_step_time`."""
+    gauges never divide by zero).  ``ServiceFeed.counters_snapshot``
+    publishes it as ``wire_compress_ratio_max``."""
     if not raw_bytes or not wire_bytes or wire_bytes <= 0:
         return 1.0
     return raw_bytes / float(wire_bytes)
@@ -120,85 +102,6 @@ def _device_peak(table, table_name):
 
 def peak_flops_per_device():
     return _device_peak(PEAK_FLOPS, "PEAK_FLOPS")
-
-
-def peak_bytes_per_sec_per_device():
-    return _device_peak(PEAK_BYTES_PER_SEC, "PEAK_BYTES_PER_SEC")
-
-
-def estimate_step_flops(jitted_fn, *args, **kwargs):
-    """Per-device FLOPs of one compiled step from XLA's cost analysis
-    (falls back to None).
-
-    XLA reports the cost of the post-SPMD-partitioning per-device module, so
-    on an N-device mesh this is ~1/N of the global step FLOPs — pair it with
-    the per-device peak (see :meth:`TimeHistory.mfu`)."""
-    return estimate_step_cost(jitted_fn, *args, **kwargs)["flops"]
-
-
-def estimate_step_cost(jitted_fn, *args, **kwargs):
-    """Cost-analyze one compiled step: per-device FLOPs, bytes accessed,
-    and the lower+compile wall time.
-
-    Returns ``{"flops": float|None, "bytes_accessed": float|None,
-    "compile_secs": float}``.  ``bytes accessed`` (the XLA key has a space)
-    is the cost model's total HBM traffic for the per-device module — the
-    denominator of the arithmetic intensity :func:`roofline` classifies on.
-    Both figures fall back to None when the backend has no cost model;
-    ``compile_secs`` is always real (it times the lower+compile even on a
-    failure path, where it reports the time spent failing)."""
-    t0 = time.perf_counter()
-    try:
-        compiled = jitted_fn.lower(*args, **kwargs).compile()
-        compile_secs = time.perf_counter() - t0
-        cost = compiled.cost_analysis()
-        return {
-            "flops": float(cost.get("flops", 0.0)) or None,
-            "bytes_accessed": float(cost.get("bytes accessed", 0.0)) or None,
-            "compile_secs": compile_secs,
-        }
-    except Exception:
-        logger.warning("cost analysis unavailable", exc_info=True)
-        return {"flops": None, "bytes_accessed": None,
-                "compile_secs": time.perf_counter() - t0}
-
-
-def roofline(step_flops, bytes_accessed, peak_flops=None, peak_bps=None):
-    """Roofline classification of one step fn.
-
-    Args are per-device figures (XLA cost analysis reports the partitioned
-    module).  ``peak_flops``/``peak_bps`` default to the local device's
-    table entries.  Returns None when any input is unknowable, else::
-
-        {"arithmetic_intensity": flops/byte,
-         "ridge_point":          peak_flops / peak_bps (flops/byte),
-         "bound":                "memory" | "compute",
-         "ceiling_flops_per_sec": min(peak_flops, intensity * peak_bps),
-         "ideal_step_seconds":   step_flops / ceiling}
-
-    ``ideal_step_seconds`` is the time the device MUST spend on this step
-    at the roofline ceiling — the device-compute bucket of the attribution
-    report; everything a measured step takes beyond it is starvation,
-    drain, collective time, or device inefficiency.
-    """
-    if not step_flops or not bytes_accessed:
-        return None
-    if peak_flops is None:
-        peak_flops = peak_flops_per_device()
-    if peak_bps is None:
-        peak_bps = peak_bytes_per_sec_per_device()
-    if not peak_flops or not peak_bps:
-        return None
-    intensity = step_flops / bytes_accessed
-    ridge = peak_flops / peak_bps
-    ceiling = min(peak_flops, intensity * peak_bps)
-    return {
-        "arithmetic_intensity": intensity,
-        "ridge_point": ridge,
-        "bound": "memory" if intensity < ridge else "compute",
-        "ceiling_flops_per_sec": ceiling,
-        "ideal_step_seconds": step_flops / ceiling,
-    }
 
 
 def device_memory_counters():
@@ -242,41 +145,6 @@ def device_memory_counters():
     return out
 
 
-#: Attribution bucket names, in report order.  The buckets decompose one
-#: measured wall duration on the step loop and always sum to 100%.
-ATTRIBUTION_BUCKETS = ("device_compute", "collective", "infeed_starved",
-                       "ckpt_drain", "unattributed")
-
-
-def attribute_step_time(measured_us, device_compute_us, collective_us=0.0,
-                        infeed_starved_us=0.0, ckpt_drain_us=0.0):
-    """Decompose ``measured_us`` of step-loop wall time into percentage
-    buckets that sum to exactly 100.
-
-    ``device_compute_us`` is the roofline-ideal device time
-    (steps * :func:`roofline` ``ideal_step_seconds``); ``collective_us``
-    estimated communication time; ``infeed_starved_us``/``ckpt_drain_us``
-    the goodput counters.  The remainder is ``unattributed`` — device
-    inefficiency plus host overhead the other buckets can't see.  When the
-    named buckets overshoot the measurement (clock skew, an optimistic
-    collective model) they are scaled down proportionally so the report
-    never claims more than 100% of the wall.  Returns None when
-    ``measured_us`` is not positive."""
-    measured = float(measured_us)
-    if measured <= 0:
-        return None
-    named = [max(float(v), 0.0) for v in (device_compute_us, collective_us,
-                                          infeed_starved_us, ckpt_drain_us)]
-    total_named = sum(named)
-    if total_named > measured:
-        scale = measured / total_named
-        named = [v * scale for v in named]
-        total_named = measured
-    parts = named + [measured - total_named]
-    return {"%s_pct" % name: 100.0 * v / measured
-            for name, v in zip(ATTRIBUTION_BUCKETS, parts)}
-
-
 class TimeHistory(object):
     """Per-N-step timing + throughput recorder (reference ``common.py:177``).
 
@@ -300,7 +168,7 @@ class TimeHistory(object):
 
         self.batch_size = batch_size
         self.log_steps = log_steps
-        self.step_flops = step_flops  # per-device FLOPs (post-partitioning)
+        self.step_flops = step_flops  # per-device model FLOPs, as stated
         self.num_devices = num_devices or len(jax.devices())
         # optional tensorflowonspark_tpu.summary.SummaryWriter: window
         # scalars (loss/throughput/MFU) land in TensorBoard (chief-only by
@@ -434,9 +302,9 @@ class TimeHistory(object):
             self.summary_writer.flush()
 
     def mfu(self, step_seconds):
-        # step_flops and peak are both per-device figures (XLA cost analysis
-        # reports the partitioned per-device module), so no num_devices term;
-        # delegated so the runtime accountant provably shares the formula.
+        # step_flops and peak are both per-device figures, so no num_devices
+        # term; delegated so the runtime accountant provably shares the
+        # formula.
         return mfu_from_step_time(self.step_flops, step_seconds)
 
     # -- summary (reference build_stats, common.py:202-245) ---------------

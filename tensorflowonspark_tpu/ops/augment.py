@@ -3,8 +3,7 @@
 The TPU-first half of the pre-decoded ImageNet path
 (``examples/resnet/imagenet_input.predecode_shards``): the host ships the
 stored ``store_px`` uint8 rows untouched (its only per-pixel work is one
-contiguous memcpy into the batch — measured 8k rows/s/core on a 1-core
-box, ``docs/PERF.md`` round 5) plus three tiny int vectors, and the crop
+contiguous memcpy into the batch) plus three tiny int vectors, and the crop
 window + flip happen HERE, fused into the training step where they are
 effectively free (a dynamic-slice and a reverse on data XLA already has
 in registers on its way into the conv).

@@ -524,8 +524,8 @@ class ShardedFeed(object):
     def wire_formats(self):
         """Transport/format counts the underlying feed observed, e.g.
         ``{"colv1": 120}`` when the zero-copy framed ring path carried every
-        chunk (see :attr:`~tensorflowonspark_tpu.datafeed.DataFeed.wire_formats`);
-        the bench feedplane leg records this next to its throughput."""
+        chunk (see
+        :attr:`~tensorflowonspark_tpu.datafeed.DataFeed.wire_formats`)."""
         return dict(getattr(self.feed, "wire_formats", None) or {})
 
     def terminate(self):

@@ -27,8 +27,8 @@ How a capture travels (no new connections, no new ports):
    message; :meth:`CaptureCoordinator.receive` lands them under
    ``profiles/<capture_id>/node-<executor_id>/`` on the driver and, when the
    last node reports, writes a ``capture.json`` manifest carrying the
-   cluster metrics snapshot (including the ``attrib_*`` attribution report)
-   so ``scripts/analyze_profile.py`` can merge + explain from one directory.
+   cluster metrics snapshot so ``scripts/analyze_profile.py`` can merge
+   from one directory.
 
 A ``profiling/capture_flow`` trace flow links trigger -> per-node capture ->
 collection on the merged Perfetto timeline (telemetry wall-clock-µs
@@ -376,9 +376,9 @@ class CaptureCoordinator(object):
         if stale:
             manifest["stale"] = True
             manifest["unreported"] = sorted(map(str, capture["pending"]))
-        # The cluster metrics snapshot (incl. the attrib_* attribution
-        # report) rides in the manifest so analyze_profile.py explains the
-        # timeline from one directory.
+        # The cluster metrics snapshot rides in the manifest so
+        # analyze_profile.py reads the timeline and the counters from one
+        # directory.
         try:
             manifest["metrics"] = self.server.metrics_snapshot()
         except Exception:

@@ -152,10 +152,10 @@ def tfrecord_iterator(path, use_native=True, verify_crc=True):
     guard against truncation) — for hot read paths over data this process
     tree wrote and verified at write time, e.g. the pre-decoded ImageNet
     rows, where the masked-crc pass costs more than the entire record
-    parse (measured 0.25 ms vs 0.05 ms on 196 KB rows, docs/PERF.md
-    round 5).  The native engine always verifies; skipping routes through
-    the python framing loop, which is FASTER than native-with-crc for
-    large records (one syscall-sized read per field, no per-byte work)."""
+    parse (0.25 ms against 0.05 ms on 196 KB rows, on a CPU dev box).  The
+    native engine always verifies; skipping routes through the python
+    framing loop, which is FASTER than native-with-crc for large records
+    (one syscall-sized read per field, no per-byte work)."""
     path = fsio.strip_file_scheme(path)
     lib = (_lib() if use_native and verify_crc
            and not fsio.is_remote(path) else None)

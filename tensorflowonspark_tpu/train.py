@@ -134,9 +134,13 @@ class Trainer(object):
         (auxes are not averaged — they may be arbitrary pytrees), so
         aux-derived metrics like accuracy sample 1/accum_steps of the
         batch; the loss itself IS the full-batch value.
+      step_flops_override: per-device MODEL FLOPs of one optimizer step,
+        stated by the model's owner from shapes (recomputed work under
+        rematerialization does not count) — the only source of the MFU and
+        achieved-FLOP/s figures.  Not given: none is reported.
       aot_cache: warm-start executable store — a directory path or a
-        :class:`~tensorflowonspark_tpu.compilecache.AOTCache`.  The step /
-        multi-step / repeat-scan programs are resolved through it: a
+        :class:`~tensorflowonspark_tpu.compilecache.AOTCache`.  The step
+        and multi-step programs are resolved through it: a
         fingerprint-matched serialized executable dispatches WITHOUT ever
         tracing (second-scale elastic rejoin); a cold store compiles once
         and persists for the next restart; any mismatch falls back to
@@ -160,7 +164,7 @@ class Trainer(object):
                  extra_state=None, compute_dtype=None, batch_size=None,
                  log_steps=20, donate=True, accum_steps=1,
                  summary_writer=None, param_sharding=None,
-                 extra_step_flops=0, step_flops_override=None,
+                 step_flops_override=None,
                  aot_cache=None, aot_program_version=None):
         self.mesh = mesh if mesh is not None else mesh_mod.build_mesh()
         self.loss_fn = loss_fn
@@ -172,22 +176,7 @@ class Trainer(object):
         # optional summary.SummaryWriter: window scalars -> TensorBoard
         # (create it on the chief only; see checkpoint.should_export)
         self.summary_writer = summary_writer
-        # Per-device FLOPs/step XLA's cost analysis cannot see — pallas
-        # kernels are custom calls with no cost model, so a flash-attention
-        # model's attention work would otherwise vanish from the MFU
-        # numerator (making the fused kernel look SLOWER per "reported"
-        # FLOP than the naive path it beats).  The model owner computes
-        # the analytic figure (e.g. bench.build_lm_trainer for the LM
-        # legs) and passes it here; added to the cost-analysis estimate
-        # when TimeHistory is built.
-        self.extra_step_flops = extra_step_flops
-        # Full replacement of the MFU numerator: MODEL FLOPs stated by the
-        # model owner.  XLA cost analysis prices the EXECUTED program —
-        # under rematerialization that includes the recomputed forward, so
-        # a remat model's cost-analysis MFU is inflated by work that isn't
-        # model progress.  When set, cost analysis is skipped entirely
-        # (extra_step_flops is ignored too: the override is the whole
-        # numerator).
+        # the MFU numerator and its only source (None: no MFU is reported)
         self.step_flops_override = step_flops_override
         self._has_extra = extra_state is not None
 
@@ -309,9 +298,6 @@ class Trainer(object):
                 state.params, state.extra, cast_batch(batch), mask)
             return apply_update(state, grads, loss, aux, new_extra)
 
-        # _plain_core: the accumulation-free full-batch step — the canonical
-        # unit that MFU accounting is defined on (see _ensure_history).
-        self._plain_core = train_step
         self._step_core = train_step if accum_steps == 1 else train_step_accum
         self._donate = (0,) if donate else ()
         # Every step program hands the state back laid out as it came in.
@@ -352,9 +338,9 @@ class Trainer(object):
         # and achieved-FLOP/s / MFU gauges.  Step timing comes from
         # TimeHistory's SYNCED window boundaries (dispatch wall alone
         # measures dispatch rate, not device time — see TimeHistory), so
-        # the gauges agree with the bench-side MFU computation by
-        # construction: both call metrics.mfu_from_step_time on the same
-        # step_flops and a device-synced clock.
+        # the gauges agree with TimeHistory.mfu by construction: both call
+        # metrics.mfu_from_step_time on the same step_flops and a
+        # device-synced clock.
         self._goodput_dispatch_us = 0
         self._goodput_infeed_starved_us = 0
         self._goodput_ckpt_drain_us = 0
@@ -369,14 +355,6 @@ class Trainer(object):
         self._flops_per_sec = None   # latest achieved per-device FLOP/s
         self._acct_history = None    # TimeHistory the accountant follows
         self._windows_seen = 0       # timestamp_log entries consumed
-        # Roofline/attribution inputs captured at compile time
-        # (_ensure_history): cost-analysis bytes accessed, lower+compile
-        # wall seconds, and the metrics.roofline() classification for the
-        # canonical step program.  None until the first compile / when the
-        # backend has no cost model.
-        self._step_bytes = None
-        self._compile_secs = None
-        self._roofline = None
         # Training-health telemetry, observed ONLY at TimeHistory window
         # boundaries (the one place the pipeline already syncs): last
         # finite loss / grad-norm gauges plus cumulative nonfinite tallies.
@@ -453,20 +431,8 @@ class Trainer(object):
         :data:`~tensorflowonspark_tpu.metrics.STEP_MS_BUCKETS`;
         ``train_mfu_pct_max`` / ``train_flops_per_sec_max`` are the latest
         window's gauges (``_max`` suffix -> merged by max, rendered as
-        Prometheus gauges).
-
-        Attribution report (once the first window closes):
-        ``attrib_<bucket>_pct_max`` for the buckets in
-        :data:`~tensorflowonspark_tpu.metrics.ATTRIBUTION_BUCKETS`,
-        decomposing the device-synced step-loop wall time accumulated so
-        far (``step_ms_sum_us``) into roofline-ideal device compute,
-        collective, infeed starvation, checkpoint drain, and the
-        unattributed remainder — always summing to 100 (see
-        :func:`~tensorflowonspark_tpu.metrics.attribute_step_time`).
-        The observatory renders them as ``tfos_attrib_*`` gauges.  Plus
-        ``train_compile_us_max`` (lower+compile wall time of the canonical
-        step) and ``train_step_bytes_max`` (cost-analysis bytes accessed
-        per step) when known.
+        Prometheus gauges): the stated ``step_flops_override`` over the
+        window's device-synced step time, absent when no count was stated.
 
         The router's load, for a model with ``TopKExperts`` layers only
         (summed over the steps the device has finished; a step in flight
@@ -511,10 +477,6 @@ class Trainer(object):
             snap["train_mfu_pct_max"] = round(self._mfu_pct, 4)
         if self._flops_per_sec is not None:
             snap["train_flops_per_sec_max"] = self._flops_per_sec
-        if self._compile_secs is not None:
-            snap["train_compile_us_max"] = int(self._compile_secs * 1e6)
-        if self._step_bytes:
-            snap["train_step_bytes_max"] = self._step_bytes
         # Training-health block (first window boundary onward):
         # train_health_windows boundary observations, train_loss_max /
         # train_grad_norm_max the last FINITE readings (gauges — never
@@ -534,10 +496,6 @@ class Trainer(object):
         if self._moe_pending or self._moe_totals:
             self._fold_moe()
             snap.update(self._moe_totals)   # the loss names them moe_*
-        attrib = self.attribution_report()
-        if attrib:
-            for name, pct in attrib.items():
-                snap["attrib_%s_max" % name] = round(pct, 4)
         return snap
 
     def apply_knob(self, name, value):
@@ -569,41 +527,12 @@ class Trainer(object):
         self._steps_per_call_req = max(int(value), 1)
         return True
 
-    def attribution_report(self):
-        """Decompose the closed-window step-loop wall time into the
-        :data:`~tensorflowonspark_tpu.metrics.ATTRIBUTION_BUCKETS`
-        percentage buckets (summing to exactly 100), or None before the
-        first window closes.
-
-        ``device_compute`` is the roofline-ideal time — closed-window steps
-        times the per-step floor the device cannot beat at the roofline
-        ceiling (:func:`~tensorflowonspark_tpu.metrics.roofline`); 0 when
-        the backend has no cost model.  ``collective`` is 0 today (no
-        per-collective timing source on a single-controller mesh — XLA
-        overlaps them with compute; the bucket exists so the report shape
-        is stable when a timing source lands).  ``infeed_starved`` /
-        ``ckpt_drain`` come from the cumulative goodput tallies (whole-run
-        figures, marginally wider than the closed-window wall — the
-        proportional-downscale rule in ``attribute_step_time`` keeps the
-        report honest).  ``unattributed`` is the remainder: device
-        inefficiency below the roofline ceiling plus host overhead the
-        other buckets cannot see — the bucket MFU work burns down."""
-        measured_us = self._step_ms_sum_us
-        if not measured_us:
-            return None
-        ideal = (self._roofline or {}).get("ideal_step_seconds")
-        device_us = self._step_ms_count * ideal * 1e6 if ideal else 0.0
-        return metrics_mod.attribute_step_time(
-            measured_us, device_us,
-            infeed_starved_us=self._goodput_infeed_starved_us,
-            ckpt_drain_us=self._goodput_ckpt_drain_us)
-
     def _account_windows(self):
         """Fold newly-closed TimeHistory windows into the step-time
         histogram and the MFU / achieved-FLOP/s gauges.  Window boundaries
         carry a forced device sync (see TimeHistory), so the per-step time
-        derived here is honest under async dispatch — the same clock the
-        bench-side ``build_stats`` MFU uses."""
+        derived here is honest under async dispatch — the same clock
+        ``TimeHistory.build_stats``' MFU uses."""
         hist = self.history
         if hist is None:
             return
@@ -696,10 +625,7 @@ class Trainer(object):
         only warn ("donated buffers were not usable") and change nothing.
         Stack handover is instead the dispatch-side deletion in
         :meth:`multi_step` (``donate_batches=True``)."""
-        key = k
-        if key not in self._multi_cache:
-            donate = self._donate
-
+        if k not in self._multi_cache:
             def multi(state, batches, masks):
                 def body(st, bm):
                     b, m = bm
@@ -713,33 +639,10 @@ class Trainer(object):
                 # addressable) arrays
                 return state, (losses, losses[-1],
                                losses.mean(), gnorms.mean())
-            self._multi_cache[key] = jax.jit(
-                multi, donate_argnums=donate,
+            self._multi_cache[k] = jax.jit(
+                multi, donate_argnums=self._donate,
                 out_shardings=(self._state_shardings, None))
-        return self._multi_cache[key]
-
-    def _get_repeat_step(self, k):
-        """Jitted program running ``k`` train steps over the SAME batch in
-        one dispatch (``lax.scan`` with no scanned inputs).  The synthetic-
-        benchmark counterpart of :meth:`multi_step` (reference benchmark
-        mode reuses one device-resident batch, ``common.py:315-363``);
-        returns the same on-device window reductions."""
-        key = ("repeat", k)
-        if key not in self._multi_cache:
-            def repeat(state, batch, mask):
-                def body(st, _):
-                    new_st, loss, packed = self._step_core(st, batch, mask)
-                    return new_st, (loss, packed[1])
-                state, (losses, gnorms) = jax.lax.scan(
-                    body, state, None, length=k)
-                # reductions inside jit (multi-host safety; see
-                # _get_multi_step)
-                return state, (losses, losses[-1],
-                               losses.mean(), gnorms.mean())
-            self._multi_cache[key] = jax.jit(
-                repeat, donate_argnums=self._donate,
-                out_shardings=(self._state_shardings, None))
-        return self._multi_cache[key]
+        return self._multi_cache[k]
 
     def set_aot_cache(self, cache):
         """Attach a warm-start AOT executable store (a directory path or
@@ -779,8 +682,8 @@ class Trainer(object):
                    "compute_dtype": str(self.compute_dtype),
                    "program_id": self._aot_program_id,
                    "program_version": self._aot_program_version,
-                   # output-structure revision of the loop programs (multi/
-                   # repeat grew on-device window reductions): a serialized
+                   # output-structure revision of the loop programs (multi
+                   # grew on-device window reductions): a serialized
                    # executable from an older revision would deserialize
                    # fine but return the old structure, so it must miss
                    "loop_rev": 2})
@@ -822,71 +725,16 @@ class Trainer(object):
                 self._aot_exec[name] = None
         return jit_fn(*args)
 
-    def _ensure_history(self, example_batch, example_mask, stacked=False):
-        """Lazily build the metrics recorder with per-step FLOPs.
-
-        FLOPs always come from cost-analyzing the CANONICAL program — the
-        accumulation-free full-batch single step (``_plain_core``) — never
-        the dispatched scan variant: XLA's HloCostAnalysis is inconsistent
-        about while/scan bodies (measured on one backend: an xs=None scan
-        counted its body once, a microbatch-accumulation scan counted it
-        per-trip), so deriving per-step cost from a scan program is
-        guesswork.  The canonical program is lowered with abstract inputs
-        (compile-only, never executed; the persistent compile cache dedups
-        it across processes).
-
-        ``stacked=True``: the examples carry a leading scan dim — strip it
-        into ShapeDtypeStructs sharded like a single fed batch."""
+    def _ensure_history(self):
+        """Build the metrics recorder on first use.  A step's FLOPs are the
+        stated ``step_flops_override`` or unknown: nothing is lowered or
+        compiled here."""
         if self.history is None:
-            if stacked:
-                shard = mesh_mod.batch_sharding(self.mesh)
-
-                def strip(x):
-                    return jax.ShapeDtypeStruct(x.shape[1:], x.dtype,
-                                                sharding=shard)
-
-                example_batch = jax.tree_util.tree_map(strip, example_batch)
-                example_mask = jax.tree_util.tree_map(strip, example_mask)
-            if self.step_flops_override is not None:
-                flops = self.step_flops_override
-            else:
-                cost = metrics_mod.estimate_step_cost(
-                    jax.jit(self._plain_core), self.state,
-                    example_batch, example_mask)
-                flops = cost["flops"]
-                self._step_bytes = cost["bytes_accessed"]
-                self._compile_secs = cost["compile_secs"]
-                # only supplement a SUCCESSFUL base estimate: when cost
-                # analysis is unavailable (returns None) the supplement
-                # alone would publish a confidently tiny MFU with the
-                # matmul work missing from the numerator — None (honestly
-                # unknown) is the right answer there
-                if self.extra_step_flops and flops:
-                    flops = flops + self.extra_step_flops
-                self._roofline = metrics_mod.roofline(flops,
-                                                      self._step_bytes)
             self.history = metrics_mod.TimeHistory(
                 batch_size=self.batch_size or 0, log_steps=self.log_steps,
-                step_flops=flops, summary_writer=self.summary_writer)
+                step_flops=self.step_flops_override,
+                summary_writer=self.summary_writer)
             self.history.on_train_begin()
-
-    def repeat_step(self, batch, mask, k):
-        """Run ``k`` steps on one batch in a single dispatch; returns the
-        final step's loss.  The full per-step loss vector (the scan's ys)
-        goes to the metrics recorder, so the TensorBoard curve keeps
-        per-step density; window boundaries sync only the O(1) on-device
-        loss mean, and the grad-norm mean buffers for the health gauges."""
-        fn = self._get_repeat_step(k)
-        self._ensure_history(batch, mask)
-        self.state, (losses, final, loss_mean, gnorm_mean) = \
-            self._aot_dispatch("repeat_%d" % k, fn,
-                               (self.state, batch, mask))
-        self._health_grad_norm = gnorm_mean
-        self._steps_per_call_gauge = k
-        self._steps_per_call_hwm = max(self._steps_per_call_hwm, k)
-        self._steps_total += k
-        self.history.on_steps_end(k, losses, window_value=loss_mean)
-        return final
 
     def multi_step(self, batches, masks, donate_batches=False):
         """Run K steps in one dispatch; ``batches``/``masks`` leaves carry a
@@ -910,7 +758,7 @@ class Trainer(object):
         :meth:`_get_multi_step`.)"""
         k = int(jax.tree_util.tree_leaves(masks)[0].shape[0])
         fn = self._get_multi_step(k)
-        self._ensure_history(batches, masks, stacked=True)
+        self._ensure_history()
         self.state, (losses, final, loss_mean, gnorm_mean) = \
             self._aot_dispatch("multi_%d" % k, fn,
                                (self.state, batches, masks))
@@ -977,28 +825,20 @@ class Trainer(object):
         weight_total = max(float(weight_total), 1.0)
         return {k: float(v) / weight_total for k, v in totals.items()}
 
-    def compile_and_measure(self, example_batch, example_mask):
-        """Lower/compile once and capture per-step FLOPs for MFU reporting."""
-        self._ensure_history(example_batch, example_mask)
-        return self.history.step_flops
-
     def reset_history(self):
-        """Replace the metrics recorder with a fresh one (same measured step
+        """Replace the metrics recorder with a fresh one (same stated step
         FLOPs), so compile/warmup steps don't pollute the reported stats.
         No-op before the first step."""
         if self.history is not None:
-            self.history = metrics_mod.TimeHistory(
-                batch_size=self.batch_size or 0, log_steps=self.log_steps,
-                step_flops=self.history.step_flops,
-                summary_writer=self.summary_writer)
-            self.history.on_train_begin()
+            self.history = None
+            self._ensure_history()
 
     def step(self, batch, mask=None):
         """Run one global step; returns (loss, aux)."""
         if mask is None:
             first = jax.tree_util.tree_leaves(batch)[0]
             mask = jnp.ones((first.shape[0],), jnp.float32)
-        self._ensure_history(batch, mask)
+        self._ensure_history()
         self.state, loss, packed = self._aot_dispatch(
             "step", self._train_step, (self.state, batch, mask))
         # apply_update rides the grad norm out next to the user aux; keep
@@ -1194,9 +1034,9 @@ class Trainer(object):
         else:
             stats = {}
         stats["overlap"] = overlap
-        # Megastep stamp: how this fit's dispatches were shaped — the bench
-        # legs and the CI gates copy this block into their evidence so every
-        # reported number says which engine produced it.
+        # Megastep stamp: how this fit's dispatches were shaped — the CI
+        # gates copy this block into their evidence so every reported
+        # number says which engine produced it.
         stats["megastep"] = {
             "steps_per_call": steps_per_call,
             "steps_per_call_last": self._steps_per_call_gauge or 1,

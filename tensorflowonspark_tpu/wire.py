@@ -74,7 +74,7 @@ MAGIC = b"TFWC"
 VERSION = 1
 
 # Wire-format tags carried by marker.ShmChunk tokens (and reported by
-# DataFeed.wire_formats / the bench feedplane leg):
+# DataFeed.wire_formats):
 WIRE_PICKLE = "pickle"   # pickled Chunk/ColChunk object bytes (legacy path)
 WIRE_COLV1 = "colv1"     # this module's columnar frame, version 1
 

@@ -52,13 +52,12 @@ import pytest  # noqa: E402
 
 @pytest.fixture
 def cpu_peaks(monkeypatch):
-    """Peaks for the CPU test device, passed explicitly.  The tables hold
+    """A peak for the CPU test device, passed explicitly.  The table holds
     accelerators only (a CPU run reports no utilization), so a test that
-    wants the MFU and roofline arithmetic to run puts its own rows in."""
+    wants the MFU arithmetic to run puts its own row in."""
     from tensorflowonspark_tpu import metrics
 
     monkeypatch.setitem(metrics.PEAK_FLOPS, "cpu", 1e11)
-    monkeypatch.setitem(metrics.PEAK_BYTES_PER_SEC, "cpu", 5e10)
 
 
 @pytest.hookimpl(hookwrapper=True)

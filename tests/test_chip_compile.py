@@ -53,7 +53,7 @@ def _compile(fn, *args):
     return compiled.as_text()
 
 
-# (batch, seq, heads, head_dim): the bench LM's shape, and one longer and
+# (batch, seq, heads, head_dim): chip_smoke's LM shape, and one longer and
 # wider point the model zoo allows
 SHAPES = [(8, 1024, 16, 64), (2, 4096, 8, 128)]
 
@@ -81,7 +81,7 @@ def test_flash_kernel_compiles_for_v5e(topo, kernel, shape):
 
 
 def test_lm_block_with_flash_compiles_for_v5e(topo, monkeypatch):
-    """One transformer block at the bench LM's widths (d_model 1024, 16x64
+    """One transformer block at chip_smoke's LM widths (d_model 1024, 16x64
     heads, seq 1024, batch 8, bf16), forward and backward: the kernel in
     its real surroundings (qkv projection layouts, the custom VJP)."""
     from tensorflowonspark_tpu.models import transformer

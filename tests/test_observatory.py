@@ -1,5 +1,6 @@
 """Observatory tests: Prometheus exposition conformance, scrape consistency
-under node death, and runtime-MFU vs bench-MFU agreement (CPU mesh)."""
+under node death, and runtime-MFU vs closed-window-MFU agreement (CPU
+mesh)."""
 
 import json
 import re
@@ -217,11 +218,12 @@ def _linear_loss(params, batch, mask):
 
 
 class TestRuntimeMfuAgreement:
-    def test_runtime_mfu_matches_bench_formula_within_5pct(self, cpu_peaks):
-        """The Trainer's runtime MFU gauge must agree with the bench's MFU
-        computation (TimeHistory.mfu over a closed window) within 5% on a
-        tiny jitted step — they share formula AND clock, so disagreement
-        means the accountant folded the wrong window."""
+    def test_runtime_mfu_matches_closed_window_mfu_within_5pct(
+            self, cpu_peaks):
+        """The Trainer's runtime MFU gauge must agree with TimeHistory.mfu
+        over a closed window within 5% on a tiny jitted step — they share
+        formula AND clock, so disagreement means the accountant folded the
+        wrong window."""
         mesh = build_mesh()
         # a matmul big enough that a 5-step window is not pure noise
         rng = np.random.RandomState(0)
@@ -237,9 +239,11 @@ class TestRuntimeMfuAgreement:
         batch = {"x": jax.device_put(x, sharding),
                  "y": jax.device_put(rng.rand(256).astype(np.float32),
                                      sharding)}
+        # the count, stated from shapes: 6 a parameter and example, per device
         tr = Trainer(loss_fn, {"w": w}, optax.sgd(0.01), mesh=mesh,
-                     batch_size=256, log_steps=5)
-        # bench procedure (_run_synthetic_leg): warm up, reset, measure
+                     batch_size=256, log_steps=5,
+                     step_flops_override=6 * 128 * 256 / mesh.size)
+        # warm up, reset, measure
         for _ in range(3):
             tr.step(batch)
         tr.reset_history()
@@ -253,9 +257,9 @@ class TestRuntimeMfuAgreement:
         log = tr.history.timestamp_log
         assert len(log) >= 2, log
         (s0, t0), (s1, t1) = log[-2], log[-1]
-        bench_mfu = tr.history.mfu((t1 - t0) / (s1 - s0))
-        assert bench_mfu is not None
-        assert runtime_mfu == pytest.approx(bench_mfu, rel=0.05)
+        window_mfu = tr.history.mfu((t1 - t0) / (s1 - s0))
+        assert window_mfu is not None
+        assert runtime_mfu == pytest.approx(window_mfu, rel=0.05)
         # achieved FLOP/s gauge agrees with the same window too
         assert snap["train_flops_per_sec_max"] == pytest.approx(
             metrics_mod.achieved_flops_per_sec(
@@ -270,13 +274,15 @@ class TestRuntimeMfuAgreement:
         assert cum[-1] <= snap["step_ms_count"]
 
     def test_whole_run_mfu_same_ballpark(self, cpu_peaks):
-        """build_stats' whole-run mfu (what bench.py publishes) and the
-        runtime gauge's latest-window mfu measure the same steady loop —
-        generous 2x band only to absorb CPU scheduler jitter."""
+        """build_stats' whole-run mfu and the runtime gauge's latest-window
+        mfu measure the same steady loop — generous 2x band only to absorb
+        CPU scheduler jitter."""
         mesh = build_mesh()
         params = {"w": jnp.zeros((2,)), "b": jnp.zeros(())}
+        # a count large enough that the gauge's four decimal places of a
+        # percent hold it on a slow box; its size is not what is compared
         tr = Trainer(_linear_loss, params, optax.sgd(0.01), mesh=mesh,
-                     batch_size=64, log_steps=5)
+                     batch_size=64, log_steps=5, step_flops_override=1e6)
         batch = {"x": jnp.ones((64, 2)), "y": jnp.ones((64,))}
         for _ in range(3):
             tr.step(batch)
@@ -288,8 +294,6 @@ class TestRuntimeMfuAgreement:
         tr._account_windows()
         stats = tr.history.build_stats(loss=float(loss))
         snap = tr.counters_snapshot()
-        if "mfu" not in stats or snap.get("train_mfu_pct_max") is None:
-            pytest.skip("no step_flops on this backend")
         runtime = snap["train_mfu_pct_max"] / 100.0
         assert stats["mfu"] / 2 <= runtime <= stats["mfu"] * 2, \
             (stats["mfu"], runtime)

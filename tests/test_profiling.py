@@ -1,6 +1,5 @@
-"""Device-plane profiling + attribution tests: the roofline accountant
-(``metrics``), the capture coordinator (``profiling``), and the pure-Python
-xplane decoder (``scripts/analyze_profile.py``).  All CPU, no sockets —
+"""Device-plane profiling tests: the device-memory counters (``metrics``),
+the capture coordinator (``profiling``), and the pure-Python xplane decoder (``scripts/analyze_profile.py``).  All CPU, no sockets —
 the coordinator is driven through a duck-typed fake reservation server."""
 
 import json
@@ -17,82 +16,7 @@ sys.path.insert(0, os.path.join(ROOT, "scripts"))
 import analyze_profile  # noqa: E402
 
 
-# -- roofline accountant -----------------------------------------------------
-
-
-class TestAttribution:
-    def test_buckets_sum_to_100(self):
-        report = metrics_mod.attribute_step_time(
-            1_000_000, 400_000, collective_us=100_000,
-            infeed_starved_us=200_000, ckpt_drain_us=50_000)
-        assert report["device_compute_pct"] == pytest.approx(40.0)
-        assert report["collective_pct"] == pytest.approx(10.0)
-        assert report["infeed_starved_pct"] == pytest.approx(20.0)
-        assert report["ckpt_drain_pct"] == pytest.approx(5.0)
-        assert report["unattributed_pct"] == pytest.approx(25.0)
-        assert sum(report.values()) == pytest.approx(100.0)
-
-    def test_overshoot_scales_down_proportionally(self):
-        # named buckets claim 2x the measured wall: scaled to fit, ratios
-        # preserved, nothing left unattributed
-        report = metrics_mod.attribute_step_time(
-            1_000_000, 1_500_000, infeed_starved_us=500_000)
-        assert report["device_compute_pct"] == pytest.approx(75.0)
-        assert report["infeed_starved_pct"] == pytest.approx(25.0)
-        assert report["unattributed_pct"] == pytest.approx(0.0)
-        assert sum(report.values()) == pytest.approx(100.0)
-
-    def test_not_positive_measurement_is_none(self):
-        assert metrics_mod.attribute_step_time(0, 10) is None
-        assert metrics_mod.attribute_step_time(-5, 10) is None
-
-    def test_negative_bucket_clamps_to_zero(self):
-        report = metrics_mod.attribute_step_time(100, -50)
-        assert report["device_compute_pct"] == 0.0
-        assert report["unattributed_pct"] == pytest.approx(100.0)
-
-
-class TestRoofline:
-    def test_memory_bound(self):
-        # intensity 1 flop/byte < ridge 10: memory-bound, ceiling = bw
-        r = metrics_mod.roofline(1e9, 1e9, peak_flops=1e12, peak_bps=1e11)
-        assert r["bound"] == "memory"
-        assert r["arithmetic_intensity"] == pytest.approx(1.0)
-        assert r["ridge_point"] == pytest.approx(10.0)
-        assert r["ceiling_flops_per_sec"] == pytest.approx(1e11)
-        assert r["ideal_step_seconds"] == pytest.approx(1e9 / 1e11)
-
-    def test_compute_bound(self):
-        r = metrics_mod.roofline(1e12, 1e9, peak_flops=1e12, peak_bps=1e11)
-        assert r["bound"] == "compute"
-        assert r["ceiling_flops_per_sec"] == pytest.approx(1e12)
-
-    def test_unknowable_inputs_are_none(self):
-        assert metrics_mod.roofline(None, 1e9, 1e12, 1e11) is None
-        assert metrics_mod.roofline(1e9, None, 1e12, 1e11) is None
-        assert metrics_mod.roofline(1e9, 1e9, peak_flops=1e12,
-                                    peak_bps=0) is None
-
-    def test_device_tables_feed_the_math(self, cpu_peaks):
-        # peaks default to the device's rows of the tables
-        assert metrics_mod.peak_bytes_per_sec_per_device() == 5e10
-        assert metrics_mod.roofline(1e6, 1e6) is not None
-
-
-def test_estimate_step_cost_smoke():
-    import jax
-    import jax.numpy as jnp
-
-    f = jax.jit(lambda x: (x @ x).sum())
-    cost = metrics_mod.estimate_step_cost(f, jnp.ones((16, 16)))
-    assert set(cost) == {"flops", "bytes_accessed", "compile_secs"}
-    assert cost["compile_secs"] > 0
-    # CPU backends may or may not expose a cost model; when they do, a
-    # 16x16 matmul has real flops and traffic
-    if cost["flops"] is not None:
-        assert cost["flops"] > 0
-    if cost["bytes_accessed"] is not None:
-        assert cost["bytes_accessed"] > 0
+# -- device-memory counters --------------------------------------------------
 
 
 def test_device_memory_counters_shape():
@@ -119,33 +43,6 @@ def test_device_memory_counters_without_backend_init(monkeypatch):
 
     monkeypatch.setattr(xb, "_backends", {})
     assert metrics_mod.device_memory_counters() == {}
-
-
-def test_trainer_emits_attrib_gauges():
-    import jax.numpy as jnp
-    import optax
-
-    from tensorflowonspark_tpu.parallel import build_mesh
-    from tensorflowonspark_tpu.train import Trainer
-
-    def loss(params, batch, mask):
-        pred = batch["x"] @ params["w"]
-        return ((pred - batch["y"]) ** 2).mean(), pred
-
-    tr = Trainer(loss, {"w": jnp.zeros((2,))}, optax.sgd(0.1),
-                 mesh=build_mesh())
-    assert tr.attribution_report() is None  # no closed windows yet
-    # simulate the accountant's closed-window tallies: 10 steps, 1s wall,
-    # roofline-ideal 40 ms/step, 100 ms infeed-starved
-    tr._step_ms_count = 10
-    tr._step_ms_sum_us = 1_000_000
-    tr._roofline = {"ideal_step_seconds": 0.040}
-    tr._goodput_infeed_starved_us = 100_000
-    snap = tr.counters_snapshot()
-    assert snap["attrib_device_compute_pct_max"] == pytest.approx(40.0)
-    assert snap["attrib_infeed_starved_pct_max"] == pytest.approx(10.0)
-    total = sum(v for k, v in snap.items() if k.startswith("attrib_"))
-    assert total == pytest.approx(100.0, abs=0.01)
 
 
 # -- capture plumbing --------------------------------------------------------
@@ -213,11 +110,9 @@ class _FakeServer:
 
     def metrics_snapshot(self):
         return {"nodes": {},
-                "aggregate": {"attrib_device_compute_pct_max": 40.0,
-                              "attrib_collective_pct_max": 0.0,
-                              "attrib_infeed_starved_pct_max": 10.0,
-                              "attrib_ckpt_drain_pct_max": 5.0,
-                              "attrib_unattributed_pct_max": 45.0}}
+                "aggregate": {"dispatch_count": 12,
+                              "goodput_dispatch_us": 40_000,
+                              "goodput_infeed_starved_us": 10_000}}
 
 
 def _coordinator(tmp_path, metas=None):
@@ -276,8 +171,7 @@ class TestCaptureCoordinator:
         assert manifest["capture_id"] == out["capture_id"]
         assert manifest["nodes"]["0"]["files"] == ["run/a.xplane.pb"]
         assert manifest["errors"] == {"1": "capture failed"}
-        assert "attrib_device_compute_pct_max" in manifest["metrics"][
-            "aggregate"]
+        assert manifest["metrics"]["aggregate"]["dispatch_count"] == 12
 
         # the capture is closed: a new trigger is admitted again
         assert coord.trigger()["capture_id"] != out["capture_id"]
@@ -369,7 +263,7 @@ class TestXplaneDecoder:
         assert x["ts"] == pytest.approx(1_000_002.0)
         assert x["dur"] == pytest.approx(5.0)
 
-    def test_merge_capture_and_attribution_table(self, tmp_path):
+    def test_merge_capture_into_one_timeline(self, tmp_path):
         cap = tmp_path / "cap-001"
         node = cap / "node-0" / "run"
         node.mkdir(parents=True)
@@ -392,9 +286,3 @@ class TestXplaneDecoder:
         names = {ev.get("name") for ev in merged["traceEvents"]}
         assert {"matmul.1", "host_span"} <= names  # one merged timeline
         assert merged["otherData"]["capture_id"] == "cap-001"
-
-        rows = analyze_profile.attribution_rows(manifest)
-        assert [b for b, _ in rows] == ["device_compute", "collective",
-                                        "infeed_starved", "ckpt_drain",
-                                        "unattributed"]
-        assert sum(p for _, p in rows) == pytest.approx(100.0)

@@ -32,40 +32,87 @@ The backend contract (used by :mod:`~tensorflowonspark_tpu.cluster`):
   that has no use for it (:class:`SparkBackend`) accepts and ignores it.
 
 **LocalBackend's pipe protocol.**  The driver sends an executor
-``(job, task_id, ahead, pickled_fn, partition_items)`` and gets back
+``(job, task_id, ahead, built, pickled_fn, partition_items)`` and gets back
 ``(job, task_id, ok, result_or_traceback)``; ``None`` shuts the executor
 down.  ``job`` is the driver's number for the ``JobHandle`` the task belongs
 to: two tasks may be outstanding on one pipe, so one reader a connection
-routes each reply to its own task.
+routes each reply to its own task.  ``built`` is the driver's monotonic
+clock when it began to build the message (one clock a machine): the
+executor times the hand-over from it (:func:`task_handover`).
+
+**What crosses the pipe, and what travels beside it.**  A message is
+pickled in protocol 5.  Contiguous buffers of ``_BESIDE_MIN`` bytes (64 KB)
+or more, which a numpy array offers and nothing else a partition commonly
+holds, are taken out of the pickle stream and laid end to end (each at the
+next multiple of 64 bytes) in one shared-memory segment of their size; what
+crosses the pipe is then a :class:`_Beside` (the in-band pickle, the
+buffers' lengths) and, behind it on the socket, the segment's descriptor.
+The executor's receiver thread maps the segment (shared, writable, its pages
+populated there and not by the task's first pass) and unpickles with
+``buffers=``: the task's arrays are views of the mapping (no copy; a task
+that writes to one writes to its own segment, which nobody else reads), and
+the mapping lives as long as anything in the executor references one of
+them.  So the bulk of a
+partition is copied once, by the kernel, into the segment, and never passes
+through ``write``/``read`` in socket-buffer pieces or through a pickle
+stream.  Smaller buffers, ``bytes``, scalars, arrays that are not
+contiguous, and everything a start task or a FILES feed sends (15 KB, 4 KB)
+stay in band: such a message crosses the pipe whole, in one send, as it
+always did.  **The fall-back is what the code observes**: where no segment
+can be made (no ``memfd_create``, no room), that message's buffers stay in
+band too.  The replies are not touched.
+
+**Who owns a segment: the driver.**  A segment is an anonymous memory file
+(``memfd_create``): it has no name, so nothing of it can be left under
+``/dev/shm`` whatever dies, in whatever order; its space is reserved before
+the first byte is written, so no room is an ``OSError`` when it is made (the
+fall-back above) and never a ``SIGBUS`` in a copy.  It is a task's own and
+never reused: a task that keeps a row past the arrival of the next
+partition still reads its own bytes.  The driver (``_Task.segment``) makes
+it when it builds the message and closes its descriptor when the task is
+answered (run, failed or skipped), when the executor died or was hung up on
+(running and waiting task alike), and at ``stop()``; the executor closes
+the descriptor it received as soon as the segment is mapped, and its
+mapping goes with the task's last row.  What no process holds is gone.
 
 **One task of look-ahead for feed jobs.**  An executor runs its tasks
 strictly one at a time, in the order they were dispatched, but a job that
 asked for look-ahead may have one task *waiting* in an executor while
 another of the same job *runs* there: the waiting task's message (for a
-feed job, the next partition: 100 MB in the benchmark's cell) is sent,
-received and unpickled by the executor's receiver thread while the running
-task feeds, instead of after it.  A free executor is always preferred, and
-one that is busy with another job's task may come free: nothing is sent
-ahead until every live executor works for this job.  There is never more
-than one waiting task an executor, and never one behind a task of another
-job.  The cost is one more partition resident in the executor while such a
-job runs (the one in hand, the one waiting).  What happens to a waiting
-task when things go wrong:
+feed job, the next partition: 100 MB in the benchmark's cell) is built,
+sent, received and unpickled by the executor's receiver thread while the
+running task feeds, instead of after it.  A free executor is always
+preferred, and one that is busy with another job's task may come free:
+nothing is sent ahead until every live executor works for this job.  There
+is never more than one waiting task an executor, and never one behind a
+task of another job.  The cost is one more partition resident while such a
+job runs (the one in hand, the one waiting), in shared memory for as long
+as it waits or runs.  What happens to a waiting task, and to its segment,
+when things go wrong:
 
 - the task before it fails: the waiting task is not run; the executor
-  answers ``task skipped: job cancelled after an earlier task failure``
-  (retryable, so a supervised ``train`` re-feeds both partitions once);
+  drops its rows (and with them its mapping) and answers ``task skipped:
+  job cancelled after an earlier task failure`` (retryable, so a supervised
+  ``train`` re-feeds both partitions once); the answer releases the
+  driver's hold like any other;
 - the executor dies: running and waiting task are both reported
-  ``executor N died ...`` (retryable);
+  ``executor N died ...`` (retryable); each task's thread closes its
+  segment as it reports, and the dead process's mappings went with it;
 - the executor is fenced (:meth:`LocalBackend.exclude`): nothing more is
-  sent ahead to it; a task already waiting runs, as one in flight does;
-- ``stop()``: the waiting task is dropped with the executor.
+  sent ahead to it; a task already waiting runs, as one in flight does, and
+  its segment goes when it is answered;
+- ``stop()``: the waiting task is dropped with the executor, and ``stop()``
+  closes the segment of every task still in flight.
 """
 
 import collections
+import errno
+import io
 import itertools
 import logging
+import mmap
 import os
+import pickle
 import queue as _queue
 import shutil
 import socket
@@ -77,6 +124,7 @@ import weakref
 
 import cloudpickle
 from multiprocessing import get_context
+from multiprocessing import reduction as _mp_reduction
 from multiprocessing import util as _mp_util
 
 logger = logging.getLogger(__name__)
@@ -208,31 +256,190 @@ class JobHandle(object):
 #: the dispatcher answers the job's unsent tasks with the same words).
 TASK_SKIPPED = "task skipped: job cancelled after an earlier task failure"
 
-#: How the task that this executor process is running came in: ``(ahead,
-#: ready)``, see :func:`task_handover`.  Written by ``_executor_main`` alone.
-_handover = (False, False)
+#: How the task that this executor process is running came in, see
+#: :func:`task_handover`.
+Handover = collections.namedtuple(
+    "Handover", "ahead ready oob_bytes inband_bytes us")
+
+#: Written by ``_executor_main`` alone.
+_handover = Handover(False, False, 0, 0, 0)
 
 
 def task_handover():
-    """``(ahead, ready)`` for the task this executor process is running:
-    ``ahead`` if its message had begun to arrive before the task before it
-    returned (the look-ahead engaged), ``ready`` if it was wholly unpickled
-    by then (the hand-over was hidden completely).  ``(False, False)``
-    outside a :class:`LocalBackend` executor and for an executor's first
-    task."""
+    """How the task this executor process is running came in, a
+    :class:`Handover`: ``ahead`` if its message had begun to arrive before
+    the task before it returned (the look-ahead engaged), ``ready`` if it
+    was wholly unpickled by then (the hand-over was hidden completely);
+    ``oob_bytes``, the bytes of its message that travelled beside the pipe
+    in a shared-memory segment, and ``inband_bytes``, the bytes that
+    crossed the pipe; ``us``, how long the hand-over took, from the moment
+    the driver began to build the message to the message whole in this
+    process (microseconds of the machine's monotonic clock).  ``ahead`` and
+    ``ready`` are false for an executor's first task; all five are false
+    or zero outside a :class:`LocalBackend` executor."""
     return _handover
+
+
+# ---------------------------------------------------------------------------
+# LocalBackend: a message's large buffers travel beside the pipe
+# ---------------------------------------------------------------------------
+
+#: A contiguous buffer of this many bytes or more leaves a message's pickle
+#: stream and travels in a shared-memory segment.  What a message with no
+#: arrays costs today is one ``send`` of a few kilobytes (a start task's is
+#: 15 KB, a feed task's closure 4.5 KB, the shutdown task's 4 KB), which the
+#: socket pair's buffer (208 KB by default) takes without waiting for the
+#: reader; a segment costs eight system calls whatever it holds.  So a
+#: buffer leaves the stream only where its bytes cost more than those calls
+#: (at the pipe's 117 MB/s, 64 KB is half a millisecond), and a message of
+#: small things still crosses the pipe in one piece, as it did.
+_BESIDE_MIN = 64 << 10
+
+#: Buffers lie in a segment at multiples of this, so that an array mapped
+#: from one is aligned as one that ``pickle`` allocated would be.
+_BESIDE_ALIGN = 64
+
+#: What crosses the pipe in place of a message whose large buffers travel
+#: beside it: the message's in-band pickle and the buffers' lengths (they
+#: lie in the segment in this order, each at the next multiple of
+#: ``_BESIDE_ALIGN``); the segment's descriptor follows on the socket.
+_Beside = collections.namedtuple("_Beside", "inband lengths")
+
+
+#: Linux's (Python 3.10 names it); elsewhere a mapping's pages come as they
+#: are touched.
+_MAP_POPULATE = getattr(mmap, "MAP_POPULATE", 0)
+
+
+def _layout(lengths):
+    """``(offsets, size)`` of buffers of ``lengths`` laid end to end."""
+    offsets, end = [], 0
+    for n in lengths:
+        offsets.append(end)
+        end += -(-n // _BESIDE_ALIGN) * _BESIDE_ALIGN
+    return offsets, end
+
+
+class _Segment(object):
+    """The driver's hold on one message's shared-memory segment: an
+    anonymous memory file (``memfd_create``: it has no name, so nothing of it
+    can be left under ``/dev/shm`` whatever dies) with ``buffers`` copied
+    into it.  Its space is reserved before the first byte is written, so no
+    room is an ``OSError`` here and never a ``SIGBUS`` in somebody's copy.
+    The executor gets a descriptor of its own over the socket pair and maps
+    it; the memory goes when the driver has closed its descriptor and the
+    executor's last row of it is gone."""
+
+    def __init__(self, buffers):
+        self.lengths = [raw.nbytes for raw in buffers]
+        offsets, self.size = _layout(self.lengths)
+        self._lock = threading.Lock()
+        if not hasattr(os, "memfd_create"):  # not Linux: the pipe, then
+            raise OSError(errno.ENOSYS, "no memfd_create on this platform")
+        self._fd = os.memfd_create("tfos-handover", os.MFD_CLOEXEC)
+        try:
+            os.posix_fallocate(self._fd, 0, self.size)
+            for raw, offset in zip(buffers, offsets):
+                while raw.nbytes:  # the kernel copies; the GIL is released
+                    n = os.pwrite(self._fd, raw, offset)
+                    raw, offset = raw[n:], offset + n
+        except BaseException:
+            self.close()
+            raise
+
+    def send(self, conn):
+        """Pass a descriptor of the segment over ``conn``'s socket."""
+        with self._lock:
+            if self._fd is None:
+                raise OSError("segment already released")
+            _mp_reduction.send_handle(conn, self._fd, None)
+
+    def close(self):
+        with self._lock:
+            fd, self._fd = self._fd, None
+        if fd is not None:
+            os.close(fd)
+
+
+def _dumps(obj, buffer_callback=None):
+    """``obj`` as ``Connection.send`` would pickle it, in protocol 5."""
+    out = io.BytesIO()
+    _mp_reduction.ForkingPickler(out, 5, True, buffer_callback).dump(obj)
+    return out.getbuffer()
+
+
+def _pack(msg):
+    """``(data, segment)`` for one message: the bytes that cross the pipe
+    and, where ``msg`` offered contiguous buffers of ``_BESIDE_MIN`` bytes
+    or more (a numpy array does) and a segment could be made, the segment
+    that holds them (the caller's to ``send`` after ``data`` and to
+    ``close``).  Else ``segment`` is None and ``data`` is the whole message,
+    as it always was: nothing large in it, or no shared memory to be had."""
+    buffers = []
+
+    def beside(buf):  # pickle's buffer_callback: true keeps it in band
+        try:
+            raw = buf.raw()
+        except BufferError:  # not contiguous
+            return True
+        if raw.nbytes < _BESIDE_MIN:
+            return True
+        buffers.append(raw)
+        return False
+
+    inband = _dumps(msg, beside)
+    if not buffers:
+        return inband, None
+    try:
+        segment = _Segment(buffers)
+    except OSError:  # no room, or no shared memory of this kind here
+        return _dumps(msg), None
+    return _dumps(_Beside(bytes(inband), segment.lengths)), segment
+
+
+def _unpack(conn):
+    """Read one message off ``conn``; returns ``(msg, oob_bytes,
+    inband_bytes)``.  Where a :class:`_Beside` came, the segment is mapped
+    and the message's arrays are views of the mapping, which lives as long
+    as anything in this process references one of them.  The mapping is
+    shared and writable (a task that writes to its rows writes to its own
+    segment, which nobody else reads: a private one would copy every page
+    it populates) and populated here, on the receiver thread: a fresh
+    mapping's page faults, one a 4 KB page, cost the task's first pass over
+    its rows more than the hand-over itself (166 ms against 110 for 100 MB
+    on the benchmark's host; populated, 6 ms)."""
+    data = conn.recv_bytes()
+    msg = pickle.loads(data)
+    if not isinstance(msg, _Beside):
+        return msg, 0, len(data)
+    fd = _mp_reduction.recv_handle(conn)
+    try:
+        offsets, size = _layout(msg.lengths)
+        view = memoryview(mmap.mmap(
+            fd, size, flags=mmap.MAP_SHARED | _MAP_POPULATE,
+            prot=mmap.PROT_READ | mmap.PROT_WRITE))
+    except OSError as e:  # not the pipe's end: a message that cannot be had
+        raise RuntimeError("cannot map a segment of {} bytes: {}".format(
+            size, e))
+    finally:
+        os.close(fd)
+    buffers = [view[o:o + n] for o, n in zip(offsets, msg.lengths)]
+    return (pickle.loads(msg.inband, buffers=buffers), sum(msg.lengths),
+            len(data))
 
 
 def _receive_tasks(conn, inbox, closing):
     """Executor's receiver thread: read and unpickle the driver's messages
-    while the main thread runs a task; stamp when each began to arrive and
-    when it was whole.  ``inbox`` holds at most one waiting task; at the
+    while the main thread runs a task; hand each over as ``(msg, began,
+    whole, oob_bytes, inband_bytes)``: when it began to arrive, when it was
+    whole, how it came.  ``inbox`` holds at most one waiting task; at the
     shutdown (``closing``) a task still waiting there is dropped, not run."""
     while True:
+        oob = inband = 0
         try:
             conn.poll(None)  # the first bytes of the next message
             began = time.monotonic()
-            msg = conn.recv()
+            msg, oob, inband = _unpack(conn)
         except (EOFError, OSError):
             msg = None
         except Exception as e:
@@ -246,21 +453,24 @@ def _receive_tasks(conn, inbox, closing):
             except _queue.Full:
                 pass  # it will see ``closing`` when it takes what waits
             return
-        inbox.put((msg, began, time.monotonic()))
+        inbox.put((msg, began, time.monotonic(), oob, inband))
         if isinstance(msg, Exception):
             return
+        # the main thread's alone now: a partition (and its segment's
+        # mapping) goes with its task, not when the next message arrives
+        msg = None
 
 
 def _executor_main(executor_index, workdir, conn, env_overrides):
     """Long-lived executor process: apply env, chdir, serve tasks over a pipe.
 
-    Tasks arrive as ``(job, task_id, ahead, pickled_fn, partition_items)``;
-    results return as ``(job, task_id, ok, result_or_traceback)``.  A
-    receiver thread reads and unpickles the messages, so a task sent ahead
-    arrives while the one before it runs; this thread runs them one at a
-    time, in arrival order.  Environment overrides are applied *before* any
-    task runs so that e.g. ``JAX_PLATFORMS`` is set before the first
-    ``import jax`` in user code.
+    Tasks arrive as ``(job, task_id, ahead, built, pickled_fn,
+    partition_items)``; results return as ``(job, task_id, ok,
+    result_or_traceback)``.  A receiver thread reads and unpickles the
+    messages, so a task sent ahead arrives while the one before it runs;
+    this thread runs them one at a time, in arrival order.  Environment
+    overrides are applied *before* any task runs so that e.g.
+    ``JAX_PLATFORMS`` is set before the first ``import jax`` in user code.
     """
     global _handover
     os.environ.update(env_overrides or {})
@@ -287,18 +497,21 @@ def _executor_main(executor_index, workdir, conn, env_overrides):
         arrival = inbox.get()
         if arrival is None or closing.is_set():  # backend shutdown
             break
-        msg, began, whole = arrival
+        msg, began, whole, oob, inband = arrival
         if isinstance(msg, Exception):
             raise msg
-        job, task_id, ahead, fn_bytes, items = msg
+        job, task_id, ahead, built, fn_bytes, items = msg
         del arrival
         if ahead and job == failed_job:
             # it waited here behind a task of its job that failed: not run
+            del msg, items  # its segment's mapping goes with them
             returned = time.monotonic()
             conn.send((job, task_id, False, TASK_SKIPPED))
             continue
-        _handover = ((began < returned, whole < returned)
-                     if returned is not None else (False, False))
+        _handover = Handover(
+            returned is not None and began < returned,
+            returned is not None and whole < returned,
+            oob, inband, max(0, int((whole - built) * 1e6)))
         try:
             fn = cloudpickle.loads(fn_bytes)
             result = fn(iter(items))
@@ -327,11 +540,21 @@ class _Task(object):
         self.items = items
         self.handle = handle
         self.ahead = False  # sent to wait behind a running task of its job
+        # the shared-memory segment its message's large buffers travel in
+        # (none: the message crosses the pipe whole), from the moment it is
+        # made to the task's last moment in ``_run_one``
+        self.segment = None
         self.after = None  # the ``sent`` of the task before it on the pipe
         self.sent = threading.Event()  # its message has left (or never will)
         # from the connection's reader: True once the reply has reached
         # ``handle``, False if the pipe ended first
         self.answered = _queue.SimpleQueue()
+
+    def release(self):
+        """Close the driver's hold on the task's segment, if it has one
+        (again does no harm)."""
+        if self.segment is not None:
+            self.segment.close()
 
 
 class LocalBackend(object):
@@ -508,11 +731,15 @@ class LocalBackend(object):
                 while task.after is not None and not task.after.wait(1.0):
                     if not proc.is_alive():
                         raise EOFError("executor process died")
+                data, task.segment = _pack(
+                    (task.job, task_id, task.ahead, time.monotonic(),
+                     task.fn_bytes, task.items))
                 with self._send_locks[executor_index]:
-                    conn.send((task.job, task_id, task.ahead, task.fn_bytes,
-                               task.items))
+                    conn.send_bytes(data)
+                    if task.segment is not None:
+                        task.segment.send(conn)
             finally:
-                task.items = None
+                task.items = data = None
                 task.sent.set()
             # wait with a LIVENESS poll, not a bare get: an executor whose
             # task spawned children (every node runtime forks a manager
@@ -546,6 +773,9 @@ class LocalBackend(object):
                 ),
             )
         finally:
+            # answered (run, failed or skipped), or never to be: the
+            # executor died, was hung up on, or the backend stopped
+            task.release()
             with self._cv:
                 queue = self._inflight[executor_index]
                 queue.remove(task)
@@ -622,7 +852,8 @@ class LocalBackend(object):
         job's next task is sent to wait in an executor that is running one
         of its tasks, so that the partition travels and is unpickled while
         the one before it is fed (see the module docstring).  It costs one
-        more partition resident in that executor."""
+        more partition resident (in shared memory, where its arrays travel
+        beside the pipe)."""
         handle = JobHandle(len(partitions))
         fn_bytes = cloudpickle.dumps(fn)
         job = next(self._job_ids)
@@ -702,6 +933,12 @@ class LocalBackend(object):
             self._hang_up(i)
         for reader in list(self._readers):
             reader.join(timeout=2)
+        # a task that waited in an executor went with it; its thread lets
+        # go of its segment in its own time, this does it now
+        with self._cv:
+            tasks = [task for queue in self._inflight for task in queue]
+        for task in tasks:
+            task.release()
         if self._owns_root:
             shutil.rmtree(self.workdir_root, ignore_errors=True)
 

@@ -1033,15 +1033,18 @@ def _publish_feeder_metrics(mgr, putter):
     manager mid-chaos) is swallowed: the counters are dropped, never the
     chunk or the task."""
     putter.clock.switch("between_tasks")
-    # how this task's partition came in (LocalBackend's look-ahead): zeros
-    # under Spark and for jobs that did not ask
-    ahead, ready = backend.task_handover()
+    # how this task's partition came in (LocalBackend's look-ahead, and what
+    # of the message travelled beside the pipe): zeros under Spark
+    handover = backend.task_handover()
     try:
         mgr.set("feeder_metrics", telemetry.merge_counters(
             [mgr.get("feeder_metrics"), putter.clock.delta("feeder_"),
              putter.counters_delta(),
-             {"feeder_tasks": 1, "feeder_tasks_ahead": int(ahead),
-              "feeder_tasks_ready": int(ready)}]))
+             {"feeder_tasks": 1, "feeder_tasks_ahead": int(handover.ahead),
+              "feeder_tasks_ready": int(handover.ready),
+              "feeder_handover_oob_bytes": handover.oob_bytes,
+              "feeder_handover_inband_bytes": handover.inband_bytes,
+              "feeder_handover_us": handover.us}]))
     except Exception as e:
         logger.debug("feeder metrics publish failed: %s", e)
 

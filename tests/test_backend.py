@@ -362,3 +362,309 @@ def test_stop_with_a_task_waiting(tmp_path):
         assert _threads_left(b) == []
     finally:
         b.stop()
+
+
+# ---------------------------------------------------------------------------
+# a message's large buffers travel beside the pipe, in a shared-memory
+# segment that the driver owns
+# ---------------------------------------------------------------------------
+
+SEGMENT = "memfd:tfos-handover"
+
+
+def _family(pid):
+    """``pid`` and every live descendant of it."""
+    parent_of = {}
+    for name in os.listdir("/proc"):
+        if name.isdigit():
+            try:
+                with open("/proc/{}/stat".format(name)) as f:
+                    parent_of[int(name)] = int(
+                        f.read().rsplit(")", 1)[1].split()[1])
+            except OSError:
+                pass    # it went meanwhile
+    family, grew = {pid}, True
+    while grew:
+        more = {p for p, parent in parent_of.items() if parent in family}
+        grew = not more <= family
+        family |= more
+    return family
+
+
+def _segments_held(pid):
+    """How many descriptors and mappings of hand-over segments ``pid``
+    holds (0 for a process that is gone)."""
+    held = 0
+    try:
+        for fd in os.listdir("/proc/{}/fd".format(pid)):
+            try:
+                held += SEGMENT in os.readlink(
+                    "/proc/{}/fd/{}".format(pid, fd))
+            except OSError:
+                pass
+        with open("/proc/{}/maps".format(pid)) as f:
+            held += sum(SEGMENT in line for line in f)
+    except OSError:
+        pass
+    return held
+
+
+def _segments_left(within=5.0):
+    """What this process and its descendants (the executors, their
+    children) still hold of hand-over segments ``within`` seconds from now
+    at the latest: ``{pid: count}``, empty if all is well."""
+    deadline = time.monotonic() + within
+    while True:
+        left = {pid: n for pid in _family(os.getpid())
+                for n in [_segments_held(pid)] if n}
+        if not left or time.monotonic() > deadline:
+            return left
+        time.sleep(0.05)
+
+
+@pytest.fixture(autouse=True)
+def no_segment_is_left():
+    """After every test of this file: no shared-memory object that a
+    hand-over made is held by this process, an executor or a child of one
+    (a memory file has no name: what no process holds is gone)."""
+    yield
+    assert _segments_left() == {}
+
+
+def _mixed_rows():
+    """Rows as users send them: arrays above the size that travels beside
+    the pipe (C and Fortran order, several dtypes, one read-only), small
+    ones, ``bytes``, scalars, an array that is not contiguous and one of
+    size zero.  Returns ``(rows, bytes_of_the_large_contiguous_arrays)``."""
+    import numpy as np
+
+    rng = np.random.default_rng(31)
+    image = rng.integers(0, 255, (256, 256, 3), dtype=np.uint8)
+    wide = rng.standard_normal((130, 131))               # float64, 136 KB
+    fortran = np.asfortranarray(
+        rng.standard_normal((96, 200)).astype(np.float32))
+    frozen = rng.integers(-5, 5, (40000,), dtype=np.int16)
+    frozen.flags.writeable = False
+    strided = rng.standard_normal((600, 300))[::2, ::3]  # 240 KB, gaps
+    small = np.arange(12, dtype=np.int32).reshape(3, 4)
+    empty = np.zeros((0, 7), np.float32)
+    rows = [(image, 7, b"label-7"), (wide, 2.5, None), (fortran, "text"),
+            (frozen, empty), (strided, small, b"x" * 100000), 41,
+            (image[:, :, 1].copy(), [1, 2, {"k": small}])]
+    large = [image, wide, fortran, frozen, image[:, :, 1]]
+    return rows, sum(a.nbytes for a in large)
+
+
+def _arrays(x):
+    """The numpy arrays in ``x``, in the order of its nesting."""
+    if isinstance(x, (list, tuple)):
+        return [a for item in x for a in _arrays(item)]
+    if isinstance(x, dict):
+        return [a for item in x.values() for a in _arrays(item)]
+    return [x] if hasattr(x, "flags") else []
+
+
+def _flags(x):
+    return [(a.flags.writeable, a.flags.c_contiguous, a.flags.f_contiguous,
+             a.flags.aligned) for a in _arrays(x)]
+
+
+def _same(got, want):
+    """Equal in values, dtypes, shapes, order and nesting."""
+    import numpy as np
+
+    if isinstance(want, np.ndarray):
+        return (isinstance(got, np.ndarray) and got.dtype == want.dtype
+                and got.shape == want.shape and np.array_equal(got, want))
+    if isinstance(want, (list, tuple)):
+        return (type(got) is type(want) and len(got) == len(want)
+                and all(_same(g, w) for g, w in zip(got, want)))
+    if isinstance(want, dict):
+        return (type(got) is dict and list(got) == list(want)
+                and all(_same(got[k], want[k]) for k in want))
+    return type(got) is type(want) and got == want
+
+
+def _echo(it):
+    """How the task's rows came in, what kind of arrays they are (whether
+    each can be written to, its order, its alignment), and the rows
+    themselves (they travel back in the reply)."""
+    from tensorflowonspark_tpu import backend
+
+    rows = list(it)
+    flags = _flags(rows)
+    for a in _arrays(rows):
+        if a.flags.writeable and a.size:
+            a.flat[0] = a.flat[0]   # its own segment, nobody else's
+    return [tuple(backend.task_handover()), flags, rows]
+
+
+@pytest.mark.parametrize("way", ["beside", "in_band"])
+def test_a_partition_of_arrays_arrives_as_it_was_sent(
+        one_executor, monkeypatch, way):
+    """(a): values, dtypes, shapes, order; ``task_handover`` says how it
+    came.  ``in_band`` is the fall-back the code takes by itself where no
+    segment can be made (no shared memory, no room): the message then
+    travels whole, as it always did."""
+    if way == "in_band":
+        def no_room(buffers):
+            raise OSError(28, "No space left on device")
+
+        monkeypatch.setattr(backend, "_Segment", no_room)
+    import pickle
+
+    rows, large = _mixed_rows()
+    (handover, flags, got), = one_executor.map_partitions([rows], _echo)
+    assert _same(got, rows)
+    # each array is what pickle alone would have made of it: read-only if
+    # it was, in its order, aligned
+    assert flags == _flags(pickle.loads(pickle.dumps(rows, 5)))
+    assert [f[0] for f in flags] == [True, True, True, False, True, True,
+                                     True, True, True]
+    came = backend.Handover(*handover)
+    rest = 240000 + 100000      # the array with gaps, the ``bytes``
+    everything = large + rest
+    if way == "beside":
+        assert came.oob_bytes == large
+        assert rest < came.inband_bytes < rest + 20000
+    else:
+        assert came.oob_bytes == 0
+        assert everything < came.inband_bytes < everything + 20000
+    assert not came.ahead and not came.ready    # nobody asked
+    assert 0 < came.us < 30e6
+
+
+def test_a_message_with_no_large_buffer_crosses_the_pipe_whole(one_executor):
+    """(b): what crosses the pipe is the message's own pickle, in one
+    piece, and no segment is made: arrays under the size, ``bytes`` of any
+    size, scalars, an array that is not contiguous."""
+    import pickle
+
+    import numpy as np
+
+    just_under = np.zeros(backend._BESIDE_MIN - 1, np.uint8)
+    strided = np.ones((400, 400))[:, ::2]
+    items = [just_under, b"y" * (1 << 20), 3, "s", strided]
+    msg = (0, 0, False, 1.5, b"fn", items)
+    data, segment = backend._pack(msg)
+    assert segment is None
+    assert _same(pickle.loads(data), msg)
+    (handover, _, got), = one_executor.map_partitions([items], _echo)
+    assert _same(got, items)
+    came = backend.Handover(*handover)
+    assert came.oob_bytes == 0
+    assert came.inband_bytes > (1 << 20) + just_under.nbytes + strided.nbytes
+    # one byte more in one array, and that array alone goes beside
+    data, segment = backend._pack(
+        (0, 0, False, 1.5, b"fn", [np.zeros(backend._BESIDE_MIN, np.uint8),
+                                   just_under]))
+    try:
+        assert segment.lengths == [backend._BESIDE_MIN]
+        assert isinstance(pickle.loads(data), backend._Beside)
+    finally:
+        segment.close()
+
+
+def _big(k, hold=0.0, fail=False, kill=False):
+    """A partition of two 256 KB arrays filled with ``k``."""
+    import numpy as np
+
+    return [np.full((1 << 16,), k, np.int32), np.full((1 << 18,), k, np.uint8),
+            {"hold": hold, "fail": fail, "kill": kill}]
+
+
+def _check_big(it):
+    import os
+    import signal
+    import time
+
+    a, b, todo = it
+    time.sleep(todo["hold"])
+    if todo["kill"]:
+        os.kill(os.getpid(), signal.SIGKILL)
+    if todo["fail"]:
+        raise ValueError("boom in a big task")
+    assert (a == a[0]).all() and (b == a[0]).all()
+    return [int(a[0])]
+
+
+@pytest.mark.parametrize(
+    "case", ["answered", "skipped", "killed", "excluded", "stopped"])
+def test_no_segment_outlives_its_task(tmp_path, case):
+    """(c): the driver owns the segment and lets go of it when the task is
+    answered, when a waiting task was skipped after the failure of the one
+    before it, when the executor was killed with one task running and one
+    waiting, after ``exclude``, and at ``stop()`` with a task waiting.
+    While two tasks are in flight the driver holds two (so the probe can
+    see one), and a task's mapping goes with its rows."""
+    b = backend.LocalBackend(1, workdir_root=str(tmp_path))
+    try:
+        first = dict(hold=0.5, fail=case == "skipped", kill=case == "killed")
+        handle = b.foreach_partition_async(
+            [_big(0, **first), _big(1), _big(2)], _check_big,
+            look_ahead=True)
+        executor = b._procs[0].pid
+        deadline = time.monotonic() + 10
+        while (not (_segments_held(os.getpid()) == 2
+                    and _segments_held(executor))
+               and time.monotonic() < deadline):
+            time.sleep(0.01)    # task 0 runs, task 1 waits behind it
+        assert _segments_held(os.getpid()) == 2
+        assert _segments_held(executor) >= 1
+        if case == "excluded":
+            b.exclude(0)
+        if case == "stopped":
+            b.stop()
+        else:
+            handle.wait_settled(30)
+        errors = dict(handle.failed_tasks())
+        if case == "answered":
+            assert handle.results == [[0], [1], [2]]
+        elif case == "skipped":
+            assert errors[1] == errors[2] == backend.TASK_SKIPPED
+        elif case == "killed":
+            assert sorted(errors) == [0, 1, 2]
+        elif case == "excluded":
+            assert handle.results[:2] == [[0], [1]] and sorted(errors) == [2]
+        if case == "stopped":
+            assert _threads_left(b) == []
+        assert _segments_left() == {}
+        if case in ("answered", "skipped", "excluded"):
+            assert b._procs[0].is_alive()   # and it holds no mapping
+    finally:
+        b.stop()
+    assert _segments_left() == {}
+
+
+def test_a_kept_row_outlives_the_next_partitions_arrival(one_executor):
+    """(d): a segment is a task's own, never reused: a task that keeps a
+    row past the arrival of the next partitions still reads its own bytes,
+    and each kept row keeps its own mapping alive."""
+
+    def keep(it):
+        import builtins
+        import time
+
+        a, b, _ = it
+        kept = builtins.__dict__.setdefault("_kept_rows", [])
+        kept.append(a)
+        time.sleep(0.2)         # the next partition arrives meanwhile
+        with open("/proc/self/maps") as f:
+            mapped = sum("memfd:tfos-handover" in line for line in f)
+        return [[(int(r[0]), int(r.min()), int(r.max())) for r in kept],
+                mapped]
+
+    def forget(it):
+        import builtins
+
+        del builtins.__dict__["_kept_rows"][:]
+        return []
+
+    runs = one_executor.foreach_partition_async(
+        [_big(k + 5) for k in range(4)], keep, look_ahead=True).wait(30)
+    for k, (kept, mapped) in enumerate(runs):
+        assert kept == [(j + 5,) * 3 for j in range(k + 1)]
+        # the kept rows' segments, this task's, and the next one's if it
+        # has arrived
+        assert k + 1 <= mapped <= k + 2
+    one_executor.map_partitions([[0]], forget)

@@ -162,6 +162,69 @@ def test_look_ahead_counters_of_a_two_partition_train(streaming, ahead):
     assert 0 < in_tasks <= wall_us, (snap, wall_us)
 
 
+HANDOVER = ["feeder_handover_oob_bytes", "feeder_handover_inband_bytes",
+            "feeder_handover_us"]
+
+
+@pytest.mark.parametrize("side", [128, 16])
+def test_handover_counters_of_a_two_partition_train(side):
+    """``LocalBackend`` says how each feed task's partition came in, and
+    the feeder publishes it once a task with its other counters.  Rows of
+    64 KB (``side`` 128) travel beside the pipe in a shared-memory segment,
+    rows of 1 KB cross it in band; either way the counters of a
+    two-partition ``train`` are the two tasks' added up."""
+    rows, parts = 16, 2
+    data = [(np.full((side, side), i, np.float32), i) for i in range(rows)]
+    row_bytes = side * side * 4
+    b = backend.LocalBackend(1)
+    try:
+        c = cluster.run(b, _consume_and_snapshot, {"batch": 8},
+                        num_executors=1, input_mode=InputMode.SPARK)
+        t0 = time.monotonic()
+        c.train(backend.partition(data, parts), chunk_size=4)
+        wall_us = (time.monotonic() - t0) * 1e6
+        c.shutdown(grace_secs=1)
+        with open(os.path.join(b.workdir_root, "executor-0",
+                               "snapshot.json")) as f:
+            snap = json.load(f)
+    finally:
+        b.stop()
+    assert snap["feed_items"] == snap["feeder_items"] == rows
+    assert snap["feeder_tasks"] == parts
+    for key in HANDOVER:
+        assert isinstance(snap[key], int), key
+    if row_bytes >= backend._BESIDE_MIN:
+        assert snap["feeder_handover_oob_bytes"] == rows * row_bytes
+        # the task's closure, the labels, the arrays' headers: twice
+        assert 2 * 2000 < snap["feeder_handover_inband_bytes"] < 2 * 20000
+    else:
+        assert snap["feeder_handover_oob_bytes"] == 0
+        assert (rows * row_bytes + 2 * 2000
+                < snap["feeder_handover_inband_bytes"]
+                < rows * row_bytes + 2 * 20000)
+    # two hand-overs, each shorter than the whole call
+    assert 0 < snap["feeder_handover_us"] < 2 * wall_us
+
+
+def test_handover_counters_are_zero_outside_a_local_backend(harness):
+    """A feed task that no ``LocalBackend`` executor runs (Spark's Python
+    worker; here, this process) publishes the three hand-over counters as
+    zeros, with the others: the hand-over was somebody else's."""
+    feed = DataFeed(harness.mgr)
+    harness.consume(feed)
+    fn = node.train(harness.cluster_info, harness.meta, chunk_size=4)
+    big = [(np.full((128, 128), i, np.float32), i) for i in range(8)]
+    assert fn(iter(big)) == [8]
+    published = harness.mgr.get("feeder_metrics")
+    assert published["feeder_tasks"] == 1 and published["feeder_items"] == 8
+    assert [published[key] for key in HANDOVER] == [0, 0, 0]
+    assert tuple(backend.task_handover()) == (False, False, 0, 0, 0)
+    snap = feed.counters_snapshot()
+    assert [snap[key] for key in HANDOVER] == [0, 0, 0]
+    harness.finish(feed)
+    assert harness.errors == []
+
+
 # ---------------------------------------------------------------------------
 # the feeder in this process: the identity against its own clock
 # ---------------------------------------------------------------------------

@@ -33,12 +33,25 @@ configuration:
   attention with per-head q/k RMSNorm and RoPE for each layer,
   ``num_dense_layers`` leading SwiGLU feed-forwards and then
   :class:`TopKExperts` (top-k of E by sigmoid scores with a selection bias,
-  nothing dropped, told which experts it holds).
+  nothing dropped, told which experts it holds);
+- :func:`deepseek_v2_spec` (registered as ``deepseek_v2``): the DeepSeek-V2
+  family's ``config.json`` without ``q_lora_rank``: RMSNorm, latent
+  attention (``op="mla"``: a down-projection to a ``kv_lora_rank`` latent
+  and one shared rotary key, an RMSNorm on the latent, an up-projection to
+  per-head keys and values narrower than the scores' 192 dimensions, YaRN
+  frequencies with interleaved pairing on the rotary part only, the
+  family's softmax scale), ``first_k_dense_replace`` leading SwiGLU
+  feed-forwards and then :class:`TopKExperts` by softmax scores without a
+  bias leaf and without renormalisation, beside a shared SwiGLU that every
+  token passes through, and a read-out of its own (``tied_readout=False``).
 
 Scopes a device trace can be read by (``jax.named_scope`` under the flax
 module names): ``block_i/short_conv``, ``block_i/attention/flash``,
+``block_i/attention/latent`` (everything latent attention puts round the
+kernel: the three projections, the latent's norm, RoPE, building K),
 ``block_i/moe/route`` (router, top-k, sort), ``moe/dispatch`` (gather),
-``moe/experts`` (the grouped products), ``moe/combine`` (scale, gather back).
+``moe/experts`` (the grouped products), ``moe/combine`` (scale, gather back),
+``moe/shared`` (the shared expert).
 """
 
 import dataclasses
@@ -58,6 +71,7 @@ class LayerSpec:
     kinds, and the widths they need."""
 
     op: str = "attention"          # attention | conv (gated short convolution)
+    #                                | mla (latent attention, see below)
     ff: str = "gelu"               # gelu | switch (top-1, capacity: MoEMlp)
     #                                | swiglu | experts (top-k: TopKExperts)
     norm: str = "layernorm"        # layernorm | rmsnorm
@@ -72,6 +86,20 @@ class LayerSpec:
     num_kv_heads: Optional[int] = None
     qk_norm: bool = False          # per-head RMSNorm on q and on k
     rope_theta: float = 10000.0
+    rope_pairing: str = "half"     # half: dimension i turns with i + D/2
+    #                                | interleaved: 2i with 2i + 1
+    # YaRN: (factor, original_max_position_embeddings, beta_fast, beta_slow,
+    # mscale, mscale_all_dim); None: the plain frequencies of rope_theta
+    rope_yarn: Optional[Tuple[float, ...]] = None
+    # op="mla": q is num_heads x (nope_dim + rope_dim) straight from x; k's
+    # nope_dim and v's v_dim a head come up from an RMSNormed latent of
+    # kv_rank, k's rope_dim is one rotary key shared by all heads; RoPE on
+    # the rope_dim parts only; scores times attn_scale (None: 1/sqrt(width))
+    kv_rank: int = 0
+    nope_dim: int = 0
+    rope_dim: int = 0
+    v_dim: int = 0
+    attn_scale: Optional[float] = None
     flash_block: int = 128         # q and k block of attention="flash"
     conv_kernel: int = 3
     ff_size: int = 0               # the dense feed-forward's width
@@ -82,15 +110,20 @@ class LayerSpec:
     # weights this layer holds (one chip's share under expert parallelism);
     # None holds them all
     held_experts: Optional[Tuple[int, int]] = None
+    router_score: str = "sigmoid"  # sigmoid | softmax (over all the experts)
+    selection_bias: bool = True    # an expert_bias leaf in the top-k's choice
     norm_topk: bool = True
     routed_scaling: float = 1.0
+    shared_size: int = 0           # a shared SwiGLU's width beside the
+    #                                routed experts; 0 = none
     capacity_factor: float = 1.25  # ff="switch"
 
 
 @dataclasses.dataclass(frozen=True)
 class DecoderSpec:
     """The whole decoder: embedding, optional position table, the layers,
-    the final norm, a read-out tied to the embedding."""
+    the final norm, and the read-out: the embedding's transpose, or a
+    ``head`` matrix of its own."""
 
     vocab_size: int
     hidden_size: int
@@ -98,6 +131,7 @@ class DecoderSpec:
     learned_positions: int = 0     # rows of the position table; 0 = none
     norm: str = "layernorm"        # the final norm
     norm_eps: float = 1e-6
+    tied_readout: bool = True      # False: a ``head`` leaf [hidden, vocab]
 
 
 def gpt2_layer(num_heads, head_dim, mlp="dense", mlp_ratio=4, num_experts=8,
@@ -160,21 +194,130 @@ def lfm2_moe_spec(config):
                        norm="rmsnorm", norm_eps=config["norm_eps"])
 
 
+def yarn_mscale(scale, mscale):
+    """YaRN's attention factor ``0.1 * mscale * ln(scale) + 1`` (1 where the
+    context is not stretched)."""
+    import math
+
+    return 1.0 if scale <= 1 else 0.1 * mscale * math.log(scale) + 1.0
+
+
+def deepseek_v2_spec(config):
+    """:class:`DecoderSpec` of a DeepSeek-V2 ``config.json`` without a query
+    latent (a dict with the source's keys: ``kv_lora_rank``,
+    ``qk_nope_head_dim``, ``qk_rope_head_dim``, ``v_head_dim``,
+    ``rope_scaling``, ``first_k_dense_replace``, ``n_shared_experts``,
+    ``scoring_func``, ...).  ``n_routed_experts`` is the router's width;
+    ``held_experts`` (``[first, count]``, optional) the experts this program
+    holds of each expert layer; ``flash_block`` (optional) the attention
+    kernel's block.  What the family's modelling code does and no key says:
+    interleaved RoPE pairing; a softmax scale of ``(nope + rope) ** -0.5``
+    times YaRN's factor of ``mscale_all_dim``, squared; cos and sin times
+    the ratio of the factors of ``mscale`` and ``mscale_all_dim``."""
+    unsupported = {
+        "q_lora_rank": config.get("q_lora_rank") is not None,
+        "topk_method": config.get("topk_method", "greedy") != "greedy",
+        "n_group": config.get("n_group", 1) != 1,
+        "moe_layer_freq": config.get("moe_layer_freq", 1) != 1,
+        "scoring_func": config.get("scoring_func", "softmax")
+        not in ("softmax", "sigmoid"),
+        "rope_scaling": (config.get("rope_scaling") or {"type": "yarn"})[
+            "type"] != "yarn"}
+    if any(unsupported.values()):
+        raise ValueError("deepseek_v2: no support for this config's {}"
+                         .format(sorted(k for k, v in unsupported.items()
+                                        if v)))
+    held = config.get("held_experts")
+    nope, rot = config["qk_nope_head_dim"], config["qk_rope_head_dim"]
+    scale, yarn = (nope + rot) ** -0.5, None
+    scaling = config.get("rope_scaling")
+    if scaling:
+        yarn = tuple(float(scaling[k]) for k in (
+            "factor", "original_max_position_embeddings", "beta_fast",
+            "beta_slow", "mscale", "mscale_all_dim"))
+        scale *= yarn_mscale(yarn[0], yarn[5]) ** 2
+    common = dict(
+        op="mla", norm="rmsnorm", norm_eps=config["rms_norm_eps"],
+        positions="rope", num_heads=config["num_attention_heads"],
+        head_dim=nope + rot, kv_rank=config["kv_lora_rank"], nope_dim=nope,
+        rope_dim=rot, v_dim=config["v_head_dim"],
+        rope_theta=float(config["rope_theta"]), rope_pairing="interleaved",
+        rope_yarn=yarn, attn_scale=scale,
+        flash_block=config.get("flash_block", 512),
+        ff_size=config["intermediate_size"],
+        num_experts=config["n_routed_experts"],
+        experts_per_token=config["num_experts_per_tok"],
+        expert_size=config["moe_intermediate_size"],
+        held_experts=tuple(held) if held else None,
+        router_score=config.get("scoring_func", "softmax"),
+        selection_bias=False,
+        norm_topk=config.get("norm_topk_prob", False),
+        routed_scaling=float(config.get("routed_scaling_factor", 1.0)),
+        shared_size=(config.get("n_shared_experts") or 0)
+        * config["moe_intermediate_size"])
+    layers = tuple(
+        LayerSpec(ff="swiglu" if i < config["first_k_dense_replace"]
+                  else "experts", **common)
+        for i in range(config["num_hidden_layers"]))
+    return DecoderSpec(vocab_size=config["vocab_size"],
+                       hidden_size=config["hidden_size"], layers=layers,
+                       norm="rmsnorm", norm_eps=config["rms_norm_eps"],
+                       tied_readout=config.get("tie_word_embeddings", False))
+
+
 def _norm(kind, eps, dtype):
     if kind == "rmsnorm":
         return nn.RMSNorm(epsilon=eps, dtype=dtype)
     return nn.LayerNorm(epsilon=eps, dtype=dtype)
 
 
-def rope(x, theta):
-    """Rotary positions 0..S-1 on ``x [B, S, H, D]``, rotate-half pairing
-    (dimension i with i + D/2), angles in float32."""
-    half = x.shape[-1] // 2
+def rope_frequencies(dim, theta, yarn=None):
+    """``(inv [dim / 2] float32, factor)``: the angle a position of pair
+    ``i``, and what cos and sin are multiplied by.  Plain: ``theta ** (-2i /
+    dim)`` and 1.  ``yarn = (factor, original_max, beta_fast, beta_slow,
+    mscale, mscale_all_dim)``: pairs that turn more than ``beta_fast`` times
+    over the original context keep the plain frequency, those that turn less
+    than ``beta_slow`` times take it divided by ``factor``, a linear ramp
+    over the pair index between the two; cos and sin times the ratio of
+    :func:`yarn_mscale` of ``mscale`` and of ``mscale_all_dim``."""
+    import math
+
+    half = dim // 2
     inv = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    if yarn is None:
+        return inv, 1.0
+    factor, original, beta_fast, beta_slow, mscale, mscale_all = yarn
+
+    def pair_turning(times):    # the pair index that turns so often
+        return dim * math.log(original / (times * 2 * math.pi)) / (
+            2 * math.log(theta))
+
+    low = max(math.floor(pair_turning(beta_fast)), 0)
+    high = min(math.ceil(pair_turning(beta_slow)), dim - 1)
+    ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - low)
+                    / max(high - low, 0.001), 0.0, 1.0)
+    return (inv / factor * ramp + inv * (1.0 - ramp),
+            yarn_mscale(factor, mscale) / yarn_mscale(factor, mscale_all))
+
+
+def rope(x, inv, pairing="half", factor=1.0):
+    """Rotary positions 0..S-1 on ``x [B, S, H, D]`` at the frequencies
+    ``inv [D / 2]`` (:func:`rope_frequencies`), angles in float32.
+    ``pairing="half"``: dimension i turns with i + D/2.  ``"interleaved"``:
+    dimension 2i turns with 2i + 1, and the turned pairs come out in the
+    half layout (all first members, then all second: the DeepSeek family's
+    code does the same; q and k are permuted alike, so scores are those of
+    pairs turned in place)."""
+    half = x.shape[-1] // 2
     angle = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * inv[None]
     cos, sin = jnp.cos(angle)[None, :, None], jnp.sin(angle)[None, :, None]
-    x1, x2 = x[..., :half].astype(jnp.float32), x[..., half:].astype(
-        jnp.float32)
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
+    if pairing == "interleaved":
+        x1, x2 = x[..., 0::2], x[..., 1::2]
+    else:
+        x1, x2 = x[..., :half], x[..., half:]
+    x1, x2 = x1.astype(jnp.float32), x2.astype(jnp.float32)
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
                            axis=-1).astype(x.dtype)
 
@@ -191,11 +334,47 @@ class Attention(nn.Module):
     norm_eps: float = 1e-6
     rope_theta: Optional[float] = None
     flash_block: int = 128
+    # the latent form (LayerSpec op="mla"): the layer's description, whose
+    # kv_rank, nope_dim, rope_dim, v_dim, rope_* and attn_scale are read
+    latent: Optional[LayerSpec] = None
+
+    def _latent_qkv(self, x):
+        """q ``[B, S, H, nope + rope]``, k alike, v ``[B, S, H, v_dim]`` of
+        latent attention: parameters ``q``, ``kv_a``, ``kv_norm``, ``kv_b``."""
+        spec = self.latent
+        heads, nope = self.num_heads, spec.nope_dim
+        q = nn.DenseGeneral((heads, nope + spec.rope_dim), use_bias=False,
+                            dtype=self.dtype, name="q")(x)
+        latent, k_pe = jnp.split(
+            nn.Dense(spec.kv_rank + spec.rope_dim, use_bias=False,
+                     dtype=self.dtype, name="kv_a")(x),
+            [spec.kv_rank], axis=-1)
+        kv = nn.DenseGeneral(
+            (heads, nope + spec.v_dim), use_bias=False, dtype=self.dtype,
+            name="kv_b")(nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype,
+                                    name="kv_norm")(latent))
+        inv, factor = rope_frequencies(spec.rope_dim, spec.rope_theta,
+                                       spec.rope_yarn)
+        q_pe = rope(q[..., nope:], inv, spec.rope_pairing, factor)
+        k_pe = rope(k_pe[:, :, None], inv, spec.rope_pairing, factor)
+        q = jnp.concatenate([q[..., :nope], q_pe], axis=-1)
+        # the one rotary key a position, for every head
+        k = jnp.concatenate(
+            [kv[..., :nope],
+             jnp.broadcast_to(k_pe, k_pe.shape[:2] + (heads, spec.rope_dim))],
+            axis=-1)
+        return q, k, kv[..., nope:]
 
     @nn.compact
     def __call__(self, x):
         features = self.num_heads * self.head_dim
-        if self.num_kv_heads is None:
+        scale = None
+        if self.latent is not None:
+            with jax.named_scope("latent"):
+                q, k, v = self._latent_qkv(x)
+            features = self.num_heads * self.latent.v_dim
+            scale = self.latent.attn_scale
+        elif self.num_kv_heads is None:
             qkv = nn.DenseGeneral((3, self.num_heads, self.head_dim),
                                   dtype=self.dtype, name="qkv")(x)
             q, k, v = (qkv[:, :, i] for i in range(3))
@@ -211,30 +390,35 @@ class Attention(nn.Module):
                            name="q_norm")(q)
             k = nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype,
                            name="k_norm")(k)
-        if self.rope_theta is not None:
-            q, k = rope(q, self.rope_theta), rope(k, self.rope_theta)
+        if self.rope_theta is not None and self.latent is None:
+            inv, _ = rope_frequencies(self.head_dim, self.rope_theta)
+            q, k = rope(q, inv), rope(k, inv)
         if self.attention == "flash":
             from tensorflowonspark_tpu.ops import flash_attention
 
             with jax.named_scope("flash"):
                 out = flash_attention(q, k, v, causal=True, mesh=self.mesh,
                                       block_q=self.flash_block,
-                                      block_k=self.flash_block)
+                                      block_k=self.flash_block, scale=scale)
         else:
             group = self.num_heads // k.shape[2]
             if group > 1:   # the contractions below want a KV head each
                 k, v = (jnp.repeat(t, group, axis=2) for t in (k, v))
             if self.attention == "ring":
                 assert self.mesh is not None, "ring attention needs a mesh"
-                out = ring.ring_attention(q, k, v, self.mesh, causal=True)
+                out = ring.ring_attention(q, k, v, self.mesh, causal=True,
+                                          scale=scale)
             elif self.attention == "ulysses":
                 assert self.mesh is not None, "ulysses attention needs a mesh"
-                out = ring.ulysses_attention(q, k, v, self.mesh, causal=True)
+                out = ring.ulysses_attention(q, k, v, self.mesh, causal=True,
+                                             scale=scale)
             else:
-                out = ring.reference_attention(q, k, v, causal=True)
+                out = ring.reference_attention(q, k, v, causal=True,
+                                               scale=scale)
         out = out.reshape(out.shape[0], out.shape[1], features)
-        return nn.Dense(x.shape[-1], use_bias=self.num_kv_heads is None,
-                        dtype=self.dtype, name="proj")(out)
+        fused = self.num_kv_heads is None and self.latent is None
+        return nn.Dense(x.shape[-1], use_bias=fused, dtype=self.dtype,
+                        name="proj")(out)
 
 
 class ShortConv(nn.Module):
@@ -281,15 +465,20 @@ class TopKExperts(nn.Module):
     """Top-k mixture of SwiGLU experts without dropped tokens
     (:func:`~tensorflowonspark_tpu.parallel.ep.route_topk`,
     :func:`~tensorflowonspark_tpu.parallel.ep.experts_ffn`): the router
-    scores all ``num_experts`` by a sigmoid, ``expert_bias`` enters the
-    choice of the ``experts_per_token`` only, the chosen scores are
-    renormalised (``norm_topk``) and scaled.
+    scores all ``num_experts`` by ``score`` (a sigmoid each, or a softmax
+    over them all), an ``expert_bias`` leaf (there only where
+    ``selection_bias``) enters the choice of the ``experts_per_token`` only,
+    the chosen scores are renormalised where ``norm_topk`` and scaled.
+    ``shared`` is the width of a shared SwiGLU (flax name ``shared``; 0:
+    none) that every token passes through, added to the routed sum.
 
     ``held = (first, count)`` says which of the router's experts this layer
     holds (``w1``/``w3 [count, D, F]``, ``w2 [count, F, D]``; None: all).  It
     routes over all of them and returns its own experts' part of the sum;
     what the absent experts would add is left out, which is one chip's part
     of an expert-parallel layer before the exchange (there is none here).
+    The shared expert is computed whole by every holder: when the shares of
+    a layer are added up it counts once.
 
     The token-slot counts of the call are sown under
     ``intermediates/moe_counts`` (``slots_total``, ``slots_local``,
@@ -302,6 +491,9 @@ class TopKExperts(nn.Module):
     held: Optional[Tuple[int, int]] = None
     norm_topk: bool = True
     routed_scaling: float = 1.0
+    score: str = "sigmoid"
+    selection_bias: bool = True
+    shared: int = 0
     dtype: jnp.dtype = jnp.float32
 
     @nn.compact
@@ -313,7 +505,7 @@ class TopKExperts(nn.Module):
         init = nn.initializers.lecun_normal()
         router = self.param("router", init, (d_model, self.num_experts))
         bias = self.param("expert_bias", nn.initializers.zeros,
-                          (self.num_experts,))
+                          (self.num_experts,)) if self.selection_bias else None
         w1 = self.param("w1", init, (count, d_model, self.hidden))
         w3 = self.param("w3", init, (count, d_model, self.hidden))
         w2 = self.param("w2", init, (count, self.hidden, d_model))
@@ -321,11 +513,15 @@ class TopKExperts(nn.Module):
         with jax.named_scope("route"):
             sel, weights = ep_mod.route_topk(
                 tokens, router, bias, self.experts_per_token,
-                norm_topk=self.norm_topk, scaling=self.routed_scaling)
+                norm_topk=self.norm_topk, scaling=self.routed_scaling,
+                score=self.score)
         y, load = ep_mod.experts_ffn(tokens, sel, weights, w1, w3, w2, first,
                                      dtype=self.dtype)
         self.sow("intermediates", "moe_counts", load)
-        return y.reshape(batch, seq, d_model)
+        y = y.reshape(batch, seq, d_model)
+        if self.shared:
+            y = y + SwiGLU(self.shared, self.dtype, name="shared")(x)
+        return y
 
 
 class _RouterParams(nn.Module):
@@ -493,15 +689,18 @@ class Block(nn.Module):
             h = ShortConv(spec.conv_kernel, self.dtype, name="short_conv")(h)
         else:
             # the fused form keeps flax's own name (Attention_0: checkpoints
-            # of the GPT-2 decoder), the grouped-query form is "attention"
+            # of the GPT-2 decoder), the grouped-query and latent forms are
+            # "attention"
+            latent = spec if spec.op == "mla" else None
             h = Attention(
                 spec.num_heads, spec.head_dim, self.attention, self.mesh,
                 self.dtype, num_kv_heads=spec.num_kv_heads,
                 qk_norm=spec.qk_norm, norm_eps=spec.norm_eps,
                 rope_theta=(spec.rope_theta if spec.positions == "rope"
                             else None),
-                flash_block=spec.flash_block,
-                name=None if spec.num_kv_heads is None else "attention")(h)
+                flash_block=spec.flash_block, latent=latent,
+                name=None if spec.num_kv_heads is None and not latent
+                else "attention")(h)
         x = x + h
         h = _norm(spec.norm, spec.norm_eps, self.dtype)(x)
         if spec.ff == "switch":
@@ -517,7 +716,8 @@ class Block(nn.Module):
                 experts_per_token=spec.experts_per_token,
                 hidden=spec.expert_size, held=spec.held_experts,
                 norm_topk=spec.norm_topk, routed_scaling=spec.routed_scaling,
-                dtype=self.dtype, name="moe")(h)
+                score=spec.router_score, selection_bias=spec.selection_bias,
+                shared=spec.shared_size, dtype=self.dtype, name="moe")(h)
         elif spec.ff == "swiglu":
             h = SwiGLU(spec.ff_size, self.dtype, name="mlp")(h)
         else:
@@ -572,6 +772,10 @@ class TransformerLM(nn.Module):
                           dtype=self.dtype, spec=layer,
                           name="block_%d" % i)(x)
         x = _norm(spec.norm, spec.norm_eps, self.dtype)(x)
+        if not spec.tied_readout:
+            head = self.param("head", nn.initializers.normal(0.02),
+                              (spec.hidden_size, spec.vocab_size))
+            return (x @ head.astype(self.dtype)).astype(jnp.float32)
         # weight-tied readout keeps the big vocab matmul on the MXU once
         embed = self.variables["params"]["embed"]["embedding"]
         return (x @ embed.T.astype(self.dtype)).astype(jnp.float32)
@@ -600,6 +804,17 @@ def build_lfm2_moe(config, attention="flash", mesh=None, remat=False,
     ``transformer_lm`` (grouped KV heads reach ``flash`` as they are and are
     repeated for the others)."""
     return TransformerLM(spec=lfm2_moe_spec(config), attention=attention,
+                         mesh=mesh, remat=remat, dtype=jnp.dtype(dtype))
+
+
+@register_model("deepseek_v2")
+def build_deepseek_v2(config, attention="flash", mesh=None, remat=False,
+                      dtype="float32"):
+    """The one decoder under a DeepSeek-V2 ``config.json`` (see
+    :func:`deepseek_v2_spec`); ``attention`` picks the contraction as for
+    ``transformer_lm`` (``flash`` takes values narrower than the scores'
+    width as they are; ``full`` is the same mathematics without a kernel)."""
+    return TransformerLM(spec=deepseek_v2_spec(config), attention=attention,
                          mesh=mesh, remat=remat, dtype=jnp.dtype(dtype))
 
 

@@ -26,6 +26,11 @@ dQ kernels, and the dK/dV kernel's inner grid dimension runs over the
 contributions are summed in the scratch accumulators (nothing is repeated in
 HBM).
 
+Value width: ``v`` (and with it the output and dO) may be narrower or wider
+than ``q`` and ``k`` (latent attention scores over 192 dimensions and sums
+values of 128): every block, accumulator and output has its own array's
+width, and nothing is padded to the other's.
+
 Precision: matrix products take their operands in the inputs' dtype (bf16
 inputs -> bf16 operands on the MXU, float32 inputs -> float32 as before) and
 accumulate in float32; scores, softmax statistics and the accumulators are
@@ -134,7 +139,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
         p = jnp.exp(s - m_new)                         # [BQ, BK]
         alpha = jnp.exp(m_prev - m_new)                # [BQ, 1]
         l_scr[:] = l_scr[:] * alpha + p.sum(axis=-1, keepdims=True)
-        v = v_ref[0]                                   # [BK, D]
+        v = v_ref[0]                                   # [BK, DV]
         acc_scr[:] = acc_scr[:] * alpha + _dot(
             p.astype(v.dtype), v, ((1,), (0,)))
         m_scr[:] = m_new
@@ -160,8 +165,9 @@ def _kv_maps(causal, block_q, block_k, group):
 
 
 def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret, group=1):
-    """Returns ``(out [bh, seq, d], logsumexp [bh, seq, 1])``; ``k`` and
-    ``v`` are ``[bh // group, seq, d]``.  The softmax statistics keep a
+    """Returns ``(out [bh, seq, dv], logsumexp [bh, seq, 1])``; ``q`` is
+    ``[bh, seq, d]``, ``k [bh // group, seq, d]`` and ``v [bh // group, seq,
+    dv]``.  The softmax statistics keep a
     trailing unit dim: the TPU lowering wants a block's last two dims
     divisible by (8, 128) or equal to the array's, and a ``(1, block_q)``
     row block of a ``[bh, seq]`` array is neither."""
@@ -169,6 +175,7 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret, group=1):
     from jax.experimental.pallas import tpu as pltpu
 
     bh, s_len, d = q.shape
+    dv = v.shape[-1]
     n_q = s_len // block_q
     n_k = s_len // block_k
     kernel = functools.partial(
@@ -181,20 +188,20 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret, group=1):
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, kk: (b, i, 0)),
             pl.BlockSpec((1, block_k, d), kv),
-            pl.BlockSpec((1, block_k, d), kv),
+            pl.BlockSpec((1, block_k, dv), kv),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, kk: (b, i, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda b, i, kk: (b, i, 0)),
             pl.BlockSpec((1, block_q, 1), lambda b, i, kk: (b, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, s_len, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, s_len, dv), q.dtype),
             jax.ShapeDtypeStruct((bh, s_len, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
         ],
         interpret=interpret,
     )(q, k, v)
@@ -251,7 +258,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _compute(masked):
         p = jnp.exp(_scores(q_ref, k_ref, scale, masked, qi, kk, block_q,
                             block_k) - lse_ref[0])
-        do = do_ref[0]                                 # [BQ, D]
+        do = do_ref[0]                                 # [BQ, DV]
         dv_scr[:] += _dot(p.astype(do.dtype), do, ((0,), (0,)))
         dp = _dot(do, v_ref[0], ((1,), (1,)))          # [BQ, BK]
         ds = p * (dp - delta_ref[0])
@@ -279,6 +286,7 @@ def _flash_bwd_dq(q, k, v, g, lse, delta, scale, causal, block_q, block_k,
     from jax.experimental.pallas import tpu as pltpu
 
     bh, s_len, d = q.shape
+    dv = v.shape[-1]
     n_q = s_len // block_q
     n_k = s_len // block_k
     kv = _kv_maps(causal, block_q, block_k, group)
@@ -289,8 +297,8 @@ def _flash_bwd_dq(q, k, v, g, lse, delta, scale, causal, block_q, block_k,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, kk: (b, i, 0)),
             pl.BlockSpec((1, block_k, d), kv),
-            pl.BlockSpec((1, block_k, d), kv),
-            pl.BlockSpec((1, block_q, d), lambda b, i, kk: (b, i, 0)),
+            pl.BlockSpec((1, block_k, dv), kv),
+            pl.BlockSpec((1, block_q, dv), lambda b, i, kk: (b, i, 0)),
             pl.BlockSpec((1, block_q, 1), lambda b, i, kk: (b, i, 0)),
             pl.BlockSpec((1, block_q, 1), lambda b, i, kk: (b, i, 0)),
         ],
@@ -307,6 +315,7 @@ def _flash_bwd_dkv(q, k, v, g, lse, delta, scale, causal, block_q, block_k,
     from jax.experimental.pallas import tpu as pltpu
 
     bh_kv, s_len, d = k.shape
+    dv = v.shape[-1]
     n_q = s_len // block_q
     n_k = s_len // block_k
 
@@ -327,14 +336,14 @@ def _flash_bwd_dkv(q, k, v, g, lse, delta, scale, causal, block_q, block_k,
         in_specs=[
             pl.BlockSpec((1, block_q, d), rows),
             pl.BlockSpec((1, block_k, d), lambda b, kk, j: (b, kk, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, kk, j: (b, kk, 0)),
-            pl.BlockSpec((1, block_q, d), rows),
+            pl.BlockSpec((1, block_k, dv), lambda b, kk, j: (b, kk, 0)),
+            pl.BlockSpec((1, block_q, dv), rows),
             pl.BlockSpec((1, block_q, 1), rows),
             pl.BlockSpec((1, block_q, 1), rows),
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, d), lambda b, kk, j: (b, kk, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, kk, j: (b, kk, 0)),
+            pl.BlockSpec((1, block_k, dv), lambda b, kk, j: (b, kk, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct(k.shape, k.dtype),
@@ -342,7 +351,7 @@ def _flash_bwd_dkv(q, k, v, g, lse, delta, scale, causal, block_q, block_k,
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, dv), jnp.float32),
         ],
         interpret=interpret,
     )(q, k, v, g, lse, delta)
@@ -388,7 +397,11 @@ def flash_attention(q, k, v, causal=True, block_q=128, block_k=128,
                     interpret=None, scale=None, mesh=None):
     """Memory-linear attention over ``[batch, seq, heads, dim]`` inputs;
     ``k`` and ``v`` may carry fewer heads than ``q`` (grouped-query
-    attention: query head ``h`` reads KV head ``h // (heads // kv_heads)``).
+    attention: query head ``h`` reads KV head ``h // (heads // kv_heads)``),
+    and ``v`` may have a width of its own (``q, k [.., dk]``, ``v [.., dv]``
+    -> ``[batch, seq, heads, dv]``; ``scale`` defaults to ``dk ** -0.5``).
+    A ``ValueError`` names the shapes where ``q`` and ``k`` differ in width
+    or ``k`` and ``v`` in heads.
 
     Differentiable (custom FlashAttention-2 backward kernels); softmax
     statistics live in fp32 regardless of input dtype.  ``block_q/k``
@@ -407,10 +420,15 @@ def flash_attention(q, k, v, causal=True, block_q=128, block_k=128,
         interpret = _default_interpret()
     batch, s_len, heads, dim = q.shape
     kv_heads = k.shape[2]
+    if k.shape[3] != dim:
+        raise ValueError(
+            "q {} and k {} differ in width: scores are taken over one"
+            .format(q.shape, k.shape))
     if heads % kv_heads or v.shape[2] != kv_heads:
         raise ValueError(
-            "{} query heads do not divide into {} / {} key / value heads"
-            .format(heads, kv_heads, v.shape[2]))
+            "{} query heads of q {} do not divide into the {} / {} heads of "
+            "k {} / v {}".format(heads, q.shape, kv_heads, v.shape[2],
+                                 k.shape, v.shape))
     if scale is None:
         scale = 1.0 / (dim ** 0.5)
     if mesh is not None and mesh.size > 1:
@@ -434,8 +452,8 @@ def flash_attention(q, k, v, causal=True, block_q=128, block_k=128,
             .format(s_len, block_q, block_k))
 
     def fold(x):
-        return x.transpose(0, 2, 1, 3).reshape(-1, s_len, dim)
+        return x.transpose(0, 2, 1, 3).reshape(-1, s_len, x.shape[3])
 
     out = _flash(fold(q), fold(k), fold(v), causal, block_q, block_k,
                  interpret, scale, heads // kv_heads)
-    return out.reshape(batch, heads, s_len, dim).transpose(0, 2, 1, 3)
+    return out.reshape(batch, heads, s_len, v.shape[3]).transpose(0, 2, 1, 3)

@@ -10,8 +10,9 @@ routers of :mod:`~tensorflowonspark_tpu.models.transformer`.
   gathers).  ``MoEMlp`` and :func:`moe_ffn` use it.
 - :func:`route_topk` with :func:`sort_pairs` and :func:`experts_ffn`:
   **top-k of E by sigmoid scores with a selection bias**, weights
-  renormalised over the k chosen, **no capacity and no token dropped at any
-  imbalance**.  Dispatch is a sort of the (token, slot) pairs by expert, the
+  renormalised over the k chosen, or **by softmax scores without either**
+  (the router's description says which), **no capacity and no token dropped
+  at any imbalance**.  Dispatch is a sort of the (token, slot) pairs by expert, the
   experts' SwiGLU products are grouped matrix products
   (:mod:`~tensorflowonspark_tpu.ops.grouped_matmul`) over the stacked weights
   of the experts **held here** (a contiguous range of the E the router
@@ -109,26 +110,38 @@ def _route(x, router_kernel, router_bias, num_experts, capacity):
 
 
 def route_topk(x, router_kernel, expert_bias, experts_per_token,
-               norm_topk=True, scaling=1.0):
-    """Top-k routing by sigmoid scores with a selection bias.
+               norm_topk=True, scaling=1.0, score="sigmoid"):
+    """Top-k routing by the router's scores, with or without a selection
+    bias.
 
-    ``x [T, D]``, ``router_kernel [D, E]``, ``expert_bias [E]`` ->
-    ``(sel [T, k] int32, weights [T, k] float32)``: ``s = sigmoid(x W_r)``
-    in float32 at full precision (a selection must not flip with the compute
-    dtype or the TPU's default one-pass products), ``sel = top_k(s + b)``
-    (the bias enters the selection only and gets no gradient), ``weights =
-    s[sel]``, divided by their sum (+1e-6) where ``norm_topk``, times
-    ``scaling``."""
+    ``x [T, D]``, ``router_kernel [D, E]``, ``expert_bias [E]`` or None ->
+    ``(sel [T, k] int32, weights [T, k] float32)``.  The scores ``s`` of
+    ``x W_r`` are taken in float32 at full precision (a selection must not
+    flip with the compute dtype or the TPU's default one-pass products):
+    ``score="sigmoid"`` scores each expert by itself (``s = sigmoid(x
+    W_r)``), ``"softmax"`` all ``E`` against each other (``s = softmax(x
+    W_r)`` over the experts, held here or not).  ``sel = top_k(s + b)``:
+    the bias, where there is one, enters the selection only and gets no
+    gradient.  ``weights = s[sel]``, divided by their sum (+1e-6) where
+    ``norm_topk``, times ``scaling``."""
     import jax
     import jax.numpy as jnp
     from jax import lax
 
-    scores = jax.nn.sigmoid(jnp.matmul(
+    logits = jnp.matmul(
         x.astype(jnp.float32), router_kernel.astype(jnp.float32),
-        precision=lax.Precision.HIGHEST))                    # [T, E]
-    _, sel = lax.top_k(
-        scores + lax.stop_gradient(expert_bias.astype(jnp.float32)),
-        experts_per_token)
+        precision=lax.Precision.HIGHEST)                     # [T, E]
+    if score == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    elif score == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    else:
+        raise ValueError("unknown router score {!r}".format(score))
+    chosen_by = scores
+    if expert_bias is not None:
+        chosen_by = scores + lax.stop_gradient(
+            expert_bias.astype(jnp.float32))
+    _, sel = lax.top_k(chosen_by, experts_per_token)
     weights = jnp.take_along_axis(scores, sel, axis=-1)
     if norm_topk:
         weights = weights / (weights.sum(axis=-1, keepdims=True) + 1e-6)

@@ -92,3 +92,17 @@ def pytest_runtest_call(item):
     finally:
         signal.alarm(0)
         signal.signal(signal.SIGALRM, previous)
+
+
+@pytest.fixture(params=["xla", "kernels"])
+def row_path(request, monkeypatch):
+    """Both paths of an expert layer's row movement (``ops/routed_rows``):
+    XLA's take, which is what a CPU process gets, and the kernels a TPU
+    process gets, here in interpret mode."""
+    import importlib
+
+    if request.param == "kernels":
+        monkeypatch.setattr(
+            importlib.import_module("tensorflowonspark_tpu.ops.routed_rows"),
+            "_default_impl", lambda: ("pallas", True))
+    return request.param

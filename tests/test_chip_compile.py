@@ -53,30 +53,35 @@ def _compile(fn, *args):
     return compiled.as_text()
 
 
-# (batch, seq, heads, head_dim): chip_smoke's LM shape, and one longer and
-# wider point the model zoo allows
-SHAPES = [(8, 1024, 16, 64), (2, 4096, 8, 128)]
+# (batch, seq, heads, q and k width, v width, block): chip_smoke's LM shape,
+# one longer and wider point the model zoo allows, and latent attention's at
+# the benchmark's sizes (scores over 192, values of 128, blocks of 512)
+SHAPES = [(8, 1024, 16, 64, 64, 128), (2, 4096, 8, 128, 128, 128),
+          (4, 8192, 16, 192, 128, 512)]
 
 
-@pytest.mark.parametrize("shape", SHAPES, ids=["s1024_d64", "s4096_d128"])
+@pytest.mark.parametrize("shape", SHAPES,
+                         ids=["s1024_d64", "s4096_d128", "s8192_dk192_dv128"])
 @pytest.mark.parametrize("kernel", ["fwd", "bwd_dq", "bwd_dkv"])
 def test_flash_kernel_compiles_for_v5e(topo, kernel, shape):
-    batch, seq, heads, dim = shape
+    batch, seq, heads, dk, dv, block = shape
     one = SingleDeviceSharding(topo.devices[0])
-    x = jax.ShapeDtypeStruct((batch * heads, seq, dim), jnp.bfloat16,
-                             sharding=one)
-    stat = jax.ShapeDtypeStruct((batch * heads, seq, 1), jnp.float32,
-                                sharding=one)
-    tail = (dim ** -0.5, True, 128, 128, False)  # scale, causal, blocks, interpret
+
+    def arg(width, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct((batch * heads, seq, width), dtype,
+                                    sharding=one)
+
+    qk, v, stat = arg(dk), arg(dv), arg(1, jnp.float32)
+    tail = (dk ** -0.5, True, block, block, False)  # scale, causal, blocks, interpret
     if kernel == "fwd":
         text = _compile(lambda q, k, v: fa._flash_fwd(q, k, v, *tail),
-                        x, x, x)
+                        qk, qk, v)
     else:
         launch = fa._flash_bwd_dq if kernel == "bwd_dq" else fa._flash_bwd_dkv
         text = _compile(
             lambda q, k, v, g, lse, delta: launch(q, k, v, g, lse, delta,
                                                   *tail),
-            x, x, x, x, stat, stat)
+            qk, qk, v, v, stat, stat)
     assert text.count("tpu_custom_call") == 1
 
 
@@ -134,17 +139,13 @@ def test_sharded_flash_compiles_for_v5e_2x2(topo, monkeypatch):
     assert "tpu_custom_call" in text and "all-to-all" in text
 
 
-def test_lfm2_moe_step_compiles_and_fits_v5e(topo, monkeypatch):
-    """The whole training step of ``lfm2_8b_a1b_ep4`` (the benchmark's
-    configuration: published widths, the layer pattern, 8 of 32 experts,
-    batch and 8,192-token rows as the file says, bf16 compute, remat per
-    block, Adam) compiles for one described v5e chip, with the grouped-query
-    flash kernels, the grouped expert products and the expert layer's row
-    movement (pallas kernels all) in it, and XLA's memory analysis of it
-    (arguments + outputs - aliased + temporaries) is no larger than the
-    12.71 GiB it was with XLA's gathers around the grouped products (PR 28;
-    a v5e offers 15.75).  The numbers are in the configuration's
-    ``assumed.batch_size``."""
+def _compiled_step(topo, monkeypatch, family, config_name):
+    """The whole training step of a benchmark configuration of a
+    ``TransformerLM`` family (``benchmark/configs/<config_name>.json``: its
+    widths, batch and rows, bf16 compute, remat per block, Adam) compiled
+    for one described v5e chip with every pallas kernel of the program in
+    it: ``(compiled, parameter count, XLA's memory analysis in bytes:
+    arguments + outputs - aliased + temporaries)``."""
     import json
     import sys
 
@@ -153,7 +154,7 @@ def test_lfm2_moe_step_compiles_and_fits_v5e(topo, monkeypatch):
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     if root not in sys.path:
         sys.path.insert(0, root)
-    from benchmark.adapters import lfm2_moe as adapter
+    adapter = importlib.import_module("benchmark.adapters." + family)
     from tensorflowonspark_tpu.models import get_model, transformer
 
     monkeypatch.setattr(fa, "_default_interpret", lambda: False)
@@ -164,18 +165,16 @@ def test_lfm2_moe_step_compiles_and_fits_v5e(topo, monkeypatch):
         importlib.import_module("tensorflowonspark_tpu.ops.routed_rows"),
         "_default_impl", lambda: ("pallas", False))
     with open(os.path.join(root, "benchmark", "configs",
-                           "lfm2_8b_a1b_ep4.json")) as f:
+                           config_name + ".json")) as f:
         cfg = json.load(f)
     config = adapter.program_config(cfg)
-    model = get_model("lfm2_moe", config=config, attention=cfg["attention"],
+    model = get_model(family, config=config, attention=cfg["attention"],
                       remat=cfg["remat"], dtype=cfg["dtype"])
     # parameters never depend on the attention kind: shape them without
     # tracing the kernel for the CPU
     shapes = jax.eval_shape(
-        get_model("lfm2_moe", config=config, attention="full").init,
+        get_model(family, config=config, attention="full").init,
         jax.random.PRNGKey(0), jnp.zeros((1, 128), jnp.int32))["params"]
-    assert sum(x.size for x in jax.tree_util.tree_leaves(shapes)) \
-        == 507_820_288
     optimizer = optax.adam(cfg["optimizer"]["learning_rate"])
     loss = transformer.loss_fn(model)
 
@@ -202,15 +201,38 @@ def test_lfm2_moe_step_compiles_and_fits_v5e(topo, monkeypatch):
     memory = compiled.memory_analysis()
     needed = (memory.argument_size_in_bytes + memory.output_size_in_bytes
               - memory.alias_size_in_bytes + memory.temp_size_in_bytes)
-    assert needed <= 12.71 * 2 ** 30, needed
+    return (compiled,
+            sum(x.size for x in jax.tree_util.tree_leaves(shapes)), needed)
+
+
+def _kernel_calls(compiled):
     text = compiled.as_text()
+    # none of XLA's nameless ragged-dot calls: every grouped product is a
+    # pallas kernel that carries its scope
+    assert "ragged-dot" not in text
+    return [line for line in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line]
+
+
+def test_lfm2_moe_step_compiles_and_fits_v5e(topo, monkeypatch):
+    """The whole training step of ``lfm2_8b_a1b_ep4`` (the benchmark's
+    configuration: published widths, the layer pattern, 8 of 32 experts,
+    batch and 8,192-token rows as the file says, bf16 compute, remat per
+    block, Adam) compiles for one described v5e chip, with the grouped-query
+    flash kernels, the grouped expert products and the expert layer's row
+    movement (pallas kernels all) in it, and XLA's memory analysis of it
+    (arguments + outputs - aliased + temporaries) is no larger than the
+    12.71 GiB it was with XLA's gathers around the grouped products (PR 28;
+    a v5e offers 15.75).  The numbers are in the configuration's
+    ``assumed.batch_size``."""
+    compiled, parameters, needed = _compiled_step(
+        topo, monkeypatch, "lfm2_moe", "lfm2_8b_a1b_ep4")
+    assert parameters == 507_820_288
+    assert needed <= 12.71 * 2 ** 30, needed
     # 4 expert layers x 3 grouped products x (forward, recomputed forward,
     # two gradients), and the flash kernels (forward twice, dQ, dK/dV): all
-    # pallas kernels that carry their scope, none of XLA's nameless
-    # ragged-dot calls
-    assert "ragged-dot" not in text
-    calls = [line for line in text.splitlines()
-             if 'custom_call_target="tpu_custom_call"' in line]
+    # pallas kernels that carry their scope
+    calls = _kernel_calls(compiled)
     # ... and ten kernels of the row movement an expert layer, under the
     # scopes moe_route_ms_per_step reads: dispatch packs the tokens and
     # gathers them (forward and recomputed forward) and its gradient packs
@@ -224,6 +246,28 @@ def test_lfm2_moe_step_compiles_and_fits_v5e(topo, monkeypatch):
         assert sum("/moe/{}/".format(scope) in line
                    and "/routed_rows_{}/pallas_call".format(kernel) in line
                    for line in calls) == count, (scope, kernel)
+
+
+def test_deepseek_v2_lite_step_compiles_and_fits_v5e(topo, monkeypatch):
+    """The whole training step of ``deepseek_v2_lite_ep8`` (published
+    widths; one dense and four expert layers; latent attention through the
+    flash kernels at 192 / 128; 8 of 64 experts by softmax top-6 beside the
+    shared expert; an untied read-out over 12,800 rows; batch and
+    8,192-token rows as the file says) compiles for one described v5e chip
+    and fits its 15.75 GiB by XLA's memory analysis: 13.04 GiB at batch 4
+    (PR 32), which it may not outgrow.  The numbers are in the
+    configuration's ``assumed.batch_size``."""
+    compiled, parameters, needed = _compiled_step(
+        topo, monkeypatch, "deepseek_v2", "deepseek_v2_lite_ep8")
+    assert parameters == 535_060_992
+    assert needed <= 13.1 * 2 ** 30, needed
+    calls = _kernel_calls(compiled)
+    # five attention layers x (forward, recomputed forward, dQ, dK/dV), all
+    # under attention/flash; four expert layers x 3 grouped products x 4
+    # passes, and their row movement
+    assert sum("/attention/flash/" in line for line in calls) == 20
+    assert sum("/moe/experts/" in line for line in calls) == 48
+    assert len(calls) >= 20 + 48 + 40
 
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],

@@ -66,20 +66,6 @@ def _reference_layer(w, x, held=(0, 8)):
                       for row in x])
 
 
-@pytest.fixture(params=["xla", "kernels"])
-def row_path(request, monkeypatch):
-    """Both paths of the layer's row movement (``ops/routed_rows``): XLA's
-    take, which is what a CPU process gets, and the kernels a TPU process
-    gets, here in interpret mode."""
-    import importlib
-
-    if request.param == "kernels":
-        monkeypatch.setattr(
-            importlib.import_module("tensorflowonspark_tpu.ops.routed_rows"),
-            "_default_impl", lambda: ("pallas", True))
-    return request.param
-
-
 def test_the_shares_add_up_to_the_whole_layer(row_path):
     """8 experts in 4 shares of 2: the four partial sums that the chips of an
     expert-parallel layer compute equal the uncut reference's whole layer,

@@ -115,6 +115,58 @@ def test_grouped_query_flash_matches_reference(causal, blocks):
             err_msg="d{} mismatch".format(name))
 
 
+@pytest.mark.parametrize("widths", [(192, 128), (24, 16), (16, 24),
+                                    (16, 16)],
+                         ids=["dk192_dv128", "dk24_dv16", "dk16_dv24",
+                              "dk16_dv16"])
+@pytest.mark.parametrize("group", [1, 4], ids=["mha", "group4"])
+def test_flash_with_a_value_width_of_its_own(widths, group):
+    """Latent attention's shapes: scores over ``dk`` (192 = 128 + 64 rotary),
+    values ``dv`` wide (128), the caller's scale: the output is ``dv`` wide,
+    and values and all three gradients agree with plain attention; V is
+    never padded (dV has V's own shape).  ``dk == dv`` is the kernel as it
+    was."""
+    dk, dv = widths
+    seq = 64 if dk > 64 else 128
+    keys = jax.random.split(jax.random.PRNGKey(5), 3)
+    q = jax.random.normal(keys[0], (2, seq, 4, dk))
+    k = jax.random.normal(keys[1], (2, seq, 4 // group, dk))
+    v = jax.random.normal(keys[2], (2, seq, 4 // group, dv))
+    scale = 0.114722 * (192 / dk) ** 0.5
+
+    def flash(q, k, v):
+        o = flash_attention(q, k, v, causal=True, block_q=32, block_k=32,
+                            scale=scale)
+        return (o ** 2).sum(), o
+
+    def ref(q, k, v):
+        o = ring.reference_attention(q, jnp.repeat(k, group, axis=2),
+                                     jnp.repeat(v, group, axis=2),
+                                     causal=True, scale=scale)
+        return (o ** 2).sum(), o
+
+    (_, got), g_flash = jax.value_and_grad(
+        flash, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    (_, want), g_ref = jax.value_and_grad(
+        ref, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    assert got.shape == (2, seq, 4, dv)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-5, rtol=2e-5)
+    for gf, gr, name in zip(g_flash, g_ref, "qkv"):
+        assert gf.shape == gr.shape
+        np.testing.assert_allclose(
+            np.asarray(gf), np.asarray(gr), atol=5e-4, rtol=5e-4,
+            err_msg="d{} mismatch".format(name))
+
+
+def test_flash_names_the_shapes_it_refuses():
+    q, k, v = _qkv(heads=4)
+    with pytest.raises(ValueError, match=r"k \(2, 128, 4, 8\) differ in width"):
+        flash_attention(q, k[..., :8], v)
+    with pytest.raises(ValueError, match=r"4 query heads.*4 / 2 heads"):
+        flash_attention(q, k, v[:, :, :2])
+
+
 def test_flash_refuses_head_counts_that_do_not_group():
     q, k, v = _qkv(heads=6)
     with pytest.raises(ValueError, match="6 query heads"):

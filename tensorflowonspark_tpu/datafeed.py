@@ -10,6 +10,7 @@ return columnar numpy arrays ready for a single per-host ``jax.device_put``
 into a sharded global batch (see :mod:`tensorflowonspark_tpu.parallel.infeed`).
 """
 
+import collections
 import logging
 import queue as _queue
 import threading
@@ -26,10 +27,42 @@ _BUFFERED = object()     # _pull() buffered a chunk; nothing to hand over yet
 #: The consumer's phases (see :class:`~tensorflowonspark_tpu.telemetry.PhaseClock`):
 #: ``away`` between two ``next_batch*`` calls (the caller's transform, the
 #: host-to-device put, a full prefetch queue), ``wait`` blocked on the empty
-#: queue, ``read`` the ring read with its decode (or an in-queue chunk's
-#: unpacking) and the chunk's ``task_done`` round trip, ``assemble`` slicing
-#: and concatenating columns.
+#: queue, ``read`` the ring read (on the array path with its one copy, from
+#: the in-ring views into the batch's buffers; on the row path with its
+#: decode) or an in-queue chunk's unpacking, and the chunk's ``task_done``
+#: round trip, ``assemble`` what is left of building a batch: the loop's
+#: bookkeeping and the copy of in-queue and object chunks' rows into the
+#: batch's buffers.
 FEED_PHASES = ("away", "wait", "read", "assemble")
+
+
+class _Batch(object):
+    """A batch under construction on the array path: one buffer a column,
+    shaped ``[size, ...]`` in the dtype the caller gets, and the rows
+    filled so far."""
+
+    __slots__ = ("cols", "asked", "tuple_rows", "size", "count")
+
+    def __init__(self, cols, asked, tuple_rows, size):
+        self.cols = cols
+        self.asked = asked  # the dtype the caller named for each, or None
+        self.tuple_rows = tuple_rows
+        self.size = size
+        self.count = 0
+
+
+def _buffers_key(cols):
+    """What a batch's buffers hold (batch size, column shapes and dtypes),
+    or ``None`` when ``cols`` are not whole buffers of one batch: views,
+    read-only or strided arrays, columns of different lengths."""
+    key = []
+    for c in cols:
+        if not (isinstance(c, np.ndarray) and c.size and c.base is None
+                and c.flags.c_contiguous and c.flags.writeable
+                and c.shape[0] == cols[0].shape[0]):
+            return None
+        key.append((c.shape, c.dtype.str))
+    return tuple(key) or None
 
 
 def _rows_to_fields(rows):
@@ -158,6 +191,21 @@ class DataFeed(object):
         self._buffer = []
         self._buffer_idx = 0
         self._chunk_q = None
+        # The array path's batches under construction (see _Batch): during
+        # a next_batch_arrays call the head is the batch in hand; between
+        # calls the deque holds only what a ring chunk's tail filled beyond
+        # it (the ring slot is consumed with the chunk's one copy, so the
+        # rows must already be somewhere).  ``_buffer`` is exhausted
+        # whenever rows wait here, and the pending ack in ``_chunk_q`` is
+        # then of the chunk whose last row lies in the LAST of them.
+        self._batches = collections.deque()
+        # Batch buffers the reader handed back (see release): the columns
+        # of whole batches, all of one kind (``_free_key``).  Empty for a
+        # caller that never hands back: every batch is then new memory.
+        self._free = []
+        self._free_key = None
+        self.buffers_reused = 0
+        self.buffers_new = 0
         # Transport observability: {format: chunks seen} — wire.WIRE_COLV1
         # for zero-copy framed ring records, wire.WIRE_PICKLE for pickled
         # ring records, "queue" for in-queue chunks: a throughput number
@@ -222,6 +270,8 @@ class DataFeed(object):
         tensors = ([] if self.input_tensors is None
                    else {tensor: [] for tensor in self.input_tensors})
         count = 0
+        if self._batches:
+            self._unbatch()  # rows next_batch_arrays copied ahead come first
         while count < batch_size:
             if self._buffer_idx < self._buflen():
                 item = self._bufrow(self._buffer_idx)
@@ -296,12 +346,15 @@ class DataFeed(object):
         finally:
             self.stall_secs += (self._clock.switch("read") - t0) / 1e9
 
-    def _pull(self, queue):
+    def _pull(self, queue, into=None):
         """The next thing off the queue, accounted: blocked on the empty
-        queue (phase ``wait``), then a chunk's payload read into the buffer
-        (phase ``read``; the ack is deferred, see ctor), then back to
-        ``assemble``.  Returns ``_BUFFERED`` for a chunk, ``_INTERRUPTED``,
-        or the loose item / ``None`` / ``EndPartition`` for the caller."""
+        queue (phase ``wait``), then a chunk's payload read (phase ``read``;
+        the ack is deferred, see ctor), then back to ``assemble``.  A chunk
+        goes into the row buffer; with ``into`` (the array path, see
+        :meth:`_ring_read`) a framed ring chunk's rows go straight into the
+        batch's buffers instead.  Returns ``_BUFFERED`` for a chunk,
+        ``_INTERRUPTED``, or the loose item / ``None`` / ``EndPartition``
+        for the caller."""
         item = self._get_interruptible(queue)
         try:
             if item is _INTERRUPTED:
@@ -310,7 +363,15 @@ class DataFeed(object):
                 if isinstance(item, marker.ShmChunk):
                     # Payload took the native shm-ring fast path; the token
                     # preserves ordering/join semantics (see marker.ShmChunk).
-                    item = self._ring_read(item)
+                    item = self._ring_read(item, into=into)
+                    if item is None:
+                        # Copied into the batches.  Acked now if its last
+                        # row is in the batch in hand, else by the call that
+                        # takes the batch that holds it.
+                        self._chunk_q = queue
+                        if len(self._batches) <= 1:
+                            self._ack_chunk()
+                        return _BUFFERED
                 elif isinstance(item, (marker.Chunk, marker.ColChunk)):
                     self._note_transport("queue")
                 if not isinstance(item, (marker.Chunk, marker.ColChunk)):
@@ -349,7 +410,7 @@ class DataFeed(object):
     def _note_transport(self, fmt):
         self.wire_formats[fmt] = self.wire_formats.get(fmt, 0) + 1
 
-    def _ring_read(self, token, timeout_secs=600):
+    def _ring_read(self, token, timeout_secs=600, into=None):
         """Pop one chunk payload from the shm ring named by the token;
         returns the chunk object (:class:`~tensorflowonspark_tpu.marker.Chunk`
         or :class:`~tensorflowonspark_tpu.marker.ColChunk`; legacy payloads
@@ -358,8 +419,13 @@ class DataFeed(object):
         ``fmt`` on the token picks the record decoding: framed columnar
         records (:data:`~tensorflowonspark_tpu.wire.WIRE_COLV1`) take the
         two-phase peek/consume path — the in-ring bytes are wrapped with
-        ``np.frombuffer`` views and each column is copied exactly once into
-        the chunk, with no intermediate record buffer and no unpickle."""
+        ``np.frombuffer`` views and each column is copied exactly once, with
+        no intermediate record buffer and no unpickle.  Where to: into a
+        chunk of its own (the row path and the drain), or, with ``into``
+        (``into(columns, tuple_rows, count)``, the array path's
+        :meth:`_copy_rows`), straight from the views into the batch's
+        buffers, in which case nothing is returned.  Either way the ring
+        slot is consumed when the copy is done, and also when it raises."""
         import pickle
 
         from tensorflowonspark_tpu import shmring, wire
@@ -373,34 +439,50 @@ class DataFeed(object):
         if fmt == wire.WIRE_COLV1:
             view = ring.peek(timeout_secs)
             try:
-                obj = wire.decode_chunk(view, copy=True)
+                columns, n, tuple_rows = wire.decode(view, copy=into is None)
+                self._note_transport(fmt)
+                self._check_count(token, n)
+                if into is None:
+                    return marker.ColChunk(columns, n, tuple_rows)
+                into(columns, tuple_rows, n)
+                return None
             finally:
-                # Consume even when decode raises: tokens and records must
-                # stay 1:1 or every later chunk on this ring desyncs.
+                # Consume even when decode or the copy raises: tokens and
+                # records must stay 1:1 or every later chunk on this ring
+                # desyncs.
                 ring.consume()
-        else:
-            obj = pickle.loads(ring.get_bytes(timeout_secs))
+        obj = pickle.loads(ring.get_bytes(timeout_secs))
         self._note_transport(fmt)
         if isinstance(obj, list):
             obj = marker.Chunk(obj)
-        n = obj.count if isinstance(obj, marker.ColChunk) else len(obj.items)
+        self._check_count(
+            token,
+            obj.count if isinstance(obj, marker.ColChunk) else len(obj.items))
+        return obj
+
+    @staticmethod
+    def _check_count(token, n):
         if n != token.count:
             # Token/record desync would silently deliver wrong training data;
             # must survive python -O, so not an assert.
             raise RuntimeError(
                 "shm ring {} desync: token promised {} items, record has "
                 "{}".format(token.ring_name, token.count, n))
-        return obj
 
     def next_batch_arrays(self, batch_size, dtypes=None):
         """TPU-first variant: assemble the batch directly into numpy arrays.
 
         Columnar end to end: feeders ship
         :class:`~tensorflowonspark_tpu.marker.ColChunk` blocks (a few
-        contiguous ndarrays), and this method concatenates column *slices* —
-        no per-row Python objects ever exist on this path.  Object chunks /
-        loose items degrade gracefully to per-row ``np.asarray``.  Pairs with
-        ``parallel.infeed.ShardedFeed`` for a single per-host device transfer.
+        contiguous ndarrays), and this method copies each block's columns
+        **once**, into their rows of the batch's buffers (one array a
+        column, ``[batch_size, ...]``, in the dtype asked for) — for a
+        framed ring chunk straight from the in-ring bytes, with the
+        ``dtypes`` cast in that same copy.  No per-row Python objects ever
+        exist on this path.  Object chunks / loose items degrade gracefully
+        to per-row ``np.asarray``.  Pairs with
+        ``parallel.infeed.ShardedFeed`` for a single per-host device
+        transfer.
 
         Returns ``(arrays, count)`` where ``count`` is the number of real
         rows (may be < batch_size at end of feed) and ``arrays`` is:
@@ -413,7 +495,17 @@ class DataFeed(object):
 
         ``dtypes``: optional cast — a dict keyed by tensor name (with
         input_mapping), a sequence matching the field count (tuple rows), or
-        a single dtype (single-value rows).
+        a single dtype (single-value rows).  Pass the same ``batch_size``
+        and ``dtypes`` from call to call: rows that a chunk's tail put into
+        the next batch already are in that batch's dtype.
+
+        **Who owns a batch.**  The caller does, for as long as it keeps the
+        arrays: no later call writes to them.  A caller that is done with a
+        whole batch (its host-to-device transfer is over, nothing it keeps
+        refers to the memory) may hand the arrays back with :meth:`release`;
+        a later batch of the same kind is then built in that memory, which
+        is already mapped, instead of in new pages.  Never handing back is
+        fine and costs only that.
         """
         self._clock.switch("assemble")
         try:
@@ -423,13 +515,24 @@ class DataFeed(object):
 
     def _next_batch_arrays(self, batch_size, dtypes):
         queue = self.mgr.get_queue(self.qname_in)
-        parts = []       # per-part tuple of per-field array slices
-        tuple_rows = None
-        count = 0
-        while count < batch_size:
+        batches = self._batches
+
+        def into(fields, tuple_rows, n):
+            self._copy_rows(fields, tuple_rows, n, batch_size, dtypes)
+
+        if batches and not self._as_asked(batches[0], batch_size, dtypes):
+            self._unbatch()
+        if len(batches) == 1:
+            # the last rows copied ahead are in the batch in hand now
+            self._ack_read()
+        # More than one batch waits only between calls, and the head is
+        # then full: inside the loop the deque holds the batch in hand or
+        # nothing, until a ring chunk's tail spills and ends the loop.
+        while not batches or batches[0].count < batch_size:
             buflen = self._buflen()
             if self._buffer_idx < buflen:
-                take = min(batch_size - count, buflen - self._buffer_idx)
+                take = batch_size - batches[0].count if batches else batch_size
+                take = min(take, buflen - self._buffer_idx)
                 i0 = self._buffer_idx
                 buf = self._buffer
                 if isinstance(buf, marker.ColChunk):
@@ -437,21 +540,15 @@ class DataFeed(object):
                     tr = buf.tuple_rows
                 else:
                     fields, tr = _rows_to_fields(buf[i0:i0 + take])
-                if tuple_rows is None:
-                    tuple_rows = tr
-                elif tuple_rows != tr or (parts and len(parts[-1]) != len(fields)):
-                    raise ValueError(
-                        "inconsistent row structure across feed chunks "
-                        "(tuple_rows {} vs {})".format(tuple_rows, tr))
-                parts.append(fields)
-                count += take
+                into(fields, tr, take)
                 self._buffer_idx += take
                 if self._buffer_idx >= buflen:
                     self._ack_read()
                 continue
-            item = self._pull(queue)
+            item = self._pull(queue, into)
             if item is _INTERRUPTED:
-                logger.info("next_batch_arrays: interrupted at %d rows", count)
+                logger.info("next_batch_arrays: interrupted at %d rows",
+                            batches[0].count if batches else 0)
                 break
             if item is _BUFFERED:
                 continue
@@ -462,28 +559,137 @@ class DataFeed(object):
                 break
             if isinstance(item, marker.EndPartition):
                 queue.task_done()
-                if count > 0:
+                if batches:
                     break
                 continue
-            # A loose (unchunked) item: treat as a one-row part, under the
-            # same structure-consistency contract as the chunk path.
-            fields, tr = _rows_to_fields([item])
-            if tuple_rows is None:
-                tuple_rows = tr
-            elif tuple_rows != tr or (parts and len(parts[-1]) != len(fields)):
-                raise ValueError(
-                    "inconsistent row structure across feed items "
-                    "(tuple_rows {} vs {})".format(tuple_rows, tr))
-            parts.append(fields)
-            count += 1
+            # A loose (unchunked) item: a one-row part, under the same
+            # structure-consistency contract as the chunk path.
+            into(*_rows_to_fields([item]), 1)
             queue.task_done()
+        if not batches:
+            return assemble_columns([], None, None, self.input_tensors), 0
+        batch = batches.popleft()
+        count = batch.count
         self.items_consumed += count
         self._fault.on_items(count)
-        return self._assemble_columns(parts, tuple_rows, dtypes), count
+        cols = (batch.cols if count == batch.size
+                else [c[:count] for c in batch.cols])
+        if self.input_tensors is not None:
+            return dict(zip(self.input_tensors, cols)), count
+        return (tuple(cols) if batch.tuple_rows else cols[0]), count
 
-    def _assemble_columns(self, parts, tuple_rows, dtypes):
-        return assemble_columns(parts, tuple_rows, dtypes,
-                                self.input_tensors)
+    def _field_dtypes(self, dtypes, arity, tuple_rows):
+        """``dtypes`` as the caller gives it -> one dtype (or None) a field."""
+        if self.input_tensors is not None:
+            if arity != len(self.input_tensors):
+                raise ValueError(
+                    "input_mapping names {} tensors but feed rows have {} "
+                    "fields".format(len(self.input_tensors), arity))
+            if dtypes is not None:
+                return [dtypes.get(t) for t in self.input_tensors]
+        if dtypes is None:
+            return [None] * arity
+        return [dtypes[f] for f in range(arity)] if tuple_rows else [dtypes]
+
+    def _as_asked(self, batch, batch_size, dtypes):
+        """Whether a batch begun by an earlier call is what this call asks
+        for: its size, and the dtypes named."""
+        return batch.size == batch_size and batch.asked == self._field_dtypes(
+            dtypes, len(batch.cols), batch.tuple_rows)
+
+    def _new_batch(self, fields, tuple_rows, batch_size, dtypes):
+        """Buffers for a batch whose rows look like ``fields``: handed-back
+        ones of that kind if the feed holds any, else new memory."""
+        asked = self._field_dtypes(dtypes, len(fields), tuple_rows)
+        key = tuple(
+            ((batch_size,) + f.shape[1:],
+             (f.dtype if d is None else np.dtype(d)).str)
+            for f, d in zip(fields, asked))
+        if self._free and key == self._free_key:
+            cols = self._free.pop()
+            self.buffers_reused += 1
+        else:
+            cols = [np.empty(shape, dtype) for shape, dtype in key]
+            self.buffers_new += 1
+        return _Batch(cols, asked, tuple_rows, batch_size)
+
+    def _copy_rows(self, fields, tuple_rows, n, batch_size, dtypes):
+        """Copy ``n`` rows, given as one array (or in-ring view) a field,
+        into the batches under construction: the last one while it has
+        room, then new ones.  The only copy of a row on the array path; a
+        cast to ``dtypes`` happens in it."""
+        batches = self._batches
+        at = 0
+        while at < n:
+            batch = batches[-1] if batches else None
+            if batch is None or batch.count >= batch.size:
+                batch = self._new_batch(fields, tuple_rows, batch_size, dtypes)
+                batches.append(batch)
+            elif (batch.tuple_rows != tuple_rows
+                  or len(batch.cols) != len(fields)):
+                raise ValueError(
+                    "inconsistent row structure across feed chunks "
+                    "(tuple_rows {} vs {})".format(batch.tuple_rows,
+                                                   tuple_rows))
+            lo = batch.count
+            take = min(batch.size - lo, n - at)
+            for f, src in enumerate(fields):
+                dst = batch.cols[f]
+                if src.shape[1:] != dst.shape[1:]:
+                    raise ValueError(
+                        "inconsistent row structure across feed chunks "
+                        "(field {} has shape {} after {})".format(
+                            f, src.shape[1:], dst.shape[1:]))
+                if src.dtype != dst.dtype and batch.asked[f] is None and \
+                        not np.can_cast(src.dtype, dst.dtype, "safe"):
+                    # what np.concatenate would have given: the rows so far
+                    # move to the wider dtype (a rare, slow path: object
+                    # chunks whose python numbers change kind mid-batch)
+                    dst = batch.cols[f] = dst.astype(
+                        np.result_type(src.dtype, dst.dtype))
+                np.copyto(dst[lo:lo + take], src[at:at + take],
+                          casting="unsafe")
+            batch.count = lo + take
+            at += take
+
+    def _unbatch(self):
+        """Rows that the array path copied ahead go back into the row
+        buffer as one columnar chunk, ack and all: for :meth:`next_batch`,
+        and for a ``next_batch_arrays`` call that asks for another batch
+        size or dtype than the one that copied them."""
+        batches = list(self._batches)
+        self._batches.clear()
+        parts = [[c[:b.count] for c in b.cols] for b in batches]
+        cols = tuple(p[0] if len(p) == 1 else np.concatenate(p)
+                     for p in zip(*parts))
+        self._buffer = marker.ColChunk(
+            cols, sum(b.count for b in batches), batches[0].tuple_rows)
+        self._buffer_idx = 0
+
+    def release(self, arrays):
+        """Hand a batch's arrays back: ``arrays`` as
+        :meth:`next_batch_arrays` returned them, from a caller that will
+        not touch that memory again (see "Who owns a batch" there).  A
+        later batch of the same kind is built in them.  Returns whether the
+        feed took them: only whole batches are taken (not the views of a
+        partial one), and only once."""
+        if isinstance(arrays, dict):
+            if self.input_tensors is None:
+                return False
+            cols = [arrays.get(t) for t in self.input_tensors]
+        else:
+            cols = list(arrays) if isinstance(arrays, tuple) else [arrays]
+        key = _buffers_key(cols)
+        if key is None:
+            return False
+        if key != self._free_key:
+            # one kind at a time: what a changed batch size or row shape
+            # left behind goes, so the list never outgrows its reader
+            self._free, self._free_key = [], key
+        if any(held[0] is cols[0] for held in self._free):
+            return False
+        self._free.append(cols)
+        return True
 
     def counters_snapshot(self):
         """Flat telemetry counters for heartbeat payloads.
@@ -515,7 +721,9 @@ class DataFeed(object):
         """This feed's counters alone, with no manager round trip (the
         heartbeat provider's view: it merges the feeders' KV itself, once)."""
         snap = {"feed_items": self.items_consumed,
-                "feed_stall_secs": round(self.stall_secs, 6)}
+                "feed_stall_secs": round(self.stall_secs, 6),
+                "feed_batch_buffers_reused": self.buffers_reused,
+                "feed_batch_buffers_new": self.buffers_new}
         snap.update(self._clock.snapshot("feed_"))
         for fmt, n in self.wire_formats.copy().items():
             snap["wire_{}".format(fmt)] = n
@@ -544,12 +752,14 @@ class DataFeed(object):
             self.mgr.set("state", "terminating")
             self._ack_chunk()  # release a partially-consumed chunk's join hold
             self._buffer, self._buffer_idx = [], 0
+            self._batches.clear()
             queue = self.mgr.get_queue(self.qname_in)
         except (EOFError, BrokenPipeError, ConnectionError, OSError):
             # the manager died before the drain even started (driver-side
             # shutdown won the race) — nothing left to mark or drain
             logger.info("manager gone at terminate(); assuming shutdown")
             self._buffer, self._buffer_idx = [], 0
+            self._batches.clear()
             return
         count = 0
         done = False

@@ -175,6 +175,10 @@ class ShardedFeed(object):
         # the trainer's dispatch leg (pop_dispatch_flow).  Best-effort,
         # bounded; single producer (the prefetch thread), single consumer.
         self._dispatch_flows = collections.deque(maxlen=16)
+        # The feed's host arrays whose transfer may still be under way,
+        # with the device arrays made of them: (lent, leaves), oldest
+        # first (see _hand_back).  One thread: the one that assembles.
+        self._lent = collections.deque()
         # Ride this node's heartbeats: the metrics provider duck-types
         # counters_snapshot() over every registered source, so the infeed_*
         # tallies reach the driver's metrics_snapshot() aggregate.  Guarded:
@@ -314,7 +318,10 @@ class ShardedFeed(object):
 
     def _next_local(self):
         """Assemble this host's local batch as final columnar arrays;
-        returns (arrays, count) or None when no usable rows remain."""
+        returns (arrays, count, lent) or None when no usable rows remain.
+        ``lent`` is the batch as the feed's ``next_batch_arrays`` returned
+        it, before the transform (``None`` on the row-list path): what
+        :meth:`_shard` queues for the hand-back (:meth:`_hand_back`)."""
         start = time.perf_counter()
         with telemetry.span("infeed/assemble"):
             local = self._next_local_inner()
@@ -334,10 +341,13 @@ class ShardedFeed(object):
                 return None
             with telemetry.span("infeed/transform"):
                 arrays = self.preprocess(items)
+            lent = None
         else:
+            self._hand_back()
             arrays, count = self.feed.next_batch_arrays(self.local_batch_size)
             if count == 0:
                 return None
+            lent = arrays
             if self.transform is not None:
                 with telemetry.span("infeed/transform"):
                     arrays = self.transform(arrays)
@@ -345,11 +355,14 @@ class ShardedFeed(object):
             # partial tail with padding disabled: drop it (documented)
             logger.info("dropping %d-row partial tail (pad_final=False)", count)
             return None
-        return arrays, count
+        return arrays, count, lent
 
-    def _shard(self, arrays, count):
+    def _shard(self, arrays, count, lent=None):
         """Pad to the local batch size and transfer to devices as this
         process's shard of the global batch; returns (batch, mask).
+        ``lent``, the feed's own arrays that ``arrays`` were made of, go
+        back to the feed once the transfer has read them
+        (:meth:`_hand_back`).
 
         The transfer is an explicit ``make_array_from_process_local_data``
         into freshly-allocated device buffers — donation-safe (the step may
@@ -382,7 +395,44 @@ class ShardedFeed(object):
         self._tally_put(start)
         self._n_batches += 1
         self._note_flow("infeed_device_put", rows=count)
+        if lent is not None:
+            self._lent.append((lent, jax.tree_util.tree_leaves(batch)))
+            self._hand_back()
         return batch, mask
+
+    def _hand_back(self):
+        """Give the feed its batch buffers back (``DataFeed.release``; a
+        feed without the method keeps today's contract), oldest first, as
+        far as nothing on the device side reads them any more: the
+        transfer that was made of them is complete, and no device array is
+        the host memory itself.  A device with memory of its own copied;
+        the CPU client may have wrapped an aligned numpy buffer instead,
+        which its buffer's address shows.  Never waits: a transfer still
+        under way is looked at again before the next batch is asked for.
+        Where it cannot be told (a device array deleted by a donating
+        step), nothing is handed back."""
+        import jax
+
+        release = getattr(self.feed, "release", None)
+        if release is None:
+            self._lent.clear()
+            return
+        while self._lent:
+            lent, leaves = self._lent[0]
+            try:
+                if not all(leaf.is_ready() for leaf in leaves):
+                    return
+                spans = [(a.ctypes.data, a.ctypes.data + a.nbytes)
+                         for a in jax.tree_util.tree_leaves(lent)]
+                mine = any(
+                    lo <= shard.data.unsafe_buffer_pointer() < hi
+                    for leaf in leaves for shard in leaf.addressable_shards
+                    if shard.device.platform == "cpu" for lo, hi in spans)
+            except Exception:  # noqa: BLE001 — cannot tell
+                mine = True
+            self._lent.popleft()
+            if not mine:
+                release(lent)
 
     # -- public iteration -------------------------------------------------
 
@@ -566,7 +616,8 @@ class ShardedFeed(object):
         self.feed.terminate()
 
     def _local_iter(self):
-        """Yields (arrays, count) per step, then a single None at end-of-feed.
+        """Yields (arrays, count, lent) per step (see :meth:`_next_local`),
+        then a single None at end-of-feed.
 
         Stops *without another blocking queue read* once the feed reported
         end-of-feed — the final partial batch consumes the queue's only None
@@ -586,8 +637,8 @@ class ShardedFeed(object):
             if local is None:
                 yield None
                 return
-            arrays, count = local
-            batch, mask = self._shard(arrays, count)
+            arrays, count, lent = local
+            batch, mask = self._shard(arrays, count, lent)
             yield batch, mask, count
 
     def _scan_sharding(self, ndim_stacked):
@@ -673,11 +724,11 @@ class ShardedFeed(object):
         for local in self._local_iter():
             if local is None:
                 break
-            arrays, count = local
+            arrays, count, lent = local
             if not singles_mode and count == self.local_batch_size:
                 if not pending:
                     group_k = self._live_group_k()
-                pending.append(self._shard(arrays, count))
+                pending.append(self._shard(arrays, count, lent))
                 if len(pending) >= group_k:
                     item = self._assemble_group(pending)
                     pending = []
@@ -687,7 +738,7 @@ class ShardedFeed(object):
             for b, m in pending:
                 yield ("single", b, m)
             pending = []
-            b, m = self._shard(arrays, count)
+            b, m = self._shard(arrays, count, lent)
             yield ("single", b, m)
         for b, m in pending:
             yield ("single", b, m)
@@ -716,7 +767,7 @@ class ShardedFeed(object):
         for local in self._local_iter():
             if local is None:
                 break
-            arrays, count = local
+            arrays, count, _ = local  # stacked on the host: never handed back
             if not singles_mode and count == self.local_batch_size:
                 if not pending:
                     group_k = self._live_group_k()

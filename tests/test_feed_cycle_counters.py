@@ -323,6 +323,51 @@ def test_feeder_phases_sum_to_the_feeders_wall_time(harness):
     assert snap["feed_assemble_us"] > 0
 
 
+def test_consumer_phases_sum_to_its_wall_time_with_one_copy_a_row(harness):
+    """ISSUE 33: the ring read with its one copy (and the ack) is ``read``,
+    what is left of building a batch ``assemble``; both stay in the
+    snapshot, beside how often a batch found a buffer waiting."""
+    t0 = time.monotonic_ns()
+    feed = DataFeed(harness.mgr)
+    made_us = (time.monotonic_ns() - t0) / 1e3   # its clock started in here
+    handed = []
+
+    def loop():     # chunks of 5 against batches of 8: most chunks straddle
+        while not feed.should_stop():
+            arrays, count = feed.next_batch_arrays(8)
+            if count:
+                harness.rows.extend(arrays[1].tolist())
+                handed.append(feed.release(arrays))
+
+    t = threading.Thread(target=loop, daemon=True)
+    t.start()
+    node._feeder_clocks[harness.qname] = telemetry.PhaseClock(
+        node.FEEDER_PHASES)
+    fn = node.train(harness.cluster_info, harness.meta, chunk_size=5,
+                    num_epochs=2)
+    assert fn(iter(_data(40))) == [80]
+    harness.mgr.get_queue(harness.qname).put(None)
+    t.join(10)
+    assert not t.is_alive()
+    before_us = (time.monotonic_ns() - t0) / 1e3
+    snap = feed.counters_snapshot()     # reads the clock, then the manager
+    after_us = (time.monotonic_ns() - t0) / 1e3
+    assert harness.rows == list(range(40)) * 2
+    for key in FEED_US + ["feed_batch_buffers_reused",
+                          "feed_batch_buffers_new"]:
+        assert isinstance(snap[key], int) and snap[key] >= 0, key
+    # the phases are the feed's whole life (each rounds down to a us)
+    assert before_us - made_us - 10 <= sum(snap[k] for k in FEED_US) \
+        <= after_us, (snap, made_us, before_us, after_us)
+    assert snap["feed_read_us"] > 0 and snap["feed_wait_us"] > 0
+    assert snap.get("wire_colv1", 0) + snap.get("wire_queue", 0) == 16
+    # ten whole batches, each handed back: the first is new memory, the
+    # rest is that memory again
+    assert handed == [True] * 10
+    assert snap["feed_batch_buffers_new"] == 1
+    assert snap["feed_batch_buffers_reused"] == 9
+
+
 def test_inference_feeder_accounts_on_its_own_queue(harness):
     """The inference closure shares ``_ChunkPutter``: its task is accounted
     the same way and leaves the clock between tasks."""

@@ -528,3 +528,113 @@ def test_prefetch_depth_from_env(mgr, monkeypatch):
     assert sf2._prefetch_depth == infeed_mod.DEFAULT_PREFETCH
     assert ShardedFeed(DataFeed(mgr), build_mesh(), global_batch_size=8,
                        prefetch=0)._prefetch_depth == 0
+
+
+# -- the hand-back of batch buffers (ISSUE 33) --------------------------------
+
+def _rows(n, width=4):
+    return [(np.full((width,), i, np.float32), i) for i in range(n)]
+
+
+def _aliased(device_array, host_arrays):
+    """Whether a CPU device buffer IS memory of one of ``host_arrays``."""
+    spans = [(a.ctypes.data, a.ctypes.data + a.nbytes) for a in host_arrays]
+    return any(lo <= s.data.unsafe_buffer_pointer() < hi
+               for s in device_array.addressable_shards for lo, hi in spans)
+
+
+@pytest.mark.parametrize("prefetch", [0, 2])
+@pytest.mark.parametrize("transform", ["views", "copies"])
+def test_delivered_batches_keep_their_rows_while_buffers_go_round(
+        mgr, prefetch, transform):
+    """On the CPU a device array may BE the host buffer: such a buffer is
+    never handed back, any other is, and either way a batch delivered
+    earlier still holds its own rows many batches later."""
+    _fill(mgr, _rows(8 * 9))
+    feed = DataFeed(mgr)
+    back = []           # the first column of every batch the feed took back
+    release = feed.release
+
+    def spy(arrays):
+        took = release(arrays)
+        if took:
+            back.append(arrays[0])
+        return took
+
+    feed.release = spy
+    if transform == "views":
+        def fn(cols):
+            return {"x": cols[0][:, :2], "y": cols[1]}
+    else:
+        def fn(cols):
+            return {"x": cols[0] * 2.0, "y": cols[1].astype(np.int32)}
+    sf = ShardedFeed(feed, build_mesh(), global_batch_size=8, transform=fn,
+                     prefetch=prefetch)
+    lent = []           # (the feed's arrays, the device batch made of them)
+    shard = sf._shard
+
+    def spy_shard(arrays, count, mine):
+        batch, mask = shard(arrays, count, mine)
+        lent.append((mine, batch))
+        return batch, mask
+
+    sf._shard = spy_shard
+    scale = 1.0 if transform == "views" else 2.0
+    out = []
+    for batch, mask in sf.batches():
+        out.append(batch)
+        for b, earlier in enumerate(out):     # every batch so far, again
+            assert np.asarray(earlier["y"]).tolist() == \
+                list(range(8 * b, 8 * b + 8))
+            assert np.asarray(earlier["x"])[:, 0].tolist() == \
+                [scale * i for i in range(8 * b, 8 * b + 8)]
+    assert len(out) == 9 and len(lent) == 9
+    for arrays, batch in lent:
+        mine = any(_aliased(leaf, arrays) for leaf in batch.values())
+        took = any(arrays[0] is b for b in back)
+        assert took == (not mine), "handed back iff no device array is it"
+    snap = feed.counters_snapshot()
+    assert snap["feed_batch_buffers_new"] + \
+        snap["feed_batch_buffers_reused"] == 9
+    if transform == "copies":       # nothing on the device is a feed buffer
+        assert len(back) == 9
+        assert snap["feed_batch_buffers_new"] == 1
+        assert snap["feed_batch_buffers_reused"] == 8
+
+
+def test_a_feed_without_the_method_is_skipped():
+    class Plain(object):
+        def __init__(self):
+            self.left = 3
+
+        def should_stop(self):
+            return self.left == 0
+
+        def next_batch_arrays(self, n):
+            self.left -= 1
+            return np.full((n, 2), float(self.left), np.float32), n
+
+        def interrupt(self):
+            pass
+
+    sf = ShardedFeed(Plain(), build_mesh(), global_batch_size=8, prefetch=0)
+    got = [float(np.asarray(b)[0, 0]) for b, _ in sf.batches()]
+    assert got == [2.0, 1.0, 0.0]
+
+
+def test_the_grouped_paths_hand_back_only_what_the_device_path_shards(mgr):
+    for assembly, want in (("device", 6), ("host", 0)):
+        _fill(mgr, _rows(8 * 6))
+        feed = DataFeed(mgr)
+        sf = ShardedFeed(feed, build_mesh(), global_batch_size=8,
+                         transform=lambda c: {"x": c[0] + 0.0, "y": c[1] + 0},
+                         prefetch=0, group_assembly=assembly)
+        ys = []
+        for kind, batch, _ in sf.grouped_batches(2):
+            assert kind == "multi"
+            ys.extend(np.asarray(batch["y"]).reshape(-1).tolist())
+        assert ys == list(range(48))
+        snap = feed.counters_snapshot()
+        assert snap["feed_batch_buffers_new"] + \
+            snap["feed_batch_buffers_reused"] == 6
+        assert snap["feed_batch_buffers_reused"] == max(want - 1, 0)

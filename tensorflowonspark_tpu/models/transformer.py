@@ -43,12 +43,30 @@ configuration:
   family's softmax scale), ``first_k_dense_replace`` leading SwiGLU
   feed-forwards and then :class:`TopKExperts` by softmax scores without a
   bias leaf and without renormalisation, beside a shared SwiGLU that every
-  token passes through, and a read-out of its own (``tied_readout=False``).
+  token passes through, and a read-out of its own (``tied_readout=False``);
+- :func:`keye_vl2_spec` (registered as ``keye_vl2``): the language model of
+  the Keye-VL-2.0 mixture family's ``config.json``: RMSNorm, grouped-query
+  attention with per-head q/k RMSNorm and RoPE **over the keys a learned
+  index picks** (``sa_config``: ``index_heads`` index query heads of
+  ``index_dim`` and one index key head score every causal pair on a detached
+  copy of the layer's input, each query keeps its ``index_topk`` best keys,
+  exactly; :mod:`tensorflowonspark_tpu.ops.sparse_index`), an expert layer
+  in every layer (softmax scores, top-k renormalised, no bias leaf, no
+  shared expert) and an untied read-out.  The index is trained by a loss of
+  its own, sown a layer (``dsa_index_loss``) and added by :func:`loss_fn`:
+  its leaves (``index_q``, ``index_k``, ``index_k_norm``, ``index_w``) get
+  that loss's gradient alone and every other leaf the cross-entropy's alone.
 
 Scopes a device trace can be read by (``jax.named_scope`` under the flax
 module names): ``block_i/short_conv``, ``block_i/attention/flash``,
 ``block_i/attention/latent`` (everything latent attention puts round the
 kernel: the three projections, the latent's norm, RoPE, building K),
+``block_i/attention/indexer`` (the index's three projections, its norm and
+RoPE), ``block_i/attention/select`` (the one kernel that scores every causal
+pair and picks each query's keys: the scores never leave VMEM, so they have
+no scope apart from the search), ``block_i/attention/tiles`` (the count of
+the tiles the picks touch), ``block_i/attention/index_loss`` (the kernel of
+the index's loss),
 ``block_i/moe/route`` (router, top-k, sort), ``moe/dispatch`` (gather),
 ``moe/experts`` (the grouped products), ``moe/combine`` (scale, gather back),
 ``moe/shared`` (the shared expert).
@@ -100,6 +118,15 @@ class LayerSpec:
     rope_dim: int = 0
     v_dim: int = 0
     attn_scale: Optional[float] = None
+    # a learned index picks each query's keys (attention="flash"):
+    # index_heads index query heads of index_dim and one index key head
+    # (LayerNorm, RoPE at rope_theta over all of index_dim) score every
+    # causal pair on a detached input, sum_j w_j relu(q_j . k), and attention
+    # runs over the index_topk keys of largest score; 0 = every causal key,
+    # and then nothing of a layer changes
+    index_heads: int = 0
+    index_dim: int = 0
+    index_topk: int = 0
     flash_block: int = 128         # q and k block of attention="flash"
     conv_kernel: int = 3
     ff_size: int = 0               # the dense feed-forward's width
@@ -265,6 +292,53 @@ def deepseek_v2_spec(config):
                        tied_readout=config.get("tie_word_embeddings", False))
 
 
+def keye_vl2_spec(config):
+    """:class:`DecoderSpec` of the language model under a Keye-VL-2.0
+    mixture ``config.json`` (a dict with the source's keys: ``head_dim``,
+    ``num_key_value_heads``, ``num_experts_per_tok``, ``norm_topk_prob``,
+    ``sa_config`` with ``indexer_num_heads``, ``indexer_head_dim``,
+    ``indexer_num_kv_heads`` and ``topk``, ...).  ``num_experts`` is the
+    router's width; ``held_experts`` (``[first, count]``, optional) the
+    experts this program holds of each layer; ``flash_block`` (optional) the
+    attention kernel's block.  Text rows only: the three sections of
+    ``mrope_section`` all carry the token's position, which is plain RoPE.
+    What the family's modelling code does and no key says: per-head RMSNorm
+    on q and k, rotate-half pairing."""
+    sparse = config.get("sa_config") or {}
+    unsupported = {
+        "decoder_sparse_step": config.get("decoder_sparse_step", 1) != 1,
+        "mlp_only_layers": bool(config.get("mlp_only_layers")),
+        "use_sliding_window": bool(config.get("use_sliding_window")),
+        "attention_bias": bool(config.get("attention_bias")),
+        "sa_config.indexer_num_kv_heads":
+            sparse.get("indexer_num_kv_heads", 1) != 1}
+    if any(unsupported.values()):
+        raise ValueError("keye_vl2: no support for this config's {}".format(
+            sorted(k for k, v in unsupported.items() if v)))
+    held = config.get("held_experts")
+    layer = LayerSpec(
+        op="attention", ff="experts", norm="rmsnorm",
+        norm_eps=config["rms_norm_eps"], positions="rope",
+        num_heads=config["num_attention_heads"], head_dim=config["head_dim"],
+        num_kv_heads=config["num_key_value_heads"], qk_norm=True,
+        rope_theta=float(config["rope_theta"]),
+        index_heads=sparse.get("indexer_num_heads", 0),
+        index_dim=sparse.get("indexer_head_dim", 0),
+        index_topk=sparse.get("topk", 0),
+        flash_block=config.get("flash_block", 512),
+        num_experts=config["num_experts"],
+        experts_per_token=config["num_experts_per_tok"],
+        expert_size=config["moe_intermediate_size"],
+        held_experts=tuple(held) if held else None,
+        router_score="softmax", selection_bias=False,
+        norm_topk=config.get("norm_topk_prob", True))
+    return DecoderSpec(vocab_size=config["vocab_size"],
+                       hidden_size=config["hidden_size"],
+                       layers=(layer,) * config["num_hidden_layers"],
+                       norm="rmsnorm", norm_eps=config["rms_norm_eps"],
+                       tied_readout=config.get("tie_word_embeddings", False))
+
+
 def _norm(kind, eps, dtype):
     if kind == "rmsnorm":
         return nn.RMSNorm(epsilon=eps, dtype=dtype)
@@ -337,6 +411,30 @@ class Attention(nn.Module):
     # the latent form (LayerSpec op="mla"): the layer's description, whose
     # kv_rank, nope_dim, rope_dim, v_dim, rope_* and attn_scale are read
     latent: Optional[LayerSpec] = None
+    # a learned index over the keys (LayerSpec.index_*); 0 heads = none
+    index_heads: int = 0
+    index_dim: int = 0
+    index_topk: int = 0
+
+    @nn.nowrap     # no scope of its own: the caller's named scopes are read
+    def _index(self, x):
+        """The index's queries ``[B, S, J, E]``, its one key a position
+        ``[B, S, E]`` and its head weights ``[B, S, J]`` (float32, with the
+        scores' ``J ** -0.5 * E ** -0.5``), all of a detached ``x``:
+        parameters ``index_q``, ``index_k``, ``index_k_norm``, ``index_w``."""
+        x = jax.lax.stop_gradient(x)
+        heads, dim = self.index_heads, self.index_dim
+        iq = nn.DenseGeneral((heads, dim), use_bias=False, dtype=self.dtype,
+                             name="index_q")(x)
+        ik = nn.LayerNorm(epsilon=self.norm_eps, dtype=self.dtype,
+                          name="index_k_norm")(
+            nn.Dense(dim, use_bias=False, dtype=self.dtype,
+                     name="index_k")(x))
+        inv, _ = rope_frequencies(dim, self.rope_theta)
+        iq, ik = rope(iq, inv), rope(ik[:, :, None], inv)[:, :, 0]
+        iw = nn.Dense(heads, use_bias=False, dtype=jnp.float32,
+                      name="index_w")(x.astype(jnp.float32))
+        return iq, ik, iw * (heads ** -0.5 * dim ** -0.5)
 
     def _latent_qkv(self, x):
         """q ``[B, S, H, nope + rope]``, k alike, v ``[B, S, H, v_dim]`` of
@@ -364,6 +462,40 @@ class Attention(nn.Module):
              jnp.broadcast_to(k_pe, k_pe.shape[:2] + (heads, spec.rope_dim))],
             axis=-1)
         return q, k, kv[..., nope:]
+
+    @nn.nowrap
+    def _indexed(self, x, q, k, v):
+        """Attention over the keys the index picks; sows the index's loss
+        (``dsa_index_loss [B]``) and the tiles its picks touch
+        (``dsa_counts``)."""
+        from tensorflowonspark_tpu.ops import (flash_attention_lse,
+                                               sparse_index)
+
+        if self.attention != "flash" or self.latent is not None:
+            raise ValueError(
+                "an index over the keys runs with grouped-query attention "
+                "under attention=\"flash\": {!r}".format(self.attention))
+        block = self.flash_block
+        with jax.named_scope("indexer"):
+            iq, ik, iw = self._index(x)
+        with jax.named_scope("select"):
+            bits, index_lse = sparse_index.select_keys(
+                iq, ik, iw, self.index_topk, chunk=block)
+        with jax.named_scope("tiles"):
+            touched, causal = sparse_index.tiles_touched(
+                bits, x.shape[1], block)
+        with jax.named_scope("flash"):
+            out, lse = flash_attention_lse(
+                q, k, v, causal=True, block_q=block, block_k=block,
+                key_bits=bits)
+        with jax.named_scope("index_loss"):
+            loss = sparse_index.index_loss(iq, ik, iw, q, k, lse, index_lse,
+                                           bits, block=block)
+        self.sow("intermediates", "dsa_index_loss", loss)
+        self.sow("intermediates", "dsa_counts",
+                 {"tiles_touched": touched,
+                  "tiles_causal": jnp.asarray(causal, jnp.int32)})
+        return out
 
     @nn.compact
     def __call__(self, x):
@@ -393,7 +525,9 @@ class Attention(nn.Module):
         if self.rope_theta is not None and self.latent is None:
             inv, _ = rope_frequencies(self.head_dim, self.rope_theta)
             q, k = rope(q, inv), rope(k, inv)
-        if self.attention == "flash":
+        if self.index_heads:
+            out = self._indexed(x, q, k, v)
+        elif self.attention == "flash":
             from tensorflowonspark_tpu.ops import flash_attention
 
             with jax.named_scope("flash"):
@@ -699,6 +833,8 @@ class Block(nn.Module):
                 rope_theta=(spec.rope_theta if spec.positions == "rope"
                             else None),
                 flash_block=spec.flash_block, latent=latent,
+                index_heads=spec.index_heads, index_dim=spec.index_dim,
+                index_topk=spec.index_topk,
                 name=None if spec.num_kv_heads is None and not latent
                 else "attention")(h)
         x = x + h
@@ -818,6 +954,17 @@ def build_deepseek_v2(config, attention="flash", mesh=None, remat=False,
                          mesh=mesh, remat=remat, dtype=jnp.dtype(dtype))
 
 
+@register_model("keye_vl2")
+def build_keye_vl2(config, attention="flash", mesh=None, remat=False,
+                   dtype="float32"):
+    """The one decoder under a Keye-VL-2.0 mixture ``config.json``'s language
+    model (see :func:`keye_vl2_spec`).  The index over the keys runs under
+    ``attention="flash"`` (its kernels, and the flash kernels reading a key
+    set a query) alone."""
+    return TransformerLM(spec=keye_vl2_spec(config), attention=attention,
+                         mesh=mesh, remat=remat, dtype=jnp.dtype(dtype))
+
+
 def _sown(tree, name):
     """Every value sown under ``name`` anywhere in the intermediates tree."""
     found = []
@@ -856,6 +1003,26 @@ def _sum_moe_counts(tree):
             "moe_layers_steps": jnp.asarray(len(found), jnp.int32)}
 
 
+def _sum_dsa(tree, mask):
+    """What the layers with an index over the keys sowed: ``(loss, counts)``
+    with ``loss`` the layers' ``dsa_index_loss [B]`` added up and averaged
+    over the rows as ``mask`` weighs them, and ``counts`` under the names of
+    ``train.Trainer``'s counters (``dsa_tiles_causal``, ``dsa_tiles_touched``
+    of the causal ``[flash_block, flash_block]`` tiles and of those that
+    hold a picked key, ``dsa_index_loss``, ``dsa_layers_steps``); None
+    without such layers."""
+    losses = _sown(tree, "dsa_index_loss")
+    if not losses:
+        return None
+    loss = (sum(losses) * mask).sum() / jnp.maximum(mask.sum(), 1.0)
+    tiles = _sown(tree, "dsa_counts")
+    return loss, {
+        "dsa_tiles_causal": sum(c["tiles_causal"] for c in tiles),
+        "dsa_tiles_touched": sum(c["tiles_touched"] for c in tiles),
+        "dsa_index_loss": jax.lax.stop_gradient(loss),
+        "dsa_layers_steps": jnp.asarray(len(losses), jnp.int32)}
+
+
 def loss_fn(model, moe_aux_weight=0.01):
     """Next-token cross-entropy with per-row masking.
 
@@ -869,7 +1036,11 @@ def loss_fn(model, moe_aux_weight=0.01):
     reported via ``aux["moe_aux_loss"]``.  ``TopKExperts`` layers have no
     auxiliary loss; their token-slot counts of the step come out as
     ``aux["moe_counts"]`` (device scalars; ``train.Trainer`` adds them up
-    into its ``moe_*`` counters without a host sync).
+    into its ``moe_*`` counters without a host sync).  Layers with an index
+    over the keys sow that index's loss: it is added to the step's loss as
+    it is (its gradient reaches the index's leaves alone), reported as
+    ``aux["dsa_index_loss"]``, and with the tiles the picks touch goes out
+    as ``aux["dsa_counts"]`` (the ``Trainer``'s ``dsa_*`` counters).
     """
     import optax
 
@@ -891,6 +1062,10 @@ def loss_fn(model, moe_aux_weight=0.01):
         counts = _sum_moe_counts(sown)
         if counts is not None:
             aux["moe_counts"] = counts
+        dsa = _sum_dsa(sown, mask)
+        if dsa is not None:
+            aux["dsa_index_loss"], aux["dsa_counts"] = dsa[0], dsa[1]
+            ce = ce + dsa[0]
         return ce, aux
 
     return loss

@@ -10,7 +10,8 @@ Every kernel runs in pallas interpret mode off-TPU, so the suite validates
 them on the CPU mesh.
 """
 
-from tensorflowonspark_tpu.ops.flash_attention import flash_attention  # noqa: F401
+from tensorflowonspark_tpu.ops.flash_attention import (  # noqa: F401
+    flash_attention, flash_attention_lse)
 from tensorflowonspark_tpu.ops.grouped_matmul import grouped_matmul  # noqa: F401
 from tensorflowonspark_tpu.ops.routed_rows import (  # noqa: F401
     gather_rows, gather_sum_rows)

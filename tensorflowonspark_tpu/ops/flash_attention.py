@@ -41,6 +41,15 @@ index maps clamp to the last tile a row block needs, and a block whose index
 does not change is not copied again); the mask itself is applied on the tiles
 the diagonal crosses only.
 
+Key sets: ``key_bits`` (``[batch, groups, seq, 128]`` int32, the layout of
+:mod:`~tensorflowonspark_tpu.ops.sparse_index`: bit ``(s % 4096) // 128`` of
+word ``[b, s // 4096, t, s % 128]`` says whether query ``t`` may read key
+``s``) restricts every query to its own keys inside the causal triangle: the
+three kernels read one ``[block_q, 128]`` tile of words for up to 32 k blocks
+and mask the scores of the tiles they compute.  Every causal tile is still
+computed (a masked pair's products are thrown away); without ``key_bits``
+nothing of it is traced and the kernels are the ones they were.
+
 Layout contract: ``[batch, seq, heads, dim]`` like
 :mod:`~tensorflowonspark_tpu.parallel.ring`; blocks default to 128 (MXU
 tile) and the sequence length must divide by the block size: a
@@ -52,8 +61,11 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 NEG_INF = -1e30
+KEY_LANES = 128          # a word of key_bits holds one key a lane ...
+KEY_GROUP = 32 * 128     # ... of each of 32 runs of 128 keys
 
 
 def _default_interpret():
@@ -72,11 +84,26 @@ def _dot(a, b, contract):
                                preferred_element_type=jnp.float32)
 
 
+def key_mask(words, k_block_id, block_k):
+    """bool ``[block_q, block_k]``: the keys of k block ``k_block_id`` that
+    each query may read, from its ``[block_q, 128]`` tile of ``key_bits``
+    words (the group's; ``block_k`` divides by 128 and divides 4096)."""
+    runs = block_k // KEY_LANES
+    first = (k_block_id % (KEY_GROUP // block_k)) * runs
+    return jnp.concatenate(
+        [jax.lax.shift_right_logical(words, first + r) & 1
+         for r in range(runs)], axis=1) == 1
+
+
 def _scores(q_ref, k_ref, scale, masked, q_block_id, k_block_id, block_q,
-            block_k):
+            block_k, bits_ref=None):
     """float32 ``q k^T * scale`` of one (q block, k block) tile, the causal
-    mask applied where ``masked`` (a tile the diagonal crosses)."""
+    mask applied where ``masked`` (a tile the diagonal crosses), and the
+    queries' key sets where ``bits_ref`` holds them."""
     s = _dot(q_ref[0], k_ref[0], ((1,), (1,))) * scale       # [BQ, BK]
+    if bits_ref is not None:
+        s = jnp.where(key_mask(bits_ref[0, 0], k_block_id, block_k), s,
+                      NEG_INF)
     if masked:
         rows = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
                 + q_block_id * block_q)
@@ -117,10 +144,12 @@ def _first_q_block(kk, block_q, block_k):
 # forward
 # ---------------------------------------------------------------------------
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-                *, scale, causal, block_q, block_k, n_k):
+def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, block_q, block_k,
+                n_k, keyed):
     from jax.experimental import pallas as pl
 
+    bits_ref, (o_ref, lse_ref, m_scr, l_scr, acc_scr) = _bits_first(
+        refs, keyed)
     kk = pl.program_id(2)
     # program_id must be read OUTSIDE pl.when bodies (interpret mode can't
     # substitute it inside a cond branch); close over the values instead.
@@ -133,7 +162,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
     def _compute(masked):
-        s = _scores(q_ref, k_ref, scale, masked, qi, kk, block_q, block_k)
+        s = _scores(q_ref, k_ref, scale, masked, qi, kk, block_q, block_k,
+                    bits_ref)
         m_prev = m_scr[:]                              # [BQ, 1]
         m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)                         # [BQ, BK]
@@ -164,7 +194,32 @@ def _kv_maps(causal, block_q, block_k, group):
     return kv
 
 
-def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret, group=1):
+def _bits_first(refs, keyed):
+    """``(bits_ref, the others)`` of the references a kernel gets behind its
+    fixed inputs: ``key_bits`` come first among them where there are any."""
+    return (refs[0], refs[1:]) if keyed else (None, refs)
+
+
+def _bits_spec(rows, block_q, block_k, q_block, k_block):
+    """Block spec of ``key_bits`` on a launcher's grid: a step reads the
+    words of q block ``q_block(*ids)`` in the group of k block
+    ``k_block(*ids)``; the grid's first index counts ``rows`` (heads) a
+    batch row."""
+    from jax.experimental import pallas as pl
+
+    if block_k % KEY_LANES or KEY_GROUP % block_k:
+        raise ValueError(
+            "key_bits want a k block that divides by {} and divides {}: "
+            "{}".format(KEY_LANES, KEY_GROUP, block_k))
+    per_group = KEY_GROUP // block_k
+    return pl.BlockSpec(
+        (1, 1, block_q, KEY_LANES),
+        lambda b, x, y: (b // rows, k_block(x, y) // per_group,
+                         q_block(x, y), 0))
+
+
+def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret, group=1,
+               bits=None):
     """Returns ``(out [bh, seq, dv], logsumexp [bh, seq, 1])``; ``q`` is
     ``[bh, seq, d]``, ``k [bh // group, seq, d]`` and ``v [bh // group, seq,
     dv]``.  The softmax statistics keep a
@@ -180,16 +235,23 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret, group=1):
     n_k = s_len // block_k
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, block_q=block_q,
-        block_k=block_k, n_k=n_k)
+        block_k=block_k, n_k=n_k, keyed=bits is not None)
     kv = _kv_maps(causal, block_q, block_k, group)
+    in_specs = [
+        pl.BlockSpec((1, block_q, d), lambda b, i, kk: (b, i, 0)),
+        pl.BlockSpec((1, block_k, d), kv),
+        pl.BlockSpec((1, block_k, dv), kv),
+    ]
+    args = (q, k, v)
+    if bits is not None:
+        in_specs.append(_bits_spec(
+            bh // bits.shape[0], block_q, block_k,
+            lambda i, kk: i, lambda i, kk: kv(0, i, kk)[1]))
+        args += (bits,)
     out, lse = pl.pallas_call(
         kernel,
         grid=(bh, n_q, n_k),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, kk: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), kv),
-            pl.BlockSpec((1, block_k, dv), kv),
-        ],
+        in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, block_q, dv), lambda b, i, kk: (b, i, 0)),
             pl.BlockSpec((1, block_q, 1), lambda b, i, kk: (b, i, 0)),
@@ -204,7 +266,7 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret, group=1):
             pltpu.VMEM((block_q, dv), jnp.float32),
         ],
         interpret=interpret,
-    )(q, k, v)
+    )(*args)
     return out, lse
 
 
@@ -212,10 +274,11 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret, group=1):
 # backward
 # ---------------------------------------------------------------------------
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                   dq_scr, *, scale, causal, block_q, block_k, n_k):
+def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
+                   scale, causal, block_q, block_k, n_k, keyed):
     from jax.experimental import pallas as pl
 
+    bits_ref, (dq_ref, dq_scr) = _bits_first(refs, keyed)
     kk = pl.program_id(2)
     qi = pl.program_id(1)  # read outside pl.when bodies (interpret mode)
 
@@ -226,7 +289,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     def _compute(masked):
         # p = exp(q k^T * scale - L), recomputed from the saved logsumexp
         p = jnp.exp(_scores(q_ref, k_ref, scale, masked, qi, kk, block_q,
-                            block_k) - lse_ref[0])
+                            block_k, bits_ref) - lse_ref[0])
         dp = _dot(do_ref[0], v_ref[0], ((1,), (1,)))   # [BQ, BK]
         ds = p * (dp - delta_ref[0])
         k = k_ref[0]
@@ -239,11 +302,11 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, dk_scr, dv_scr,
-                    *, scale, causal, block_q, block_k, n_q, group):
+def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
+                    scale, causal, block_q, block_k, n_q, group, keyed):
     from jax.experimental import pallas as pl
 
+    bits_ref, (dk_ref, dv_ref, dk_scr, dv_scr) = _bits_first(refs, keyed)
     # the inner grid dimension runs over the KV head's ``group`` query heads
     # and, for each, over the q blocks: one accumulation for all of them
     j = pl.program_id(2)
@@ -257,7 +320,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     def _compute(masked):
         p = jnp.exp(_scores(q_ref, k_ref, scale, masked, qi, kk, block_q,
-                            block_k) - lse_ref[0])
+                            block_k, bits_ref) - lse_ref[0])
         do = do_ref[0]                                 # [BQ, DV]
         dv_scr[:] += _dot(p.astype(do.dtype), do, ((0,), (0,)))
         dp = _dot(do, v_ref[0], ((1,), (1,)))          # [BQ, BK]
@@ -281,7 +344,7 @@ def _bwd_delta(out, g):
 
 
 def _flash_bwd_dq(q, k, v, g, lse, delta, scale, causal, block_q, block_k,
-                  interpret, group=1):
+                  interpret, group=1, bits=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -290,27 +353,36 @@ def _flash_bwd_dq(q, k, v, g, lse, delta, scale, causal, block_q, block_k,
     n_q = s_len // block_q
     n_k = s_len // block_k
     kv = _kv_maps(causal, block_q, block_k, group)
+    kernel = functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
+                               block_q=block_q, block_k=block_k, n_k=n_k,
+                               keyed=bits is not None)
+    in_specs = [
+        pl.BlockSpec((1, block_q, d), lambda b, i, kk: (b, i, 0)),
+        pl.BlockSpec((1, block_k, d), kv),
+        pl.BlockSpec((1, block_k, dv), kv),
+        pl.BlockSpec((1, block_q, dv), lambda b, i, kk: (b, i, 0)),
+        pl.BlockSpec((1, block_q, 1), lambda b, i, kk: (b, i, 0)),
+        pl.BlockSpec((1, block_q, 1), lambda b, i, kk: (b, i, 0)),
+    ]
+    args = (q, k, v, g, lse, delta)
+    if bits is not None:
+        in_specs.append(_bits_spec(
+            bh // bits.shape[0], block_q, block_k,
+            lambda i, kk: i, lambda i, kk: kv(0, i, kk)[1]))
+        args += (bits,)
     return pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k, n_k=n_k),
+        kernel,
         grid=(bh, n_q, n_k),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, kk: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), kv),
-            pl.BlockSpec((1, block_k, dv), kv),
-            pl.BlockSpec((1, block_q, dv), lambda b, i, kk: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i, kk: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i, kk: (b, i, 0)),
-        ],
+        in_specs=in_specs,
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, kk: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
-    )(q, k, v, g, lse, delta)
+    )(*args)
 
 
 def _flash_bwd_dkv(q, k, v, g, lse, delta, scale, causal, block_q, block_k,
-                   interpret, group=1):
+                   interpret, group=1, bits=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -328,19 +400,27 @@ def _flash_bwd_dkv(q, k, v, g, lse, delta, scale, causal, block_q, block_k,
             i = jnp.maximum(i, _first_q_block(kk, block_q, block_k))
         return (b * group + j // n_q, i, 0)
 
+    kernel = functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
+                               block_q=block_q, block_k=block_k, n_q=n_q,
+                               group=group, keyed=bits is not None)
+    in_specs = [
+        pl.BlockSpec((1, block_q, d), rows),
+        pl.BlockSpec((1, block_k, d), lambda b, kk, j: (b, kk, 0)),
+        pl.BlockSpec((1, block_k, dv), lambda b, kk, j: (b, kk, 0)),
+        pl.BlockSpec((1, block_q, dv), rows),
+        pl.BlockSpec((1, block_q, 1), rows),
+        pl.BlockSpec((1, block_q, 1), rows),
+    ]
+    args = (q, k, v, g, lse, delta)
+    if bits is not None:
+        in_specs.append(_bits_spec(
+            bh_kv // bits.shape[0], block_q, block_k,
+            lambda kk, j: rows(0, kk, j)[1], lambda kk, j: kk))
+        args += (bits,)
     return pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k, n_q=n_q,
-                          group=group),
+        kernel,
         grid=(bh_kv, n_k, group * n_q),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), rows),
-            pl.BlockSpec((1, block_k, d), lambda b, kk, j: (b, kk, 0)),
-            pl.BlockSpec((1, block_k, dv), lambda b, kk, j: (b, kk, 0)),
-            pl.BlockSpec((1, block_q, dv), rows),
-            pl.BlockSpec((1, block_q, 1), rows),
-            pl.BlockSpec((1, block_q, 1), rows),
-        ],
+        in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, block_k, d), lambda b, kk, j: (b, kk, 0)),
             pl.BlockSpec((1, block_k, dv), lambda b, kk, j: (b, kk, 0)),
@@ -354,16 +434,17 @@ def _flash_bwd_dkv(q, k, v, g, lse, delta, scale, causal, block_q, block_k,
             pltpu.VMEM((block_k, dv), jnp.float32),
         ],
         interpret=interpret,
-    )(q, k, v, g, lse, delta)
+    )(*args)
 
 
-def _flash_bwd(res, g, scale, causal, block_q, block_k, interpret, group):
+def _flash_bwd(res, g, scale, causal, block_q, block_k, interpret, group,
+               bits=None):
     q, k, v, out, lse = res
     delta = _bwd_delta(out, g)
     dq = _flash_bwd_dq(q, k, v, g, lse, delta, scale, causal, block_q,
-                       block_k, interpret, group)
+                       block_k, interpret, group, bits)
     dk, dv = _flash_bwd_dkv(q, k, v, g, lse, delta, scale, causal, block_q,
-                            block_k, interpret, group)
+                            block_k, interpret, group, bits)
     return dq, dk, dv
 
 
@@ -371,26 +452,47 @@ def _flash_bwd(res, g, scale, causal, block_q, block_k, interpret, group):
 # public op
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
-def _flash(q, k, v, causal, block_q, block_k, interpret, scale, group):
-    out, _ = _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret,
-                        group)
-    return out
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
+def _flash(q, k, v, bits, causal, block_q, block_k, interpret, scale, group):
+    """``(out, logsumexp)``, over each query's own keys where ``bits`` holds
+    them (None: every causal key).  No gradient is taken through the
+    logsumexp rows, nor to ``bits``."""
+    return _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret,
+                      group, bits)
 
 
-def _flash_vjp_fwd(q, k, v, causal, block_q, block_k, interpret, scale,
+def _flash_vjp_fwd(q, k, v, bits, causal, block_q, block_k, interpret, scale,
                    group):
     out, lse = _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret,
-                          group)
-    return out, (q, k, v, out, lse)
+                          group, bits)
+    return (out, lse), (q, k, v, out, lse, bits)
 
 
 def _flash_vjp_bwd(causal, block_q, block_k, interpret, scale, group, res, g):
-    return _flash_bwd(res, g, scale, causal, block_q, block_k, interpret,
-                      group)
+    bits = res[5]
+    return _flash_bwd(res[:5], g[0], scale, causal, block_q, block_k,
+                      interpret, group, bits) + (
+                          None if bits is None else
+                          np.zeros(bits.shape, jax.dtypes.float0),)
 
 
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
+
+
+def _checked(q, k, v, scale, interpret):
+    """``(scale, interpret)`` with their defaults in, once the shapes fit."""
+    dim, kv_heads = q.shape[3], k.shape[2]
+    if k.shape[3] != dim:
+        raise ValueError(
+            "q {} and k {} differ in width: scores are taken over one"
+            .format(q.shape, k.shape))
+    if q.shape[2] % kv_heads or v.shape[2] != kv_heads:
+        raise ValueError(
+            "{} query heads of q {} do not divide into the {} / {} heads of "
+            "k {} / v {}".format(q.shape[2], q.shape, kv_heads, v.shape[2],
+                                 k.shape, v.shape))
+    return (1.0 / (dim ** 0.5) if scale is None else scale,
+            _default_interpret() if interpret is None else interpret)
 
 
 def flash_attention(q, k, v, causal=True, block_q=128, block_k=128,
@@ -416,21 +518,7 @@ def flash_attention(q, k, v, causal=True, block_q=128, block_k=128,
     independent per batch row and head, so no collective is needed; with
     grouped KV heads both head counts must divide by the ``tensor`` axis).
     """
-    if interpret is None:
-        interpret = _default_interpret()
-    batch, s_len, heads, dim = q.shape
-    kv_heads = k.shape[2]
-    if k.shape[3] != dim:
-        raise ValueError(
-            "q {} and k {} differ in width: scores are taken over one"
-            .format(q.shape, k.shape))
-    if heads % kv_heads or v.shape[2] != kv_heads:
-        raise ValueError(
-            "{} query heads of q {} do not divide into the {} / {} heads of "
-            "k {} / v {}".format(heads, q.shape, kv_heads, v.shape[2],
-                                 k.shape, v.shape))
-    if scale is None:
-        scale = 1.0 / (dim ** 0.5)
+    scale, interpret = _checked(q, k, v, scale, interpret)
     if mesh is not None and mesh.size > 1:
         from jax.sharding import PartitionSpec as P
 
@@ -443,6 +531,22 @@ def flash_attention(q, k, v, causal=True, block_q=128, block_k=128,
         # pallas_call's outputs carry no varying-axes annotation
         return jax.shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
                              out_specs=spec, check_vma=False)(q, k, v)
+    return flash_attention_lse(q, k, v, causal, block_q, block_k, interpret,
+                               scale)[0]
+
+
+def flash_attention_lse(q, k, v, causal=True, block_q=128, block_k=128,
+                        interpret=None, scale=None, key_bits=None):
+    """:func:`flash_attention` on one device with the softmax's statistics
+    beside the output: ``(out, logsumexp [batch, seq, heads])`` (float32,
+    natural log, of the scaled scores over the query's keys; no gradient
+    passes through it), for a caller that needs the probabilities again.
+
+    ``key_bits`` (``[batch, groups, seq, 128]`` int32, made by
+    :func:`tensorflowonspark_tpu.ops.sparse_index.select_keys`; see the
+    module docstring) keeps every query to its own keys."""
+    scale, interpret = _checked(q, k, v, scale, interpret)
+    batch, s_len, heads, _ = q.shape
     block_q = min(block_q, s_len)
     block_k = min(block_k, s_len)
     if s_len % block_q or s_len % block_k:
@@ -454,6 +558,9 @@ def flash_attention(q, k, v, causal=True, block_q=128, block_k=128,
     def fold(x):
         return x.transpose(0, 2, 1, 3).reshape(-1, s_len, x.shape[3])
 
-    out = _flash(fold(q), fold(k), fold(v), causal, block_q, block_k,
-                 interpret, scale, heads // kv_heads)
-    return out.reshape(batch, heads, s_len, v.shape[3]).transpose(0, 2, 1, 3)
+    def unfold(x):
+        return x.reshape(batch, heads, s_len, x.shape[2]).transpose(0, 2, 1, 3)
+
+    out, lse = _flash(fold(q), fold(k), fold(v), key_bits, causal, block_q,
+                      block_k, interpret, scale, heads // k.shape[2])
+    return unfold(out), jax.lax.stop_gradient(unfold(lse)[..., 0])

@@ -394,8 +394,12 @@ class Trainer(object):
         self._moe_lock = threading.Lock()
 
     def _note_moe(self, aux):
-        counts = aux.get("moe_counts") if isinstance(aux, dict) else None
-        if counts is None:
+        if not isinstance(aux, dict):
+            return
+        # the index over the keys (dsa_*) counts the same way
+        counts = dict(aux.get("moe_counts") or {},
+                      **(aux.get("dsa_counts") or {}))
+        if not counts:
             return
         with self._moe_lock:
             self._moe_pending.append(counts)
@@ -443,7 +447,15 @@ class Trainer(object):
         ``moe_expert_load_max_sum`` / ``moe_expert_load_mean_sum`` the
         heaviest and the mean held expert's pairs summed over layers and
         steps (their ratio is the imbalance the grouped products see),
-        ``moe_layers_steps`` the expert-layer calls counted."""
+        ``moe_layers_steps`` the expert-layer calls counted.
+
+        The index over the keys, for a model whose attention has one (the
+        same way): ``dsa_tiles_causal`` the causal ``[flash_block,
+        flash_block]`` tiles of (queries, keys) and ``dsa_tiles_touched``
+        those of them that hold a picked key (what a kernel that skipped
+        empty tiles could save on this data), ``dsa_index_loss`` the index's
+        loss summed over the steps, ``dsa_layers_steps`` the layer calls
+        counted."""
         snap = {
             "dispatch_count": self._dispatch_count,
             "dispatch_gap_us": self._dispatch_gap_us,
@@ -495,7 +507,7 @@ class Trainer(object):
             snap["train_rollbacks_total"] = self._rollbacks
         if self._moe_pending or self._moe_totals:
             self._fold_moe()
-            snap.update(self._moe_totals)   # the loss names them moe_*
+            snap.update(self._moe_totals)   # the loss names them moe_*, dsa_*
         return snap
 
     def apply_knob(self, name, value):

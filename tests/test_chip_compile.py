@@ -22,6 +22,7 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,  # noqa: E402
                           SingleDeviceSharding)
 
 fa = importlib.import_module("tensorflowonspark_tpu.ops.flash_attention")
+si = importlib.import_module("tensorflowonspark_tpu.ops.sparse_index")
 
 
 @pytest.fixture(scope="module")
@@ -158,6 +159,7 @@ def _compiled_step(topo, monkeypatch, family, config_name):
     from tensorflowonspark_tpu.models import get_model, transformer
 
     monkeypatch.setattr(fa, "_default_interpret", lambda: False)
+    monkeypatch.setattr(si, "_default_interpret", lambda: False)
     monkeypatch.setattr(
         importlib.import_module("tensorflowonspark_tpu.ops.grouped_matmul"),
         "_default_impl", lambda: "pallas")
@@ -170,11 +172,10 @@ def _compiled_step(topo, monkeypatch, family, config_name):
     config = adapter.program_config(cfg)
     model = get_model(family, config=config, attention=cfg["attention"],
                       remat=cfg["remat"], dtype=cfg["dtype"])
-    # parameters never depend on the attention kind: shape them without
-    # tracing the kernel for the CPU
+    # parameters never depend on the row's length: shape them on a short one
     shapes = jax.eval_shape(
-        get_model(family, config=config, attention="full").init,
-        jax.random.PRNGKey(0), jnp.zeros((1, 128), jnp.int32))["params"]
+        model.init, jax.random.PRNGKey(0),
+        jnp.zeros((1, 128), jnp.int32))["params"]
     optimizer = optax.adam(cfg["optimizer"]["learning_rate"])
     loss = transformer.loss_fn(model)
 
@@ -268,6 +269,101 @@ def test_deepseek_v2_lite_step_compiles_and_fits_v5e(topo, monkeypatch):
     assert sum("/attention/flash/" in line for line in calls) == 20
     assert sum("/moe/experts/" in line for line in calls) == 48
     assert len(calls) >= 20 + 48 + 40
+
+
+# the learned index at the benchmark's sizes: 32,768 positions, 32 query and
+# 4 KV heads of 128, 16 index heads of 64, blocks of 512
+KEYED = dict(batch=1, seq=32768, heads=32, kv=4, dim=128, index_heads=16,
+             index_dim=64, block=512)
+
+
+def _keyed_args(topo):
+    one = SingleDeviceSharding(topo.devices[0])
+    b, t = KEYED["batch"], KEYED["seq"]
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    return arg, arg((b, si.key_groups(t), t, 128), jnp.int32)
+
+
+@pytest.mark.parametrize("kernel", ["fwd", "bwd_dq", "bwd_dkv"])
+def test_flash_kernel_with_key_bits_compiles_for_v5e(topo, kernel):
+    """The three flash kernels reading each query's own keys as bits, at the
+    sparse-attention cell's sizes (group 8, one ``[512, 128]`` tile of words
+    for eight k blocks)."""
+    arg, bits = _keyed_args(topo)
+    b, t, h, kv, d = (KEYED[k] for k in ("batch", "seq", "heads", "kv",
+                                         "dim"))
+    q, k, stat = arg((b * h, t, d)), arg((b * kv, t, d)), arg(
+        (b * h, t, 1), jnp.float32)
+    tail = (d ** -0.5, True, KEYED["block"], KEYED["block"], False, h // kv)
+    if kernel == "fwd":
+        text = _compile(lambda q, k, v, bits: fa._flash_fwd(
+            q, k, v, *tail, bits), q, k, k, bits)
+    else:
+        launch = fa._flash_bwd_dq if kernel == "bwd_dq" else fa._flash_bwd_dkv
+        text = _compile(
+            lambda q, k, v, g, lse, delta, bits: launch(
+                q, k, v, g, lse, delta, *tail, bits),
+            q, k, k, q, stat, stat, bits)
+    assert text.count("tpu_custom_call") == 1
+
+
+@pytest.mark.parametrize("kernel", ["select", "loss", "loss_grads"])
+def test_index_kernels_compile_for_v5e(topo, kernel):
+    """The selection kernel (a block of 256 queries' scores over all 32,768
+    keys in VMEM: 32 MiB of its 100 MiB limit) and the kernel of the index's
+    loss, without and with its gradients (every head's query block and the
+    resident gradient of the index's keys in VMEM), at the cell's sizes."""
+    arg, bits = _keyed_args(topo)
+    b, t, h, kv, d, j, e = (KEYED[k] for k in (
+        "batch", "seq", "heads", "kv", "dim", "index_heads", "index_dim"))
+    index = (arg((b, t, j, e)), arg((b, t, e)), arg((b, t, j), jnp.float32))
+    if kernel == "select":
+        text = _compile(lambda *a: si.select_keys(*a, 2048, interpret=False),
+                        *index)
+    else:
+        rest = (arg((b, t, h, d)), arg((b, t, kv, d)),
+                arg((b, t, h), jnp.float32), arg((b, t), jnp.float32), bits)
+
+        def loss(*a):
+            return si.index_loss(*a, block=KEYED["block"],
+                                 interpret=False).sum()
+
+        text = _compile(loss if kernel == "loss"
+                        else jax.grad(loss, (0, 1, 2)), *index, *rest)
+    assert text.count("tpu_custom_call") == 1
+
+
+def test_keye_vl2_step_compiles_and_fits_v5e(topo, monkeypatch):
+    """The whole training step of ``keye_vl2_30b_a3b_ep8`` (published
+    widths; four layers; grouped-query attention over the 2,048 keys a
+    learned index picks of a 32,768-token row; 16 of 128 experts by softmax
+    top-8; an untied read-out over 18,992 rows; batch 1, as the file says)
+    compiles for one described v5e chip with every kernel of the index in it
+    and fits its 15.75 GiB by XLA's memory analysis: 12.80 GiB (PR 37),
+    which it may not outgrow; no array of it is ``[T, T]``.  The numbers are
+    in the configuration's ``assumed.batch_size``."""
+    compiled, parameters, needed = _compiled_step(
+        topo, monkeypatch, "keye_vl2", "keye_vl2_30b_a3b_ep8")
+    assert parameters == 465_391_104
+    assert needed <= 12.9 * 2 ** 30, needed
+    assert "32768,32768" not in compiled.as_text()
+    calls = _kernel_calls(compiled)
+
+    def count(scope, kernel):
+        return sum("/attention/{}/".format(scope) in line and kernel in line
+                   for line in calls)
+
+    # four layers x (forward, recomputed forward, dQ, dK/dV) under
+    # attention/flash; the selection forward and recomputed; the index's
+    # loss once alone (forward) and once with its gradients (recomputed)
+    assert count("flash", "pallas_call") == 16
+    assert count("select", "dsa_select/") == 8
+    assert count("index_loss", "dsa_index_loss/") == 4
+    assert count("index_loss", "dsa_index_loss_grads/") == 4
+    assert sum("/moe/experts/" in line for line in calls) == 48
 
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],

@@ -206,14 +206,14 @@ class _TokenSource(object):
     terminate = interrupt
 
 
-def _fit(model, steps=3):
+def _fit(model, steps=3, seq=16):
     mesh = build_mesh({"data": 1}, devices=jax.devices()[:1])
     params = model.init(jax.random.PRNGKey(0),
-                        jnp.zeros((2, 16), jnp.int32))["params"]
+                        jnp.zeros((2, seq), jnp.int32))["params"]
     trainer = Trainer(transformer.loss_fn(model), params, optax.adam(1e-3),
                       mesh=mesh, batch_size=2, log_steps=1,
                       step_flops_override=1.0)
-    feed = ShardedFeed(_TokenSource(steps, 2, 16, 61), mesh, 2, prefetch=0)
+    feed = ShardedFeed(_TokenSource(steps, 2, seq, 61), mesh, 2, prefetch=0)
     stats = trainer.fit_feed(feed)
     assert stats["global_steps"] == steps
     jax.block_until_ready(trainer.state.params)

@@ -7,7 +7,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from tensorflowonspark_tpu.ops import flash_attention
+from tensorflowonspark_tpu.ops import flash_attention, flash_attention_lse
 from tensorflowonspark_tpu.parallel import ring
 
 
@@ -157,6 +157,83 @@ def test_flash_with_a_value_width_of_its_own(widths, group):
         np.testing.assert_allclose(
             np.asarray(gf), np.asarray(gr), atol=5e-4, rtol=5e-4,
             err_msg="d{} mismatch".format(name))
+
+
+def _key_bits(kept):
+    """bool ``[B, T, S]`` -> the ``[B, groups, T, 128]`` int32 words that
+    ``flash_attention_lse(key_bits=)`` reads."""
+    batch, rows, seq = kept.shape
+    groups = -(-seq // 4096)
+    padded = np.zeros((batch, rows, groups * 4096), np.int64)
+    padded[:, :, :seq] = kept
+    runs = padded.reshape(batch, rows, groups, 32, 128)
+    words = (runs << np.arange(32)[None, None, None, :, None]).sum(axis=3)
+    return jnp.asarray(words.transpose(0, 2, 1, 3).astype(np.uint32).view(
+        np.int32))
+
+
+@pytest.mark.parametrize("shape", [(256, 128, 128, 4, 16, 16),
+                                   (256, 128, 128, 1, 24, 16),
+                                   (512, 256, 128, 2, 16, 16),
+                                   (512, 128, 256, 2, 16, 16)],
+                         ids=["group4", "mha_dk24_dv16", "q256k128",
+                              "q128k256"])
+@pytest.mark.parametrize("density", [0.15, 1.0], ids=["sparse", "all"])
+def test_flash_over_each_querys_own_keys(shape, density):
+    """``key_bits``: every query reads its own keys inside the causal
+    triangle (here a random set that always holds the query itself; some
+    rows find none of theirs in the first k block).  Values, the logsumexp
+    rows and the three gradients against plain attention under the same
+    mask; a set that holds every causal key is the kernel without one."""
+    seq, block_q, block_k, group, dk, dv = shape
+    keys = jax.random.split(jax.random.PRNGKey(13), 4)
+    q = jax.random.normal(keys[0], (2, seq, 4, dk))
+    k = jax.random.normal(keys[1], (2, seq, 4 // group, dk))
+    v = jax.random.normal(keys[2], (2, seq, 4 // group, dv))
+    causal = np.tril(np.ones((seq, seq), bool))
+    kept = (np.asarray(jax.random.uniform(keys[3], (2, seq, seq)))
+            < density) & causal | np.eye(seq, dtype=bool)
+    bits = _key_bits(kept)
+
+    def flash(q, k, v):
+        o, lse = flash_attention_lse(q, k, v, causal=True, block_q=block_q,
+                                     block_k=block_k, key_bits=bits)
+        return (o ** 2).sum(), (o, lse)
+
+    def ref(q, k, v):
+        s = jnp.einsum("bthd,bshd->bhts", q,
+                       jnp.repeat(k, group, axis=2)) * dk ** -0.5
+        s = jnp.where(kept[:, None], s, -jnp.inf)
+        o = jnp.einsum("bhts,bshd->bthd", jax.nn.softmax(s, axis=-1),
+                       jnp.repeat(v, group, axis=2))
+        return (o ** 2).sum(), (o, jax.nn.logsumexp(s, axis=-1).transpose(
+            0, 2, 1))
+
+    (_, got), g_flash = jax.value_and_grad(
+        flash, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    (_, want), g_ref = jax.value_and_grad(
+        ref, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    for a, b in zip(got, want):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=2e-5, rtol=2e-5)
+    for gf, gr, name in zip(g_flash, g_ref, "qkv"):
+        assert gf.shape == gr.shape
+        np.testing.assert_allclose(
+            np.asarray(gf), np.asarray(gr), atol=5e-4, rtol=5e-4,
+            err_msg="d{} mismatch".format(name))
+    if density == 1.0:
+        np.testing.assert_allclose(
+            np.asarray(got[0]), np.asarray(flash_attention(
+                q, k, v, causal=True, block_q=block_q, block_k=block_k)),
+            atol=1e-6)
+
+
+def test_key_bits_name_the_blocks_they_refuse():
+    q = jnp.zeros((1, 128, 2, 8))
+    bits = jnp.zeros((1, 1, 128, 128), jnp.int32)
+    with pytest.raises(ValueError, match="key_bits want a k block"):
+        flash_attention_lse(q, q, q, block_q=64, block_k=64, key_bits=bits)
 
 
 def test_flash_names_the_shapes_it_refuses():

@@ -901,7 +901,21 @@ class TransformerLM(nn.Module):
         # keep per layer) are recomputed during backprop instead of
         # stored — the standard TPU recipe for configs whose stored
         # activations exceed HBM (e.g. d2048 x 16L x b16 full attention).
-        block_cls = nn.remat(Block) if self.remat else Block
+        # Kept beside a block's input [B, S, hidden] are the residuals that
+        # cost a kernel run to make again, by the names the ops give them
+        # and wherever the trace holds such a name: under "flash" the
+        # kernel's output and logsumexp rows (B S H dv elements of the
+        # compute dtype and B H S float32 a layer), and an indexed layer's
+        # key bits and index logsumexp (S S / 8 bytes and S float32 a batch
+        # row).  Everything else in the block is recomputed.
+        block_cls = Block
+        if self.remat:
+            from tensorflowonspark_tpu.ops import sparse_index
+            from tensorflowonspark_tpu.ops.flash_attention import KEPT
+
+            block_cls = nn.remat(
+                Block, policy=jax.checkpoint_policies.save_only_these_names(
+                    *KEPT, *sparse_index.KEPT))
         for i, layer in enumerate(spec.layers):
             x = block_cls(attention=self.attention, ep_mode=self.ep_mode,
                           mesh=self.mesh, ep_batch_axes=self.ep_batch_axes,

@@ -50,6 +50,20 @@ and mask the scores of the tiles they compute.  Every causal tile is still
 computed (a masked pair's products are thrown away); without ``key_bits``
 nothing of it is traced and the kernels are the ones they were.
 
+Under a checkpoint (``jax.checkpoint``, ``TransformerLM(remat=True)``): of
+the residuals the backward kernels read, two cost a kernel run to make again
+and are small, the output (``[batch*heads, seq, dv]``, the inputs' dtype) and
+the logsumexp rows (float32, kept as ``[batch*heads, seq]``: in the kernel's
+own ``[batch*heads, seq, 1]`` the chip holds one row a 128-lane tile, and the
+compiled step of five latent layers at 8,192 x 4 kept 0.99 GiB more).  The
+forward rule of the ``custom_vjp`` (:func:`_flash_vjp_fwd`) names them
+``KEPT_OUT`` and ``KEPT_LSE`` with ``jax.ad_checkpoint.checkpoint_name``, on
+the kernel's own results, so that a policy of
+``save_only_these_names(*KEPT)`` keeps the very arrays the backward rule
+reads and the recomputed pass holds no forward kernel.  A name put on the
+op's result outside the rule would name a copy.  Without a policy a name is
+the identity.
+
 Layout contract: ``[batch, seq, heads, dim]`` like
 :mod:`~tensorflowonspark_tpu.parallel.ring`; blocks default to 128 (MXU
 tile) and the sequence length must divide by the block size: a
@@ -62,10 +76,14 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 NEG_INF = -1e30
 KEY_LANES = 128          # a word of key_bits holds one key a lane ...
 KEY_GROUP = 32 * 128     # ... of each of 32 runs of 128 keys
+# the residuals a checkpoint policy may keep by name (module docstring)
+KEPT_OUT, KEPT_LSE = "flash_out", "flash_lse"
+KEPT = (KEPT_OUT, KEPT_LSE)
 
 
 def _default_interpret():
@@ -465,13 +483,15 @@ def _flash_vjp_fwd(q, k, v, bits, causal, block_q, block_k, interpret, scale,
                    group):
     out, lse = _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret,
                           group, bits)
-    return (out, lse), (q, k, v, out, lse, bits)
+    out = checkpoint_name(out, KEPT_OUT)
+    rows = checkpoint_name(lse[..., 0], KEPT_LSE)
+    return (out, rows[..., None]), (q, k, v, out, rows, bits)
 
 
 def _flash_vjp_bwd(causal, block_q, block_k, interpret, scale, group, res, g):
-    bits = res[5]
-    return _flash_bwd(res[:5], g[0], scale, causal, block_q, block_k,
-                      interpret, group, bits) + (
+    q, k, v, out, rows, bits = res
+    return _flash_bwd((q, k, v, out, rows[..., None]), g[0], scale, causal,
+                      block_q, block_k, interpret, group, bits) + (
                           None if bits is None else
                           np.zeros(bits.shape, jax.dtypes.float0),)
 

@@ -37,6 +37,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 from tensorflowonspark_tpu.ops.flash_attention import (
     KEY_GROUP, KEY_LANES, NEG_INF, _default_interpret, _dot, key_mask)
@@ -45,6 +46,9 @@ _INT_MIN = -2 ** 31
 # the kernels keep a block's scores (select) or a row block of every head
 # (loss) in VMEM: more than the compiler's default share of the 128 MiB
 _VMEM_LIMIT = 100 * 1024 * 1024
+# select_keys' results, by the names a checkpoint policy may keep them under
+KEPT_BITS, KEPT_INDEX_LSE = "dsa_key_bits", "dsa_index_lse"
+KEPT = (KEPT_BITS, KEPT_INDEX_LSE)
 
 
 def key_groups(seq):
@@ -186,7 +190,9 @@ def select_keys(index_q, index_k, index_w, topk, block_q=256, chunk=512,
     ``index_q [B, T, J, E]``, ``index_k [B, T, E]``, ``index_w [B, T, J]``
     float32.  Returns ``(key_bits [B, groups, T, 128] int32, logsumexp [B,
     T] float32 of the kept scores)`` (the module docstring has the layout).
-    A constant of the step: no gradient passes."""
+    A constant of the step: no gradient passes.  Both results carry a name
+    (``KEPT``) that a checkpoint policy may keep, so that a recomputed block
+    does not search again."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -228,7 +234,8 @@ def select_keys(index_q, index_k, index_w, topk, block_q=256, chunk=512,
         interpret=interpret,
         name="dsa_select",
     )(_fold_heads(index_q), index_k, index_w.astype(jnp.float32))
-    return bits, lse[..., 0]
+    return (checkpoint_name(bits, KEPT_BITS),
+            checkpoint_name(lse[..., 0], KEPT_INDEX_LSE))
 
 
 def tiles_touched(key_bits, seq, block):

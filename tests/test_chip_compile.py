@@ -223,23 +223,26 @@ def test_lfm2_moe_step_compiles_and_fits_v5e(topo, monkeypatch):
     flash kernels, the grouped expert products and the expert layer's row
     movement (pallas kernels all) in it, and XLA's memory analysis of it
     (arguments + outputs - aliased + temporaries) is no larger than the
-    12.71 GiB it was with XLA's gathers around the grouped products (PR 28;
-    a v5e offers 15.75).  The numbers are in the configuration's
+    12.01 GiB it is with the attention layer's kernel output and logsumexp
+    rows kept across the recomputed block (PR 38: 12.00 without them; a
+    v5e offers 15.75).  The numbers of PR 28 are in the configuration's
     ``assumed.batch_size``."""
     compiled, parameters, needed = _compiled_step(
         topo, monkeypatch, "lfm2_moe", "lfm2_8b_a1b_ep4")
     assert parameters == 507_820_288
-    assert needed <= 12.71 * 2 ** 30, needed
+    assert needed <= 12.05 * 2 ** 30, needed
     # 4 expert layers x 3 grouped products x (forward, recomputed forward,
-    # two gradients), and the flash kernels (forward twice, dQ, dK/dV): all
-    # pallas kernels that carry their scope
+    # two gradients), and the flash kernels (forward once: the checkpoint
+    # keeps its output and logsumexp; dQ, dK/dV): all pallas kernels that
+    # carry their scope
     calls = _kernel_calls(compiled)
+    assert sum("/attention/flash/" in line for line in calls) == 3
     # ... and ten kernels of the row movement an expert layer, under the
     # scopes moe_route_ms_per_step reads: dispatch packs the tokens and
     # gathers them (forward and recomputed forward) and its gradient packs
     # and gather-sums; combine packs and gather-sums once (its recomputed
     # forward is dead code) and its gradient packs and gathers
-    assert len(calls) >= 48 + 4 + 40
+    assert len(calls) >= 48 + 3 + 40
     for scope, kernel, count in (("dispatch", "gather", 8),
                                  ("dispatch", "sum", 4),
                                  ("combine", "sum", 4),
@@ -255,20 +258,22 @@ def test_deepseek_v2_lite_step_compiles_and_fits_v5e(topo, monkeypatch):
     flash kernels at 192 / 128; 8 of 64 experts by softmax top-6 beside the
     shared expert; an untied read-out over 12,800 rows; batch and
     8,192-token rows as the file says) compiles for one described v5e chip
-    and fits its 15.75 GiB by XLA's memory analysis: 13.04 GiB at batch 4
-    (PR 32), which it may not outgrow.  The numbers are in the
-    configuration's ``assumed.batch_size``."""
+    and fits its 15.75 GiB by XLA's memory analysis: 13.56 GiB at batch 4
+    with five layers' kernel outputs and logsumexp rows kept across their
+    recomputed blocks (PR 38: 13.04 without them), which it may not
+    outgrow.  The numbers of PR 32 are in the configuration's
+    ``assumed.batch_size``."""
     compiled, parameters, needed = _compiled_step(
         topo, monkeypatch, "deepseek_v2", "deepseek_v2_lite_ep8")
     assert parameters == 535_060_992
-    assert needed <= 13.1 * 2 ** 30, needed
+    assert needed <= 13.6 * 2 ** 30, needed
     calls = _kernel_calls(compiled)
-    # five attention layers x (forward, recomputed forward, dQ, dK/dV), all
-    # under attention/flash; four expert layers x 3 grouped products x 4
-    # passes, and their row movement
-    assert sum("/attention/flash/" in line for line in calls) == 20
+    # five attention layers x (forward, dQ, dK/dV), all under
+    # attention/flash: no forward kernel in the recomputed pass; four expert
+    # layers x 3 grouped products x 4 passes, and their row movement
+    assert sum("/attention/flash/" in line for line in calls) == 15
     assert sum("/moe/experts/" in line for line in calls) == 48
-    assert len(calls) >= 20 + 48 + 40
+    assert len(calls) >= 15 + 48 + 40
 
 
 # the learned index at the benchmark's sizes: 32,768 positions, 32 query and
@@ -342,13 +347,15 @@ def test_keye_vl2_step_compiles_and_fits_v5e(topo, monkeypatch):
     learned index picks of a 32,768-token row; 16 of 128 experts by softmax
     top-8; an untied read-out over 18,992 rows; batch 1, as the file says)
     compiles for one described v5e chip with every kernel of the index in it
-    and fits its 15.75 GiB by XLA's memory analysis: 12.80 GiB (PR 37),
-    which it may not outgrow; no array of it is ``[T, T]``.  The numbers are
-    in the configuration's ``assumed.batch_size``."""
+    and fits its 15.75 GiB by XLA's memory analysis: 14.25 GiB with four
+    layers' kernel outputs, logsumexp rows, key bits and index logsumexp
+    kept across their recomputed blocks (PR 38: 12.80 without them),
+    which it may not outgrow; no array of it is ``[T, T]``.  The numbers of
+    PR 37 are in the configuration's ``assumed.batch_size``."""
     compiled, parameters, needed = _compiled_step(
         topo, monkeypatch, "keye_vl2", "keye_vl2_30b_a3b_ep8")
     assert parameters == 465_391_104
-    assert needed <= 12.9 * 2 ** 30, needed
+    assert needed <= 14.3 * 2 ** 30, needed
     assert "32768,32768" not in compiled.as_text()
     calls = _kernel_calls(compiled)
 
@@ -356,11 +363,11 @@ def test_keye_vl2_step_compiles_and_fits_v5e(topo, monkeypatch):
         return sum("/attention/{}/".format(scope) in line and kernel in line
                    for line in calls)
 
-    # four layers x (forward, recomputed forward, dQ, dK/dV) under
-    # attention/flash; the selection forward and recomputed; the index's
-    # loss once alone (forward) and once with its gradients (recomputed)
-    assert count("flash", "pallas_call") == 16
-    assert count("select", "dsa_select/") == 8
+    # four layers x (forward, dQ, dK/dV) under attention/flash and one
+    # selection each: the recomputed pass holds neither; the index's loss
+    # once alone (forward) and once with its gradients (backward)
+    assert count("flash", "pallas_call") == 12
+    assert count("select", "dsa_select/") == 4
     assert count("index_loss", "dsa_index_loss/") == 4
     assert count("index_loss", "dsa_index_loss_grads/") == 4
     assert sum("/moe/experts/" in line for line in calls) == 48

@@ -421,8 +421,7 @@ def _rollback_node_fn(args, ctx):
         # re-arm on the retry attempt's fresh feed (an artifact of
         # injection-by-env, not of the fault model), so drop the spec the
         # moment the rollback command lands.
-        while getattr(trainer, "_rollback_req", None) is None \
-                and getattr(trainer, "_rollbacks", 0) == 0:
+        while not trainer._rollback_tokens:
             _time.sleep(0.01)
         _os.environ.pop(fault_mod.FAULT_SPEC_ENV, None)
 
@@ -431,7 +430,9 @@ def _rollback_node_fn(args, ctx):
                              max_steps=ROLLBACK_STEPS)
     with open("result.json", "w") as f:
         _json.dump({"step": int(trainer.state.step),
-                    "rollbacks": int(getattr(trainer, "_rollbacks", 0)),
+                    # rollback commands the trainer took (each one is
+                    # honoured by fit_supervised's restore)
+                    "rollbacks": len(trainer._rollback_tokens),
                     "ckpt_entries": sorted(_os.listdir("ckpt"))}, f)
 
 

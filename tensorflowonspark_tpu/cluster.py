@@ -704,6 +704,8 @@ def run(cluster_backend, map_fun, tf_args, num_executors=None, num_ps=0,
         Falls back to the ``TFOS_COMPILE_CACHE_DIR`` env var; None with
         no env leaves the compile plane off.
     """
+    # The bring-up's account starts here (telemetry.Bringup; always on).
+    telemetry_mod.bringup.begin()
     if hasattr(cluster_backend, "parallelize"):  # raw SparkContext
         cluster_backend = backend_mod.SparkBackend(cluster_backend)
     num_executors = num_executors or cluster_backend.num_executors
@@ -776,7 +778,11 @@ def run(cluster_backend, map_fun, tf_args, num_executors=None, num_ps=0,
                     new_index, start_fn,
                     [{"executor_id": new_index,
                       "job_name": released["job_name"],
-                      "task_index": released["task_index"]}])
+                      "task_index": released["task_index"],
+                      # a replacement's account starts where it is
+                      # dispatched, not where the cluster's did
+                      "bringup": [[int(telemetry_mod.wall_time_us()),
+                                   "spawn"]]}])
         except Exception:
             logger.exception("replacement provisioning failed; the run "
                              "continues on the surviving nodes")
@@ -1085,10 +1091,6 @@ def run(cluster_backend, map_fun, tf_args, num_executors=None, num_ps=0,
                               else os.environ.get(
                                   compilecache_mod.CACHE_DIR_ENV)),
     }
-    tracer.instant("cluster/start", num_executors=num_executors,
-                   input_mode=str(input_mode),
-                   cluster_id=cluster_meta["id"])
-
     # Launch the start job in the background (reference daemon thread +
     # foreachPartition, TFCluster.py:312-329): SPARK-mode workers run the user
     # fn in a background process so their task returns and frees the slot for
@@ -1127,6 +1129,14 @@ def run(cluster_backend, map_fun, tf_args, num_executors=None, num_ps=0,
     else:
         start_ids = list(range(num_executors))
     start_parts = [[i] for i in start_ids]
+    # The start job goes to the backend: ``driver`` ends and ``spawn``
+    # begins, and the marks so far ride cluster_meta (pickled with the start
+    # closure in the call below) to every node.
+    telemetry_mod.bringup.instant("spawn", "cluster/start",
+                                  num_executors=num_executors,
+                                  input_mode=str(input_mode),
+                                  cluster_id=cluster_meta["id"])
+    cluster_meta["bringup"] = telemetry_mod.bringup.export()
     start_job = cluster_backend.foreach_partition_async(start_parts, start_fn)
 
     # Propagate async start-job failures into the reservation wait (reference

@@ -16,6 +16,10 @@ argument, arXiv:2101.12127), on two tiers:
    replacement node's compiles collapse to deserialization.
    Hit/miss/saved-time counters are derived from jax's monitoring events
    and ride heartbeats into the observatory as ``tfos_compile_cache_*``.
+   The same listeners keep the compile plane's own books in every process
+   that hosts jax, cache directory or none (:func:`listen`): the time
+   spent tracing, lowering, in the backend's compiler and reading the
+   cache, each instant booked once, and the programs made executable.
 
 2. **AOT executable store** (:class:`AOTCache`): explicit
    ``jax.experimental.serialize_executable`` round trips, keyed by a
@@ -49,6 +53,7 @@ mount whose writers you trust exactly as much as the training job itself
 — this store is local-filesystem / shared-mount only.
 """
 
+import collections
 import logging
 import os
 import pickle
@@ -83,6 +88,18 @@ _HIT_EVENT = "/jax/compilation_cache/cache_hits"
 _MISS_EVENT = "/jax/compilation_cache/cache_misses"
 _SAVED_EVENT = "/jax/compilation_cache/compile_time_saved_sec"
 _RETRIEVAL_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+# jax 0.9.0 times these three with ``dispatch.log_elapsed_time``: a scalar
+# event on entry, a duration event on exit.  They nest: a jitted function
+# called while another is traced is traced inside the outer one's time, a
+# constant computed while tracing is a whole small program (trace, lower,
+# compile) inside it, and the backend-compile event wraps
+# ``compile_or_get_cached``, so on a persistent-cache hit it fires too and
+# holds the retrieval.
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
+_STAGE_OF = {_TRACE_EVENT: "trace_us", _LOWER_EVENT: "lower_us",
+             _BACKEND_EVENT: "backend_us"}
 
 
 class _CacheStats(object):
@@ -101,6 +118,17 @@ class _CacheStats(object):
         self.fallback = 0           # AOT artifacts rejected -> JIT fallback
         self.saved_us = 0           # compile time the disk cache saved
         self.retrieval_us = 0       # time spent reading cached executables
+        # the plane's own books, cache directory or none: each instant under
+        # one of jax's compile events is booked once, on the innermost
+        self.trace_us = 0           # tracing to a jaxpr
+        self.lower_us = 0           # jaxpr -> MLIR module
+        self.backend_us = 0         # the backend's compiler (less retrieval)
+        self.programs = 0           # programs made executable, from the
+        #                             cache or by the compiler
+        # the last step programs a Trainer's dispatch made, each with its
+        # name, the step and the cost (Trainer._note_compile writes them;
+        # the flight recorder tells them as ``compile_programs``)
+        self.record = collections.deque(maxlen=32)
         self.aot_load = 0           # AOT executables deserialized + loaded
         self.aot_save = 0           # AOT executables serialized + persisted
         self.aot_load_us = 0
@@ -143,13 +171,12 @@ class _CacheStats(object):
         ``compile_cache_aot_save`` AOT store traffic with byte and
         microsecond tallies, and ``compile_cache_dir_bytes_hwm`` the
         cache directory footprint (``_hwm`` -> merged by max, rendered
-        as a gauge)."""
-        return {
+        as a gauge); beside them :meth:`tallies`."""
+        return dict(self.tallies(), **{
             "compile_cache_hit": self.cache_hit,
             "compile_cache_miss": self.cache_miss,
             "compile_cache_fallback": self.fallback,
             "compile_cache_saved_us": self.saved_us,
-            "compile_cache_retrieval_us": self.retrieval_us,
             "compile_cache_aot_load": self.aot_load,
             "compile_cache_aot_save": self.aot_save,
             "compile_cache_aot_load_us": self.aot_load_us,
@@ -157,6 +184,21 @@ class _CacheStats(object):
             "compile_cache_aot_bytes_read": self.aot_bytes_read,
             "compile_cache_aot_bytes_written": self.aot_bytes_written,
             "compile_cache_dir_bytes_hwm": self._dir_bytes_now(),
+        })
+
+    def tallies(self):
+        """What making programs executable has cost this process so far:
+        ``compile_trace_us``, ``compile_lower_us``, ``compile_backend_us``
+        and ``compile_cache_retrieval_us`` (no instant in two of them: a
+        nested event's time is taken out of the one around it, so the four
+        add up to the time spent under any of them) and ``compile_programs``
+        (backend-compile events: one a program, hit or miss)."""
+        return {
+            "compile_trace_us": self.trace_us,
+            "compile_lower_us": self.lower_us,
+            "compile_backend_us": self.backend_us,
+            "compile_cache_retrieval_us": self.retrieval_us,
+            "compile_programs": self.programs,
         }
 
 
@@ -169,6 +211,11 @@ _feed_registered = False
 _configured_dir = None
 
 
+# the compile events this thread is inside, innermost last: [stage, the
+# microseconds of what ended inside it]
+_nest = threading.local()
+
+
 def _on_event(event, **kwargs):
     if event == _HIT_EVENT:
         stats.cache_hit += 1
@@ -176,14 +223,40 @@ def _on_event(event, **kwargs):
         stats.cache_miss += 1
 
 
+def _on_scalar(event, value=0.0, **kwargs):
+    # log_elapsed_time's entry: one of the three stages begins
+    stage = _STAGE_OF.get(event)
+    if stage is not None:
+        if not hasattr(_nest, "open"):
+            _nest.open = []
+        _nest.open.append([stage, 0])
+
+
 def _on_duration(event, duration=0.0, **kwargs):
+    micros = int(duration * 1e6)
     if event == _SAVED_EVENT:
         # jax reports saved = original compile - retrieval, which goes
         # NEGATIVE for millisecond-scale programs; clamp per event so the
         # counter stays a monotone "time not spent recompiling"
-        stats.saved_us += max(0, int(duration * 1e6))
-    elif event == _RETRIEVAL_EVENT:
-        stats.retrieval_us += int(duration * 1e6)
+        stats.saved_us += max(0, micros)
+        return
+    opened = getattr(_nest, "open", None)
+    if event == _RETRIEVAL_EVENT:
+        stats.retrieval_us += micros
+    elif event in _STAGE_OF:
+        stage, inside = _STAGE_OF[event], 0
+        while opened:                # the entry that this exit answers
+            top = opened.pop()
+            if top[0] == stage:
+                inside = top[1]
+                break
+        setattr(stats, stage, getattr(stats, stage) + max(0, micros - inside))
+        if event == _BACKEND_EVENT:
+            stats.programs += 1
+    else:
+        return
+    if opened:
+        opened[-1][1] += micros      # not the time of the stage around it
 
 
 def _install_listeners():
@@ -195,6 +268,7 @@ def _install_listeners():
         from jax import monitoring
 
         monitoring.register_event_listener(_on_event)
+        monitoring.register_scalar_listener(_on_scalar)
         monitoring.register_event_duration_secs_listener(_on_duration)
         _listeners_installed = True
 
@@ -211,6 +285,52 @@ def _register_stats_feed():
     from tensorflowonspark_tpu import node
 
     node._register_feed(stats)
+
+
+def _when_jax_is_imported(callback):
+    """Call ``callback()`` now if this process has imported jax, else right
+    after it does (a one-shot finder in front of ``sys.meta_path`` that
+    lets the ordinary machinery find jax and runs the callback once the
+    package's own code has): a worker whose user function never touches
+    jax never pays for importing it."""
+    import importlib.util
+    import sys
+
+    if "jax" in sys.modules:
+        return callback()
+
+    class _Finder(object):
+        def find_spec(self, name, path=None, target=None):
+            if name != "jax":
+                return None
+            sys.meta_path.remove(self)
+            spec = importlib.util.find_spec(name)
+            if spec is not None and spec.loader is not None:
+                exec_module = spec.loader.exec_module
+
+                def exec_then_call(module):
+                    exec_module(module)
+                    callback()
+
+                spec.loader.exec_module = exec_then_call
+            return spec
+
+    sys.meta_path.insert(0, _Finder())
+
+
+def listen():
+    """Keep the compile plane's books in this process (:meth:`tallies`) and
+    send them with the node's heartbeats, whether or not a cache directory
+    is named: a user without a persistent cache compiles too.  ``node.run``
+    calls it in the process that runs a worker's user function, before that
+    function: the listeners are on from the moment the process imports jax
+    (at once where :func:`configure` already has)."""
+    from tensorflowonspark_tpu import telemetry
+
+    _when_jax_is_imported(_install_listeners)
+    _register_stats_feed()
+    telemetry.register_flight_source("compile_programs",
+                                     lambda: list(stats.record))
 
 
 def configured_dir():

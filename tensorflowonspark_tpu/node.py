@@ -198,7 +198,8 @@ def _node_metrics_provider(mgr, qname="input"):
     - every live DataFeed's counters (rows, stall time, wire formats);
     - feeder-side counters published to the manager KV by feed tasks
       (they run in a different process — the executor shell);
-    - the input queue's depth high-water mark, sampled per beat.
+    - the input queue's depth high-water mark, sampled per beat;
+    - the process's bring-up account, once its first dispatch has returned.
 
     Every leg is individually guarded: metrics must never cost a beat.
     """
@@ -221,6 +222,9 @@ def _node_metrics_provider(mgr, qname="input"):
             parts.append(telemetry.get_tracer().counters_snapshot())
         except Exception:
             pass
+        # the process's bring-up (empty until its first dispatch returned):
+        # once, however many trainers this node has
+        parts.append(telemetry.bringup.snapshot())
         for ref in list(_feeds):
             feed = ref()
             if feed is None:
@@ -229,7 +233,9 @@ def _node_metrics_provider(mgr, qname="input"):
             try:
                 # a DataFeed's own counters: its public snapshot also
                 # fetches the feeders' KV, which is merged once, below,
-                # however many feeds this node has
+                # however many feeds this node has (a Trainer's likewise:
+                # its public snapshot adds the process's bring-up and
+                # compile tallies)
                 parts.append(getattr(feed, "_own_counters",
                                      feed.counters_snapshot)())
             except Exception:
@@ -254,10 +260,7 @@ def _node_metrics_provider(mgr, qname="input"):
             depth = mgr.get_queue(qname).qsize()
             if depth > hwm["queue_depth_hwm"]:
                 hwm["queue_depth_hwm"] = depth
-            # Instantaneous depth next to the high-water mark: the HWM can
-            # never come back down, so a live backlog signal (is the queue
-            # draining NOW?) needs its own gauge.
-            parts.append(dict(hwm, queue_depth_max=depth))
+            parts.append(dict(hwm))
         except Exception:
             pass
         return telemetry.merge_counters(parts)
@@ -583,9 +586,17 @@ def run(fn, tf_args, cluster_meta, tensorboard=False, log_dir=None,
                     job_name, task_index,
                     " (replacement)" if assignment is not None else "")
         tracer = telemetry.configure_from_meta(cluster_meta)
-        tracer.instant("node/role_assigned", executor_id=executor_id,
-                       job_name=job_name, task_index=task_index,
-                       replacement=assignment is not None)
+        # The bring-up's account (telemetry.Bringup) goes on from the
+        # driver's marks: ``spawn`` ends and ``node`` begins.  A node in a
+        # driver thread keeps an account of its own that nobody reads: that
+        # process's account is the driver's.
+        bringup = telemetry.Bringup() if driver_local else telemetry.bringup
+        bringup.adopt((assignment or {}).get("bringup")
+                      or cluster_meta.get("bringup"))
+        bringup.instant("node", "node/role_assigned",
+                        executor_id=executor_id, job_name=job_name,
+                        task_index=task_index,
+                        replacement=assignment is not None)
 
         # Apply cluster-level env (TPU/XLA perf knobs, device_info.tpu_env)
         # FIRST: libtpu/XLA read these only when the jax client is created,
@@ -717,8 +728,9 @@ def run(fn, tf_args, cluster_meta, tensorboard=False, log_dir=None,
             tracer.flow_start("reservation/register_flow", reg_flow,
                               leg="node_register", executor_id=executor_id,
                               job_name=job_name)
-        with tracer.span("node/register", executor_id=executor_id,
-                         job_name=job_name, task_index=task_index):
+        with bringup.span("rendezvous", "node/register",
+                          executor_id=executor_id, job_name=job_name,
+                          task_index=task_index):
             client.register(node_meta)
         with tracer.span("node/await", executor_id=executor_id):
             cluster_info = client.await_reservations(
@@ -746,8 +758,9 @@ def run(fn, tf_args, cluster_meta, tensorboard=False, log_dir=None,
                 process_id = i
                 break
         coordinator_address = "{}:{}".format(jax_nodes[0]["host"], jax_nodes[0]["port"])
-        tracer.instant("node/cluster_ready", executor_id=executor_id,
-                       num_processes=num_processes, process_id=process_id)
+        bringup.instant("launch", "node/cluster_ready",
+                        executor_id=executor_id, num_processes=num_processes,
+                        process_id=process_id)
 
         ctx = TPUNodeContext(
             executor_id, job_name, task_index, cluster_info,
@@ -770,13 +783,24 @@ def run(fn, tf_args, cluster_meta, tensorboard=False, log_dir=None,
             from tensorflowonspark_tpu import compilecache
 
             compilecache.configure_from_meta(cluster_meta)
+            if context.job_name in _JAX_JOBS:
+                # the compile plane keeps its books in every process that
+                # hosts jax, with or without a cache directory (from the
+                # moment the process imports jax: nothing is imported here)
+                compilecache.listen()
             if profiler_port:
                 from tensorflowonspark_tpu import profiler as profiler_mod
 
                 profiler_mod.start_server_when_backend_is_up(profiler_port)
             if isinstance(args, list):
                 sys.argv = args
-            fn(args, context)
+            # ``launch`` ends and ``user`` begins: from here on every
+            # instant that the program does not claim is the user's
+            with bringup.span("user", "node/user_fn",
+                              executor_id=executor_id,
+                              job_name=context.job_name,
+                              task_index=context.task_index):
+                fn(args, context)
 
         heartbeat_interval = cluster_meta.get("heartbeat_interval", 0)
 
@@ -811,10 +835,7 @@ def run(fn, tf_args, cluster_meta, tensorboard=False, log_dir=None,
             tracer = telemetry.get_tracer()
             reason = None
             try:
-                with tracer.span("node/user_fn", executor_id=executor_id,
-                                 job_name=context.job_name,
-                                 task_index=context.task_index):
-                    wrapper_fn(args, context)
+                wrapper_fn(args, context)
                 reason = "done"
             except Exception:
                 try:
@@ -889,9 +910,7 @@ def run(fn, tf_args, cluster_meta, tensorboard=False, log_dir=None,
             fault.from_env().arm_preempt_notice()
             reason = None
             try:
-                with tracer.span("node/user_fn", executor_id=executor_id,
-                                 job_name=job_name, task_index=task_index):
-                    wrapper_fn(tf_args, ctx)
+                wrapper_fn(tf_args, ctx)
                 reason = "done"
             except Exception:
                 errq.put(traceback.format_exc())

@@ -25,7 +25,11 @@ Three legs, all dependency-free:
    the same way by two phase clocks (:class:`PhaseClock`):
    ``feeder_<phase>_us`` in the executor's feed tasks, ``feed_<phase>_us``
    in the ``DataFeed``.  Every instant belongs to one phase, so the phases
-   sum to the wall time.
+   sum to the wall time.  The bring-up is kept by one more account of the
+   same kind, across the three processes it passes through (the driver,
+   the executor's start task, the process that holds the chip):
+   ``bringup_<phase>_us`` from ``cluster.run`` entered to the first
+   dispatch returned (:data:`bringup`).
 3. **Hang flight recorder** — :meth:`Tracer.dump` writes all-thread
    stacktraces, the open span stack, counters, and caller-supplied state to
    ``<dir>/flight-<host>-<pid>.json``; triggered by SIGUSR1
@@ -178,6 +182,115 @@ class PhaseClock(object):
                for name, us, told in zip(self.phases, now, self._told_us)}
         self._told_us = now
         return out
+
+
+# -- the bring-up's account ------------------------------------------------
+
+#: the phases of a bring-up, in the order a first one meets them (see
+#: docs/OBSERVABILITY.md, "Bring-up", for the mark that opens each)
+BRINGUP_PHASES = ("driver", "spawn", "node", "rendezvous", "launch", "user",
+                  "trainer_init", "first_batch", "first_dispatch")
+
+
+class Bringup(object):
+    """Always-on account of the wall time from ``cluster.run`` entered to
+    the chip-holding process's first dispatch returned.
+
+    A :class:`PhaseClock` whose time crosses processes: instead of sums
+    over a monotonic clock it keeps the marks themselves, ``[wall
+    microseconds, phase that begins there]`` in :func:`wall_time_us`, so
+    that the driver's marks can ride ``cluster_meta`` to the executor, a
+    forked child goes on where its parent stood, and all of them lie on
+    the axis of the tracer's files.  Every instant between the first mark
+    and the closing one belongs to the phase marked last, so the phases
+    sum to last less first by construction; a mark never lies before the
+    one in front of it (a wall clock may step back: such a mark is held
+    at its predecessor).  One thread marks at a time (the thread that
+    brings the process up); any thread may read.
+    """
+
+    def __init__(self):
+        self.marks = []       # [[us, phase]], phase None on the closing one
+
+    @property
+    def open(self):
+        """True while the account can still take a mark."""
+        return not (self.marks and self.marks[-1][1] is None)
+
+    def current(self):
+        return self.marks[-1][1] if self.marks else "user"
+
+    def _append(self, phase):
+        now = int(wall_time_us())
+        if self.marks and now < self.marks[-1][0]:
+            now = self.marks[-1][0]
+        self.marks.append([now, phase])
+
+    def mark(self, phase):
+        """``phase`` begins now; returns the phase that was current (a
+        process whose account has no mark yet is running its user's code).
+        ``None`` marks nothing, and nothing is marked once closed."""
+        was = self.current()
+        if phase is not None and self.open:
+            self._append(phase)
+        return was
+
+    def begin(self):
+        """The driver enters ``cluster.run``: a new account, on ``driver``."""
+        self.adopt(None)
+        self.mark("driver")
+
+    def span(self, phase, name, **attrs):
+        """:meth:`mark` and the tracer's span ``name`` in one call, for a
+        phase that begins where a span of the program already stands."""
+        self.mark(phase)
+        return _tracer.span(name, **attrs)
+
+    def instant(self, phase, name, **attrs):
+        """:meth:`mark` and the tracer's instant ``name`` in one call."""
+        self.mark(phase)
+        _tracer.instant(name, **attrs)
+
+    def export(self):
+        """The marks so far as plain lists (``cluster_meta["bringup"]``)."""
+        return [list(m) for m in self.marks]
+
+    def adopt(self, marks):
+        """Start over from another process's marks (the driver's, off
+        ``cluster_meta``).  Marks that lie after this host's "now" are two
+        hosts' clocks apart: the whole set is moved back to end now, so
+        the difference shortens the phase that crosses the hosts
+        (``spawn``, to 0 at least) and no other."""
+        marks = [[int(us), str(phase)] for us, phase in (marks or ())]
+        if marks:
+            ahead = marks[-1][0] - int(wall_time_us())
+            if ahead > 0:
+                marks = [[us - ahead, phase] for us, phase in marks]
+        self.marks = marks
+
+    def close(self):
+        """The last mark: the account is whole and :meth:`snapshot` tells
+        it from now on.  Returns False (the caller's "still open" flag)."""
+        if self.open and self.marks:
+            self._append(None)
+        return False
+
+    def snapshot(self):
+        """``bringup_<phase>_us`` for every phase and ``bringup_wall_us``
+        (last mark less first, which they sum to) once closed; nothing of
+        it before."""
+        if self.open:
+            return {}
+        sums = dict.fromkeys(BRINGUP_PHASES, 0)
+        for (us, phase), (then, _) in zip(self.marks, self.marks[1:]):
+            sums[phase] = sums.get(phase, 0) + then - us
+        told = {"bringup_%s_us" % k: v for k, v in sums.items()}
+        told["bringup_wall_us"] = self.marks[-1][0] - self.marks[0][0]
+        return told
+
+
+#: the process's account (a forked child goes on with its parent's)
+bringup = Bringup()
 
 
 class _NullSpan(object):

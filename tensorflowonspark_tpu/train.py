@@ -21,7 +21,9 @@ from typing import Any, Callable, Optional
 import jax
 import jax.numpy as jnp
 
+from tensorflowonspark_tpu import compilecache
 from tensorflowonspark_tpu import metrics as metrics_mod
+from tensorflowonspark_tpu import telemetry
 from tensorflowonspark_tpu.parallel import mesh as mesh_mod
 
 logger = logging.getLogger(__name__)
@@ -166,6 +168,8 @@ class Trainer(object):
                  summary_writer=None, param_sharding=None,
                  step_flops_override=None,
                  aot_cache=None, aot_program_version=None):
+        # the bring-up's account: ``trainer_init`` from here to the return
+        resume = telemetry.bringup.mark("trainer_init")
         self.mesh = mesh if mesh is not None else mesh_mod.build_mesh()
         self.loss_fn = loss_fn
         self.optimizer = optimizer
@@ -369,11 +373,9 @@ class Trainer(object):
         # Poison-step rollback (remediator ``train_rollback`` command knob):
         # a pending request token armed by apply_knob, the set of tokens
         # already honoured (the knob coordinator re-broadcasts on every
-        # heartbeat, so dedupe lives here), and a completed-rollback tally
-        # published as ``train_rollbacks_total``.
+        # heartbeat, so dedupe lives here).
         self._rollback_req = None
         self._rollback_tokens = set()
-        self._rollbacks = 0
         # Megastep telemetry: dispatched train steps (counter), the K of
         # the most recent dispatch, and the session-max K (the heartbeat
         # gauge — the tail of a feed degrades to K=1 singles, so "last K"
@@ -392,6 +394,37 @@ class Trainer(object):
         self._moe_pending = []
         self._moe_totals = {}
         self._moe_lock = threading.Lock()
+        # Which program was made, at which step (see _note_compile): the
+        # compile plane's tallies as of the last program seen, the names of
+        # the step programs that have run, the programs made again under a
+        # name that had run (the records themselves are the compile
+        # plane's, ``compilecache.stats.record``).
+        self._compile_seen = compilecache.stats.tallies()
+        self._programs_run = set()
+        self._recompiles = 0
+        telemetry.bringup.mark(resume)
+
+    def _note_compile(self, name):
+        """A dispatch of step program ``name`` made programs executable
+        (``compile_programs`` moved across it): keep which, at which step
+        and at what cost.  The cost is the compile plane's tallies since
+        the last program this trainer saw made, so the tracing and lowering
+        in front of the compile are in it."""
+        now = compilecache.stats.tallies()
+        cost = {k: v - self._compile_seen[k] for k, v in now.items()}
+        self._compile_seen = now
+        again = name in self._programs_run
+        if again:
+            self._recompiles += 1
+        entry = dict(cost, program=name, steps_total=self._steps_total,
+                     recompile=again)
+        compilecache.stats.record.append(entry)
+        telemetry.get_tracer().instant("compile/program", **entry)
+        if again:
+            logger.warning(
+                "step program %s was made again at step %d (%s): a batch of "
+                "another shape, or a state laid out anew", name,
+                self._steps_total, cost)
 
     def _note_moe(self, aux):
         if not isinstance(aux, dict):
@@ -417,7 +450,7 @@ class Trainer(object):
                     self._moe_totals[key] = self._moe_totals.get(
                         key, 0) + val.item()
 
-    def counters_snapshot(self):
+    def _own_counters(self):
         """Flat overlap + goodput counters for heartbeat payloads /
         :func:`~tensorflowonspark_tpu.telemetry.merge_counters`:
         ``dispatch_count`` dispatches, ``dispatch_gap_us`` total host-side
@@ -455,7 +488,14 @@ class Trainer(object):
         those of them that hold a picked key (what a kernel that skipped
         empty tiles could save on this data), ``dsa_index_loss`` the index's
         loss summed over the steps, ``dsa_layers_steps`` the layer calls
-        counted."""
+        counted.
+
+        ``train_recompiles_total``: dispatches that made a step program
+        executable under a name (``step``, ``multi_<k>``) that had run
+        before: a batch of another shape, a state laid out anew.  Which
+        program, at which step and at what cost is in
+        ``compilecache.stats.record`` (the flight recorder's
+        ``compile_programs``) and in the instant ``compile/program``."""
         snap = {
             "dispatch_count": self._dispatch_count,
             "dispatch_gap_us": self._dispatch_gap_us,
@@ -503,11 +543,27 @@ class Trainer(object):
                 snap["train_loss_max"] = self._health_loss
             if self._health_grad is not None:
                 snap["train_grad_norm_max"] = round(self._health_grad, 6)
-        if self._rollbacks:
-            snap["train_rollbacks_total"] = self._rollbacks
+        if self._recompiles:
+            snap["train_recompiles_total"] = self._recompiles
         if self._moe_pending or self._moe_totals:
             self._fold_moe()
             snap.update(self._moe_totals)   # the loss names them moe_*, dsa_*
+        return snap
+
+    def counters_snapshot(self):
+        """:meth:`_own_counters` (see there) and what the process keeps
+        for all its trainers: the compile plane's tallies
+        (``compilecache.stats.tallies()``: ``compile_trace_us``,
+        ``compile_lower_us``, ``compile_backend_us``,
+        ``compile_cache_retrieval_us``, ``compile_programs``) and, once the
+        first dispatch of ``fit_feed`` has returned and nothing of it
+        before, the bring-up's account (``telemetry.bringup``:
+        ``bringup_<phase>_us`` for the nine phases and ``bringup_wall_us``,
+        which they sum to).  A node's heartbeats carry these two once a
+        process, not once a trainer (``node._node_metrics_provider``)."""
+        snap = self._own_counters()
+        snap.update(compilecache.stats.tallies())
+        snap.update(telemetry.bringup.snapshot())
         return snap
 
     def apply_knob(self, name, value):
@@ -664,8 +720,6 @@ class Trainer(object):
         ctor choice."""
         if self._aot is not None or cache is None:
             return
-        from tensorflowonspark_tpu import compilecache
-
         self._aot = (cache if isinstance(cache, compilecache.AOTCache)
                      else compilecache.AOTCache(cache))
 
@@ -681,8 +735,6 @@ class Trainer(object):
             return None
         if name in self._aot_exec:
             return self._aot_exec[name]
-        from tensorflowonspark_tpu import compilecache
-
         if self._aot_program_id is None:
             # the Python half of the program — avals alone cannot tell two
             # losses (or two learning rates) with identical shapes apart
@@ -726,16 +778,25 @@ class Trainer(object):
         buffers are still intact for the retry — jax raises TypeError for
         aval mismatches and ValueError for sharding/layout mismatches
         (version-dependent), both from pre-execution argument checks."""
-        fn = self._aot_resolve(name, jit_fn, args)
-        if fn is not None:
-            try:
-                return fn(*args)
-            except (TypeError, ValueError):
-                logger.warning(
-                    "AOT executable %s rejected the call (aval drift); "
-                    "reverting this program to JIT dispatch", name)
-                self._aot_exec[name] = None
-        return jit_fn(*args)
+        made = compilecache.stats.programs
+        if made != self._compile_seen["compile_programs"]:
+            # programs made since the last dispatch are not this one's
+            self._compile_seen = compilecache.stats.tallies()
+        try:
+            fn = self._aot_resolve(name, jit_fn, args)
+            if fn is not None:
+                try:
+                    return fn(*args)
+                except (TypeError, ValueError):
+                    logger.warning(
+                        "AOT executable %s rejected the call (aval drift); "
+                        "reverting this program to JIT dispatch", name)
+                    self._aot_exec[name] = None
+            return jit_fn(*args)
+        finally:
+            if compilecache.stats.programs != made:
+                self._note_compile(name)
+            self._programs_run.add(name)
 
     def _ensure_history(self):
         """Build the metrics recorder on first use.  A step's FLOPs are the
@@ -908,8 +969,11 @@ class Trainer(object):
         dispatch-gap counters merged with the feed's ``infeed_*`` tallies
         (see :meth:`counters_snapshot`)."""
         from tensorflowonspark_tpu import fault as fault_mod
-        from tensorflowonspark_tpu import telemetry
 
+        # The bring-up's account: from here to the first batch in hand is
+        # ``first_batch``.
+        bringing_up = telemetry.bringup.open
+        resume = telemetry.bringup.mark("first_batch")
         tracer = telemetry.get_tracer()
         guard_level = _resolve_transfer_guard(transfer_guard)
         # Chaos hooks (null-object when TFOS_FAULT_SPEC is unset: one env
@@ -971,6 +1035,8 @@ class Trainer(object):
             # an explicit next(), so that the wait for a batch has a span
             with telemetry.span("train/next_batch"):
                 item = next(source, None)
+            if bringing_up:
+                telemetry.bringup.mark(resume)
             if item is None:
                 break
             kind, batch, mask = item
@@ -995,6 +1061,8 @@ class Trainer(object):
                 # iteration's on_steps hook was spent waiting on the feed.
                 self._goodput_infeed_starved_us += max(
                     0, gap_us - self._last_drain_us)
+            if bringing_up:
+                telemetry.bringup.mark("first_dispatch")
             with telemetry.span("train/dispatch", kind=kind), \
                     _transfer_guard_ctx(guard_level):
                 if kind == "multi":
@@ -1006,6 +1074,9 @@ class Trainer(object):
                     loss, _ = self.step(batch, mask)
                     steps_done += 1
             prev_return = time.perf_counter()
+            if bringing_up:
+                # the first dispatch has returned: the account is whole
+                bringing_up = telemetry.bringup.close()
             self._goodput_dispatch_us += int((prev_return - start) * 1e6)
             self._dispatch_count += 1
             self._account_windows()
@@ -1129,7 +1200,6 @@ def fit_supervised(trainer, feed_factory, ckpt_manager, retry_policy=None,
     """
     from tensorflowonspark_tpu import fault as fault_mod
     from tensorflowonspark_tpu import node as node_mod
-    from tensorflowonspark_tpu import telemetry
 
     policy = retry_policy or fault_mod.RetryPolicy()
     tracer = telemetry.get_tracer()
@@ -1239,13 +1309,10 @@ def fit_supervised(trainer, feed_factory, ckpt_manager, retry_policy=None,
                 rollbacks += 1
                 if rollbacks > max_rollbacks:
                     raise
-                trainer._rollbacks = rollbacks
                 logger.warning(
                     "poison rollback %d/%d at host step %s: restoring last "
                     "VALID checkpoint (poisoned steps quarantined as "
                     "<step>.corrupt)", rollbacks, max_rollbacks, rb.step)
-                tracer.instant("train/rollback", step=rb.step, token=rb.token,
-                               rollbacks=rollbacks)
                 # Loop straight back to restore_latest(validate=True): it
                 # walks newest-first, quarantines every checkpoint that
                 # fails validation, and restores the last valid one.

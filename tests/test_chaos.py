@@ -263,16 +263,10 @@ def test_elastic_replacement_full_loop():
         b.stop()
 
 
-@pytest.mark.chaos(timeout=240)
-def test_chaos_timeline_reconstructs_kill_fence_reclaim_replace(tmp_path):
-    """Observability flagship: rerun the elastic loop with ``telemetry=True``
-    and reconstruct the WHOLE incident from the trace files alone —
-    injected kill → liveness fence → slot release → replacement admission —
-    with consistent executor/generation attributes and causal ordering.
-    This is what an operator gets when they load a chaos run's telemetry
-    directory into Perfetto."""
+def _chaos_timeline_run(tdir):
+    """One elastic run with ``telemetry=True`` and executor 0 killed after
+    five items; returns every process's trace events by name."""
     spec = json.dumps({"kill_after_items": 5})
-    tdir = str(tmp_path / "telemetry")
     b = backend.LocalBackend(
         3, env_per_executor=[{fault.FAULT_SPEC_ENV: spec}, None, None])
     try:
@@ -286,50 +280,85 @@ def test_chaos_timeline_reconstructs_kill_fence_reclaim_replace(tmp_path):
         c.train(backend.partition(range(30), 3), retry_policy=policy)
         assert c.tf_status.get("replacements"), c.tf_status
         c.shutdown(grace_secs=1)
-
-        # every process wrote a parseable Chrome trace
-        events = []
-        for path in glob.glob(os.path.join(tdir, "trace-*.json")):
-            with open(path) as f:
-                events.extend(json.load(f)["traceEvents"])
-        by_name = {}
-        for e in events:
-            by_name.setdefault(e["name"], []).append(e)
-
-        # the injected kill itself is on the timeline (the injector flushes
-        # its trace before SIGKILLing the process)
-        (kill,) = by_name["fault/kill_after_items"]
-        assert kill["args"]["items"] >= 5
-
-        # fence -> release -> admission, all naming the same incident
-        (fence,) = by_name["reservation/fence"]
-        assert fence["args"]["executor_id"] == 0
-        (release,) = by_name["reservation/release"]
-        assert release["args"]["executor_id"] == 0
-        assert release["args"]["job_name"] == fence["args"]["job_name"]
-        admissions = [e for e in by_name["reservation/admission"]
-                      if e["args"].get("replacement")]
-        assert len(admissions) == 1, by_name["reservation/admission"]
-        adm = admissions[0]["args"]
-        assert adm["executor_id"] == 3
-        assert (adm["job_name"], adm["task_index"]) == (
-            release["args"]["job_name"], release["args"]["task_index"])
-        # the admission bumped the generation the release was observed at
-        assert adm["generation"] == release["args"]["generation"] + 1
-
-        # causal order on the shared wall-clock timeline
-        assert (kill["ts"] <= fence["ts"] <= release["ts"]
-                <= admissions[0]["ts"])
-
-        # the driver's replacement dispatch and the new node's bring-up are
-        # also present (the "replace" leg of the story)
-        assert by_name.get("cluster/replacement_dispatched")
-        assert by_name.get("backend/provision_replacement")
-        replacement_regs = [e for e in by_name["node/register"]
-                            if e["args"].get("executor_id") == 3]
-        assert replacement_regs, by_name["node/register"]
     finally:
         b.stop()
+    # every process wrote a parseable Chrome trace
+    by_name = {}
+    for path in glob.glob(os.path.join(tdir, "trace-*.json")):
+        with open(path) as f:
+            for e in json.load(f)["traceEvents"]:
+                by_name.setdefault(e["name"], []).append(e)
+    return by_name
+
+
+@pytest.mark.chaos(timeout=240)
+def test_chaos_timeline_reconstructs_kill_fence_reclaim_replace(tmp_path):
+    """Observability flagship: rerun the elastic loop with ``telemetry=True``
+    and reconstruct the WHOLE incident from the trace files alone —
+    injected kill → liveness fence → slot release → replacement admission —
+    with consistent executor/generation attributes and causal ordering.
+    This is what an operator gets when they load a chaos run's telemetry
+    directory into Perfetto."""
+    def of_executor(name, executor_id):
+        return [e for e in by_name.get(name, [])
+                if e["args"].get("executor_id") == executor_id]
+
+    for attempt in range(3):
+        by_name = _chaos_timeline_run(str(tmp_path / ("telemetry-%d"
+                                                      % attempt)))
+        # One second of silence fences a node, and on a loaded machine a
+        # healthy one is silent that long: where that happened to executor 0
+        # itself before its kill fired, the run holds no injected incident
+        # to reconstruct (the kill hit a node already replaced), and the
+        # scenario is run again.  A healthy PEER fenced beside it is fine:
+        # that is a second story in the same files.
+        kills = by_name.get("fault/kill_after_items", [])
+        early = [f for f in of_executor("reservation/fence", 0)
+                 for k in kills if f["ts"] < k["ts"]]
+        if kills and not early:
+            break
+    # the injected kill itself is on the timeline (the injector flushes
+    # its trace before SIGKILLing the process)
+    (kill,) = by_name["fault/kill_after_items"]
+    assert kill["args"]["items"] >= 5
+
+    # fence -> release -> admission, all naming the same incident: the
+    # one injected, executor 0's.  (Under load, one second of silence
+    # can fence a healthy node too, and that node gets a story of its
+    # own; the assertions below hold this one to exactly one of each.)
+    (fence,) = of_executor("reservation/fence", 0)
+    (release,) = of_executor("reservation/release", 0)
+    assert release["args"]["job_name"] == fence["args"]["job_name"]
+    assert release["args"]["task_index"] == fence["args"]["task_index"]
+    # the driver dispatched exactly one replacement for it, and names it
+    (dispatched,) = [e for e in by_name["cluster/replacement_dispatched"]
+                     if e["args"]["dead_executor"] == 0]
+    new_id = dispatched["args"]["new_executor"]
+    assert new_id >= 3 and (new_id == 3 or len(
+        by_name["cluster/replacement_dispatched"]) > 1)
+    replacements = [e for e in by_name["reservation/admission"]
+                    if e["args"].get("replacement")]
+    (admission,) = [e for e in replacements
+                    if e["args"]["executor_id"] == new_id]
+    adm = admission["args"]
+    assert (adm["job_name"], adm["task_index"]) == (
+        release["args"]["job_name"], release["args"]["task_index"])
+    # the admission bumped the generation the release was observed at
+    # (each replacement admitted in between bumped it once more)
+    between = [e for e in replacements if e is not admission
+               and release["ts"] <= e["ts"] <= admission["ts"]]
+    assert adm["generation"] == (release["args"]["generation"] + 1
+                                 + len(between))
+
+    # causal order on the shared wall-clock timeline
+    assert (kill["ts"] <= fence["ts"] <= release["ts"]
+            <= admission["ts"])
+    assert release["ts"] <= dispatched["ts"]
+
+    # the driver's replacement dispatch and the new node's bring-up are
+    # also present (the "replace" leg of the story)
+    assert by_name.get("backend/provision_replacement")
+    assert of_executor("node/register", new_id), by_name["node/register"]
 
 
 @pytest.mark.chaos(timeout=180)

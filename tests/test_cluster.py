@@ -730,3 +730,80 @@ def test_initialize_distributed_refuses_processes_that_form_no_world(
     # a world that did form is left alone
     monkeypatch.setattr(jax, "process_count", lambda: 4)
     ctx.initialize_distributed()
+
+
+def _bringup_map_fun(args, ctx):
+    """Builds a small Trainer, takes one step from ``fit_feed`` and writes
+    what ``counters_snapshot()`` says afterwards."""
+    import json
+
+    import jax.numpy as jnp
+    import numpy as np
+    import optax
+
+    from tensorflowonspark_tpu import train as train_mod
+
+    def loss(params, batch, mask):
+        err = (batch["x"] @ params["w"] - batch["y"]) ** 2 * mask
+        return err.sum() / jnp.maximum(mask.sum(), 1.0), {}
+
+    trainer = train_mod.Trainer(loss, {"w": jnp.zeros((2,))},
+                                optax.sgd(0.1), batch_size=4)
+    before = trainer.counters_snapshot()
+
+    class OneBatch(object):
+        def batches(self):
+            yield ({"x": np.ones((4, 2), np.float32),
+                    "y": np.ones((4,), np.float32)},
+                   np.ones((4,), np.float32))
+
+    trainer.fit_feed(OneBatch())
+    with open("bringup.json", "w") as f:
+        json.dump({"before": before, "after": trainer.counters_snapshot()},
+                  f)
+    if args and args[0] == "spark":
+        feed = ctx.get_data_feed()
+        while not feed.should_stop():
+            feed.next_batch(4)
+
+
+@pytest.mark.parametrize("mode", ["files", "spark"])
+def test_bringup_account_crosses_driver_executor_and_trainer(mode):
+    """The bring-up's account, always on (telemetry is off here): the
+    driver's marks reach the executor through ``cluster_meta``, in SPARK
+    mode the forked child goes on with its parent's, and once the first
+    dispatch of ``fit_feed`` has returned ``counters_snapshot()`` tells
+    nine phases that sum to ``bringup_wall_us`` to the microsecond."""
+    import json
+
+    from tensorflowonspark_tpu import telemetry
+
+    b = backend.LocalBackend(1)
+    try:
+        c = cluster.run(b, _bringup_map_fun, tf_args=[mode], num_executors=1,
+                        input_mode=(InputMode.SPARK if mode == "spark"
+                                    else InputMode.FILES))
+        # the driver's marks rode cluster_meta: two, `driver` then `spawn`
+        assert [p for _, p in c.cluster_meta["bringup"]] == ["driver",
+                                                             "spawn"]
+        if mode == "spark":
+            c.train(backend.partition(range(8), 2))
+        c.shutdown()
+        with open(os.path.join(b.workdir_root, "executor-0",
+                               "bringup.json")) as f:
+            told = json.load(f)
+    finally:
+        b.stop()
+    # nothing of it before the first dispatch has returned
+    assert not [k for k in told["before"] if k.startswith("bringup_")]
+    after = told["after"]
+    phases = {p: after["bringup_%s_us" % p] for p in telemetry.BRINGUP_PHASES}
+    assert len(phases) == 9
+    assert all(isinstance(v, int) and v >= 0 for v in phases.values()), phases
+    assert sum(phases.values()) == after["bringup_wall_us"], phases
+    assert phases["spawn"] > 0 and phases["rendezvous"] > 0, phases
+    # the step program's making was in the first dispatch, and it is named
+    assert phases["first_dispatch"] > 0 and phases["trainer_init"] > 0
+    assert after["compile_programs"] >= 1
+    assert after["compile_trace_us"] + after["compile_backend_us"] > 0
+    assert "train_recompiles_total" not in after

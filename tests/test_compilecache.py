@@ -406,3 +406,179 @@ class TestConfigure:
         b = compilecache.fingerprint(extra={"program": "y"})
         diff = sorted(k for k in set(a) | set(b) if a.get(k) != b.get(k))
         assert diff == ["program"]
+
+
+class TestThePlanesOwnBooks:
+    """The listeners keep trace / lower / backend / retrieval time and the
+    programs made, cache directory or none, each instant once."""
+
+    def _watch(self):
+        """The raw events beside the tallies: every backend-compile event,
+        and the plain sum of the four durations (nested ones twice)."""
+        from jax import monitoring
+
+        raw = {"programs": 0, "secs": 0.0}
+
+        def listener(event, duration, **kwargs):
+            if event in compilecache._STAGE_OF or \
+                    event == compilecache._RETRIEVAL_EVENT:
+                raw["secs"] += duration
+            if event == compilecache._BACKEND_EVENT:
+                raw["programs"] += 1
+
+        monitoring.register_event_duration_secs_listener(listener)
+        return raw, lambda: monitoring.unregister_event_duration_listener(
+            listener)
+
+    def test_counts_a_jitted_function_with_no_cache_directory(self):
+        """No cache directory is configured in this process: the tallies
+        still count a jitted function's trace, lowering and compile, a
+        function traced inside another's trace is not counted twice, and
+        installing the listeners twice counts once."""
+        assert compilecache.configured_dir() is None
+        compilecache._install_listeners()
+        compilecache._install_listeners()
+        raw, stop = self._watch()
+        try:
+            before = compilecache.stats.tallies()
+
+            @jax.jit
+            def inner(x):
+                return jnp.tanh(x) * 3
+
+            @jax.jit
+            def outer(x):
+                return inner(x) + inner(x + 1) + jnp.where(x > 0, x, 2 * x)
+
+            outer(jnp.arange(7, dtype=jnp.float32)).block_until_ready()
+            after = compilecache.stats.tallies()
+        finally:
+            stop()
+        moved = {k: after[k] - before[k] for k in after}
+        assert moved["compile_programs"] == raw["programs"] >= 1
+        assert moved["compile_trace_us"] > 0
+        assert moved["compile_lower_us"] > 0
+        assert moved["compile_backend_us"] > 0
+        assert moved["compile_cache_retrieval_us"] == 0
+        own = sum(v for k, v in moved.items() if k.endswith("_us"))
+        # the nested traces are in the raw sum twice and in the tallies once
+        assert 0 < own < raw["secs"] * 1e6 + 1000
+        # a second call of the same shape makes nothing
+        outer(jnp.arange(7, dtype=jnp.float32)).block_until_ready()
+        assert compilecache.stats.tallies() == after
+
+    def test_nested_events_are_booked_once(self):
+        """By hand: a trace of 10 ms holding a whole small program (trace
+        1, lower 2, compile 3 with a retrieval of 2 inside) books 4 on its
+        own, and the five add up to the outermost 10."""
+        stats = compilecache.stats
+        before = stats.tallies()
+        for event, secs in (
+                (compilecache._TRACE_EVENT, None),
+                (compilecache._TRACE_EVENT, None),
+                (compilecache._TRACE_EVENT, 0.001),
+                (compilecache._LOWER_EVENT, None),
+                (compilecache._LOWER_EVENT, 0.002),
+                (compilecache._BACKEND_EVENT, None),
+                (compilecache._RETRIEVAL_EVENT, 0.002),
+                (compilecache._BACKEND_EVENT, 0.003),
+                (compilecache._TRACE_EVENT, 0.010)):
+            if secs is None:
+                compilecache._on_scalar(event, 0.0, fun_name="f")
+            else:
+                compilecache._on_duration(event, secs, fun_name="f")
+        moved = {k: v - before[k] for k, v in stats.tallies().items()}
+        assert moved == {"compile_trace_us": 1000 + 4000,
+                         "compile_lower_us": 2000,
+                         "compile_backend_us": 1000,
+                         "compile_cache_retrieval_us": 2000,
+                         "compile_programs": 1}
+
+    def test_a_cache_hit_is_a_program_and_not_a_compile(self, tmp_path):
+        """With a cache directory: a cold process misses and compiles, a
+        warm one makes the same programs executable from the cache, every
+        one a hit, with the read booked as retrieval and not as the
+        compiler's time.  (In processes of their own: jax's persistent cache
+        is a process's for life.)"""
+        import subprocess
+        import sys
+
+        script = tmp_path / "job.py"
+        script.write_text(
+            "import json, sys\n"
+            "import jax, jax.numpy as jnp\n"
+            "from tensorflowonspark_tpu import compilecache as cc\n"
+            "cc.configure(sys.argv[1], register_feed=False)\n"
+            "f = jax.jit(lambda x: jnp.tanh(x) @ x.T)\n"
+            "f(jnp.ones((8, 8))).block_until_ready()\n"
+            "print(json.dumps(dict(cc.stats.counters_snapshot())))\n")
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=root)
+        env.pop(compilecache.JAX_CACHE_DIR_ENV, None)
+        runs = []
+        for _ in range(2):
+            done = subprocess.run(
+                [sys.executable, str(script), str(tmp_path / "cache")],
+                env=env, stdout=subprocess.PIPE, stderr=subprocess.PIPE,
+                text=True, timeout=300)
+            assert done.returncode == 0, done.stderr[-2000:]
+            runs.append(json.loads(done.stdout.strip().splitlines()[-1]))
+        cold, warm = runs
+        assert cold["compile_programs"] == warm["compile_programs"] >= 1
+        assert cold["compile_cache_miss"] == cold["compile_programs"]
+        assert cold["compile_cache_hit"] == 0
+        assert cold["compile_cache_retrieval_us"] == 0
+        assert warm["compile_cache_hit"] == warm["compile_programs"]
+        assert warm["compile_cache_miss"] == 0
+        assert warm["compile_cache_retrieval_us"] > 0
+        assert warm["compile_trace_us"] > 0 and warm["compile_lower_us"] > 0
+
+    def test_listen_waits_for_the_process_to_import_jax(self, tmp_path):
+        """``node.run`` calls ``listen()`` before the user function: a
+        worker that never touches jax does not import it for the books'
+        sake, and one that does is counted from its first program."""
+        import subprocess
+        import sys
+
+        script = tmp_path / "job.py"
+        script.write_text(
+            "import json, sys\n"
+            "from tensorflowonspark_tpu import compilecache as cc\n"
+            "cc.listen()\n"
+            "early = 'jax' in sys.modules or cc._listeners_installed\n"
+            "import jax, jax.numpy as jnp\n"
+            "on = cc._listeners_installed\n"
+            "jax.jit(lambda x: x * 2)(jnp.ones(3)).block_until_ready()\n"
+            "cc.listen()\n"
+            "print(json.dumps([early, on, cc.stats.tallies()]))\n")
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        done = subprocess.run(
+            [sys.executable, str(script)], stdout=subprocess.PIPE,
+            stderr=subprocess.PIPE, text=True, timeout=300,
+            env=dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=root))
+        assert done.returncode == 0, done.stderr[-2000:]
+        early, on, tallies = json.loads(done.stdout.strip().splitlines()[-1])
+        assert early is False and on is True
+        assert tallies["compile_programs"] >= 1
+        assert tallies["compile_trace_us"] > 0
+
+    def test_listen_sends_the_books_with_the_heartbeats_and_the_flight_record(
+            self, monkeypatch):
+        """``listen()`` is what a node calls for a process that hosts jax:
+        no directory is needed, the tallies ride the stats feed, and the
+        record of the step programs made is a flight source."""
+        from tensorflowonspark_tpu import node, telemetry
+
+        monkeypatch.setattr(compilecache, "_feed_registered", False)
+        monkeypatch.setattr(node, "_feeds", [])
+        monkeypatch.setattr(telemetry, "_flight_sources", {})
+        compilecache.listen()
+        assert [ref() for ref in node._feeds] == [compilecache.stats]
+        assert set(compilecache.stats.counters_snapshot()) >= set(
+            compilecache.stats.tallies())
+        compilecache.stats.record.append({"program": "step"})
+        try:
+            assert telemetry._flight_sources["compile_programs"]() == [
+                {"program": "step"}]
+        finally:
+            compilecache.stats.record.clear()

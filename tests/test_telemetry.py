@@ -257,3 +257,155 @@ def test_null_tracer_counter_max_is_noop():
     get_tracer() returns — the NULL tracer must absorb it, not raise."""
     telemetry.NULL.counter_max("depth_hwm", 5)
     telemetry.NULL.counter_add("n", 2)
+
+
+# ---------------------------------------------------------------------------
+# the bring-up's account (telemetry.Bringup)
+# ---------------------------------------------------------------------------
+
+def _told(account):
+    snap = account.snapshot()
+    phases = {p: snap["bringup_%s_us" % p] for p in telemetry.BRINGUP_PHASES}
+    return phases, snap["bringup_wall_us"]
+
+
+def test_bringup_phases_sum_to_last_mark_less_first(monkeypatch):
+    """Every instant between the first mark and the closing one belongs to
+    the phase marked last: the phases sum to the wall time to the
+    microsecond, a phase entered twice adds up, and nothing is told before
+    the account is closed."""
+    clock = iter([1000, 1250, 1300, 2300, 2301, 9000, 9500])
+    monkeypatch.setattr(telemetry, "wall_time_us", lambda: next(clock))
+    account = telemetry.Bringup()
+    account.begin()                                   # 1000 driver
+    account.mark("spawn")                             # 1250
+    assert account.mark("user") == "spawn"            # 1300
+    assert account.mark("trainer_init") == "user"     # 2300
+    account.mark("user")                              # 2301
+    account.mark("first_dispatch")                    # 9000
+    assert account.snapshot() == {} and account.open
+    assert account.close() is False and not account.open   # 9500
+    phases, wall = _told(account)
+    assert phases == {"driver": 250, "spawn": 50, "node": 0, "rendezvous": 0,
+                      "launch": 0, "user": 1000 + 6699, "trainer_init": 1,
+                      "first_batch": 0, "first_dispatch": 500}
+    assert wall == 8500 == sum(phases.values())
+
+
+def test_bringup_mark_after_the_first_dispatch_changes_nothing():
+    account = telemetry.Bringup()
+    account.begin()
+    account.mark("user")
+    account.close()
+    before, marks = account.snapshot(), account.export()
+    assert account.mark("trainer_init") is None   # nothing to hand back to
+    account.mark(None)
+    account.close()
+    assert account.snapshot() == before and account.export() == marks
+
+
+def test_bringup_wall_clock_stepping_back_never_makes_a_phase_negative(
+        monkeypatch):
+    clock = iter([5000, 4000, 6000])
+    monkeypatch.setattr(telemetry, "wall_time_us", lambda: next(clock))
+    account = telemetry.Bringup()
+    account.begin()
+    account.mark("spawn")        # the clock stepped back: held at 5000
+    account.close()
+    phases, wall = _told(account)
+    assert phases["driver"] == 0 and phases["spawn"] == 1000 == wall
+
+
+def test_bringup_driver_marks_arrive_through_cluster_meta():
+    """The driver's marks ride ``cluster_meta`` as plain lists (they pass
+    through pickle or JSON), the executor's account goes on from them, and
+    the sum still holds across the two."""
+    driver = telemetry.Bringup()
+    driver.begin()
+    time.sleep(0.002)
+    driver.mark("spawn")
+    meta = json.loads(json.dumps({"bringup": driver.export()}))
+    time.sleep(0.002)
+    node = telemetry.Bringup()
+    node.mark("user")            # a mark of an earlier life of the process
+    node.adopt(meta["bringup"])
+    node.mark("node")
+    node.mark("rendezvous")
+    node.close()
+    phases, wall = _told(node)
+    assert phases["driver"] >= 2000 and phases["spawn"] >= 2000
+    assert phases["user"] == 0
+    assert sum(phases.values()) == wall
+    assert wall == node.marks[-1][0] - driver.marks[0][0]
+
+
+def test_bringup_two_hosts_clocks_apart_shorten_spawn_and_nothing_else():
+    """A driver whose clock is ahead of the executor's: ``spawn`` is floored
+    at 0 and the driver's own phase keeps its length."""
+    ahead = int(telemetry.wall_time_us()) + 60 * 10 ** 6
+    node = telemetry.Bringup()
+    node.adopt([[ahead - 700, "driver"], [ahead, "spawn"]])
+    node.mark("node")
+    node.close()
+    phases, wall = _told(node)
+    assert phases["driver"] == 700 and 0 <= phases["spawn"] < 10 ** 6
+    assert sum(phases.values()) == wall
+
+
+def test_bringup_marks_made_in_a_forked_child_go_on_with_the_parents(
+        monkeypatch):
+    """SPARK mode: the executor's start task forks the process that runs
+    the user function, and the child inherits the process's account."""
+    import multiprocessing
+
+    account = telemetry.Bringup()
+    monkeypatch.setattr(telemetry, "bringup", account)
+    account.begin()
+    account.mark("spawn")
+    account.mark("node")
+    account.mark("launch")
+    parent_marks = account.export()
+
+    def child(conn):
+        telemetry.bringup.mark("user")
+        telemetry.bringup.mark("first_dispatch")
+        telemetry.bringup.close()
+        conn.send((telemetry.bringup.snapshot(), telemetry.bringup.export()))
+
+    here, there = multiprocessing.get_context("fork").Pipe()
+    p = multiprocessing.get_context("fork").Process(target=child,
+                                                    args=(there,))
+    p.start()
+    snap, marks = here.recv()
+    p.join(10)
+    assert marks[:len(parent_marks)] == parent_marks
+    phases = {k: v for k, v in snap.items() if k != "bringup_wall_us"}
+    assert sum(phases.values()) == snap["bringup_wall_us"] \
+        == marks[-1][0] - marks[0][0]
+    # the parent's own account stays open: it never dispatched
+    assert account.snapshot() == {} and account.open
+
+
+def test_bringup_span_and_instant_mark_and_trace_in_one_call(tmp_path,
+                                                             monkeypatch):
+    """A phase begins where a span or an instant of the program already
+    stands: one call makes the mark and, with telemetry on, the event of
+    today's name; a phase of ``None`` (a node in a driver thread) marks
+    nothing and still traces."""
+    tracer = telemetry.configure(True, str(tmp_path))
+    account = telemetry.Bringup()
+    account.begin()
+    account.instant("node", "node/role_assigned", executor_id=0)
+    with account.span("rendezvous", "node/register", executor_id=0):
+        pass
+    with account.span(None, "node/await"):
+        pass
+    assert [p for _, p in account.export()] == ["driver", "node",
+                                                "rendezvous"]
+    names = [e["name"] for e in _load_trace(tracer)["traceEvents"]]
+    assert {"node/role_assigned", "node/register", "node/await"} <= set(names)
+    # telemetry off: the same calls mark all the same
+    telemetry.configure(False)
+    with account.span("launch", "node/user_fn"):
+        pass
+    assert account.current() == "launch"

@@ -1,5 +1,6 @@
 """Trainer / metrics / checkpoint tests (CPU mesh)."""
 
+import json
 import os
 
 import numpy as np
@@ -739,3 +740,113 @@ def test_counters_snapshot_never_compiles_or_syncs(lowered):
     assert not step_was_done
     assert snap["dispatch_count"] == 0 and snap["train_steps_total"] == 2
     assert lowered == []
+
+
+def _compile_watching_trainer():
+    from tensorflowonspark_tpu import compilecache
+
+    compilecache._install_listeners()   # what node.run does for a worker
+    mesh = build_mesh()
+    tr = Trainer(_linear_loss, {"w": jnp.zeros((2,)), "b": jnp.zeros(())},
+                 optax.sgd(0.1), mesh=mesh, batch_size=64, log_steps=1000)
+    return mesh, tr, compilecache.stats
+
+
+def test_a_batch_of_another_shape_is_named_as_a_recompile(tmp_path):
+    """Which program, at which step: the first dispatch makes ``step`` and
+    is no recompile; a batch of another shape makes it again, and the record
+    names the program, the step it happened at and what it cost, counts
+    ``train_recompiles_total`` and, with telemetry on, emits the instant
+    ``compile/program``."""
+    from tensorflowonspark_tpu import telemetry
+
+    tracer = telemetry.configure(True, str(tmp_path))
+    try:
+        mesh, tr, stats = _compile_watching_trainer()
+        stats.record.clear()
+        for _ in range(3):
+            tr.step(_make_batch(mesh, n=64))
+        first, = list(stats.record)
+        assert first["program"] == "step" and first["steps_total"] == 0
+        assert first["recompile"] is False
+        assert first["compile_programs"] >= 1
+        assert "train_recompiles_total" not in tr.counters_snapshot()
+        tr.step(_make_batch(mesh, n=32))          # the fourth step
+        again = stats.record[-1]
+        assert len(stats.record) == 2
+        assert again["program"] == "step" and again["steps_total"] == 3
+        assert again["recompile"] is True
+        assert again["compile_programs"] >= 1
+        assert again["compile_trace_us"] > 0 and again["compile_lower_us"] > 0
+        assert again["compile_backend_us"] > 0
+        assert again["compile_cache_retrieval_us"] >= 0
+        snap = tr.counters_snapshot()
+        assert snap["train_recompiles_total"] == 1
+        assert snap["compile_programs"] == stats.programs
+        path = tracer.flush()
+    finally:
+        telemetry.configure(False)
+    with open(path) as f:
+        instants = [e for e in json.load(f)["traceEvents"]
+                    if e["name"] == "compile/program"]
+    assert [(e["args"]["program"], e["args"]["steps_total"],
+             e["args"]["recompile"]) for e in instants] == [
+        ("step", 0, False), ("step", 3, True)]
+
+
+def test_a_steady_run_names_one_program_and_no_recompile():
+    """Steps of one shape, single and grouped: each program is made once,
+    under its own name, and ``train_recompiles_total`` stays absent; a
+    program made between two dispatches (the caller's own) is not booked on
+    the next one."""
+    mesh, tr, stats = _compile_watching_trainer()
+    stats.record.clear()
+    batch = _make_batch(mesh)
+    mask = jax.device_put(np.ones((64,), np.float32), batch_sharding(mesh))
+    for _ in range(3):
+        tr.step(batch, mask)
+    jax.jit(lambda x: x * 5 + 2)(jnp.arange(3.0)).block_until_ready()
+    tr.step(batch, mask)
+    stacked = _stacked((batch, mask), 2, mesh)
+    tr.multi_step(*stacked)
+    tr.multi_step(*_stacked((batch, mask), 2, mesh))
+    assert [(r["program"], r["steps_total"], r["recompile"])
+            for r in stats.record] == [("step", 0, False),
+                                       ("multi_2", 4, False)]
+    assert "train_recompiles_total" not in tr.counters_snapshot()
+
+
+def test_counters_snapshot_tells_the_bringup_only_once_it_is_whole(
+        monkeypatch):
+    """``counters_snapshot()`` returns the account once the first dispatch
+    of ``fit_feed`` has returned and nothing of it before; the heartbeat's
+    share (``_own_counters``) never holds what the process keeps once."""
+    from tensorflowonspark_tpu import telemetry
+
+    account = telemetry.Bringup()
+    monkeypatch.setattr(telemetry, "bringup", account)
+    mesh = build_mesh()
+    tr = Trainer(_linear_loss, {"w": jnp.zeros((2,)), "b": jnp.zeros(())},
+                 optax.sgd(0.1), mesh=mesh, batch_size=64, log_steps=1000)
+    assert [p for _, p in account.export()] == ["trainer_init", "user"]
+    assert not [k for k in tr.counters_snapshot() if k.startswith("bringup")]
+
+    class Feed(object):
+        def batches(self):
+            for _ in range(3):
+                yield _make_batch(mesh), jax.device_put(
+                    np.ones((64,), np.float32), batch_sharding(mesh))
+
+    seen = []
+    tr.fit_feed(Feed(), on_steps=lambda s: seen.append(
+        (s, tr.counters_snapshot().get("bringup_wall_us"))))
+    snap = tr.counters_snapshot()
+    phases = {p: snap["bringup_%s_us" % p] for p in telemetry.BRINGUP_PHASES}
+    assert sum(phases.values()) == snap["bringup_wall_us"] > 0
+    assert phases["trainer_init"] > 0 and phases["first_dispatch"] > 0
+    assert [p for _, p in account.export()] == [
+        "trainer_init", "user", "first_batch", "user", "first_dispatch", None]
+    # whole from the first hook on, and the same ever after
+    assert [w for _, w in seen] == [snap["bringup_wall_us"]] * 3
+    assert not [k for k in tr._own_counters()
+                if k.startswith(("bringup_", "compile_"))]

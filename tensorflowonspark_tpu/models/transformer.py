@@ -396,6 +396,26 @@ def rope(x, inv, pairing="half", factor=1.0):
                            axis=-1).astype(x.dtype)
 
 
+@jax.custom_vjp
+def _backward_together(main, side):
+    """``(main, side)`` as they come; in the backward pass ``main``'s
+    gradient is not handed on before ``side``'s is made.
+
+    For a branch whose gradient ends in parameters alone (the index of
+    :meth:`Attention._indexed`, which reads a detached ``x``): nothing of the
+    layers below waits for it, so the compiler is free to put its backward
+    kernels behind theirs, and holds what they read (a layer's folded ``q``
+    and ``k``, the index's queries) all the while.  At 32,768-token rows two
+    layers' worth of that, 0.9 GB, lay over the third's expert layer, the
+    step's peak (``tests/test_chip_compile.py``, the keye step)."""
+    return main, side
+
+
+_backward_together.defvjp(
+    lambda main, side: ((main, side), None),
+    lambda _, grads: jax.lax.optimization_barrier(grads))
+
+
 class Attention(nn.Module):
     num_heads: int
     head_dim: int
@@ -478,6 +498,8 @@ class Attention(nn.Module):
         block = self.flash_block
         with jax.named_scope("indexer"):
             iq, ik, iw = self._index(x)
+        # the index's backward kernels run in this layer's backward pass
+        q, (iq, ik, iw) = _backward_together(q, (iq, ik, iw))
         with jax.named_scope("select"):
             bits, index_lse = sparse_index.select_keys(
                 iq, ik, iw, self.index_topk, chunk=block)

@@ -13,6 +13,24 @@ matmuls:
   blocks, one accumulating dK/dV over q blocks — recomputing p = exp(qk -
   L) from the saved logsumexp instead of storing probabilities.
 
+Where a query's softmax statistics live: never one to a 128-lane tile.
+In HBM the logsumexp and ``delta`` are ``[batch*heads, 1, seq]`` float32,
+the query on the lane axis (a ``(1, 1, block_q)`` block is 2 KB where the
+``[.., seq, 1]`` form was 256 KB, and nothing round the kernels relayouts
+them).  In VMEM each kernel holds them the way its tile wants them applied:
+
+- forward and dQ take the tile queries by keys; their statistics (running
+  maximum and sum; logsumexp and ``delta``) are ``[block_q, 128]`` with a
+  query's value on every lane, so that applying one to a ``[block_q,
+  block_k]`` tile is a plain element-wise pass over each run of 128 keys and
+  updating one is 64 whole vregs' work, not 64 vregs with one lane alive.
+  The forward turns its logsumexp into the dense row once a q block, when it
+  writes it; dQ turns the two dense rows into that form once a q block, when
+  it starts on it.
+- dK/dV takes the tile keys by queries (``k q^T``): the dense rows are
+  applied by a broadcast along sublanes, and ``p^T dO`` and ``ds^T q`` are
+  plain products (no transposed ``[block, block]`` operand).
+
 Off-TPU the same kernels run in pallas interpret mode (tests compare
 against the reference attention, values and grads), so
 ``attention="flash"`` is portable; on TPU they compile to Mosaic
@@ -46,16 +64,16 @@ Key sets: ``key_bits`` (``[batch, groups, seq, 128]`` int32, the layout of
 word ``[b, s // 4096, t, s % 128]`` says whether query ``t`` may read key
 ``s``) restricts every query to its own keys inside the causal triangle: the
 three kernels read one ``[block_q, 128]`` tile of words for up to 32 k blocks
-and mask the scores of the tiles they compute.  Every causal tile is still
+and mask the scores of the tiles they compute (dK/dV turns the words with
+its tile, 64 vregs a grid step).  Every causal tile is still
 computed (a masked pair's products are thrown away); without ``key_bits``
 nothing of it is traced and the kernels are the ones they were.
 
 Under a checkpoint (``jax.checkpoint``, ``TransformerLM(remat=True)``): of
 the residuals the backward kernels read, two cost a kernel run to make again
 and are small, the output (``[batch*heads, seq, dv]``, the inputs' dtype) and
-the logsumexp rows (float32, kept as ``[batch*heads, seq]``: in the kernel's
-own ``[batch*heads, seq, 1]`` the chip holds one row a 128-lane tile, and the
-compiled step of five latent layers at 8,192 x 4 kept 0.99 GiB more).  The
+the logsumexp rows (float32, ``[batch*heads, 1, seq]`` as the kernel writes
+them: dense, 4 bytes a query).  The
 forward rule of the ``custom_vjp`` (:func:`_flash_vjp_fwd`) names them
 ``KEPT_OUT`` and ``KEPT_LSE`` with ``jax.ad_checkpoint.checkpoint_name``, on
 the kernel's own results, so that a policy of
@@ -102,33 +120,65 @@ def _dot(a, b, contract):
                                preferred_element_type=jnp.float32)
 
 
-def key_mask(words, k_block_id, block_k):
+def key_mask(words, k_block_id, block_k, keys_first=False):
     """bool ``[block_q, block_k]``: the keys of k block ``k_block_id`` that
     each query may read, from its ``[block_q, 128]`` tile of ``key_bits``
-    words (the group's; ``block_k`` divides by 128 and divides 4096)."""
+    words (the group's; ``block_k`` divides by 128 and divides 4096).
+    ``keys_first``: the words come turned (``[128, block_q]``) and so does
+    the mask (``[block_k, block_q]``)."""
     runs = block_k // KEY_LANES
     first = (k_block_id % (KEY_GROUP // block_k)) * runs
     return jnp.concatenate(
         [jax.lax.shift_right_logical(words, first + r) & 1
-         for r in range(runs)], axis=1) == 1
+         for r in range(runs)], axis=0 if keys_first else 1) == 1
 
 
 def _scores(q_ref, k_ref, scale, masked, q_block_id, k_block_id, block_q,
-            block_k, bits_ref=None):
-    """float32 ``q k^T * scale`` of one (q block, k block) tile, the causal
-    mask applied where ``masked`` (a tile the diagonal crosses), and the
-    queries' key sets where ``bits_ref`` holds them."""
-    s = _dot(q_ref[0], k_ref[0], ((1,), (1,))) * scale       # [BQ, BK]
+            block_k, bits_ref=None, keys_first=False):
+    """float32 ``q k^T * scale`` of one (q block, k block) tile (``k q^T``,
+    keys by queries, where ``keys_first``), the causal mask applied where
+    ``masked`` (a tile the diagonal crosses), and the queries' key sets where
+    ``bits_ref`` holds them."""
+    q_axis, k_axis = (1, 0) if keys_first else (0, 1)
+    operands = (k_ref[0], q_ref[0]) if keys_first else (q_ref[0], k_ref[0])
+    s = _dot(*operands, ((1,), (1,))) * scale
     if bits_ref is not None:
-        s = jnp.where(key_mask(bits_ref[0, 0], k_block_id, block_k), s,
-                      NEG_INF)
+        words = bits_ref[0, 0]
+        s = jnp.where(key_mask(words.T if keys_first else words, k_block_id,
+                               block_k, keys_first), s, NEG_INF)
     if masked:
-        rows = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-                + q_block_id * block_q)
-        cols = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        queries = (jax.lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
+                   + q_block_id * block_q)
+        keys = (jax.lax.broadcasted_iota(jnp.int32, s.shape, k_axis)
                 + k_block_id * block_k)
-        s = jnp.where(rows >= cols, s, NEG_INF)
+        s = jnp.where(queries >= keys, s, NEG_INF)
     return s
+
+
+def _stat_lanes(block_k):
+    """How many lanes a query's statistic is held on beside a ``[block_q,
+    block_k]`` tile: 128, one vreg's, wherever the tile is whole runs of
+    them (a block below 128 is the tiny tests', in interpret mode)."""
+    return KEY_LANES if block_k % KEY_LANES == 0 else block_k
+
+
+def _across(stat, width):
+    """A ``[block_q, lanes]`` statistic (a query's value on every lane)
+    against ``width`` columns: the same vregs again, run after run."""
+    lanes = stat.shape[1]
+    if width == lanes:
+        return stat
+    if width < lanes:
+        return stat[:, :width]
+    if width % lanes == 0:
+        return jnp.concatenate([stat] * (width // lanes), axis=1)
+    return jnp.broadcast_to(stat[:, :1], (stat.shape[0], width))
+
+
+def _on_lanes(row, lanes):
+    """A dense ``[1, block_q]`` row of statistics turned to ``[block_q,
+    lanes]``, a query's value on every lane."""
+    return jnp.broadcast_to(row, (lanes, row.shape[1])).T
 
 
 def _when_needed(causal, qi, kk, block_q, block_k, compute):
@@ -182,13 +232,13 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, block_q, block_k,
     def _compute(masked):
         s = _scores(q_ref, k_ref, scale, masked, qi, kk, block_q, block_k,
                     bits_ref)
-        m_prev = m_scr[:]                              # [BQ, 1]
+        m_prev = m_scr[:]                              # [BQ, lanes]
         m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)                         # [BQ, BK]
-        alpha = jnp.exp(m_prev - m_new)                # [BQ, 1]
+        p = jnp.exp(s - _across(m_new, block_k))       # [BQ, BK]
+        alpha = jnp.exp(m_prev - m_new)                # [BQ, lanes]
         l_scr[:] = l_scr[:] * alpha + p.sum(axis=-1, keepdims=True)
         v = v_ref[0]                                   # [BK, DV]
-        acc_scr[:] = acc_scr[:] * alpha + _dot(
+        acc_scr[:] = acc_scr[:] * _across(alpha, v.shape[1]) + _dot(
             p.astype(v.dtype), v, ((1,), (0,)))
         m_scr[:] = m_new
 
@@ -197,8 +247,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, block_q, block_k,
     @pl.when(kk == n_k - 1)
     def _emit():
         l = jnp.maximum(l_scr[:], 1e-30)
-        o_ref[0] = (acc_scr[:] / l).astype(o_ref.dtype)
-        lse_ref[0] = m_scr[:] + jnp.log(l)
+        o_ref[0] = (acc_scr[:] / _across(l, acc_scr.shape[1])).astype(
+            o_ref.dtype)
+        # the dense row: every lane holds the query's value, one is written
+        lse_ref[0] = (m_scr[:] + jnp.log(l)).T[:1]
 
 
 def _kv_maps(causal, block_q, block_k, group):
@@ -238,12 +290,13 @@ def _bits_spec(rows, block_q, block_k, q_block, k_block):
 
 def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret, group=1,
                bits=None):
-    """Returns ``(out [bh, seq, dv], logsumexp [bh, seq, 1])``; ``q`` is
+    """Returns ``(out [bh, seq, dv], logsumexp [bh, 1, seq])``; ``q`` is
     ``[bh, seq, d]``, ``k [bh // group, seq, d]`` and ``v [bh // group, seq,
-    dv]``.  The softmax statistics keep a
-    trailing unit dim: the TPU lowering wants a block's last two dims
-    divisible by (8, 128) or equal to the array's, and a ``(1, block_q)``
-    row block of a ``[bh, seq]`` array is neither."""
+    dv]``.  The logsumexp rows are dense, the query on the lanes; the unit
+    dim in the middle is for the TPU lowering, which wants a block's last
+    two dims divisible by (8, 128) or equal to the array's: a ``(1, 1,
+    block_q)`` block is, a ``(1, block_q)`` row block of ``[bh, seq]`` is
+    not."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -272,15 +325,15 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret, group=1,
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, block_q, dv), lambda b, i, kk: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i, kk: (b, i, 0)),
+            pl.BlockSpec((1, 1, block_q), lambda b, i, kk: (b, 0, i)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, s_len, dv), q.dtype),
-            jax.ShapeDtypeStruct((bh, s_len, 1), jnp.float32),
+            jax.ShapeDtypeStruct((bh, 1, s_len), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, _stat_lanes(block_k)), jnp.float32),
+            pltpu.VMEM((block_q, _stat_lanes(block_k)), jnp.float32),
             pltpu.VMEM((block_q, dv), jnp.float32),
         ],
         interpret=interpret,
@@ -296,20 +349,23 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
                    scale, causal, block_q, block_k, n_k, keyed):
     from jax.experimental import pallas as pl
 
-    bits_ref, (dq_ref, dq_scr) = _bits_first(refs, keyed)
+    bits_ref, (dq_ref, dq_scr, lse_scr, delta_scr) = _bits_first(refs, keyed)
     kk = pl.program_id(2)
     qi = pl.program_id(1)  # read outside pl.when bodies (interpret mode)
 
     @pl.when(kk == 0)
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
+        # the q block's dense rows, turned once for all of its tiles
+        lse_scr[:] = _on_lanes(lse_ref[0], lse_scr.shape[1])
+        delta_scr[:] = _on_lanes(delta_ref[0], delta_scr.shape[1])
 
     def _compute(masked):
         # p = exp(q k^T * scale - L), recomputed from the saved logsumexp
         p = jnp.exp(_scores(q_ref, k_ref, scale, masked, qi, kk, block_q,
-                            block_k, bits_ref) - lse_ref[0])
+                            block_k, bits_ref) - _across(lse_scr[:], block_k))
         dp = _dot(do_ref[0], v_ref[0], ((1,), (1,)))   # [BQ, BK]
-        ds = p * (dp - delta_ref[0])
+        ds = p * (dp - _across(delta_scr[:], block_k))
         k = k_ref[0]
         dq_scr[:] += scale * _dot(ds.astype(k.dtype), k, ((1,), (0,)))
 
@@ -337,14 +393,16 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
     def _compute(masked):
+        # the tile keys by queries: the dense rows go along the lanes
         p = jnp.exp(_scores(q_ref, k_ref, scale, masked, qi, kk, block_q,
-                            block_k, bits_ref) - lse_ref[0])
+                            block_k, bits_ref, keys_first=True)
+                    - lse_ref[0])                      # [BK, BQ] - [1, BQ]
         do = do_ref[0]                                 # [BQ, DV]
-        dv_scr[:] += _dot(p.astype(do.dtype), do, ((0,), (0,)))
-        dp = _dot(do, v_ref[0], ((1,), (1,)))          # [BQ, BK]
+        dv_scr[:] += _dot(p.astype(do.dtype), do, ((1,), (0,)))
+        dp = _dot(v_ref[0], do, ((1,), (1,)))          # [BK, BQ]
         ds = p * (dp - delta_ref[0])
         q = q_ref[0]
-        dk_scr[:] += scale * _dot(ds.astype(q.dtype), q, ((0,), (0,)))
+        dk_scr[:] += scale * _dot(ds.astype(q.dtype), q, ((1,), (0,)))
 
     _when_needed(causal, qi, kk, block_q, block_k, _compute)
 
@@ -355,10 +413,10 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
 
 
 def _bwd_delta(out, g):
-    """D_i = rowsum(dO * O) — tiny elementwise pass, left to XLA; kept
-    ``[bh, seq, 1]`` like the logsumexp rows (see :func:`_flash_fwd`)."""
+    """D_i = rowsum(dO * O) — tiny elementwise pass, left to XLA; dense
+    ``[bh, 1, seq]`` like the logsumexp rows (see :func:`_flash_fwd`)."""
     return (g.astype(jnp.float32) * out.astype(jnp.float32)).sum(
-        -1, keepdims=True)
+        -1)[:, None, :]
 
 
 def _flash_bwd_dq(q, k, v, g, lse, delta, scale, causal, block_q, block_k,
@@ -379,8 +437,8 @@ def _flash_bwd_dq(q, k, v, g, lse, delta, scale, causal, block_q, block_k,
         pl.BlockSpec((1, block_k, d), kv),
         pl.BlockSpec((1, block_k, dv), kv),
         pl.BlockSpec((1, block_q, dv), lambda b, i, kk: (b, i, 0)),
-        pl.BlockSpec((1, block_q, 1), lambda b, i, kk: (b, i, 0)),
-        pl.BlockSpec((1, block_q, 1), lambda b, i, kk: (b, i, 0)),
+        pl.BlockSpec((1, 1, block_q), lambda b, i, kk: (b, 0, i)),
+        pl.BlockSpec((1, 1, block_q), lambda b, i, kk: (b, 0, i)),
     ]
     args = (q, k, v, g, lse, delta)
     if bits is not None:
@@ -394,7 +452,11 @@ def _flash_bwd_dq(q, k, v, g, lse, delta, scale, causal, block_q, block_k,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, kk: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        scratch_shapes=[
+            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, _stat_lanes(block_k)), jnp.float32),
+            pltpu.VMEM((block_q, _stat_lanes(block_k)), jnp.float32),
+        ],
         interpret=interpret,
     )(*args)
 
@@ -418,6 +480,10 @@ def _flash_bwd_dkv(q, k, v, g, lse, delta, scale, causal, block_q, block_k,
             i = jnp.maximum(i, _first_q_block(kk, block_q, block_k))
         return (b * group + j // n_q, i, 0)
 
+    def stats(b, kk, j):
+        head, i, _ = rows(b, kk, j)
+        return (head, 0, i)
+
     kernel = functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
                                block_q=block_q, block_k=block_k, n_q=n_q,
                                group=group, keyed=bits is not None)
@@ -426,8 +492,8 @@ def _flash_bwd_dkv(q, k, v, g, lse, delta, scale, causal, block_q, block_k,
         pl.BlockSpec((1, block_k, d), lambda b, kk, j: (b, kk, 0)),
         pl.BlockSpec((1, block_k, dv), lambda b, kk, j: (b, kk, 0)),
         pl.BlockSpec((1, block_q, dv), rows),
-        pl.BlockSpec((1, block_q, 1), rows),
-        pl.BlockSpec((1, block_q, 1), rows),
+        pl.BlockSpec((1, 1, block_q), stats),
+        pl.BlockSpec((1, 1, block_q), stats),
     ]
     args = (q, k, v, g, lse, delta)
     if bits is not None:
@@ -484,14 +550,14 @@ def _flash_vjp_fwd(q, k, v, bits, causal, block_q, block_k, interpret, scale,
     out, lse = _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret,
                           group, bits)
     out = checkpoint_name(out, KEPT_OUT)
-    rows = checkpoint_name(lse[..., 0], KEPT_LSE)
-    return (out, rows[..., None]), (q, k, v, out, rows, bits)
+    lse = checkpoint_name(lse, KEPT_LSE)
+    return (out, lse), (q, k, v, out, lse, bits)
 
 
 def _flash_vjp_bwd(causal, block_q, block_k, interpret, scale, group, res, g):
-    q, k, v, out, rows, bits = res
-    return _flash_bwd((q, k, v, out, rows[..., None]), g[0], scale, causal,
-                      block_q, block_k, interpret, group, bits) + (
+    *res, bits = res
+    return _flash_bwd(res, g[0], scale, causal, block_q, block_k, interpret,
+                      group, bits) + (
                           None if bits is None else
                           np.zeros(bits.shape, jax.dtypes.float0),)
 
@@ -583,4 +649,5 @@ def flash_attention_lse(q, k, v, causal=True, block_q=128, block_k=128,
 
     out, lse = _flash(fold(q), fold(k), fold(v), key_bits, causal, block_q,
                       block_k, interpret, scale, heads // k.shape[2])
-    return unfold(out), jax.lax.stop_gradient(unfold(lse)[..., 0])
+    lse = lse.reshape(batch, heads, s_len).transpose(0, 2, 1)
+    return unfold(out), jax.lax.stop_gradient(lse)

@@ -10,6 +10,7 @@ CPU here, so code that picks interpret mode from it is steered in the test.
 
 import importlib
 import os
+import re
 
 import numpy as np
 import pytest
@@ -54,6 +55,24 @@ def _compile(fn, *args):
     return compiled.as_text()
 
 
+def _kernel_lines(text):
+    """The lines of a compiled program's text that call a pallas kernel."""
+    return [line for line in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line]
+
+
+def _one_lane_arrays(text):
+    """The float32 arrays with a last dimension of 1 in ``text`` (kernel
+    calls' lines with their operands and results, or a list of residuals):
+    the form the chip holds one number a 128-lane tile.  A flash kernel's
+    statistics are dense rows."""
+    return re.findall(r"f32\[[0-9,]*,1\]", text)
+
+
+def _flash_calls(calls):
+    return "\n".join(line for line in calls if "/attention/flash/" in line)
+
+
 # (batch, seq, heads, q and k width, v width, block): chip_smoke's LM shape,
 # one longer and wider point the model zoo allows, and latent attention's at
 # the benchmark's sizes (scores over 192, values of 128, blocks of 512)
@@ -72,7 +91,9 @@ def test_flash_kernel_compiles_for_v5e(topo, kernel, shape):
         return jax.ShapeDtypeStruct((batch * heads, seq, width), dtype,
                                     sharding=one)
 
-    qk, v, stat = arg(dk), arg(dv), arg(1, jnp.float32)
+    qk, v = arg(dk), arg(dv)
+    stat = jax.ShapeDtypeStruct((batch * heads, 1, seq), jnp.float32,
+                                sharding=one)
     tail = (dk ** -0.5, True, block, block, False)  # scale, causal, blocks, interpret
     if kernel == "fwd":
         text = _compile(lambda q, k, v: fa._flash_fwd(q, k, v, *tail),
@@ -84,6 +105,7 @@ def test_flash_kernel_compiles_for_v5e(topo, kernel, shape):
                                                   *tail),
             qk, qk, v, v, stat, stat)
     assert text.count("tpu_custom_call") == 1
+    assert not _one_lane_arrays("\n".join(_kernel_lines(text)))
 
 
 def test_lm_block_with_flash_compiles_for_v5e(topo, monkeypatch):
@@ -111,6 +133,33 @@ def test_lm_block_with_flash_compiles_for_v5e(topo, monkeypatch):
 
     text = _compile(jax.grad(loss), params, x)
     assert text.count("tpu_custom_call") == 3  # forward, dQ, dK/dV
+
+
+def test_flash_keeps_no_one_lane_residual(capsys):
+    """What ``save_only_these_names(*KEPT)`` keeps of the op across a
+    checkpoint, and what its backward rule is handed besides: no float32
+    array whose last dimension is 1 (the logsumexp rows are kept as the
+    kernel writes them, dense; the padded form cannot come back
+    unnoticed).  Traced only, at latent attention's benchmark sizes."""
+    from jax.ad_checkpoint import print_saved_residuals
+
+    def arg(heads, width):
+        return jax.ShapeDtypeStruct((4, 8192, heads, width), jnp.bfloat16)
+
+    def op(q, k, v):
+        out, lse = fa.flash_attention_lse(q, k, v, block_q=512, block_k=512,
+                                          interpret=True)
+        return out.astype(jnp.float32).sum() + lse.sum()
+
+    print_saved_residuals(
+        jax.checkpoint(op, policy=jax.checkpoint_policies
+                       .save_only_these_names(*fa.KEPT)),
+        arg(16, 192), arg(16, 192), arg(16, 128))
+    kept = capsys.readouterr().out
+    # the two named results (a name is a reduce_precision in the trace)
+    assert "f32[64,1,8192] output of reduce_precision" in kept
+    assert "bf16[64,8192,128] output of reduce_precision" in kept
+    assert not _one_lane_arrays(kept), kept
 
 
 def test_sharded_flash_compiles_for_v5e_2x2(topo, monkeypatch):
@@ -211,8 +260,7 @@ def _kernel_calls(compiled):
     # none of XLA's nameless ragged-dot calls: every grouped product is a
     # pallas kernel that carries its scope
     assert "ragged-dot" not in text
-    return [line for line in text.splitlines()
-            if 'custom_call_target="tpu_custom_call"' in line]
+    return _kernel_lines(text)
 
 
 def test_lfm2_moe_step_compiles_and_fits_v5e(topo, monkeypatch):
@@ -223,20 +271,22 @@ def test_lfm2_moe_step_compiles_and_fits_v5e(topo, monkeypatch):
     flash kernels, the grouped expert products and the expert layer's row
     movement (pallas kernels all) in it, and XLA's memory analysis of it
     (arguments + outputs - aliased + temporaries) is no larger than the
-    12.01 GiB it is with the attention layer's kernel output and logsumexp
-    rows kept across the recomputed block (PR 38: 12.00 without them; a
-    v5e offers 15.75).  The numbers of PR 28 are in the configuration's
+    11.73 GiB it is with the attention layer's kernel output and logsumexp
+    rows kept across the recomputed block and the flash kernels' statistics
+    as dense rows (PR 40; 12.01 while they were ``[.., seq, 1]``; a v5e
+    offers 15.75).  The numbers of PR 28 are in the configuration's
     ``assumed.batch_size``."""
     compiled, parameters, needed = _compiled_step(
         topo, monkeypatch, "lfm2_moe", "lfm2_8b_a1b_ep4")
     assert parameters == 507_820_288
-    assert needed <= 12.05 * 2 ** 30, needed
+    assert needed <= 11.8 * 2 ** 30, needed
     # 4 expert layers x 3 grouped products x (forward, recomputed forward,
     # two gradients), and the flash kernels (forward once: the checkpoint
     # keeps its output and logsumexp; dQ, dK/dV): all pallas kernels that
     # carry their scope
     calls = _kernel_calls(compiled)
     assert sum("/attention/flash/" in line for line in calls) == 3
+    assert not _one_lane_arrays(_flash_calls(calls))
     # ... and ten kernels of the row movement an expert layer, under the
     # scopes moe_route_ms_per_step reads: dispatch packs the tokens and
     # gathers them (forward and recomputed forward) and its gradient packs
@@ -258,20 +308,21 @@ def test_deepseek_v2_lite_step_compiles_and_fits_v5e(topo, monkeypatch):
     flash kernels at 192 / 128; 8 of 64 experts by softmax top-6 beside the
     shared expert; an untied read-out over 12,800 rows; batch and
     8,192-token rows as the file says) compiles for one described v5e chip
-    and fits its 15.75 GiB by XLA's memory analysis: 13.56 GiB at batch 4
+    and fits its 15.75 GiB by XLA's memory analysis: 13.31 GiB at batch 4
     with five layers' kernel outputs and logsumexp rows kept across their
-    recomputed blocks (PR 38: 13.04 without them), which it may not
-    outgrow.  The numbers of PR 32 are in the configuration's
-    ``assumed.batch_size``."""
+    recomputed blocks and the statistics as dense rows (PR 40; 13.56 while
+    they were ``[.., seq, 1]``), which it may not outgrow.  The numbers of
+    PR 32 are in the configuration's ``assumed.batch_size``."""
     compiled, parameters, needed = _compiled_step(
         topo, monkeypatch, "deepseek_v2", "deepseek_v2_lite_ep8")
     assert parameters == 535_060_992
-    assert needed <= 13.6 * 2 ** 30, needed
+    assert needed <= 13.35 * 2 ** 30, needed
     calls = _kernel_calls(compiled)
     # five attention layers x (forward, dQ, dK/dV), all under
     # attention/flash: no forward kernel in the recomputed pass; four expert
     # layers x 3 grouped products x 4 passes, and their row movement
     assert sum("/attention/flash/" in line for line in calls) == 15
+    assert not _one_lane_arrays(_flash_calls(calls))
     assert sum("/moe/experts/" in line for line in calls) == 48
     assert len(calls) >= 15 + 48 + 40
 
@@ -301,7 +352,7 @@ def test_flash_kernel_with_key_bits_compiles_for_v5e(topo, kernel):
     b, t, h, kv, d = (KEYED[k] for k in ("batch", "seq", "heads", "kv",
                                          "dim"))
     q, k, stat = arg((b * h, t, d)), arg((b * kv, t, d)), arg(
-        (b * h, t, 1), jnp.float32)
+        (b * h, 1, t), jnp.float32)
     tail = (d ** -0.5, True, KEYED["block"], KEYED["block"], False, h // kv)
     if kernel == "fwd":
         text = _compile(lambda q, k, v, bits: fa._flash_fwd(
@@ -313,6 +364,7 @@ def test_flash_kernel_with_key_bits_compiles_for_v5e(topo, kernel):
                 q, k, v, g, lse, delta, *tail, bits),
             q, k, k, q, stat, stat, bits)
     assert text.count("tpu_custom_call") == 1
+    assert not _one_lane_arrays("\n".join(_kernel_lines(text)))
 
 
 @pytest.mark.parametrize("kernel", ["select", "loss", "loss_grads"])
@@ -347,15 +399,23 @@ def test_keye_vl2_step_compiles_and_fits_v5e(topo, monkeypatch):
     learned index picks of a 32,768-token row; 16 of 128 experts by softmax
     top-8; an untied read-out over 18,992 rows; batch 1, as the file says)
     compiles for one described v5e chip with every kernel of the index in it
-    and fits its 15.75 GiB by XLA's memory analysis: 14.25 GiB with four
+    and fits its 15.75 GiB by XLA's memory analysis: 13.66 GiB with four
     layers' kernel outputs, logsumexp rows, key bits and index logsumexp
-    kept across their recomputed blocks (PR 38: 12.80 without them),
-    which it may not outgrow; no array of it is ``[T, T]``.  The numbers of
-    PR 37 are in the configuration's ``assumed.batch_size``."""
+    kept across their recomputed blocks, which it may not outgrow; no array
+    of it is ``[T, T]``.  PR 40: 14.25 before it; 14.47 with the flash
+    kernels' statistics as dense rows (the most that is live at once fell
+    0.53 GB with the ``[.., seq, 1]`` arrays, and the block the compiler
+    packs the temporaries into grew: a 0.39 GB hole in the expert layer's
+    backward pass that its 0.40 GB buffers do not fit, and the analysis
+    counts such a hole twice); 13.66 with the index's backward kernels run
+    in their own layer's backward pass (``transformer._backward_together``:
+    two layers' folded ``q``, ``k`` and index queries no longer lie over the
+    third's expert layer).  The numbers of PR 37 are in the configuration's
+    ``assumed.batch_size``."""
     compiled, parameters, needed = _compiled_step(
         topo, monkeypatch, "keye_vl2", "keye_vl2_30b_a3b_ep8")
     assert parameters == 465_391_104
-    assert needed <= 14.3 * 2 ** 30, needed
+    assert needed <= 13.7 * 2 ** 30, needed
     assert "32768,32768" not in compiled.as_text()
     calls = _kernel_calls(compiled)
 
@@ -367,6 +427,7 @@ def test_keye_vl2_step_compiles_and_fits_v5e(topo, monkeypatch):
     # selection each: the recomputed pass holds neither; the index's loss
     # once alone (forward) and once with its gradients (backward)
     assert count("flash", "pallas_call") == 12
+    assert not _one_lane_arrays(_flash_calls(calls))
     assert count("select", "dsa_select/") == 4
     assert count("index_loss", "dsa_index_loss/") == 4
     assert count("index_loss", "dsa_index_loss_grads/") == 4

@@ -133,6 +133,27 @@ def test_each_loss_reaches_its_own_leaves_alone():
     assert sum(map(of_the_index, of_logits)) == 2 * 5
 
 
+def test_the_indexs_gradient_is_made_before_its_layers_is_handed_on():
+    """``_backward_together`` is the identity both ways, and its backward
+    pass holds the two gradients behind one barrier (so that the index's
+    backward kernels, which nothing below waits for, run in their own
+    layer's backward pass and free what they read)."""
+    main, side = jnp.arange(6.0).reshape(2, 3), (jnp.ones(4), jnp.ones(5))
+
+    def loss(main, side):
+        main, side = transformer._backward_together(main, side)
+        return (main ** 2).sum() + 3 * side[0].sum() + 5 * side[1].sum()
+
+    of_main, of_side = jax.grad(loss, (0, 1))(main, side)
+    np.testing.assert_array_equal(np.asarray(of_main), 2 * np.asarray(main))
+    np.testing.assert_array_equal(np.asarray(of_side[0]), np.full(4, 3.0))
+    np.testing.assert_array_equal(np.asarray(of_side[1]), np.full(5, 5.0))
+    backward = str(jax.make_jaxpr(jax.grad(loss, (0, 1)))(main, side))
+    assert backward.count("optimization_barrier") == 1
+    assert "optimization_barrier" not in str(jax.make_jaxpr(
+        transformer._backward_together)(main, side))
+
+
 # -- the selection ------------------------------------------------------------
 
 def _index_inputs(seed, batch=2, seq=256, heads=4, dim=16, coarse=False):

@@ -229,6 +229,129 @@ def test_flash_over_each_querys_own_keys(shape, density):
             atol=1e-6)
 
 
+def _selected_bits(batch, seq, topk, seed):
+    """``key_bits`` as the model makes them: ``select_keys`` over seeded
+    index scores, and the kept pairs unpacked (bool ``[B, T, S]``)."""
+    from tensorflowonspark_tpu.ops import sparse_index
+
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    bits, _ = sparse_index.select_keys(
+        jax.random.normal(ks[0], (batch, seq, 2, 8)),
+        jax.random.normal(ks[1], (batch, seq, 8)),
+        jax.random.uniform(ks[2], (batch, seq, 2)), topk, block_q=128,
+        chunk=128)
+    words = np.asarray(bits)
+    s = np.arange(seq)
+    kept = ((words[:, s // 4096, :, s % 128]
+             >> ((s % 4096) // 128)[:, None, None]) & 1).astype(bool)
+    return bits, kept.transpose(1, 2, 0)
+
+
+# the benchmark's three head shapes at test size: (q and k width, v width,
+# query heads, group, rows, keyed), with the blocks each is run at: block_q
+# != block_k both ways round, 16, 32 and 128 (key sets want a k block of 128)
+HEAD_SHAPES = {
+    "d64_group4": ((64, 64, 4, 4, 128, False),
+                   [(16, 32), (32, 16), (32, 32), (128, 128)]),
+    "d192_dv128_mha": ((192, 128, 2, 1, 128, False),
+                       [(16, 32), (32, 16), (32, 32), (128, 128)]),
+    "d128_group8_keyed": ((128, 128, 8, 8, 256, True),
+                          [(128, 128), (256, 128), (128, 256)]),
+    # ... and a value width that is no whole number of the lanes a statistic
+    # is held on (48 over a k block's 32): the last form of ``_across``
+    "d32_dv48_mha": ((32, 48, 2, 1, 128, False), [(32, 32)]),
+}
+
+
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+@pytest.mark.parametrize(
+    "shape, blocks",
+    [(name, b) for name, (_, blocks) in HEAD_SHAPES.items() for b in blocks],
+    ids=["{}-q{}k{}".format(name, *b)
+         for name, (_, blocks) in HEAD_SHAPES.items() for b in blocks])
+def test_flash_kernels_against_the_float32_reference(shape, blocks, causal):
+    """Forward, dQ and dK/dV over the benchmark's head shapes: values, the
+    logsumexp rows and the three gradients against plain float32 attention
+    (under the same key sets where the shape has them: ``select_keys``'
+    own, which lie inside the causal triangle)."""
+    dk, dv, heads, group, seq, keyed = HEAD_SHAPES[shape][0]
+    keys = jax.random.split(jax.random.PRNGKey(17), 3)
+    q = jax.random.normal(keys[0], (2, seq, heads, dk))
+    k = jax.random.normal(keys[1], (2, seq, heads // group, dk))
+    v = jax.random.normal(keys[2], (2, seq, heads // group, dv))
+    bits, allowed = None, np.ones((2, seq, seq), bool)
+    if keyed:
+        bits, allowed = _selected_bits(2, seq, 48, seed=23)
+    if causal:
+        allowed = allowed & np.tril(np.ones((seq, seq), bool))
+
+    def flash(q, k, v):
+        o, lse = flash_attention_lse(q, k, v, causal=causal,
+                                     block_q=blocks[0], block_k=blocks[1],
+                                     key_bits=bits)
+        return (o ** 2).sum(), (o, lse)
+
+    def ref(q, k, v):
+        s = jnp.einsum("bthd,bshd->bhts", q,
+                       jnp.repeat(k, group, axis=2)) * dk ** -0.5
+        s = jnp.where(allowed[:, None], s, -jnp.inf)
+        o = jnp.einsum("bhts,bshd->bthd", jax.nn.softmax(s, axis=-1),
+                       jnp.repeat(v, group, axis=2))
+        return (o ** 2).sum(), (o, jax.nn.logsumexp(s, axis=-1).transpose(
+            0, 2, 1))
+
+    (_, got), g_flash = jax.value_and_grad(
+        flash, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    (_, want), g_ref = jax.value_and_grad(
+        ref, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    assert got[1].dtype == jnp.float32
+    for a, b in zip(got, want):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=2e-5, rtol=2e-5)
+    for gf, gr, name in zip(g_flash, g_ref, "qkv"):
+        assert gf.shape == gr.shape
+        np.testing.assert_allclose(
+            np.asarray(gf), np.asarray(gr), atol=5e-4, rtol=5e-4,
+            err_msg="d{} mismatch".format(name))
+
+
+@pytest.mark.parametrize("blocks", [(32, 16), (128, 128)],
+                         ids=["q32k16", "q128k128"])
+def test_flash_logsumexp_is_the_references(blocks):
+    """The rows ``flash_attention_lse`` hands on (``[batch, seq, heads]``
+    float32) are the natural-log sum of the scaled causal scores, to float32
+    tolerance; no gradient passes through them."""
+    q, k, v = _qkv(seq=128, heads=4, dim=32, seed=3)
+    _, lse = flash_attention_lse(q, k, v, block_q=blocks[0],
+                                 block_k=blocks[1])
+    s = jnp.einsum("bthd,bshd->bhts", q, k) * 32 ** -0.5
+    s = jnp.where(np.tril(np.ones((128, 128), bool)), s, -jnp.inf)
+    assert lse.shape == (2, 128, 4) and lse.dtype == jnp.float32
+    np.testing.assert_allclose(
+        np.asarray(lse),
+        np.asarray(jax.nn.logsumexp(s, axis=-1).transpose(0, 2, 1)),
+        atol=2e-6, rtol=2e-6)
+    grads = jax.grad(lambda q: flash_attention_lse(
+        q, k, v, block_q=blocks[0], block_k=blocks[1])[1].sum())(q)
+    assert not np.asarray(grads).any()
+
+
+def test_a_single_kept_key_has_probability_one():
+    """A query whose key set holds one key (here itself) attends to it with
+    probability exactly 1: its output is that key's value, its logsumexp
+    that pair's scaled score, and every masked pair contributes exactly 0."""
+    seq = 256
+    q, k, v = _qkv(batch=1, seq=seq, heads=2, dim=16, seed=9)
+    bits = _key_bits(np.eye(seq, dtype=bool)[None])
+    out, lse = flash_attention_lse(q, k, v, block_q=128, block_k=128,
+                                   key_bits=bits)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(v))
+    np.testing.assert_allclose(
+        np.asarray(lse), np.asarray((q * k).sum(-1) * 16 ** -0.5),
+        atol=1e-6, rtol=1e-6)
+
+
 def test_key_bits_name_the_blocks_they_refuse():
     q = jnp.zeros((1, 128, 2, 8))
     bits = jnp.zeros((1, 1, 128, 128), jnp.int32)

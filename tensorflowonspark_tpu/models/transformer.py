@@ -55,10 +55,22 @@ configuration:
   shared expert) and an untied read-out.  The index is trained by a loss of
   its own, sown a layer (``dsa_index_loss``) and added by :func:`loss_fn`:
   its leaves (``index_q``, ``index_k``, ``index_k_norm``, ``index_w``) get
-  that loss's gradient alone and every other leaf the cross-entropy's alone.
+  that loss's gradient alone and every other leaf the cross-entropy's alone;
+- :func:`mellum2_spec` (registered as ``mellum2``): the Mellum 2 mixture
+  family's ``config.json``: RMSNorm, grouped-query attention with per-head
+  q/k RMSNorm in every layer, of two kinds by ``layer_types``: a
+  ``sliding_attention`` layer's query reads its last ``sliding_window`` keys
+  (``LayerSpec.window``: the flash kernels' grid follows the band, the other
+  contractions mask it) under plain RoPE, a ``full_attention`` layer's every
+  causal key under YaRN frequencies (``rope_parameters`` has a table for
+  each kind); softmax top-k experts renormalised, no bias leaf, no shared
+  expert, and an untied read-out.
 
 Scopes a device trace can be read by (``jax.named_scope`` under the flax
-module names): ``block_i/short_conv``, ``block_i/attention/flash``,
+module names): ``block_i/short_conv``, ``block_i/attention/flash`` (a layer
+whose queries read every causal key, or the keys an index picks),
+``block_i/attention/flash_window`` (a layer with a window: the banded
+kernels, so that a reader of ``attention/flash`` does not take them in),
 ``block_i/attention/latent`` (everything latent attention puts round the
 kernel: the three projections, the latent's norm, RoPE, building K),
 ``block_i/attention/indexer`` (the index's three projections, its norm and
@@ -128,6 +140,10 @@ class LayerSpec:
     index_dim: int = 0
     index_topk: int = 0
     flash_block: int = 128         # q and k block of attention="flash"
+    # a query reads its last ``window`` keys, its own among them (t - s <
+    # window); 0 = every causal key, and then nothing of a layer changes.
+    # One that covers the row is every causal key too
+    window: int = 0
     conv_kernel: int = 3
     ff_size: int = 0               # the dense feed-forward's width
     num_experts: int = 8           # the router's outputs
@@ -144,6 +160,14 @@ class LayerSpec:
     shared_size: int = 0           # a shared SwiGLU's width beside the
     #                                routed experts; 0 = none
     capacity_factor: float = 1.25  # ff="switch"
+
+    def __post_init__(self):
+        if self.window < 0 or self.window and (self.op == "conv"
+                                               or self.index_topk > 0):
+            raise ValueError(
+                "window={} wants an attention layer without an index over "
+                "the keys (op={!r}, index_topk={})".format(
+                    self.window, self.op, self.index_topk))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -339,6 +363,82 @@ def keye_vl2_spec(config):
                        tied_readout=config.get("tie_word_embeddings", False))
 
 
+def mellum2_spec(config):
+    """:class:`DecoderSpec` of a Mellum 2 mixture ``config.json`` (a dict
+    with the source's keys: ``layer_types`` of ``sliding_attention`` and
+    ``full_attention``, ``sliding_window``, ``rope_parameters`` with a table
+    for each kind of layer, ``mlp_layer_types``, ``num_experts_per_tok``,
+    ``norm_topk_prob``, ...).  ``num_experts`` is the router's width;
+    ``held_experts`` (``[first, count]``, optional) the experts this program
+    holds of each layer; ``flash_block`` (optional) the attention kernels'
+    block, in both kinds of layer.
+    A ``yarn`` table's ``attention_factor`` is what cos and sin are
+    multiplied by (absent: ``0.1 ln(factor) + 1``).  What the family's
+    modelling code does and no key says: per-head RMSNorm on q and k,
+    rotate-half pairing, the window ``t - s < sliding_window``."""
+    import math
+
+    kinds = set(config["layer_types"])
+    tables = config["rope_parameters"]
+    unsupported = {
+        "attention_bias": bool(config.get("attention_bias")),
+        "layer_types": not kinds <= {"sliding_attention", "full_attention"},
+        "mlp_layer_types": set(config.get("mlp_layer_types") or ["sparse"])
+        != {"sparse"},
+        "use_sliding_window": "sliding_attention" in kinds
+        and not config.get("use_sliding_window", True),
+        "rope_parameters": any(
+            kind not in tables or tables[kind].get("rope_type", "default")
+            not in ("default", "yarn") for kind in kinds)}
+    if any(unsupported.values()):
+        raise ValueError("mellum2: no support for this config's {}".format(
+            sorted(k for k, v in unsupported.items() if v)))
+    if len(config["layer_types"]) != config["num_hidden_layers"]:
+        raise ValueError("{} layer_types for num_hidden_layers {}".format(
+            len(config["layer_types"]), config["num_hidden_layers"]))
+    held = config.get("held_experts")
+    block = config.get("flash_block", 512)
+
+    def layer(kind):
+        table = tables[kind]
+        yarn = None
+        if table.get("rope_type", "default") == "yarn":
+            factor = float(table["factor"])
+            # rope_frequencies multiplies cos and sin by yarn_mscale(factor,
+            # mscale) / yarn_mscale(factor, mscale_all_dim): the table's
+            # attention_factor with mscale_all_dim 0
+            mscale = ((float(table["attention_factor"]) - 1.0)
+                      / (0.1 * math.log(factor))
+                      if "attention_factor" in table else 1.0)
+            yarn = (factor, float(table["original_max_position_embeddings"]),
+                    float(table["beta_fast"]), float(table["beta_slow"]),
+                    mscale, 0.0)
+        sliding = kind == "sliding_attention"
+        return LayerSpec(
+            op="attention", ff="experts", norm="rmsnorm",
+            norm_eps=config["rms_norm_eps"], positions="rope",
+            num_heads=config["num_attention_heads"],
+            head_dim=config["head_dim"],
+            num_kv_heads=config["num_key_value_heads"], qk_norm=True,
+            rope_theta=float(table["rope_theta"]), rope_yarn=yarn,
+            window=config["sliding_window"] if sliding else 0,
+            flash_block=block,
+            num_experts=config["num_experts"],
+            experts_per_token=config["num_experts_per_tok"],
+            expert_size=config["moe_intermediate_size"],
+            held_experts=tuple(held) if held else None,
+            router_score="softmax", selection_bias=False,
+            norm_topk=config.get("norm_topk_prob", True))
+
+    of_kind = {kind: layer(kind) for kind in kinds}
+    return DecoderSpec(vocab_size=config["vocab_size"],
+                       hidden_size=config["hidden_size"],
+                       layers=tuple(of_kind[kind]
+                                    for kind in config["layer_types"]),
+                       norm="rmsnorm", norm_eps=config["rms_norm_eps"],
+                       tied_readout=config.get("tie_word_embeddings", False))
+
+
 def _norm(kind, eps, dtype):
     if kind == "rmsnorm":
         return nn.RMSNorm(epsilon=eps, dtype=dtype)
@@ -427,7 +527,9 @@ class Attention(nn.Module):
     qk_norm: bool = False
     norm_eps: float = 1e-6
     rope_theta: Optional[float] = None
+    rope_yarn: Optional[Tuple[float, ...]] = None   # LayerSpec.rope_yarn
     flash_block: int = 128
+    window: int = 0           # LayerSpec.window; 0 = every causal key
     # the latent form (LayerSpec op="mla"): the layer's description, whose
     # kv_rank, nope_dim, rope_dim, v_dim, rope_* and attn_scale are read
     latent: Optional[LayerSpec] = None
@@ -545,17 +647,29 @@ class Attention(nn.Module):
             k = nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype,
                            name="k_norm")(k)
         if self.rope_theta is not None and self.latent is None:
-            inv, _ = rope_frequencies(self.head_dim, self.rope_theta)
-            q, k = rope(q, inv), rope(k, inv)
+            inv, factor = rope_frequencies(self.head_dim, self.rope_theta,
+                                           self.rope_yarn)
+            q, k = rope(q, inv, factor=factor), rope(k, inv, factor=factor)
+        window = self.window or None
         if self.index_heads:
             out = self._indexed(x, q, k, v)
         elif self.attention == "flash":
             from tensorflowonspark_tpu.ops import flash_attention
+            from tensorflowonspark_tpu.ops.flash_attention import band_tiles
 
-            with jax.named_scope("flash"):
+            with jax.named_scope("flash_window" if window else "flash"):
                 out = flash_attention(q, k, v, causal=True, mesh=self.mesh,
                                       block_q=self.flash_block,
-                                      block_k=self.flash_block, scale=scale)
+                                      block_k=self.flash_block, scale=scale,
+                                      window=window)
+            if window:      # what the band leaves of the causal tiles
+                computed, causal = band_tiles(x.shape[1], self.flash_block,
+                                              window)
+                self.sow("intermediates", "swa_counts", {
+                    "tiles_computed": jnp.asarray(x.shape[0] * computed,
+                                                  jnp.int32),
+                    "tiles_causal": jnp.asarray(x.shape[0] * causal,
+                                                jnp.int32)})
         else:
             group = self.num_heads // k.shape[2]
             if group > 1:   # the contractions below want a KV head each
@@ -563,14 +677,14 @@ class Attention(nn.Module):
             if self.attention == "ring":
                 assert self.mesh is not None, "ring attention needs a mesh"
                 out = ring.ring_attention(q, k, v, self.mesh, causal=True,
-                                          scale=scale)
+                                          scale=scale, window=window)
             elif self.attention == "ulysses":
                 assert self.mesh is not None, "ulysses attention needs a mesh"
                 out = ring.ulysses_attention(q, k, v, self.mesh, causal=True,
-                                             scale=scale)
+                                             scale=scale, window=window)
             else:
                 out = ring.reference_attention(q, k, v, causal=True,
-                                               scale=scale)
+                                               scale=scale, window=window)
         out = out.reshape(out.shape[0], out.shape[1], features)
         fused = self.num_kv_heads is None and self.latent is None
         return nn.Dense(x.shape[-1], use_bias=fused, dtype=self.dtype,
@@ -854,7 +968,8 @@ class Block(nn.Module):
                 qk_norm=spec.qk_norm, norm_eps=spec.norm_eps,
                 rope_theta=(spec.rope_theta if spec.positions == "rope"
                             else None),
-                flash_block=spec.flash_block, latent=latent,
+                rope_yarn=spec.rope_yarn, flash_block=spec.flash_block,
+                window=spec.window, latent=latent,
                 index_heads=spec.index_heads, index_dim=spec.index_dim,
                 index_topk=spec.index_topk,
                 name=None if spec.num_kv_heads is None and not latent
@@ -1001,6 +1116,18 @@ def build_keye_vl2(config, attention="flash", mesh=None, remat=False,
                          mesh=mesh, remat=remat, dtype=jnp.dtype(dtype))
 
 
+@register_model("mellum2")
+def build_mellum2(config, attention="flash", mesh=None, remat=False,
+                  dtype="float32"):
+    """The one decoder under a Mellum 2 mixture ``config.json`` (see
+    :func:`mellum2_spec`).  Under ``attention="flash"`` a sliding layer's
+    kernels visit the band's tiles alone; ``"full"`` is the same mathematics
+    with the band as a mask; the sequence-parallel contractions refuse a
+    window."""
+    return TransformerLM(spec=mellum2_spec(config), attention=attention,
+                         mesh=mesh, remat=remat, dtype=jnp.dtype(dtype))
+
+
 def _sown(tree, name):
     """Every value sown under ``name`` anywhere in the intermediates tree."""
     found = []
@@ -1059,6 +1186,21 @@ def _sum_dsa(tree, mask):
         "dsa_layers_steps": jnp.asarray(len(losses), jnp.int32)}
 
 
+def _sum_swa(tree):
+    """What the layers with a window sowed under ``attention="flash"``,
+    under the names of ``train.Trainer``'s counters: ``swa_tiles_causal``
+    the causal ``[flash_block, flash_block]`` tiles of their (queries,
+    keys), ``swa_tiles_computed`` those that the forward kernel's grid
+    computes (the band's; known when the step is traced),
+    ``swa_layers_steps`` the layer calls; None without such layers."""
+    found = _sown(tree, "swa_counts")
+    if not found:
+        return None
+    return {"swa_tiles_causal": sum(c["tiles_causal"] for c in found),
+            "swa_tiles_computed": sum(c["tiles_computed"] for c in found),
+            "swa_layers_steps": jnp.asarray(len(found), jnp.int32)}
+
+
 def loss_fn(model, moe_aux_weight=0.01):
     """Next-token cross-entropy with per-row masking.
 
@@ -1076,7 +1218,9 @@ def loss_fn(model, moe_aux_weight=0.01):
     over the keys sow that index's loss: it is added to the step's loss as
     it is (its gradient reaches the index's leaves alone), reported as
     ``aux["dsa_index_loss"]``, and with the tiles the picks touch goes out
-    as ``aux["dsa_counts"]`` (the ``Trainer``'s ``dsa_*`` counters).
+    as ``aux["dsa_counts"]`` (the ``Trainer``'s ``dsa_*`` counters).  Layers
+    with a window sow the tiles their kernels visit: ``aux["swa_counts"]``
+    (the ``Trainer``'s ``swa_*`` counters).
     """
     import optax
 
@@ -1102,6 +1246,9 @@ def loss_fn(model, moe_aux_weight=0.01):
         if dsa is not None:
             aux["dsa_index_loss"], aux["dsa_counts"] = dsa[0], dsa[1]
             ce = ce + dsa[0]
+        swa = _sum_swa(sown)
+        if swa is not None:
+            aux["swa_counts"] = swa
         return ce, aux
 
     return loss

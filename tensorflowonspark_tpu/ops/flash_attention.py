@@ -59,6 +59,21 @@ index maps clamp to the last tile a row block needs, and a block whose index
 does not change is not copied again); the mask itself is applied on the tiles
 the diagonal crosses only.
 
+Window: ``window`` (static, with ``causal``) keeps query ``t`` to the keys
+``t - window < s <= t``, its own among them.  The band is the grid: a q block
+``i`` needs the k blocks ``first..last`` (:func:`_first_k_block`,
+:func:`_last_k_block`) and a k block is seen by the q blocks ``first..last``
+(:func:`_first_q_block`, :func:`_last_q_block`); each kernel's inner grid
+extent is the longest such run (three blocks for a window of two blocks),
+not ``seq / block``, its index maps add the run's first block and clamp to
+its last, and the mask (``t - s < window`` beside ``s <= t``) is applied on
+the tiles that an edge of the band crosses.  A tile outside the band is
+neither fetched, computed nor stepped over.  A query's first tile may hold
+none of its keys (the band's lower edge crosses it): the running maximum
+stays at ``NEG_INF`` there and the next tile's ``alpha`` is exactly 0.
+Without a window nothing of it is traced.  :func:`band_tiles` counts the
+tiles the forward grid computes.
+
 Key sets: ``key_bits`` (``[batch, groups, seq, 128]`` int32, the layout of
 :mod:`~tensorflowonspark_tpu.ops.sparse_index`: bit ``(s % 4096) // 128`` of
 word ``[b, s // 4096, t, s % 128]`` says whether query ``t`` may read key
@@ -134,11 +149,12 @@ def key_mask(words, k_block_id, block_k, keys_first=False):
 
 
 def _scores(q_ref, k_ref, scale, masked, q_block_id, k_block_id, block_q,
-            block_k, bits_ref=None, keys_first=False):
+            block_k, bits_ref=None, keys_first=False, window=None):
     """float32 ``q k^T * scale`` of one (q block, k block) tile (``k q^T``,
-    keys by queries, where ``keys_first``), the causal mask applied where
-    ``masked`` (a tile the diagonal crosses), and the queries' key sets where
-    ``bits_ref`` holds them."""
+    keys by queries, where ``keys_first``), the causal mask (and the
+    window's) applied where ``masked`` (a tile that the diagonal or the
+    band's lower edge crosses), and the queries' key sets where ``bits_ref``
+    holds them."""
     q_axis, k_axis = (1, 0) if keys_first else (0, 1)
     operands = (k_ref[0], q_ref[0]) if keys_first else (q_ref[0], k_ref[0])
     s = _dot(*operands, ((1,), (1,))) * scale
@@ -151,7 +167,10 @@ def _scores(q_ref, k_ref, scale, masked, q_block_id, k_block_id, block_q,
                    + q_block_id * block_q)
         keys = (jax.lax.broadcasted_iota(jnp.int32, s.shape, k_axis)
                 + k_block_id * block_k)
-        s = jnp.where(queries >= keys, s, NEG_INF)
+        seen = queries >= keys
+        if window is not None:
+            seen = jnp.logical_and(seen, queries - keys < window)
+        s = jnp.where(seen, s, NEG_INF)
     return s
 
 
@@ -181,11 +200,15 @@ def _on_lanes(row, lanes):
     return jnp.broadcast_to(row, (lanes, row.shape[1])).T
 
 
-def _when_needed(causal, qi, kk, block_q, block_k, compute):
+def _when_needed(causal, qi, kk, block_q, block_k, compute, window=None,
+                 also=None):
     """Run ``compute(masked)`` for the tile (qi, kk): always when not
     causal; when causal only for tiles that reach the diagonal or lie below
     it, masked only where the diagonal crosses the tile.  A tile wholly
-    above the diagonal contributes p=0 / alpha=1 (exactly nothing)."""
+    above the diagonal contributes p=0 / alpha=1 (exactly nothing).  Under a
+    ``window`` a tile wholly below the band is not needed either, and one
+    that the band's lower edge crosses is masked; ``also`` is a further
+    condition of the caller's (a grid step past its run's end)."""
     from jax.experimental import pallas as pl
 
     if not causal:
@@ -193,6 +216,15 @@ def _when_needed(causal, qi, kk, block_q, block_k, compute):
         return
     needed = qi * block_q + block_q - 1 >= kk * block_k
     crossed = kk * block_k + block_k - 1 > qi * block_q
+    if window is not None:
+        # the block's first query still reaches the k block's last key
+        needed = jnp.logical_and(
+            needed, qi * block_q - (kk * block_k + block_k - 1) < window)
+        # its last query no longer reaches the k block's first key
+        crossed = jnp.logical_or(
+            crossed, qi * block_q + block_q - 1 - kk * block_k >= window)
+    if also is not None:
+        needed = jnp.logical_and(needed, also)
     pl.when(jnp.logical_and(needed, crossed))(lambda: compute(True))
     pl.when(jnp.logical_and(needed, jnp.logical_not(crossed)))(
         lambda: compute(False))
@@ -203,9 +235,53 @@ def _last_k_block(i, block_q, block_k):
     return (i * block_q + block_q - 1) // block_k
 
 
+def _first_k_block(i, block_q, block_k, window):
+    """The first k block q block ``i`` needs under ``window``: the one that
+    holds the earliest key of its first query."""
+    return jnp.maximum(i * block_q - window + 1, 0) // block_k
+
+
 def _first_q_block(kk, block_q, block_k):
     """The first q block a causal k block ``kk`` is seen by."""
     return (kk * block_k) // block_q
+
+
+def _last_q_block(kk, block_q, block_k, window, n_q):
+    """The last q block that sees k block ``kk`` under ``window``: the one
+    that holds the latest query of its last key."""
+    return jnp.minimum((kk * block_k + block_k + window - 2) // block_q,
+                       n_q - 1)
+
+
+def _k_run(n_q, block_q, block_k, window):
+    """``(longest, total)`` of the runs of k blocks that the q blocks need
+    under ``window`` (Python ints: the forward and dQ grids' inner extent,
+    and the tiles a head's forward grid computes)."""
+    runs = [_last_k_block(i, block_q, block_k) + 1
+            - max(i * block_q - window + 1, 0) // block_k
+            for i in range(n_q)]
+    return max(runs), sum(runs)
+
+
+def _q_run(n_q, n_k, block_q, block_k, window):
+    """The longest run of q blocks that sees one k block under ``window``
+    (the dK/dV grid's inner extent a query head)."""
+    return max(min((kk * block_k + block_k + window - 2) // block_q, n_q - 1)
+               + 1 - _first_q_block(kk, block_q, block_k)
+               for kk in range(n_k))
+
+
+def band_tiles(seq, block, window):
+    """``(computed, causal)``: of the causal ``[block, block]`` tiles of
+    (queries, keys) of one row and head, those that the forward kernel's
+    grid computes under ``window`` (None, or one that covers the row: all of
+    them), and how many there are."""
+    block = min(block, seq)
+    n = seq // block
+    causal = n * (n + 1) // 2
+    if window is None or window >= seq:
+        return causal, causal
+    return _k_run(n, block, block, window)[1], causal
 
 
 # ---------------------------------------------------------------------------
@@ -213,17 +289,21 @@ def _first_q_block(kk, block_q, block_k):
 # ---------------------------------------------------------------------------
 
 def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, block_q, block_k,
-                n_k, keyed):
+                n_k, keyed, window=None):
     from jax.experimental import pallas as pl
 
     bits_ref, (o_ref, lse_ref, m_scr, l_scr, acc_scr) = _bits_first(
         refs, keyed)
-    kk = pl.program_id(2)
+    # ``n_k`` is the inner grid's extent: every k block, or under a window
+    # the longest run a q block needs, counted from the run's first block
+    step = pl.program_id(2)
     # program_id must be read OUTSIDE pl.when bodies (interpret mode can't
     # substitute it inside a cond branch); close over the values instead.
     qi = pl.program_id(1)
+    kk = step if window is None else step + _first_k_block(
+        qi, block_q, block_k, window)
 
-    @pl.when(kk == 0)
+    @pl.when(step == 0)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
@@ -231,7 +311,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, block_q, block_k,
 
     def _compute(masked):
         s = _scores(q_ref, k_ref, scale, masked, qi, kk, block_q, block_k,
-                    bits_ref)
+                    bits_ref, window=window)
         m_prev = m_scr[:]                              # [BQ, lanes]
         m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
         p = jnp.exp(s - _across(m_new, block_k))       # [BQ, BK]
@@ -242,9 +322,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, block_q, block_k,
             p.astype(v.dtype), v, ((1,), (0,)))
         m_scr[:] = m_new
 
-    _when_needed(causal, qi, kk, block_q, block_k, _compute)
+    _when_needed(causal, qi, kk, block_q, block_k, _compute, window)
 
-    @pl.when(kk == n_k - 1)
+    @pl.when(step == n_k - 1)
     def _emit():
         l = jnp.maximum(l_scr[:], 1e-30)
         o_ref[0] = (acc_scr[:] / _across(l, acc_scr.shape[1])).astype(
@@ -253,11 +333,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, block_q, block_k,
         lse_ref[0] = (m_scr[:] + jnp.log(l)).T[:1]
 
 
-def _kv_maps(causal, block_q, block_k, group):
+def _kv_maps(causal, block_q, block_k, group, window=None):
     """Block index maps of K and V on a (q head, q block, k block) grid:
     query head ``b`` reads KV head ``b // group``; a causal q block never
-    moves past the last k block it needs."""
+    moves past the last k block it needs, and under a window it starts at
+    the first."""
     def kv(b, i, kk):
+        if window is not None:
+            kk = kk + _first_k_block(i, block_q, block_k, window)
         if causal:
             kk = jnp.minimum(kk, _last_k_block(i, block_q, block_k))
         return (b // group, kk, 0)
@@ -289,7 +372,7 @@ def _bits_spec(rows, block_q, block_k, q_block, k_block):
 
 
 def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret, group=1,
-               bits=None):
+               bits=None, window=None):
     """Returns ``(out [bh, seq, dv], logsumexp [bh, 1, seq])``; ``q`` is
     ``[bh, seq, d]``, ``k [bh // group, seq, d]`` and ``v [bh // group, seq,
     dv]``.  The logsumexp rows are dense, the query on the lanes; the unit
@@ -303,11 +386,13 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret, group=1,
     bh, s_len, d = q.shape
     dv = v.shape[-1]
     n_q = s_len // block_q
-    n_k = s_len // block_k
+    n_k = s_len // block_k      # the inner grid's extent: under a window ...
+    if window is not None:      # ... the longest run of k blocks
+        n_k = _k_run(n_q, block_q, block_k, window)[0]
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, block_q=block_q,
-        block_k=block_k, n_k=n_k, keyed=bits is not None)
-    kv = _kv_maps(causal, block_q, block_k, group)
+        block_k=block_k, n_k=n_k, keyed=bits is not None, window=window)
+    kv = _kv_maps(causal, block_q, block_k, group, window)
     in_specs = [
         pl.BlockSpec((1, block_q, d), lambda b, i, kk: (b, i, 0)),
         pl.BlockSpec((1, block_k, d), kv),
@@ -346,14 +431,16 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret, group=1,
 # ---------------------------------------------------------------------------
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
-                   scale, causal, block_q, block_k, n_k, keyed):
+                   scale, causal, block_q, block_k, n_k, keyed, window=None):
     from jax.experimental import pallas as pl
 
     bits_ref, (dq_ref, dq_scr, lse_scr, delta_scr) = _bits_first(refs, keyed)
-    kk = pl.program_id(2)
+    step = pl.program_id(2)   # the forward kernel's grid, see there
     qi = pl.program_id(1)  # read outside pl.when bodies (interpret mode)
+    kk = step if window is None else step + _first_k_block(
+        qi, block_q, block_k, window)
 
-    @pl.when(kk == 0)
+    @pl.when(step == 0)
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
         # the q block's dense rows, turned once for all of its tiles
@@ -363,29 +450,38 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
     def _compute(masked):
         # p = exp(q k^T * scale - L), recomputed from the saved logsumexp
         p = jnp.exp(_scores(q_ref, k_ref, scale, masked, qi, kk, block_q,
-                            block_k, bits_ref) - _across(lse_scr[:], block_k))
+                            block_k, bits_ref, window=window)
+                    - _across(lse_scr[:], block_k))
         dp = _dot(do_ref[0], v_ref[0], ((1,), (1,)))   # [BQ, BK]
         ds = p * (dp - _across(delta_scr[:], block_k))
         k = k_ref[0]
         dq_scr[:] += scale * _dot(ds.astype(k.dtype), k, ((1,), (0,)))
 
-    _when_needed(causal, qi, kk, block_q, block_k, _compute)
+    _when_needed(causal, qi, kk, block_q, block_k, _compute, window)
 
-    @pl.when(kk == n_k - 1)
+    @pl.when(step == n_k - 1)
     def _emit():
         dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
-                    scale, causal, block_q, block_k, n_q, group, keyed):
+                    scale, causal, block_q, block_k, n_q, group, keyed,
+                    window=None, rows=None):
     from jax.experimental import pallas as pl
 
     bits_ref, (dk_ref, dv_ref, dk_scr, dv_scr) = _bits_first(refs, keyed)
     # the inner grid dimension runs over the KV head's ``group`` query heads
-    # and, for each, over the q blocks: one accumulation for all of them
+    # and, for each, over the q blocks (``n_q``: all of them, or under a
+    # window the longest run that sees a k block, counted from the run's
+    # first block; ``rows`` is then how many q blocks there are): one
+    # accumulation for all of them
     j = pl.program_id(2)
     qi = j % n_q
     kk = pl.program_id(1)  # read outside pl.when bodies (interpret mode)
+    inside = None
+    if window is not None:
+        qi = qi + _first_q_block(kk, block_q, block_k)
+        inside = qi < rows
 
     @pl.when(j == 0)
     def _init():
@@ -395,7 +491,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
     def _compute(masked):
         # the tile keys by queries: the dense rows go along the lanes
         p = jnp.exp(_scores(q_ref, k_ref, scale, masked, qi, kk, block_q,
-                            block_k, bits_ref, keys_first=True)
+                            block_k, bits_ref, keys_first=True, window=window)
                     - lse_ref[0])                      # [BK, BQ] - [1, BQ]
         do = do_ref[0]                                 # [BQ, DV]
         dv_scr[:] += _dot(p.astype(do.dtype), do, ((1,), (0,)))
@@ -404,7 +500,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
         q = q_ref[0]
         dk_scr[:] += scale * _dot(ds.astype(q.dtype), q, ((1,), (0,)))
 
-    _when_needed(causal, qi, kk, block_q, block_k, _compute)
+    _when_needed(causal, qi, kk, block_q, block_k, _compute, window, inside)
 
     @pl.when(j == group * n_q - 1)
     def _emit():
@@ -420,7 +516,7 @@ def _bwd_delta(out, g):
 
 
 def _flash_bwd_dq(q, k, v, g, lse, delta, scale, causal, block_q, block_k,
-                  interpret, group=1, bits=None):
+                  interpret, group=1, bits=None, window=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -428,10 +524,12 @@ def _flash_bwd_dq(q, k, v, g, lse, delta, scale, causal, block_q, block_k,
     dv = v.shape[-1]
     n_q = s_len // block_q
     n_k = s_len // block_k
-    kv = _kv_maps(causal, block_q, block_k, group)
+    if window is not None:      # the forward kernel's grid, see there
+        n_k = _k_run(n_q, block_q, block_k, window)[0]
     kernel = functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
                                block_q=block_q, block_k=block_k, n_k=n_k,
-                               keyed=bits is not None)
+                               keyed=bits is not None, window=window)
+    kv = _kv_maps(causal, block_q, block_k, group, window)
     in_specs = [
         pl.BlockSpec((1, block_q, d), lambda b, i, kk: (b, i, 0)),
         pl.BlockSpec((1, block_k, d), kv),
@@ -462,21 +560,28 @@ def _flash_bwd_dq(q, k, v, g, lse, delta, scale, causal, block_q, block_k,
 
 
 def _flash_bwd_dkv(q, k, v, g, lse, delta, scale, causal, block_q, block_k,
-                   interpret, group=1, bits=None):
+                   interpret, group=1, bits=None, window=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     bh_kv, s_len, d = k.shape
     dv = v.shape[-1]
-    n_q = s_len // block_q
+    n_q = q_blocks = s_len // block_q
     n_k = s_len // block_k
+    if window is not None:      # a query head's steps: the longest run
+        n_q = _q_run(q_blocks, n_k, block_q, block_k, window)
 
     def rows(b, kk, j):
         """Block index of a per-query-head array: the ``j // n_q``-th query
         head of KV head ``b``, q block ``j % n_q`` (causal: never before the
-        first q block that sees k block ``kk``)."""
+        first q block that sees k block ``kk``; under a window counted from
+        it, and never past the last)."""
         i = j % n_q
-        if causal:
+        if window is not None:
+            i = jnp.minimum(
+                i + _first_q_block(kk, block_q, block_k),
+                _last_q_block(kk, block_q, block_k, window, q_blocks))
+        elif causal:
             i = jnp.maximum(i, _first_q_block(kk, block_q, block_k))
         return (b * group + j // n_q, i, 0)
 
@@ -486,7 +591,8 @@ def _flash_bwd_dkv(q, k, v, g, lse, delta, scale, causal, block_q, block_k,
 
     kernel = functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
                                block_q=block_q, block_k=block_k, n_q=n_q,
-                               group=group, keyed=bits is not None)
+                               group=group, keyed=bits is not None,
+                               window=window, rows=q_blocks)
     in_specs = [
         pl.BlockSpec((1, block_q, d), rows),
         pl.BlockSpec((1, block_k, d), lambda b, kk, j: (b, kk, 0)),
@@ -522,13 +628,13 @@ def _flash_bwd_dkv(q, k, v, g, lse, delta, scale, causal, block_q, block_k,
 
 
 def _flash_bwd(res, g, scale, causal, block_q, block_k, interpret, group,
-               bits=None):
+               bits=None, window=None):
     q, k, v, out, lse = res
     delta = _bwd_delta(out, g)
     dq = _flash_bwd_dq(q, k, v, g, lse, delta, scale, causal, block_q,
-                       block_k, interpret, group, bits)
+                       block_k, interpret, group, bits, window)
     dk, dv = _flash_bwd_dkv(q, k, v, g, lse, delta, scale, causal, block_q,
-                            block_k, interpret, group, bits)
+                            block_k, interpret, group, bits, window)
     return dq, dk, dv
 
 
@@ -536,28 +642,30 @@ def _flash_bwd(res, g, scale, causal, block_q, block_k, interpret, group,
 # public op
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
-def _flash(q, k, v, bits, causal, block_q, block_k, interpret, scale, group):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10))
+def _flash(q, k, v, bits, causal, block_q, block_k, interpret, scale, group,
+           window):
     """``(out, logsumexp)``, over each query's own keys where ``bits`` holds
-    them (None: every causal key).  No gradient is taken through the
-    logsumexp rows, nor to ``bits``."""
+    them (None: every causal key), or over its last ``window`` keys.  No
+    gradient is taken through the logsumexp rows, nor to ``bits``."""
     return _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret,
-                      group, bits)
+                      group, bits, window)
 
 
 def _flash_vjp_fwd(q, k, v, bits, causal, block_q, block_k, interpret, scale,
-                   group):
+                   group, window):
     out, lse = _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret,
-                          group, bits)
+                          group, bits, window)
     out = checkpoint_name(out, KEPT_OUT)
     lse = checkpoint_name(lse, KEPT_LSE)
     return (out, lse), (q, k, v, out, lse, bits)
 
 
-def _flash_vjp_bwd(causal, block_q, block_k, interpret, scale, group, res, g):
+def _flash_vjp_bwd(causal, block_q, block_k, interpret, scale, group, window,
+                   res, g):
     *res, bits = res
     return _flash_bwd(res, g[0], scale, causal, block_q, block_k, interpret,
-                      group, bits) + (
+                      group, bits, window) + (
                           None if bits is None else
                           np.zeros(bits.shape, jax.dtypes.float0),)
 
@@ -582,7 +690,7 @@ def _checked(q, k, v, scale, interpret):
 
 
 def flash_attention(q, k, v, causal=True, block_q=128, block_k=128,
-                    interpret=None, scale=None, mesh=None):
+                    interpret=None, scale=None, mesh=None, window=None):
     """Memory-linear attention over ``[batch, seq, heads, dim]`` inputs;
     ``k`` and ``v`` may carry fewer heads than ``q`` (grouped-query
     attention: query head ``h`` reads KV head ``h // (heads // kv_heads)``),
@@ -596,7 +704,9 @@ def flash_attention(q, k, v, causal=True, block_q=128, block_k=128,
     default to the 128 MXU tile and are clamped to the sequence length;
     ``seq`` must divide by the clamped blocks (``ValueError`` otherwise).
     ``interpret`` defaults to True off-TPU so the same kernel runs (slowly)
-    everywhere.
+    everywhere.  ``window`` (static; needs ``causal``): query ``t`` reads the
+    keys ``t - window < s <= t`` (module docstring); one that covers the row
+    is the causal kernel.
 
     ``mesh``: the compiler cannot partition a Mosaic kernel, so under a
     multi-device mesh the call is mapped per shard here — batch over the
@@ -613,16 +723,17 @@ def flash_attention(q, k, v, causal=True, block_q=128, block_k=128,
                  "tensor" if "tensor" in mesh.axis_names else None, None)
         local = functools.partial(
             flash_attention, causal=causal, block_q=block_q, block_k=block_k,
-            interpret=interpret, scale=scale)
+            interpret=interpret, scale=scale, window=window)
         # pallas_call's outputs carry no varying-axes annotation
         return jax.shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
                              out_specs=spec, check_vma=False)(q, k, v)
     return flash_attention_lse(q, k, v, causal, block_q, block_k, interpret,
-                               scale)[0]
+                               scale, window=window)[0]
 
 
 def flash_attention_lse(q, k, v, causal=True, block_q=128, block_k=128,
-                        interpret=None, scale=None, key_bits=None):
+                        interpret=None, scale=None, key_bits=None,
+                        window=None):
     """:func:`flash_attention` on one device with the softmax's statistics
     beside the output: ``(out, logsumexp [batch, seq, heads])`` (float32,
     natural log, of the scaled scores over the query's keys; no gradient
@@ -630,9 +741,20 @@ def flash_attention_lse(q, k, v, causal=True, block_q=128, block_k=128,
 
     ``key_bits`` (``[batch, groups, seq, 128]`` int32, made by
     :func:`tensorflowonspark_tpu.ops.sparse_index.select_keys`; see the
-    module docstring) keeps every query to its own keys."""
+    module docstring) keeps every query to its own keys; ``window`` keeps it
+    to its last ``window`` keys, and is refused beside ``key_bits`` or
+    without ``causal`` (``ValueError``)."""
     scale, interpret = _checked(q, k, v, scale, interpret)
     batch, s_len, heads, _ = q.shape
+    if window is not None:
+        if not causal or key_bits is not None or window < 1:
+            raise ValueError(
+                "window={!r} wants causal=True and no key_bits (causal={}, "
+                "key_bits {})".format(window, causal,
+                                      "given" if key_bits is not None
+                                      else "none"))
+        if window >= s_len:     # every causal key: the causal kernel
+            window = None
     block_q = min(block_q, s_len)
     block_k = min(block_k, s_len)
     if s_len % block_q or s_len % block_k:
@@ -648,6 +770,6 @@ def flash_attention_lse(q, k, v, causal=True, block_q=128, block_k=128,
         return x.reshape(batch, heads, s_len, x.shape[2]).transpose(0, 2, 1, 3)
 
     out, lse = _flash(fold(q), fold(k), fold(v), key_bits, causal, block_q,
-                      block_k, interpret, scale, heads // k.shape[2])
+                      block_k, interpret, scale, heads // k.shape[2], window)
     lse = lse.reshape(batch, heads, s_len).transpose(0, 2, 1)
     return unfold(out), jax.lax.stop_gradient(lse)

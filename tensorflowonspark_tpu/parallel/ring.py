@@ -88,8 +88,16 @@ def _ring_shard_fn(q, k, v, axis_name, causal, scale, vary_axes):
     return out.astype(q.dtype)
 
 
+def _no_window(window, what):
+    """The sequence-parallel contractions know the causal triangle alone."""
+    if window is not None:
+        raise ValueError(
+            "{} has no window (window={}): run a layer that has one under "
+            "attention=\"flash\" or \"full\"".format(what, window))
+
+
 def ring_attention(q, k, v, mesh, seq_axis="seq", batch_axis="data",
-                   causal=False, scale=None):
+                   causal=False, scale=None, window=None):
     """Exact multi-head attention over sequence-sharded q/k/v.
 
     Args:
@@ -98,9 +106,12 @@ def ring_attention(q, k, v, mesh, seq_axis="seq", batch_axis="data",
       mesh: the device mesh; must contain ``seq_axis``.
       causal: apply causal masking using *global* sequence positions.
       scale: score scale (default 1/sqrt(head_dim)).
+      window: refused (``ValueError``) unless None: a layer whose queries
+        read their last ``window`` keys does not run sequence-parallel.
 
     Returns an array shaped/sharded like ``q``.
     """
+    _no_window(window, "ring attention")
     assert seq_axis in mesh.axis_names, (
         "mesh {} has no {!r} axis".format(dict(mesh.shape), seq_axis))
     if scale is None:
@@ -152,13 +163,14 @@ def _ulysses_shard_fn(q, k, v, axis_name, causal, scale, impl="einsum"):
 
 
 def ulysses_attention(q, k, v, mesh, seq_axis="seq", batch_axis="data",
-                      causal=False, scale=None, impl="einsum"):
+                      causal=False, scale=None, impl="einsum", window=None):
     """All-to-all ("Ulysses"-style) sequence-parallel attention.
 
     Requires ``heads % mesh.shape[seq_axis] == 0``; each device attends over
     the full sequence for its slice of heads, with two all_to_alls doing the
     re-sharding.  Same signature/semantics as :func:`ring_attention`.
     """
+    _no_window(window, "ulysses attention")
     assert q.shape[2] % mesh.shape[seq_axis] == 0, (
         "heads {} not divisible by seq-parallel degree {}".format(
             q.shape[2], mesh.shape[seq_axis]))
@@ -175,8 +187,10 @@ def ulysses_attention(q, k, v, mesh, seq_axis="seq", batch_axis="data",
     return fn(q, k, v)
 
 
-def reference_attention(q, k, v, causal=False, scale=None):
-    """Plain full attention (for tests and single-device fallback)."""
+def reference_attention(q, k, v, causal=False, scale=None, window=None):
+    """Plain full attention (for tests and single-device fallback);
+    ``window`` (with ``causal``): query ``t`` reads the keys ``t - window <
+    s <= t``, the band as a mask."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
@@ -184,6 +198,9 @@ def reference_attention(q, k, v, causal=False, scale=None):
     if causal:
         seq_q, seq_k = q.shape[1], k.shape[1]
         mask = jnp.tril(jnp.ones((seq_q, seq_k), dtype=bool))
+        if window is not None:
+            mask = jnp.logical_and(mask, jnp.logical_not(jnp.tril(
+                jnp.ones((seq_q, seq_k), dtype=bool), -window)))
         s = jnp.where(mask[None, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(jnp.float32))

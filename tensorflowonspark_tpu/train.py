@@ -429,9 +429,11 @@ class Trainer(object):
     def _note_moe(self, aux):
         if not isinstance(aux, dict):
             return
-        # the index over the keys (dsa_*) counts the same way
+        # the index over the keys (dsa_*) and the window layers (swa_*)
+        # count the same way
         counts = dict(aux.get("moe_counts") or {},
-                      **(aux.get("dsa_counts") or {}))
+                      **(aux.get("dsa_counts") or {}),
+                      **(aux.get("swa_counts") or {}))
         if not counts:
             return
         with self._moe_lock:
@@ -490,6 +492,14 @@ class Trainer(object):
         loss summed over the steps, ``dsa_layers_steps`` the layer calls
         counted.
 
+        The window layers, for a model that has them under
+        ``attention="flash"`` (the same way): ``swa_tiles_causal`` the causal
+        ``[flash_block, flash_block]`` tiles of their (queries, keys),
+        ``swa_tiles_computed`` those of them that the forward kernel's grid
+        computes (the band's: their ratio is what the window leaves of a
+        full layer's work, 100% for a kernel that masks),
+        ``swa_layers_steps`` the layer calls counted.
+
         ``train_recompiles_total``: dispatches that made a step program
         executable under a name (``step``, ``multi_<k>``) that had run
         before: a batch of another shape, a state laid out anew.  Which
@@ -547,11 +557,13 @@ class Trainer(object):
             snap["train_recompiles_total"] = self._recompiles
         if self._moe_pending or self._moe_totals:
             self._fold_moe()
-            snap.update(self._moe_totals)   # the loss names them moe_*, dsa_*
+            snap.update(self._moe_totals)   # the loss names them moe_*, dsa_*, swa_*
         return snap
 
     def counters_snapshot(self):
-        """:meth:`_own_counters` (see there) and what the process keeps
+        """:meth:`_own_counters` (see there: the step loop's, and a model's
+        ``moe_*``, ``dsa_*`` and ``swa_*`` where it has expert layers, an
+        index over the keys or window layers) and what the process keeps
         for all its trainers: the compile plane's tallies
         (``compilecache.stats.tallies()``: ``compile_trace_us``,
         ``compile_lower_us``, ``compile_backend_us``,

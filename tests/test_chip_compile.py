@@ -434,6 +434,65 @@ def test_keye_vl2_step_compiles_and_fits_v5e(topo, monkeypatch):
     assert sum("/moe/experts/" in line for line in calls) == 48
 
 
+@pytest.mark.parametrize("block", [512, 256])
+@pytest.mark.parametrize("kernel", ["fwd", "bwd_dq", "bwd_dkv"])
+def test_flash_kernel_with_a_window_compiles_for_v5e(topo, kernel, block):
+    """The three flash kernels with a window of 1,024 keys at the window
+    cell's sizes (32,768 positions, 32 query and 4 KV heads of 128), at
+    blocks of 512 (three tiles a q block) and 256 (five): the inner grid
+    extent is the band's, not ``seq / block``."""
+    one = SingleDeviceSharding(topo.devices[0])
+    t, h, kv, d = 32768, 32, 4, 128
+
+    def arg(rows, width=d, dtype=jnp.bfloat16):
+        shape = (rows, t, width) if width else (rows, 1, t)
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    q, k, stat = arg(h), arg(kv), arg(h, 0, jnp.float32)
+    tail = (d ** -0.5, True, block, block, False, h // kv, None, 1024)
+    if kernel == "fwd":
+        text = _compile(lambda q, k, v: fa._flash_fwd(q, k, v, *tail),
+                        q, k, k)
+    else:
+        launch = fa._flash_bwd_dq if kernel == "bwd_dq" else fa._flash_bwd_dkv
+        text = _compile(
+            lambda q, k, v, g, lse, delta: launch(q, k, v, g, lse, delta,
+                                                  *tail),
+            q, k, k, q, stat, stat)
+    assert text.count("tpu_custom_call") == 1
+    assert not _one_lane_arrays("\n".join(_kernel_lines(text)))
+    steps = 1024 // block + 1
+    outer = t // block
+    assert fa._k_run(outer, block, block, 1024)[0] == steps
+    assert fa._q_run(outer, outer, block, block, 1024) == steps
+
+
+def test_mellum2_step_compiles_and_fits_v5e(topo, monkeypatch):
+    """The whole training step of ``mellum2_12b_a2p5b_ep8`` (published
+    widths; one period of three sliding layers, 1,024 keys, and one full
+    layer under YaRN, over a 32,768-token row; 8 of 64 experts by softmax
+    top-8; an untied read-out over 12,288 rows; batch 1, as the file says)
+    compiles for one described v5e chip with the banded flash kernels in it
+    and fits its 15.75 GiB by XLA's memory analysis, which it may not
+    outgrow: 11.81 GiB (12.68 GB: 4.08 GB of parameters and Adam's moments
+    as arguments, 8.59 GB temporaries, gradients among them); no array of it
+    is ``[T, T]``."""
+    compiled, parameters, needed = _compiled_step(
+        topo, monkeypatch, "mellum2", "mellum2_12b_a2p5b_ep8")
+    assert parameters == 340_350_208
+    assert needed <= 11.9 * 2 ** 30, needed
+    assert "32768,32768" not in compiled.as_text()
+    calls = _kernel_calls(compiled)
+    # three sliding layers x (forward, dQ, dK/dV) under attention/flash_window
+    # and the full layer's three under attention/flash: the recomputed pass
+    # holds no forward kernel of either
+    assert sum("/attention/flash_window/" in line for line in calls) == 9
+    assert sum("/attention/flash/" in line for line in calls) == 3
+    assert not _one_lane_arrays("\n".join(
+        line for line in calls if "/attention/flash" in line))
+    assert sum("/moe/experts/" in line for line in calls) == 48
+
+
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
                          ids=["bfloat16", "float32"])
 def test_routed_rows_kernels_compile_v5e(topo, dtype):

@@ -1,6 +1,8 @@
 """Pallas kernel tests (interpret mode on the CPU mesh): flash attention
 forward and backward against the reference contraction."""
 
+import re
+
 import numpy as np
 import pytest
 
@@ -350,6 +352,121 @@ def test_a_single_kept_key_has_probability_one():
     np.testing.assert_allclose(
         np.asarray(lse), np.asarray((q * k).sum(-1) * 16 ** -0.5),
         atol=1e-6, rtol=1e-6)
+
+
+# (window, block_q, block_k, query heads, group): a window shorter than a
+# block, one of two whole blocks (three tiles a q block), one that is no
+# multiple of the block, unequal blocks both ways round, the benchmark's
+# group of 8, a window of one key, and one that covers the row
+WINDOWS = {
+    "under_a_block": (20, 32, 32, 4, 2),
+    "two_blocks": (64, 32, 32, 2, 1),
+    "no_multiple": (50, 32, 32, 4, 4),
+    "q16_k32": (40, 16, 32, 2, 1),
+    "q32_k16": (33, 32, 16, 2, 2),
+    "group8": (48, 32, 32, 8, 8),
+    "one_key": (1, 32, 32, 2, 2),
+    "the_row": (128, 32, 32, 4, 2),
+    "over_the_row": (1000, 32, 32, 4, 2),
+}
+
+
+@pytest.mark.parametrize("kept", [False, True], ids=["stored", "kept"])
+@pytest.mark.parametrize("case", sorted(WINDOWS))
+def test_flash_kernels_with_a_window_against_the_float32_reference(case,
+                                                                    kept):
+    """Forward, dQ and dK/dV with ``window``: values, the logsumexp rows and
+    the three gradients against dense float32 attention under the band's
+    mask; ``kept``: under a checkpoint that keeps the kernels' two residuals
+    by name, as a recomputed block does.  A window that covers the row is
+    the causal kernel, bit for bit."""
+    from tensorflowonspark_tpu.ops.flash_attention import KEPT
+
+    window, block_q, block_k, heads, group = WINDOWS[case]
+    seq, dim = 128, 16
+    keys = jax.random.split(jax.random.PRNGKey(29), 3)
+    q = jax.random.normal(keys[0], (2, seq, heads, dim))
+    k = jax.random.normal(keys[1], (2, seq, heads // group, dim))
+    v = jax.random.normal(keys[2], (2, seq, heads // group, dim))
+    t = np.arange(seq)
+    allowed = (t[:, None] >= t[None]) & (t[:, None] - t[None] < window)
+
+    def flash(q, k, v, window=window):
+        o, lse = flash_attention_lse(q, k, v, block_q=block_q,
+                                     block_k=block_k, window=window)
+        return (o ** 2).sum(), (o, lse)
+
+    def ref(q, k, v):
+        s = jnp.einsum("bthd,bshd->bhts", q,
+                       jnp.repeat(k, group, axis=2)) * dim ** -0.5
+        s = jnp.where(allowed, s, -jnp.inf)
+        o = jnp.einsum("bhts,bshd->bthd", jax.nn.softmax(s, axis=-1),
+                       jnp.repeat(v, group, axis=2))
+        return (o ** 2).sum(), (o, jax.nn.logsumexp(s, axis=-1).transpose(
+            0, 2, 1))
+
+    run = flash
+    if kept:
+        run = jax.checkpoint(
+            flash, policy=jax.checkpoint_policies.save_only_these_names(
+                *KEPT))
+    (_, got), g_flash = jax.value_and_grad(
+        run, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    (_, want), g_ref = jax.value_and_grad(
+        ref, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=2e-5, rtol=2e-5)
+    for gf, gr, name in zip(g_flash, g_ref, "qkv"):
+        np.testing.assert_allclose(
+            np.asarray(gf), np.asarray(gr), atol=5e-4, rtol=5e-4,
+            err_msg="d{} mismatch".format(name))
+    if window >= seq:
+        (_, causal), g_causal = jax.value_and_grad(
+            lambda q, k, v: flash(q, k, v, None), argnums=(0, 1, 2),
+            has_aux=True)(q, k, v)
+        for a, b in zip(got + g_flash, causal + g_causal):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_a_windows_grid_follows_the_band():
+    """The three kernels' inner grid extent is the band's longest run of
+    blocks, not ``seq / block``, and ``window=None`` traces as it did."""
+    q, k, v = _qkv(batch=1, seq=256, heads=2, dim=16)
+
+    def grids(window):
+        text = str(jax.make_jaxpr(jax.grad(lambda q, k, v: flash_attention(
+            q, k, v, block_q=32, block_k=32, window=window).sum(),
+            (0, 1, 2)))(q, k, v))
+        return sorted(re.findall(r"grid=\((\d+, \d+, \d+)\)", text))
+
+    assert grids(None) == ["2, 8, 8"] * 3
+    assert grids(64) == ["2, 8, 3"] * 3        # two whole blocks: three tiles
+    assert grids(34) == ["2, 8, 3"] * 3
+    assert grids(33) == ["2, 8, 2"] * 3        # one key beyond one block
+    assert grids(256) == grids(None)            # covers the row: the causal kernel
+
+
+def test_flash_refuses_a_window_it_cannot_run():
+    q, k, v = _qkv(batch=1, seq=128, heads=2, dim=16)
+    with pytest.raises(ValueError, match="wants causal=True"):
+        flash_attention(q, k, v, causal=False, window=16)
+    with pytest.raises(ValueError, match="no key_bits"):
+        flash_attention_lse(q, k, v, block_q=128, block_k=128, window=16,
+                            key_bits=jnp.zeros((1, 1, 128, 128), jnp.int32))
+    with pytest.raises(ValueError, match="window=0"):
+        flash_attention(q, k, v, window=0)
+    from jax.sharding import Mesh
+
+    mesh = Mesh(np.asarray(jax.devices()[:1]), ("seq",))
+    for contraction in (ring.ring_attention, ring.ulysses_attention):
+        with pytest.raises(ValueError, match="has no window"):
+            contraction(q, k, v, mesh, causal=True, window=16)
+    # the plain contraction takes the band as a mask
+    np.testing.assert_allclose(
+        np.asarray(ring.reference_attention(q, k, v, causal=True, window=16)),
+        np.asarray(flash_attention(q, k, v, block_q=32, block_k=32,
+                                   window=16)), atol=2e-5)
 
 
 def test_key_bits_name_the_blocks_they_refuse():

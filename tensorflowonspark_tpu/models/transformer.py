@@ -586,6 +586,21 @@ class Attention(nn.Module):
         return q, k, kv[..., nope:]
 
     @nn.nowrap
+    def _sow_grid(self, x, window=None):
+        """Sow what the flash kernels' forward grid takes for this call's
+        rows and heads (``flash_counts``): its steps, and the tiles among
+        them that compute (``ops.flash_attention.grid_tiles``; known when
+        the step is traced)."""
+        from tensorflowonspark_tpu.ops.flash_attention import grid_tiles
+
+        steps, computed = grid_tiles(x.shape[1], self.flash_block,
+                                     self.flash_block, window=window)
+        heads = x.shape[0] * self.num_heads
+        self.sow("intermediates", "flash_counts", {
+            "grid_steps": jnp.asarray(heads * steps, jnp.int32),
+            "tiles_computed": jnp.asarray(heads * computed, jnp.int32)})
+
+    @nn.nowrap
     def _indexed(self, x, q, k, v):
         """Attention over the keys the index picks; sows the index's loss
         (``dsa_index_loss [B]``) and the tiles its picks touch
@@ -612,6 +627,7 @@ class Attention(nn.Module):
             out, lse = flash_attention_lse(
                 q, k, v, causal=True, block_q=block, block_k=block,
                 key_bits=bits)
+        self._sow_grid(x)
         with jax.named_scope("index_loss"):
             loss = sparse_index.index_loss(iq, ik, iw, q, k, lse, index_lse,
                                            bits, block=block)
@@ -662,6 +678,7 @@ class Attention(nn.Module):
                                       block_q=self.flash_block,
                                       block_k=self.flash_block, scale=scale,
                                       window=window)
+            self._sow_grid(x, window)
             if window:      # what the band leaves of the causal tiles
                 computed, causal = band_tiles(x.shape[1], self.flash_block,
                                               window)
@@ -1201,6 +1218,18 @@ def _sum_swa(tree):
             "swa_layers_steps": jnp.asarray(len(found), jnp.int32)}
 
 
+def _sum_flash(tree):
+    """What the layers under ``attention="flash"`` sowed, under the names of
+    ``train.Trainer``'s counters: ``flash_grid_steps`` the steps of their
+    forward kernels' grids over the rows and heads, ``flash_tiles_computed``
+    the tiles among them that compute; None without such layers."""
+    found = _sown(tree, "flash_counts")
+    if not found:
+        return None
+    return {"flash_grid_steps": sum(c["grid_steps"] for c in found),
+            "flash_tiles_computed": sum(c["tiles_computed"] for c in found)}
+
+
 def loss_fn(model, moe_aux_weight=0.01):
     """Next-token cross-entropy with per-row masking.
 
@@ -1220,7 +1249,9 @@ def loss_fn(model, moe_aux_weight=0.01):
     ``aux["dsa_index_loss"]``, and with the tiles the picks touch goes out
     as ``aux["dsa_counts"]`` (the ``Trainer``'s ``dsa_*`` counters).  Layers
     with a window sow the tiles their kernels visit: ``aux["swa_counts"]``
-    (the ``Trainer``'s ``swa_*`` counters).
+    (the ``Trainer``'s ``swa_*`` counters), and every layer under
+    ``attention="flash"`` its kernels' grid steps beside the tiles that
+    compute: ``aux["flash_counts"]`` (the ``Trainer``'s ``flash_*``).
     """
     import optax
 
@@ -1249,6 +1280,9 @@ def loss_fn(model, moe_aux_weight=0.01):
         swa = _sum_swa(sown)
         if swa is not None:
             aux["swa_counts"] = swa
+        flash = _sum_flash(sown)
+        if flash is not None:
+            aux["flash_counts"] = flash
         return ce, aux
 
     return loss

@@ -6,8 +6,8 @@ kernels stream K/V blocks through VMEM with an online softmax, so HBM
 traffic is O(S·D) and the MXU sees back-to-back [block_q, D]x[D, block_k]
 matmuls:
 
-- forward: one kernel over grid (batch*heads, q_blocks, k_blocks) with
-  running (max, sum, acc) scratch carried across the k dimension; also
+- forward: one kernel over the (batch*heads, q block, k block) tiles with
+  running (max, sum, acc) scratch carried across a q block's k blocks; also
   emits the logsumexp rows the backward needs.
 - backward: the FlashAttention-2 split — one kernel accumulating dQ over k
   blocks, one accumulating dK/dV over q blocks — recomputing p = exp(qk -
@@ -54,10 +54,36 @@ inputs -> bf16 operands on the MXU, float32 inputs -> float32 as before) and
 accumulate in float32; scores, softmax statistics and the accumulators are
 float32 always.
 
-Causal: tiles wholly above the diagonal are neither computed nor fetched (the
-index maps clamp to the last tile a row block needs, and a block whose index
-does not change is not copied again); the mask itself is applied on the tiles
-the diagonal crosses only.
+Causal: the grid steps over the triangle alone.  Behind the heads it has one
+dimension, a step for every tile that reaches the diagonal or lies below it
+and none for the half of the square above: the tiles' coordinates
+(:func:`_causal_steps`, :func:`_causal_steps_by_keys`; host ints from
+``seq``, the blocks and ``group``), in the order the kernels accumulate, go
+into SMEM in front of the kernel as one int32 a step
+(``PrefetchScalarGridSpec``; bit fields, :func:`_listed_step`), the index
+maps and the kernels read their (q block, k block) from it, and a step whose
+tile opens a run initialises the scratch, one that closes it writes out.  A
+tile above the diagonal is neither fetched, computed nor stepped over; the
+mask itself is applied on the tiles the diagonal crosses only.  What it buys
+and costs (a v5e, ``[512, 512]`` tiles): a step that computes nothing takes
+about 0.2 us, and a listed step takes about 0.1 us more than a step of the
+rectangle, because Mosaic evaluates every operand's index map three times a
+step on the scalar core and a listed one starts with a load from SMEM; so
+rows of many blocks gain (a tile 6 to 15% less at 64 blocks, 2,016 of 4,096
+steps gone).  A launcher lists its steps where the list is the shorter grid
+and SMEM holds it (:func:`_listed`), and takes the rectangle otherwise, the
+tiles above the diagonal stepped over with the index maps clamped to the
+last tile a run needs (a block whose index does not change is not copied
+again) and nothing computed, as every causal grid was before there were
+lists: so every shape has a grid.  One q block a head is the rectangle (its
+tiles are all of it).  The list is ``(seq / block)^2 / 2`` words for the
+forward and dQ kernels and ``group`` times that for dK/dV, and
+``LISTED_STEPS`` (196,608, three quarters of a v5e's SMEM) is the longest:
+blocks of 512 list 131,072 rows in the forward and dQ kernels and up to a
+``group`` of 5 in dK/dV, 65,536 rows up to 23; blocks of 128 list 80,128
+rows, and 28,288 at a group of 8.  Not causal, the grid is the rectangle: nothing in
+it is empty.  :func:`grid_tiles` counts a grid's steps and the tiles among
+them that compute.
 
 Window: ``window`` (static, with ``causal``) keeps query ``t`` to the keys
 ``t - window < s <= t``, its own among them.  The band is the grid: a q block
@@ -201,20 +227,22 @@ def _on_lanes(row, lanes):
 
 
 def _when_needed(causal, qi, kk, block_q, block_k, compute, window=None,
-                 also=None):
+                 also=None, listed=False):
     """Run ``compute(masked)`` for the tile (qi, kk): always when not
     causal; when causal only for tiles that reach the diagonal or lie below
     it, masked only where the diagonal crosses the tile.  A tile wholly
     above the diagonal contributes p=0 / alpha=1 (exactly nothing).  Under a
     ``window`` a tile wholly below the band is not needed either, and one
     that the band's lower edge crosses is masked; ``also`` is a further
-    condition of the caller's (a grid step past its run's end)."""
+    condition of the caller's (a grid step past its run's end).  ``listed``:
+    the grid steps over the causal tiles alone (:func:`_causal_steps`), so
+    every tile it brings is needed."""
     from jax.experimental import pallas as pl
 
     if not causal:
         compute(False)
         return
-    needed = qi * block_q + block_q - 1 >= kk * block_k
+    needed = None if listed else qi * block_q + block_q - 1 >= kk * block_k
     crossed = kk * block_k + block_k - 1 > qi * block_q
     if window is not None:
         # the block's first query still reaches the k block's last key
@@ -225,9 +253,13 @@ def _when_needed(causal, qi, kk, block_q, block_k, compute, window=None,
             crossed, qi * block_q + block_q - 1 - kk * block_k >= window)
     if also is not None:
         needed = jnp.logical_and(needed, also)
-    pl.when(jnp.logical_and(needed, crossed))(lambda: compute(True))
-    pl.when(jnp.logical_and(needed, jnp.logical_not(crossed)))(
-        lambda: compute(False))
+
+    def when(kind, masked):
+        pl.when(kind if needed is None else jnp.logical_and(needed, kind))(
+            lambda: compute(masked))
+
+    when(crossed, True)
+    when(jnp.logical_not(crossed), False)
 
 
 def _last_k_block(i, block_q, block_k):
@@ -276,32 +308,190 @@ def band_tiles(seq, block, window):
     (queries, keys) of one row and head, those that the forward kernel's
     grid computes under ``window`` (None, or one that covers the row: all of
     them), and how many there are."""
-    block = min(block, seq)
-    n = seq // block
-    causal = n * (n + 1) // 2
-    if window is None or window >= seq:
-        return causal, causal
-    return _k_run(n, block, block, window)[1], causal
+    n = seq // min(block, seq)
+    return grid_tiles(seq, block, block, window=window)[1], n * (n + 1) // 2
+
+
+def _causal_steps(n_q, block_q, block_k):
+    """``(q blocks, k blocks)``, int arrays: the tiles that compute under
+    ``causal``, in the order the forward and dQ kernels accumulate: q block
+    ``i`` over its k blocks ``0.._last_k_block(i)``.  The rectangle's steps
+    in their own order, without those whose tile lies above the diagonal."""
+    runs = [_last_k_block(i, block_q, block_k) + 1 for i in range(n_q)]
+    return (np.repeat(np.arange(n_q), runs),
+            np.concatenate([np.arange(run) for run in runs]))
+
+
+def _causal_steps_by_keys(n_q, n_k, block_q, block_k, group):
+    """``(k blocks, query heads of the group, q blocks)``, int arrays: the
+    tiles that compute under ``causal``, in the order the dK/dV kernel
+    accumulates: k block ``kk`` over its ``group`` query heads and for each
+    over the q blocks ``_first_q_block(kk)..n_q - 1``."""
+    firsts = [_first_q_block(kk, block_q, block_k) for kk in range(n_k)]
+    return (np.repeat(np.arange(n_k), [group * (n_q - f) for f in firsts]),
+            np.concatenate([np.repeat(np.arange(group), n_q - f)
+                            for f in firsts]),
+            np.concatenate([np.tile(np.arange(f, n_q), group)
+                            for f in firsts]))
+
+
+# The longest list of steps a launch puts in SMEM, in int32 words: three
+# quarters of a v5e's 1 MiB, which holds the kernel's other scalars and
+# spills too (its compiler takes a list of 259,560 words and refuses one of
+# 263,168 by name: "Allocation (size=1052672) would exceed memory
+# (size=1048576) ... prefetched SMEM operand 0";
+# tests/test_chip_compile.py compiles a launch on either side).  A row of
+# 512 blocks lists 131,328 steps, so a power of two would cut at the wrong
+# side of the rows there are
+LISTED_STEPS = 3 << 16
+
+
+def _listed(steps, extents):
+    """``steps`` where the grid is to take them from a list, None where it
+    takes the rectangle ``extents`` with its index maps clamped: a list
+    serves where it leaves steps of the rectangle out (one q block's tiles
+    are the rectangle) and where SMEM holds it (``LISTED_STEPS``)."""
+    count = len(steps[0])
+    return steps if count < np.prod(extents) and count <= LISTED_STEPS \
+        else None
+
+
+def _row_grid(s_len, block_q, block_k, causal, window):
+    """``(extents, steps)`` of the forward and dQ kernels' grid behind the
+    heads: the rectangle ``(n_q, n_k)``, under a window ``(n_q, the longest
+    run of k blocks)`` counted from each run's first block; and, causal
+    without a window, the :func:`_causal_steps` of it that the grid takes
+    (the others' tiles lie above the diagonal) where it lists them
+    (:func:`_listed`), else None: every one."""
+    n_q, n_k = s_len // block_q, s_len // block_k
+    if window is not None:
+        return (n_q, _k_run(n_q, block_q, block_k, window)[0]), None
+    return (n_q, n_k), (_listed(_causal_steps(n_q, block_q, block_k),
+                                (n_q, n_k)) if causal else None)
+
+
+def grid_tiles(seq, block_q, block_k, causal=True, window=None):
+    """``(steps, computed)`` of one row and head: the steps of the forward
+    kernel's grid (dQ's are the same, and dK/dV's a query head wherever it
+    lists ``group`` times as many) and the tiles among them that compute.
+    The same number where the causal kernel's grid lists its tiles and where
+    nothing is masked away whole; a causal rectangle (a list SMEM would not
+    hold, :func:`_listed`) steps over the square, and a window's grid over
+    each q block's longest run (192 steps for 189 tiles at 32,768 / 512 /
+    1,024)."""
+    block_q, block_k = min(block_q, seq), min(block_k, seq)
+    if window is not None and window >= seq:
+        window = None
+    (n_q, run), steps = _row_grid(seq, block_q, block_k, causal, window)
+    if window is not None:
+        return n_q * run, _k_run(n_q, block_q, block_k, window)[1]
+    computed = (len(_causal_steps(n_q, block_q, block_k)[0]) if causal
+                else n_q * run)
+    return n_q * run if steps is None else len(steps[0]), computed
+
+
+def _widths(extents):
+    """The bits each coordinate of a listed step takes in its int32 word
+    (``LISTED_STEPS`` keeps their sum far under 31: a list is at least half
+    of its extents' product long)."""
+    return tuple(max(int(e) - 1, 1).bit_length() for e in extents)
+
+
+def _listed_step(steps_ref, t, widths):
+    """The coordinates of grid step ``t`` of a grid that lists its steps:
+    bit fields of one word, the last coordinate lowest (shifts and masks:
+    an index map is evaluated for every operand and step, on the scalar
+    core, in front of the step's copies)."""
+    word = steps_ref[t]
+    coords = []
+    for width in reversed(widths):
+        coords.append(word & ((1 << width) - 1))
+        word = jax.lax.shift_right_logical(word, width)
+    return tuple(reversed(coords))
+
+
+def _launch(kernel, heads, extents, steps, in_specs, out_specs, out_shape,
+            scratch_shapes, interpret, args):
+    """``kernel(listed=None)`` over the grid ``(heads, *extents)``; or,
+    where ``steps`` lists the coordinates to take (one int array a
+    coordinate, each below its extent), ``kernel(listed=widths)`` over
+    ``(heads, len(steps[0]))``: the list goes in front of the kernel's other
+    references as one int32 a step (:func:`_listed_step`), prefetched into
+    SMEM.  ``in_specs`` and ``out_specs`` are ``(block shape, index map)``,
+    the maps over ``(head, *coordinates)``."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    def block_specs(index_map):
+        return jax.tree.map(
+            lambda s: pl.BlockSpec(s[0], index_map(s[1])),
+            (in_specs, out_specs),
+            is_leaf=lambda s: isinstance(s, tuple) and callable(s[1]))
+
+    if steps is None:
+        in_specs, out_specs = block_specs(lambda where: where)
+        return pl.pallas_call(
+            functools.partial(kernel, listed=None), grid=(heads,) + extents,
+            in_specs=in_specs, out_specs=out_specs, out_shape=out_shape,
+            scratch_shapes=scratch_shapes, interpret=interpret)(*args)
+    widths = _widths(extents)
+    in_specs, out_specs = block_specs(
+        lambda where: lambda b, t, listed: where(
+            b, *_listed_step(listed, t, widths)))
+    words = np.zeros(len(steps[0]), np.int64)
+    for coordinate, width in zip(steps, widths):
+        words = words << width | coordinate
+    return pl.pallas_call(
+        functools.partial(kernel, listed=widths),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(heads, len(words)),
+            in_specs=in_specs, out_specs=out_specs,
+            scratch_shapes=scratch_shapes),
+        out_shape=out_shape, interpret=interpret)(
+            words.astype(np.int32), *args)
+
+
+def _steps_first(refs, listed):
+    """``(steps_ref, the others)`` of a kernel's references: the prefetched
+    list of the grid's steps comes first where there is one."""
+    return (refs[0], refs[1:]) if listed else (None, refs)
+
+
+def _row_step(steps_ref, block_q, block_k, n_k, window, listed):
+    """``(qi, kk, step, end)`` of a forward or dQ grid step: the tile, and
+    where it stands in its q block's run (``step == 0`` starts the run,
+    ``step == end`` closes it).  ``n_k`` is the rectangle's inner extent:
+    every k block, or under a window the longest run a q block needs,
+    counted from the run's first block; ``listed`` the widths of a listed
+    step's coordinates."""
+    from jax.experimental import pallas as pl
+
+    # program_id (and the list) must be read OUTSIDE pl.when bodies
+    # (interpret mode can't substitute it inside a cond branch); the kernels
+    # close over the values instead.
+    if listed:
+        qi, kk = _listed_step(steps_ref, pl.program_id(1), listed)
+        return qi, kk, kk, _last_k_block(qi, block_q, block_k)
+    step = pl.program_id(2)
+    qi = pl.program_id(1)
+    kk = step if window is None else step + _first_k_block(
+        qi, block_q, block_k, window)
+    return qi, kk, step, n_k - 1
 
 
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
 
-def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, block_q, block_k,
-                n_k, keyed, window=None):
+def _fwd_kernel(*refs, scale, causal, block_q, block_k, n_k, keyed,
+                listed, window=None):
     from jax.experimental import pallas as pl
 
+    steps_ref, (q_ref, k_ref, v_ref, *refs) = _steps_first(refs, listed)
     bits_ref, (o_ref, lse_ref, m_scr, l_scr, acc_scr) = _bits_first(
         refs, keyed)
-    # ``n_k`` is the inner grid's extent: every k block, or under a window
-    # the longest run a q block needs, counted from the run's first block
-    step = pl.program_id(2)
-    # program_id must be read OUTSIDE pl.when bodies (interpret mode can't
-    # substitute it inside a cond branch); close over the values instead.
-    qi = pl.program_id(1)
-    kk = step if window is None else step + _first_k_block(
-        qi, block_q, block_k, window)
+    qi, kk, step, end = _row_step(steps_ref, block_q, block_k, n_k, window,
+                                  listed)
 
     @pl.when(step == 0)
     def _init():
@@ -322,9 +512,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, block_q, block_k,
             p.astype(v.dtype), v, ((1,), (0,)))
         m_scr[:] = m_new
 
-    _when_needed(causal, qi, kk, block_q, block_k, _compute, window)
+    _when_needed(causal, qi, kk, block_q, block_k, _compute, window,
+                 listed=bool(listed))
 
-    @pl.when(step == n_k - 1)
+    @pl.when(step == end)
     def _emit():
         l = jnp.maximum(l_scr[:], 1e-30)
         o_ref[0] = (acc_scr[:] / _across(l, acc_scr.shape[1])).astype(
@@ -333,15 +524,17 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, block_q, block_k,
         lse_ref[0] = (m_scr[:] + jnp.log(l)).T[:1]
 
 
-def _kv_maps(causal, block_q, block_k, group, window=None):
+def _kv_maps(clamped, block_q, block_k, group, window=None):
     """Block index maps of K and V on a (q head, q block, k block) grid:
-    query head ``b`` reads KV head ``b // group``; a causal q block never
-    moves past the last k block it needs, and under a window it starts at
-    the first."""
+    query head ``b`` reads KV head ``b // group``; under a window a q
+    block's run starts at the first k block it needs; ``clamped`` (a causal
+    rectangle, a window's band: a grid with steps past a run's end) it never
+    moves past the last, and a block whose index does not change is not
+    copied again."""
     def kv(b, i, kk):
         if window is not None:
             kk = kk + _first_k_block(i, block_q, block_k, window)
-        if causal:
+        if clamped:
             kk = jnp.minimum(kk, _last_k_block(i, block_q, block_k))
         return (b // group, kk, 0)
     return kv
@@ -354,21 +547,18 @@ def _bits_first(refs, keyed):
 
 
 def _bits_spec(rows, block_q, block_k, q_block, k_block):
-    """Block spec of ``key_bits`` on a launcher's grid: a step reads the
-    words of q block ``q_block(*ids)`` in the group of k block
-    ``k_block(*ids)``; the grid's first index counts ``rows`` (heads) a
-    batch row."""
-    from jax.experimental import pallas as pl
-
+    """Block shape and index map (:func:`_launch`'s form) of ``key_bits`` on
+    a launcher's grid: a step reads the words of q block ``q_block(*ids)``
+    in the group of k block ``k_block(*ids)``; the grid's first index counts
+    ``rows`` (heads) a batch row."""
     if block_k % KEY_LANES or KEY_GROUP % block_k:
         raise ValueError(
             "key_bits want a k block that divides by {} and divides {}: "
             "{}".format(KEY_LANES, KEY_GROUP, block_k))
     per_group = KEY_GROUP // block_k
-    return pl.BlockSpec(
-        (1, 1, block_q, KEY_LANES),
-        lambda b, x, y: (b // rows, k_block(x, y) // per_group,
-                         q_block(x, y), 0))
+    return ((1, 1, block_q, KEY_LANES),
+            lambda b, *ids: (b // rows, k_block(*ids) // per_group,
+                             q_block(*ids), 0))
 
 
 def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret, group=1,
@@ -380,23 +570,21 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret, group=1,
     two dims divisible by (8, 128) or equal to the array's: a ``(1, 1,
     block_q)`` block is, a ``(1, block_q)`` row block of ``[bh, seq]`` is
     not."""
-    from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     bh, s_len, d = q.shape
     dv = v.shape[-1]
-    n_q = s_len // block_q
-    n_k = s_len // block_k      # the inner grid's extent: under a window ...
-    if window is not None:      # ... the longest run of k blocks
-        n_k = _k_run(n_q, block_q, block_k, window)[0]
+    extents, steps = _row_grid(s_len, block_q, block_k, causal, window)
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, block_q=block_q,
-        block_k=block_k, n_k=n_k, keyed=bits is not None, window=window)
-    kv = _kv_maps(causal, block_q, block_k, group, window)
+        block_k=block_k, n_k=extents[1], keyed=bits is not None,
+        window=window)
+    kv = _kv_maps(causal and steps is None, block_q, block_k, group,
+                  window)
     in_specs = [
-        pl.BlockSpec((1, block_q, d), lambda b, i, kk: (b, i, 0)),
-        pl.BlockSpec((1, block_k, d), kv),
-        pl.BlockSpec((1, block_k, dv), kv),
+        ((1, block_q, d), lambda b, i, kk: (b, i, 0)),
+        ((1, block_k, d), kv),
+        ((1, block_k, dv), kv),
     ]
     args = (q, k, v)
     if bits is not None:
@@ -404,13 +592,11 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret, group=1,
             bh // bits.shape[0], block_q, block_k,
             lambda i, kk: i, lambda i, kk: kv(0, i, kk)[1]))
         args += (bits,)
-    out, lse = pl.pallas_call(
-        kernel,
-        grid=(bh, n_q, n_k),
-        in_specs=in_specs,
+    out, lse = _launch(
+        kernel, bh, extents, steps, in_specs,
         out_specs=[
-            pl.BlockSpec((1, block_q, dv), lambda b, i, kk: (b, i, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b, i, kk: (b, 0, i)),
+            ((1, block_q, dv), lambda b, i, kk: (b, i, 0)),
+            ((1, 1, block_q), lambda b, i, kk: (b, 0, i)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, s_len, dv), q.dtype),
@@ -421,8 +607,7 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret, group=1,
             pltpu.VMEM((block_q, _stat_lanes(block_k)), jnp.float32),
             pltpu.VMEM((block_q, dv), jnp.float32),
         ],
-        interpret=interpret,
-    )(*args)
+        interpret=interpret, args=args)
     return out, lse
 
 
@@ -430,15 +615,16 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret, group=1,
 # backward
 # ---------------------------------------------------------------------------
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
-                   scale, causal, block_q, block_k, n_k, keyed, window=None):
+def _bwd_dq_kernel(*refs, scale, causal, block_q, block_k, n_k, keyed,
+                   listed, window=None):
     from jax.experimental import pallas as pl
 
+    steps_ref, (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                *refs) = _steps_first(refs, listed)
     bits_ref, (dq_ref, dq_scr, lse_scr, delta_scr) = _bits_first(refs, keyed)
-    step = pl.program_id(2)   # the forward kernel's grid, see there
-    qi = pl.program_id(1)  # read outside pl.when bodies (interpret mode)
-    kk = step if window is None else step + _first_k_block(
-        qi, block_q, block_k, window)
+    # the forward kernel's grid, see there
+    qi, kk, step, end = _row_step(steps_ref, block_q, block_k, n_k, window,
+                                  listed)
 
     @pl.when(step == 0)
     def _init():
@@ -457,33 +643,44 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
         k = k_ref[0]
         dq_scr[:] += scale * _dot(ds.astype(k.dtype), k, ((1,), (0,)))
 
-    _when_needed(causal, qi, kk, block_q, block_k, _compute, window)
+    _when_needed(causal, qi, kk, block_q, block_k, _compute, window,
+                 listed=bool(listed))
 
-    @pl.when(step == n_k - 1)
+    @pl.when(step == end)
     def _emit():
         dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
-                    scale, causal, block_q, block_k, n_q, group, keyed,
-                    window=None, rows=None):
+def _bwd_dkv_kernel(*refs, scale, causal, block_q, block_k, n_q, group, keyed,
+                    listed, window=None, rows=None):
     from jax.experimental import pallas as pl
 
+    steps_ref, (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                *refs) = _steps_first(refs, listed)
     bits_ref, (dk_ref, dv_ref, dk_scr, dv_scr) = _bits_first(refs, keyed)
     # the inner grid dimension runs over the KV head's ``group`` query heads
     # and, for each, over the q blocks (``n_q``: all of them, or under a
     # window the longest run that sees a k block, counted from the run's
     # first block; ``rows`` is then how many q blocks there are): one
-    # accumulation for all of them
-    j = pl.program_id(2)
-    qi = j % n_q
-    kk = pl.program_id(1)  # read outside pl.when bodies (interpret mode)
+    # accumulation for all of them.  A grid that lists its steps
+    # (:func:`_causal_steps_by_keys`) brings the same (k block, head of the
+    # group, q block) without those whose tile no query sees: a k block's
+    # first is then its first q block's of the group's first head
     inside = None
-    if window is not None:
-        qi = qi + _first_q_block(kk, block_q, block_k)
-        inside = qi < rows
+    if listed:   # read outside pl.when bodies (interpret mode)
+        kk, head, qi = _listed_step(steps_ref, pl.program_id(1), listed)
+        j = head * n_q + qi
+        start = _first_q_block(kk, block_q, block_k)
+    else:
+        j = pl.program_id(2)
+        qi = j % n_q
+        kk = pl.program_id(1)  # read outside pl.when bodies (interpret mode)
+        start = 0
+        if window is not None:
+            qi = qi + _first_q_block(kk, block_q, block_k)
+            inside = qi < rows
 
-    @pl.when(j == 0)
+    @pl.when(j == start)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
@@ -500,7 +697,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
         q = q_ref[0]
         dk_scr[:] += scale * _dot(ds.astype(q.dtype), q, ((1,), (0,)))
 
-    _when_needed(causal, qi, kk, block_q, block_k, _compute, window, inside)
+    _when_needed(causal, qi, kk, block_q, block_k, _compute, window, inside,
+                 bool(listed))
 
     @pl.when(j == group * n_q - 1)
     def _emit():
@@ -517,26 +715,25 @@ def _bwd_delta(out, g):
 
 def _flash_bwd_dq(q, k, v, g, lse, delta, scale, causal, block_q, block_k,
                   interpret, group=1, bits=None, window=None):
-    from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     bh, s_len, d = q.shape
     dv = v.shape[-1]
-    n_q = s_len // block_q
-    n_k = s_len // block_k
-    if window is not None:      # the forward kernel's grid, see there
-        n_k = _k_run(n_q, block_q, block_k, window)[0]
+    # the forward kernel's grid, see there
+    extents, steps = _row_grid(s_len, block_q, block_k, causal, window)
     kernel = functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                               block_q=block_q, block_k=block_k, n_k=n_k,
-                               keyed=bits is not None, window=window)
-    kv = _kv_maps(causal, block_q, block_k, group, window)
+                               block_q=block_q, block_k=block_k,
+                               n_k=extents[1], keyed=bits is not None,
+                               window=window)
+    kv = _kv_maps(causal and steps is None, block_q, block_k, group,
+                  window)
     in_specs = [
-        pl.BlockSpec((1, block_q, d), lambda b, i, kk: (b, i, 0)),
-        pl.BlockSpec((1, block_k, d), kv),
-        pl.BlockSpec((1, block_k, dv), kv),
-        pl.BlockSpec((1, block_q, dv), lambda b, i, kk: (b, i, 0)),
-        pl.BlockSpec((1, 1, block_q), lambda b, i, kk: (b, 0, i)),
-        pl.BlockSpec((1, 1, block_q), lambda b, i, kk: (b, 0, i)),
+        ((1, block_q, d), lambda b, i, kk: (b, i, 0)),
+        ((1, block_k, d), kv),
+        ((1, block_k, dv), kv),
+        ((1, block_q, dv), lambda b, i, kk: (b, i, 0)),
+        ((1, 1, block_q), lambda b, i, kk: (b, 0, i)),
+        ((1, 1, block_q), lambda b, i, kk: (b, 0, i)),
     ]
     args = (q, k, v, g, lse, delta)
     if bits is not None:
@@ -544,77 +741,84 @@ def _flash_bwd_dq(q, k, v, g, lse, delta, scale, causal, block_q, block_k,
             bh // bits.shape[0], block_q, block_k,
             lambda i, kk: i, lambda i, kk: kv(0, i, kk)[1]))
         args += (bits,)
-    return pl.pallas_call(
-        kernel,
-        grid=(bh, n_q, n_k),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, kk: (b, i, 0)),
+    return _launch(
+        kernel, bh, extents, steps, in_specs,
+        out_specs=((1, block_q, d), lambda b, i, kk: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[
             pltpu.VMEM((block_q, d), jnp.float32),
             pltpu.VMEM((block_q, _stat_lanes(block_k)), jnp.float32),
             pltpu.VMEM((block_q, _stat_lanes(block_k)), jnp.float32),
         ],
-        interpret=interpret,
-    )(*args)
+        interpret=interpret, args=args)
 
 
 def _flash_bwd_dkv(q, k, v, g, lse, delta, scale, causal, block_q, block_k,
                    interpret, group=1, bits=None, window=None):
-    from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     bh_kv, s_len, d = k.shape
     dv = v.shape[-1]
     n_q = q_blocks = s_len // block_q
     n_k = s_len // block_k
+    steps = None
     if window is not None:      # a query head's steps: the longest run
         n_q = _q_run(q_blocks, n_k, block_q, block_k, window)
+    elif causal:                # a step for every tile that computes
+        steps = _listed(
+            _causal_steps_by_keys(n_q, n_k, block_q, block_k, group),
+            (n_k, group, n_q))
 
-    def rows(b, kk, j):
-        """Block index of a per-query-head array: the ``j // n_q``-th query
-        head of KV head ``b``, q block ``j % n_q`` (causal: never before the
-        first q block that sees k block ``kk``; under a window counted from
-        it, and never past the last)."""
-        i = j % n_q
-        if window is not None:
-            i = jnp.minimum(
-                i + _first_q_block(kk, block_q, block_k),
-                _last_q_block(kk, block_q, block_k, window, q_blocks))
-        elif causal:
-            i = jnp.maximum(i, _first_q_block(kk, block_q, block_k))
-        return (b * group + j // n_q, i, 0)
+    if steps is None:
+        extents = (n_k, group * n_q)
 
-    def stats(b, kk, j):
-        head, i, _ = rows(b, kk, j)
+        def rows(b, kk, j):
+            """Block index of a per-query-head array: the ``j // n_q``-th
+            query head of KV head ``b``, q block ``j % n_q`` (causal: never
+            before the first q block that sees k block ``kk``; under a
+            window counted from it, and never past the last)."""
+            i = j % n_q
+            if window is not None:
+                i = jnp.minimum(
+                    i + _first_q_block(kk, block_q, block_k),
+                    _last_q_block(kk, block_q, block_k, window, q_blocks))
+            elif causal:
+                i = jnp.maximum(i, _first_q_block(kk, block_q, block_k))
+            return (b * group + j // n_q, i, 0)
+    else:
+        extents = (n_k, group, n_q)
+
+        def rows(b, kk, head, i):   # a listed step names all three
+            return (b * group + head, i, 0)
+
+    def stats(*ids):
+        head, i, _ = rows(*ids)
         return (head, 0, i)
+
+    def keys(b, kk, *_):
+        return (b, kk, 0)
 
     kernel = functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
                                block_q=block_q, block_k=block_k, n_q=n_q,
                                group=group, keyed=bits is not None,
                                window=window, rows=q_blocks)
     in_specs = [
-        pl.BlockSpec((1, block_q, d), rows),
-        pl.BlockSpec((1, block_k, d), lambda b, kk, j: (b, kk, 0)),
-        pl.BlockSpec((1, block_k, dv), lambda b, kk, j: (b, kk, 0)),
-        pl.BlockSpec((1, block_q, dv), rows),
-        pl.BlockSpec((1, 1, block_q), stats),
-        pl.BlockSpec((1, 1, block_q), stats),
+        ((1, block_q, d), rows),
+        ((1, block_k, d), keys),
+        ((1, block_k, dv), keys),
+        ((1, block_q, dv), rows),
+        ((1, 1, block_q), stats),
+        ((1, 1, block_q), stats),
     ]
     args = (q, k, v, g, lse, delta)
     if bits is not None:
         in_specs.append(_bits_spec(
             bh_kv // bits.shape[0], block_q, block_k,
-            lambda kk, j: rows(0, kk, j)[1], lambda kk, j: kk))
+            lambda *ids: rows(0, *ids)[1], lambda kk, *_: kk))
         args += (bits,)
-    return pl.pallas_call(
-        kernel,
-        grid=(bh_kv, n_k, group * n_q),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda b, kk, j: (b, kk, 0)),
-            pl.BlockSpec((1, block_k, dv), lambda b, kk, j: (b, kk, 0)),
-        ],
+    return _launch(
+        kernel, bh_kv, extents, steps, in_specs,
+        out_specs=[((1, block_k, d), keys), ((1, block_k, dv), keys)],
         out_shape=[
             jax.ShapeDtypeStruct(k.shape, k.dtype),
             jax.ShapeDtypeStruct(v.shape, v.dtype),
@@ -623,8 +827,7 @@ def _flash_bwd_dkv(q, k, v, g, lse, delta, scale, causal, block_q, block_k,
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, dv), jnp.float32),
         ],
-        interpret=interpret,
-    )(*args)
+        interpret=interpret, args=args)
 
 
 def _flash_bwd(res, g, scale, causal, block_q, block_k, interpret, group,

@@ -429,11 +429,12 @@ class Trainer(object):
     def _note_moe(self, aux):
         if not isinstance(aux, dict):
             return
-        # the index over the keys (dsa_*) and the window layers (swa_*)
-        # count the same way
+        # the index over the keys (dsa_*), the window layers (swa_*) and
+        # the flash kernels' grids (flash_*) count the same way
         counts = dict(aux.get("moe_counts") or {},
                       **(aux.get("dsa_counts") or {}),
-                      **(aux.get("swa_counts") or {}))
+                      **(aux.get("swa_counts") or {}),
+                      **(aux.get("flash_counts") or {}))
         if not counts:
             return
         with self._moe_lock:
@@ -500,6 +501,15 @@ class Trainer(object):
         full layer's work, 100% for a kernel that masks),
         ``swa_layers_steps`` the layer calls counted.
 
+        The flash kernels' grids, for a model with layers under
+        ``attention="flash"`` (the same way): ``flash_grid_steps`` the steps
+        of the forward kernels' grids over rows, heads and layers,
+        ``flash_tiles_computed`` the tiles among them that compute (their
+        ratio is 1 for a causal layer, whose grid lists the triangle's tiles,
+        192 / 189 for a window of 1,024 keys in 32,768-token rows at blocks
+        of 512, and 1.97 for the square grid over such rows, which a layer
+        takes whose list SMEM would not hold).
+
         ``train_recompiles_total``: dispatches that made a step program
         executable under a name (``step``, ``multi_<k>``) that had run
         before: a batch of another shape, a state laid out anew.  Which
@@ -557,7 +567,7 @@ class Trainer(object):
             snap["train_recompiles_total"] = self._recompiles
         if self._moe_pending or self._moe_totals:
             self._fold_moe()
-            snap.update(self._moe_totals)   # the loss names them moe_*, dsa_*, swa_*
+            snap.update(self._moe_totals)   # moe_*, dsa_*, swa_*, flash_*
         return snap
 
     def counters_snapshot(self):

@@ -73,38 +73,61 @@ def _flash_calls(calls):
     return "\n".join(line for line in calls if "/attention/flash/" in line)
 
 
-# (batch, seq, heads, q and k width, v width, block): chip_smoke's LM shape,
-# one longer and wider point the model zoo allows, and latent attention's at
-# the benchmark's sizes (scores over 192, values of 128, blocks of 512)
-SHAPES = [(8, 1024, 16, 64, 64, 128), (2, 4096, 8, 128, 128, 128),
-          (4, 8192, 16, 192, 128, 512)]
+# (batch, seq, query heads, KV heads, q and k width, v width, block):
+# chip_smoke's LM shape, one longer and wider point the model zoo allows, and
+# the benchmark's cells without a key set: latent attention's (scores over
+# 192, values of 128), 32 / 8 heads of 64 over 8,192 rows and 32 / 4 of 128
+# over 32,768, blocks of 512 (the keyed cell's are further down); then the
+# default blocks of 128 where the lists grow long: 32,768 rows and a group of
+# 8 (32,896 steps a head, and 263,168 a KV head in dK/dV, more than SMEM
+# holds: that kernel keeps the rectangle), a row of 131,072 as Ulysses hands
+# one over whole (524,800: all three keep it), and the longest list there is
+# (626 blocks, 196,251 steps of the 196,608 allowed)
+SHAPES = [(8, 1024, 16, 16, 64, 64, 128), (2, 4096, 8, 8, 128, 128, 128),
+          (4, 8192, 16, 16, 192, 128, 512), (4, 8192, 32, 8, 64, 64, 512),
+          (1, 32768, 32, 4, 128, 128, 512), (1, 32768, 8, 1, 128, 128, 128),
+          (1, 131072, 2, 2, 128, 128, 128), (1, 80128, 1, 1, 128, 128, 128)]
 
 
 @pytest.mark.parametrize("shape", SHAPES,
-                         ids=["s1024_d64", "s4096_d128", "s8192_dk192_dv128"])
+                         ids=["s1024_d64", "s4096_d128", "s8192_dk192_dv128",
+                              "s8192_d64_group4", "s32768_d128_group8",
+                              "s32768_block128_group8", "s131072_block128",
+                              "s80128_block128"])
 @pytest.mark.parametrize("kernel", ["fwd", "bwd_dq", "bwd_dkv"])
 def test_flash_kernel_compiles_for_v5e(topo, kernel, shape):
-    batch, seq, heads, dk, dv, block = shape
+    """The causal kernels on the grid that lists the triangle's tiles (a
+    table in SMEM: 2,080 steps a head at 32,768 / 512, and 16,640 a KV head
+    in dK/dV at a group of 8), up to the longest list a launcher makes
+    (``LISTED_STEPS``), and on the clamped rectangle where the list would be
+    longer: no shape is refused."""
+    batch, seq, heads, kv, dk, dv, block = shape
     one = SingleDeviceSharding(topo.devices[0])
 
-    def arg(width, dtype=jnp.bfloat16):
+    def arg(heads, width, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct((batch * heads, seq, width), dtype,
                                     sharding=one)
 
-    qk, v = arg(dk), arg(dv)
+    q, k, v, g = arg(heads, dk), arg(kv, dk), arg(kv, dv), arg(heads, dv)
     stat = jax.ShapeDtypeStruct((batch * heads, 1, seq), jnp.float32,
                                 sharding=one)
-    tail = (dk ** -0.5, True, block, block, False)  # scale, causal, blocks, interpret
+    # scale, causal, blocks, interpret, group
+    tail = (dk ** -0.5, True, block, block, False, heads // kv)
     if kernel == "fwd":
         text = _compile(lambda q, k, v: fa._flash_fwd(q, k, v, *tail),
-                        qk, qk, v)
+                        q, k, v)
     else:
         launch = fa._flash_bwd_dq if kernel == "bwd_dq" else fa._flash_bwd_dkv
         text = _compile(
             lambda q, k, v, g, lse, delta: launch(q, k, v, g, lse, delta,
                                                   *tail),
-            qk, qk, v, v, stat, stat)
+            q, k, v, g, stat, stat)
     assert text.count("tpu_custom_call") == 1
+    n = seq // block
+    steps = n * (n + 1) // 2 * (heads // kv if kernel == "bwd_dkv" else 1)
+    assert ("s32[{}]".format(steps) in text) == (steps <= fa.LISTED_STEPS)
+    assert (re.search(r"s32\[\d+\]", text) is None) == (
+        steps > fa.LISTED_STEPS)
     assert not _one_lane_arrays("\n".join(_kernel_lines(text)))
 
 
@@ -347,7 +370,7 @@ def _keyed_args(topo):
 def test_flash_kernel_with_key_bits_compiles_for_v5e(topo, kernel):
     """The three flash kernels reading each query's own keys as bits, at the
     sparse-attention cell's sizes (group 8, one ``[512, 128]`` tile of words
-    for eight k blocks)."""
+    for eight k blocks), on the grid that lists the triangle's tiles."""
     arg, bits = _keyed_args(topo)
     b, t, h, kv, d = (KEYED[k] for k in ("batch", "seq", "heads", "kv",
                                          "dim"))
@@ -363,6 +386,7 @@ def test_flash_kernel_with_key_bits_compiles_for_v5e(topo, kernel):
             lambda q, k, v, g, lse, delta, bits: launch(
                 q, k, v, g, lse, delta, *tail, bits),
             q, k, k, q, stat, stat, bits)
+    assert "s32[{}]".format(2080 * (8 if kernel == "bwd_dkv" else 1)) in text
     assert text.count("tpu_custom_call") == 1
     assert not _one_lane_arrays("\n".join(_kernel_lines(text)))
 

@@ -351,3 +351,5 @@ def test_trainer_counters_carry_the_index():
     assert snap["dsa_tiles_causal"] == snap["dsa_tiles_touched"] == 12
     assert snap["dsa_index_loss"] > 0.0
     assert snap["moe_layers_steps"] == 3 * 2
+    # the keyed flash kernels' grid: a tile a row and head, 4 heads
+    assert snap["flash_grid_steps"] == snap["flash_tiles_computed"] == 48

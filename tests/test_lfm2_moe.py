@@ -234,5 +234,28 @@ def test_trainer_counters_carry_the_routers_load():
 def test_a_model_without_experts_has_no_moe_counters():
     snap = _fit(transformer.build_transformer(
         vocab_size=61, num_layers=1, num_heads=2, head_dim=8, max_seq_len=16))
-    assert not [k for k in snap if k.startswith("moe_")]
+    assert not [k for k in snap if k.startswith(("moe_", "flash_"))]
     assert snap["dispatch_count"] == 3
+
+
+@pytest.mark.parametrize("seq, tiles, longest, steps", [
+    (16, 1, None, 1), (256, 3, None, 3), (256, 3, 2, 4)],
+    ids=["one_tile", "listed", "the_square"])
+def test_trainer_counters_carry_the_flash_kernels_grid(
+        monkeypatch, seq, tiles, longest, steps):
+    """``flash_grid_steps / flash_tiles_computed`` is 1 for causal layers:
+    the grid lists the triangle's tiles (3 steps x batch 2 x 2 heads x 2
+    layers; blocks of 128, clamped to a short row); where the list would be
+    longer than ``LISTED_STEPS`` the kernels step over the square and the
+    counters say so (4 steps for 3 tiles)."""
+    import importlib
+
+    if longest is not None:
+        monkeypatch.setattr(importlib.import_module(
+            "tensorflowonspark_tpu.ops.flash_attention"), "LISTED_STEPS",
+            longest)
+    snap = _fit(transformer.build_transformer(
+        vocab_size=61, num_layers=2, num_heads=2, head_dim=8, max_seq_len=seq,
+        attention="flash"), seq=seq)
+    assert snap["flash_tiles_computed"] == 24 * tiles
+    assert snap["flash_grid_steps"] == 24 * steps

@@ -281,6 +281,13 @@ def test_trainer_counters_carry_the_band(seq, block, window):
         snap["swa_tiles_computed"] == snap["swa_tiles_causal"])
     assert snap["moe_layers_steps"] == 3 * 4
     assert not [k for k in snap if k.startswith("dsa_")]
+    # the flash kernels' grids, 3 steps x batch 2 x 4 heads: the full layer
+    # steps over the triangle alone, a sliding one over its q blocks'
+    # longest run (the causal grid where the window covers the row)
+    triangle = n * (n + 1) // 2
+    banded = (sum(runs), n * max(runs)) if window < seq else (triangle,) * 2
+    assert snap["flash_tiles_computed"] == 24 * (3 * banded[0] + triangle)
+    assert snap["flash_grid_steps"] == 24 * (3 * banded[1] + triangle)
 
 
 def test_the_real_cells_band_is_a_tenth_of_the_triangle():

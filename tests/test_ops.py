@@ -429,22 +429,193 @@ def test_flash_kernels_with_a_window_against_the_float32_reference(case,
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+def _grids(fn, *args):
+    """The grids of the kernels in ``fn``'s trace, as their jaxpr prints
+    them, sorted."""
+    return sorted(re.findall(r"grid=\(([\d, ]+)\)",
+                             str(jax.make_jaxpr(fn)(*args))))
+
+
 def test_a_windows_grid_follows_the_band():
     """The three kernels' inner grid extent is the band's longest run of
-    blocks, not ``seq / block``, and ``window=None`` traces as it did."""
+    blocks, not ``seq / block``, and without a window the grid is the 36
+    tiles of the triangle of 8 blocks."""
     q, k, v = _qkv(batch=1, seq=256, heads=2, dim=16)
 
     def grids(window):
-        text = str(jax.make_jaxpr(jax.grad(lambda q, k, v: flash_attention(
+        return _grids(jax.grad(lambda q, k, v: flash_attention(
             q, k, v, block_q=32, block_k=32, window=window).sum(),
-            (0, 1, 2)))(q, k, v))
-        return sorted(re.findall(r"grid=\((\d+, \d+, \d+)\)", text))
+            (0, 1, 2)), q, k, v)
 
-    assert grids(None) == ["2, 8, 8"] * 3
+    assert grids(None) == ["2, 36"] * 3
     assert grids(64) == ["2, 8, 3"] * 3        # two whole blocks: three tiles
     assert grids(34) == ["2, 8, 3"] * 3
     assert grids(33) == ["2, 8, 2"] * 3        # one key beyond one block
-    assert grids(256) == grids(None)            # covers the row: the causal kernel
+    assert grids(256) == grids(None)     # covers the row: the causal kernel
+
+
+def _causal_tiles(seq, block_q, block_k):
+    """The (q block, k block) tiles that hold a (query, key) pair with the
+    key not after the query, counted pair by pair."""
+    seen = np.arange(seq)[:, None] >= np.arange(seq)[None, :]
+    return int(seen.reshape(seq // block_q, block_q, seq // block_k,
+                            block_k).any(axis=(1, 3)).sum())
+
+
+@pytest.mark.parametrize("keyed", [False, True], ids=["plain", "key_bits"])
+@pytest.mark.parametrize("seq, block_q, block_k, group", [
+    (128, 128, 128, 1),     # one q block: one tile, the rectangle
+    (512, 512, 128, 4),     # one q block over four k blocks: the rectangle
+    (256, 128, 128, 4),     # two: three tiles
+    (1024, 128, 128, 8),
+    (512, 256, 128, 4),     # block_q != block_k, both ways round
+    (512, 128, 256, 1),
+    (1024, 128, 512, 8),
+], ids=lambda x: str(x))
+def test_a_causal_grid_has_a_step_for_every_tile_that_computes(
+        seq, block_q, block_k, group, keyed):
+    """Under ``causal`` without a window the three kernels' grids hold one
+    step for every tile that the diagonal crosses or that lies below it, and
+    none for the tiles above: forward and dQ ``(query heads, tiles)``, dK/dV
+    ``(KV heads, group * tiles)``; ``grid_tiles`` says the same, and
+    ``causal=False`` keeps the rectangle, as does one q block a head, whose
+    tiles are the rectangle.  The steps come in the rectangle's own order
+    (so every sum is taken in the order it was)."""
+    import importlib
+
+    fa = importlib.import_module("tensorflowonspark_tpu.ops.flash_attention")
+    grid_tiles = fa.grid_tiles
+    heads = 8
+    q = jnp.zeros((1, seq, heads, 128))
+    k = v = jnp.zeros((1, seq, heads // group, 128))
+    bits = jnp.zeros((1, 1, seq, 128), jnp.int32) if keyed else None
+
+    def grids(causal):
+        return _grids(jax.grad(lambda q, k, v: flash_attention_lse(
+            q, k, v, causal=causal, block_q=block_q, block_k=block_k,
+            key_bits=bits)[0].sum(), (0, 1, 2)), q, k, v)
+
+    tiles = _causal_tiles(seq, block_q, block_k)
+    n_q, n_k = seq // block_q, seq // block_k
+    rectangle = sorted(
+        ["{}, {}, {}".format(heads, n_q, n_k)] * 2
+        + ["{}, {}, {}".format(heads // group, n_k, group * n_q)])
+    assert grids(True) == (rectangle if tiles == n_q * n_k else sorted(
+        ["{}, {}".format(heads, tiles)] * 2
+        + ["{}, {}".format(heads // group, group * tiles)]))
+    assert grid_tiles(seq, block_q, block_k) == (tiles, tiles)
+    assert grids(False) == rectangle
+    assert grid_tiles(seq, block_q, block_k, causal=False) == (
+        n_q * n_k, n_q * n_k)
+
+    def reaches(i, kk):     # the q block's last query, the k block's first key
+        return i * block_q + block_q - 1 >= kk * block_k
+
+    assert list(zip(*fa._causal_steps(n_q, block_q, block_k))) == [
+        (i, kk) for i in range(n_q) for kk in range(n_k) if reaches(i, kk)]
+    assert list(zip(*fa._causal_steps_by_keys(
+        n_q, n_k, block_q, block_k, group))) == [
+        (kk, head, i) for kk in range(n_k) for head in range(group)
+        for i in range(n_q) if reaches(i, kk)]
+
+
+@pytest.mark.parametrize("shape", ["d64_group4", "d192_dv128_mha",
+                                   "d128_group8_keyed"])
+def test_the_listed_grid_gives_the_rectangles_bits(shape):
+    """A change of schedule, not of arithmetic: the three launchers on the
+    grid that lists the triangle's tiles give, bit for bit, what they give
+    on the rectangle with its steps above the diagonal left in and clamped
+    (a window as long as the row is that grid: the band's, every k block
+    long)."""
+    import importlib
+
+    fa = importlib.import_module("tensorflowonspark_tpu.ops.flash_attention")
+    (dk, dv, heads, group, seq, keyed), _ = HEAD_SHAPES[shape]
+    ks = jax.random.split(jax.random.PRNGKey(5), 4)
+    q = jax.random.normal(ks[0], (2 * heads, seq, dk), jnp.bfloat16)
+    k = jax.random.normal(ks[1], (2 * heads // group, seq, dk), jnp.bfloat16)
+    v = jax.random.normal(ks[2], (2 * heads // group, seq, dv), jnp.bfloat16)
+    g = jax.random.normal(ks[3], (2 * heads, seq, dv), jnp.bfloat16)
+    bits = _selected_bits(2, seq, 40, seed=9)[0] if keyed else None
+    block = seq // 2 if keyed else 32
+
+    def run(window):
+        tail = (dk ** -0.5, True, block, block, True, group, bits, window)
+        out, lse = fa._flash_fwd(q, k, v, *tail)
+        delta = fa._bwd_delta(out, g)
+        return (out, lse, fa._flash_bwd_dq(q, k, v, g, lse, delta, *tail),
+                *fa._flash_bwd_dkv(q, k, v, g, lse, delta, *tail))
+
+    for listed, rectangle in zip(run(None), run(seq)):
+        np.testing.assert_array_equal(np.asarray(listed, np.float32),
+                                      np.asarray(rectangle, np.float32))
+
+
+@pytest.mark.parametrize("keyed", [False, True], ids=["plain", "key_bits"])
+@pytest.mark.parametrize("longest, listed", [(40, "forward and dQ"),
+                                             (10, "none")])
+def test_a_list_smem_would_not_hold_is_the_clamped_rectangle(
+        monkeypatch, longest, listed, keyed):
+    """A launcher whose list of steps would pass ``LISTED_STEPS`` words (36 a
+    head here, 4 x 36 a KV head in dK/dV) takes the rectangle with its index
+    maps clamped, as it did before there were lists: every shape has a
+    grid, the results are the listed grid's bit for bit, and ``grid_tiles``
+    counts the steps that compute nothing."""
+    import importlib
+
+    fa = importlib.import_module("tensorflowonspark_tpu.ops.flash_attention")
+    heads, group = 8, 4
+    # eight blocks a row; key bits want whole runs of 128 keys
+    seq, block = (1024, 128) if keyed else (256, 32)
+    ks = jax.random.split(jax.random.PRNGKey(11), 3)
+    q = jax.random.normal(ks[0], (1, seq, heads, 128), jnp.bfloat16)
+    k = jax.random.normal(ks[1], (1, seq, heads // group, 128), jnp.bfloat16)
+    v = jax.random.normal(ks[2], (1, seq, heads // group, 128), jnp.bfloat16)
+    bits = _selected_bits(1, seq, 40, seed=3)[0] if keyed else None
+    n = seq // block
+    tiles = n * (n + 1) // 2
+
+    def run(q, k, v):
+        out, lse = flash_attention_lse(q, k, v, block_q=block, block_k=block,
+                                       key_bits=bits)
+        return (out.astype(jnp.float32) ** 2).sum(), (out, lse)
+
+    def results():
+        (_, aux), grads = jax.value_and_grad(run, (0, 1, 2), has_aux=True)(
+            q, k, v)
+        return aux + grads, _grids(jax.grad(lambda *a: run(*a)[0], (0, 1, 2)),
+                                   q, k, v)
+
+    want, grids = results()
+    assert grids == sorted(["{}, {}".format(heads, tiles)] * 2 + [
+        "{}, {}".format(heads // group, group * tiles)])
+    monkeypatch.setattr(fa, "LISTED_STEPS", longest)
+    got, grids = results()
+    square = "{}, {}, {}".format(heads, n, n)
+    assert grids == sorted(
+        ([square] if listed == "none" else ["{}, {}".format(heads, tiles)])
+        * 2 + ["{}, {}, {}".format(heads // group, n, group * n)])
+    assert fa.grid_tiles(seq, block, block) == (
+        (n * n if listed == "none" else tiles), tiles)
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
+
+
+def test_grid_tiles_at_the_benchmarks_sizes():
+    """A row and head's grid steps and computed tiles at the cells' sizes:
+    the triangle alone where the square grid took 1.969 and 1.882 steps a
+    tile, a window's band with its three steps in 192 that compute nothing,
+    blocks clamped to a short row, a window that covers the row."""
+    from tensorflowonspark_tpu.ops.flash_attention import grid_tiles
+
+    assert grid_tiles(32768, 512, 512) == (2080, 2080)      # of 4,096
+    assert grid_tiles(8192, 512, 512) == (136, 136)         # of 256
+    assert grid_tiles(32768, 512, 512, window=1024) == (192, 189)
+    assert grid_tiles(64, 512, 512) == (1, 1)
+    assert grid_tiles(1024, 512, 512, window=4096) == (3, 3)
+    # a list of 524,800 steps is more than SMEM holds: the square's steps
+    assert grid_tiles(131072, 128, 128) == (1024 * 1024, 524800)
 
 
 def test_flash_refuses_a_window_it_cannot_run():

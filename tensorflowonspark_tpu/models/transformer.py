@@ -64,7 +64,20 @@ configuration:
   contractions mask it) under plain RoPE, a ``full_attention`` layer's every
   causal key under YaRN frequencies (``rope_parameters`` has a table for
   each kind); softmax top-k experts renormalised, no bias leaf, no shared
-  expert, and an untied read-out.
+  expert, and an untied read-out;
+- :func:`nemotron_h_spec` (registered as ``nemotron_h``): the Nemotron-H
+  family's ``config.json``: **every layer is one part alone**, ``x +
+  part(RMSNorm(x))``, by ``hybrid_override_pattern``: ``M`` a Mamba-2 layer
+  (:class:`Mamba2`: one input projection to a gate, ``x``, ``B``, ``C`` and
+  a step size a head, a causal depthwise convolution with a bias, the
+  chunked state-space scan of
+  :mod:`~tensorflowonspark_tpu.ops.ssd_scan`, a gated RMSNorm by groups,
+  the output projection), ``E`` an expert layer (sigmoid scores with a
+  selection bias, top-k renormalised and scaled, experts of **two**
+  matrices with ``relu(.)**2`` between, a shared expert of the same form),
+  ``*`` grouped-query attention with no positions at all (no RoPE, no
+  table), ``-`` a dense feed-forward of the experts' form; an untied
+  read-out.
 
 Scopes a device trace can be read by (``jax.named_scope`` under the flax
 module names): ``block_i/short_conv``, ``block_i/attention/flash`` (a layer
@@ -81,7 +94,11 @@ the tiles the picks touch), ``block_i/attention/index_loss`` (the kernel of
 the index's loss),
 ``block_i/moe/route`` (router, top-k, sort), ``moe/dispatch`` (gather),
 ``moe/experts`` (the grouped products), ``moe/combine`` (scale, gather back),
-``moe/shared`` (the shared expert).
+``moe/shared`` (the shared expert); of a Mamba-2 layer
+``block_i/mamba/in_proj``, ``mamba/conv`` (taps, bias, silu), ``mamba/scan``
+(the scan's kernels and the decays' sums they read, nothing else),
+``mamba/gate_norm`` (the skip, the gate, the grouped norm) and
+``mamba/out_proj``.
 """
 
 import dataclasses
@@ -98,16 +115,22 @@ from tensorflowonspark_tpu.parallel import ring
 @dataclasses.dataclass(frozen=True)
 class LayerSpec:
     """One decoder layer, ``x + op(norm(x))`` then ``x + ff(norm(x))``: its
-    kinds, and the widths they need."""
+    kinds, and the widths they need.  A layer may be one half alone
+    (``op="none"`` or ``ff="none"``): one norm and one residual add."""
 
     op: str = "attention"          # attention | conv (gated short convolution)
     #                                | mla (latent attention, see below)
+    #                                | mamba2 (state-space scan: Mamba2)
+    #                                | none (a feed-forward layer)
     ff: str = "gelu"               # gelu | switch (top-1, capacity: MoEMlp)
     #                                | swiglu | experts (top-k: TopKExperts)
+    #                                | relu2 (two matrices, relu(.)**2)
+    #                                | none (a mixer layer)
     norm: str = "layernorm"        # layernorm | rmsnorm
     norm_eps: float = 1e-6
     positions: str = "learned"     # learned (a table added to the embedding:
     #                                nothing in the layer) | rope (on q and k)
+    #                                | none (nothing anywhere)
     num_heads: int = 8
     head_dim: int = 64
     # None: fused qkv projection with biases, as many KV heads as query heads
@@ -157,13 +180,26 @@ class LayerSpec:
     selection_bias: bool = True    # an expert_bias leaf in the top-k's choice
     norm_topk: bool = True
     routed_scaling: float = 1.0
-    shared_size: int = 0           # a shared SwiGLU's width beside the
+    shared_size: int = 0           # a shared expert's width beside the
     #                                routed experts; 0 = none
+    # an expert's (and the shared expert's) form: swiglu, three matrices,
+    # (silu(x W_1) * (x W_3)) W_2 | relu2, two, relu(x W_1)**2 W_2
+    expert_act: str = "swiglu"
     capacity_factor: float = 1.25  # ff="switch"
+    # op="mamba2": ssm_heads heads of ssm_head_dim, a state of ssm_state a
+    # head, ssm_groups groups of B and C, conv_kernel taps, chunks of
+    # ssm_chunk positions
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 1
+    ssm_chunk: int = 128
 
     def __post_init__(self):
-        if self.window < 0 or self.window and (self.op == "conv"
-                                               or self.index_topk > 0):
+        if self.op == "none" and self.ff == "none":
+            raise ValueError("a layer with neither op nor ff")
+        if self.window < 0 or self.window and (
+                self.op in ("conv", "mamba2", "none") or self.index_topk > 0):
             raise ValueError(
                 "window={} wants an attention layer without an index over "
                 "the keys (op={!r}, index_topk={})".format(
@@ -439,6 +475,78 @@ def mellum2_spec(config):
                        tied_readout=config.get("tie_word_embeddings", False))
 
 
+def nemotron_h_spec(config):
+    """:class:`DecoderSpec` of a Nemotron-H ``config.json`` (a dict with the
+    source's keys: ``hybrid_override_pattern``, ``mamba_num_heads``,
+    ``mamba_head_dim``, ``ssm_state_size``, ``n_groups``, ``conv_kernel``,
+    ``chunk_size``, ``n_routed_experts``, ``num_experts_per_tok``,
+    ``moe_intermediate_size``, ``moe_shared_expert_intermediate_size``,
+    ``routed_scaling_factor``, ``mlp_hidden_act``, ...).  One character of
+    the pattern a layer, each one part alone: ``M`` Mamba-2, ``E`` experts,
+    ``*`` attention, ``-`` a dense feed-forward of ``intermediate_size``.
+    ``n_routed_experts`` is the router's width; ``held_experts`` (``[first,
+    count]``, optional) the experts this program holds of each expert layer;
+    ``flash_block`` (optional) the attention kernels' block.
+    ``n_groups`` is the scan's (groups of B and C) and ``n_group`` /
+    ``topk_group`` the router's.  What the family's modelling code does and
+    no key says: the inner width is ``mamba_num_heads * mamba_head_dim``
+    (``expand`` is not read), one convolution over x, B and C together, the
+    gate before the grouped norm, no clamp on the step size, ``relu2(x) =
+    relu(x) ** 2``, and **no rotary embedding** in the attention layers
+    (``rope_theta`` and ``partial_rotary_factor`` are not read)."""
+    pattern = config["hybrid_override_pattern"]
+    unsupported = {
+        "hybrid_override_pattern": not set(pattern) <= set("ME*-"),
+        "n_group": config.get("n_group", 1) != 1
+        or config.get("topk_group", 1) != 1,
+        "mlp_hidden_act": config.get("mlp_hidden_act", "relu2") != "relu2",
+        "mamba_hidden_act": config.get("mamba_hidden_act", "silu") != "silu",
+        "bias": any(config.get(k) for k in (
+            "attention_bias", "mlp_bias", "mamba_proj_bias", "use_bias")),
+        "use_conv_bias": not config.get("use_conv_bias", True),
+        "sliding_window": config.get("sliding_window") is not None}
+    if any(unsupported.values()):
+        raise ValueError("nemotron_h: no support for this config's {}".format(
+            sorted(k for k, v in unsupported.items() if v)))
+    if len(pattern) != config["num_hidden_layers"]:
+        raise ValueError(
+            "a hybrid_override_pattern of {} for num_hidden_layers {}".format(
+                len(pattern), config["num_hidden_layers"]))
+    held = config.get("held_experts")
+    eps = config.get("layer_norm_epsilon", config.get("norm_eps", 1e-5))
+    common = dict(
+        norm="rmsnorm", norm_eps=eps, positions="none",
+        num_heads=config["num_attention_heads"], head_dim=config["head_dim"],
+        num_kv_heads=config["num_key_value_heads"],
+        flash_block=config.get("flash_block", 512),
+        conv_kernel=config["conv_kernel"],
+        ssm_heads=config["mamba_num_heads"],
+        ssm_head_dim=config["mamba_head_dim"],
+        ssm_state=config["ssm_state_size"], ssm_groups=config["n_groups"],
+        ssm_chunk=config["chunk_size"],
+        ff_size=config["intermediate_size"], expert_act="relu2",
+        num_experts=config["n_routed_experts"],
+        experts_per_token=config["num_experts_per_tok"],
+        expert_size=config["moe_intermediate_size"],
+        held_experts=tuple(held) if held else None,
+        router_score="sigmoid", selection_bias=True,
+        norm_topk=config.get("norm_topk_prob", True),
+        routed_scaling=float(config.get("routed_scaling_factor", 1.0)),
+        shared_size=(config.get("n_shared_experts") or 0)
+        * config.get("moe_shared_expert_intermediate_size", 0))
+    kinds = {"M": dict(op="mamba2", ff="none"),
+             "*": dict(op="attention", ff="none"),
+             "E": dict(op="none", ff="experts"),
+             "-": dict(op="none", ff="relu2")}
+    of_kind = {kind: LayerSpec(**kinds[kind], **common)
+               for kind in set(pattern)}
+    return DecoderSpec(vocab_size=config["vocab_size"],
+                       hidden_size=config["hidden_size"],
+                       layers=tuple(of_kind[kind] for kind in pattern),
+                       norm="rmsnorm", norm_eps=eps,
+                       tied_readout=config.get("tie_word_embeddings", False))
+
+
 def _norm(kind, eps, dtype):
     if kind == "rmsnorm":
         return nn.RMSNorm(epsilon=eps, dtype=dtype)
@@ -708,6 +816,15 @@ class Attention(nn.Module):
                         name="proj")(out)
 
 
+def _causal_taps(z, taps):
+    """``c_t = sum_j taps[j] * z_{t-j}`` a channel of ``z [B, S, C]``
+    (depthwise, causal, zeros before the sequence), ``taps [kernel, C]``."""
+    kernel, seq = taps.shape[0], z.shape[1]
+    padded = jnp.pad(z, ((0, 0), (kernel - 1, 0), (0, 0)))
+    return sum(taps[j] * padded[:, kernel - 1 - j:kernel - 1 - j + seq]
+               for j in range(kernel))
+
+
 class ShortConv(nn.Module):
     """Gated short convolution (the LFM2 family's token mixer): ``[B, C, u]
     = split3(x W_in)``, ``z = B * u``, ``c_t = sum_j w_j * z_{t-j}``
@@ -719,19 +836,117 @@ class ShortConv(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        d, seq = x.shape[-1], x.shape[1]
+        d = x.shape[-1]
         gate_b, gate_c, u = jnp.split(
             nn.Dense(3 * d, use_bias=False, dtype=self.dtype,
                      name="in_proj")(x), 3, axis=-1)
         taps = self.param("conv", nn.initializers.normal(0.02),
                           (self.kernel, d)).astype(self.dtype)
-        z = gate_b * u
-        padded = jnp.pad(z, ((0, 0), (self.kernel - 1, 0), (0, 0)))
-        conv = sum(taps[j] * padded[:, self.kernel - 1 - j:
-                                    self.kernel - 1 - j + seq]
-                   for j in range(self.kernel))
         return nn.Dense(d, use_bias=False, dtype=self.dtype,
-                        name="out_proj")(gate_c * conv)
+                        name="out_proj")(
+                            gate_c * _causal_taps(gate_b * u, taps))
+
+
+def _inverse_softplus_steps(low=0.001, high=0.1, floor=1e-4):
+    """Initialiser of ``dt_bias``: the inverse softplus of a step size drawn
+    log-uniformly in ``[low, high]`` and floored (Mamba-2's)."""
+    import math
+
+    def init(key, shape, dtype=jnp.float32):
+        step = jnp.maximum(jnp.exp(jax.random.uniform(
+            key, shape, jnp.float32, math.log(low), math.log(high))), floor)
+        return (step + jnp.log(-jnp.expm1(-step))).astype(dtype)
+
+    return init
+
+
+class Mamba2(nn.Module):
+    """A Mamba-2 mixer (state-space duality, arXiv:2405.21060): ``[z | xBC |
+    dt] = u W_in`` (``heads * head_dim | heads * head_dim + 2 groups state |
+    heads``, no bias); ``xBC = silu(conv(xBC) + b)``, the convolution
+    depthwise and causal, ``conv_kernel`` taps a channel (``c_t = sum_j w_j
+    z_{t-j}``, zeros before the row), over x, B and C together; ``dt =
+    softplus(dt + dt_bias)``, ``A = -exp(A_log)`` a head; the scan ``S_t =
+    exp(dt_t A) S_{t-1} + dt_t x_t (x) B_t``, ``y_t = S_t C_t + D x_t``
+    (:func:`~tensorflowonspark_tpu.ops.ssd_scan.ssd_scan`, chunks of
+    ``chunk``; head ``h`` reads group ``h // (heads / groups)``); ``y =
+    RMSNorm_group(y * silu(z)) * w`` over groups of ``heads * head_dim /
+    groups``; ``y W_out``.  Products in ``dtype``; the step sizes, ``A``,
+    the decays, the carried state and the norm's statistics float32;
+    ``A_log``, ``dt_bias`` and ``D`` float32 leaves.
+
+    The chunks scanned and the bytes of the chunk states written are sown
+    under ``intermediates/ssd_counts``."""
+
+    heads: int
+    head_dim: int
+    state: int
+    groups: int = 1
+    conv_kernel: int = 4
+    chunk: int = 128
+    norm_eps: float = 1e-5
+    dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, u):
+        from tensorflowonspark_tpu.ops import ssd_scan as ssd
+
+        batch, seq, d_model = u.shape
+        f32 = jnp.float32
+        inner, bc = self.heads * self.head_dim, self.groups * self.state
+        z, xbc, dt = jnp.split(
+            nn.Dense(2 * inner + 2 * bc + self.heads, use_bias=False,
+                     dtype=self.dtype, name="in_proj")(u),
+            [inner, 2 * inner + 2 * bc], axis=-1)
+        taps = self.param("conv", nn.initializers.normal(0.02),
+                          (self.conv_kernel, inner + 2 * bc))
+        conv_bias = self.param("conv_bias", nn.initializers.zeros,
+                               (inner + 2 * bc,))
+        a_log = self.param(
+            "A_log", lambda key, shape: jnp.log(jax.random.uniform(
+                key, shape, f32, 1.0, 16.0)), (self.heads,))
+        dt_bias = self.param("dt_bias", _inverse_softplus_steps(),
+                             (self.heads,))
+        skip = self.param("D", nn.initializers.ones, (self.heads,))
+        scale = self.param("norm", nn.initializers.ones, (inner,))
+        with jax.named_scope("conv"):
+            xbc = nn.silu(conv_bias.astype(self.dtype)
+                          + _causal_taps(xbc, taps.astype(self.dtype)))
+        x, b, c = jnp.split(xbc, [inner, inner + bc], axis=-1)
+        x = x.reshape(batch, seq, self.heads, self.head_dim)
+        b = b.reshape(batch, seq, self.groups, self.state)
+        c = c.reshape(batch, seq, self.groups, self.state)
+        dt = jax.nn.softplus(dt.astype(f32) + dt_bias.astype(f32))
+        with jax.named_scope("scan"):
+            y = ssd.ssd_scan(x, dt, dt * -jnp.exp(a_log.astype(f32)), b, c,
+                             chunk=self.chunk)
+        chunks, state_bytes = ssd.chunk_counts(x, b, self.chunk)
+        self.sow("intermediates", "ssd_counts", {
+            "chunks": jnp.asarray(chunks, jnp.int32),
+            "state_bytes": jnp.asarray(state_bytes, f32)})
+        with jax.named_scope("gate_norm"):
+            y = y.astype(f32) + skip.astype(f32)[:, None] * x.astype(f32)
+            y = y.reshape(batch, seq, inner) * nn.silu(z.astype(f32))
+            by_group = y.reshape(batch, seq, self.groups, inner // self.groups)
+            y = (by_group * jax.lax.rsqrt(
+                jnp.square(by_group).mean(-1, keepdims=True) + self.norm_eps)
+                 ).reshape(batch, seq, inner) * scale.astype(f32)
+        return nn.Dense(d_model, use_bias=False, dtype=self.dtype,
+                        name="out_proj")(y.astype(self.dtype))
+
+
+class Relu2(nn.Module):
+    """``relu(x W_1) ** 2 W_2``, no biases, no gate."""
+
+    hidden: int
+    dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        dense = lambda n, name: nn.Dense(  # noqa: E731
+            n, use_bias=False, dtype=self.dtype, name=name)
+        return dense(x.shape[-1], "w2")(
+            jnp.square(nn.relu(dense(self.hidden, "w1")(x))))
 
 
 class SwiGLU(nn.Module):
@@ -748,16 +963,22 @@ class SwiGLU(nn.Module):
             nn.silu(dense(self.hidden, "w1")(x)) * dense(self.hidden, "w3")(x))
 
 
+_FEED_FORWARD = {"swiglu": SwiGLU, "relu2": Relu2}
+
+
 class TopKExperts(nn.Module):
-    """Top-k mixture of SwiGLU experts without dropped tokens
+    """Top-k mixture of experts without dropped tokens
     (:func:`~tensorflowonspark_tpu.parallel.ep.route_topk`,
     :func:`~tensorflowonspark_tpu.parallel.ep.experts_ffn`): the router
     scores all ``num_experts`` by ``score`` (a sigmoid each, or a softmax
     over them all), an ``expert_bias`` leaf (there only where
     ``selection_bias``) enters the choice of the ``experts_per_token`` only,
     the chosen scores are renormalised where ``norm_topk`` and scaled.
-    ``shared`` is the width of a shared SwiGLU (flax name ``shared``; 0:
+    ``shared`` is the width of a shared expert (flax name ``shared``; 0:
     none) that every token passes through, added to the routed sum.
+    ``act`` is the form of an expert and of the shared one: ``"swiglu"``,
+    three matrices ``(silu(x W_1) * (x W_3)) W_2``, or ``"relu2"``, two,
+    ``relu(x W_1) ** 2 W_2`` (no ``w3`` leaf).
 
     ``held = (first, count)`` says which of the router's experts this layer
     holds (``w1``/``w3 [count, D, F]``, ``w2 [count, F, D]``; None: all).  It
@@ -781,6 +1002,7 @@ class TopKExperts(nn.Module):
     score: str = "sigmoid"
     selection_bias: bool = True
     shared: int = 0
+    act: str = "swiglu"
     dtype: jnp.dtype = jnp.float32
 
     @nn.compact
@@ -794,7 +1016,8 @@ class TopKExperts(nn.Module):
         bias = self.param("expert_bias", nn.initializers.zeros,
                           (self.num_experts,)) if self.selection_bias else None
         w1 = self.param("w1", init, (count, d_model, self.hidden))
-        w3 = self.param("w3", init, (count, d_model, self.hidden))
+        w3 = self.param("w3", init, (count, d_model, self.hidden)) \
+            if self.act == "swiglu" else None
         w2 = self.param("w2", init, (count, self.hidden, d_model))
         tokens = x.reshape(batch * seq, d_model)
         with jax.named_scope("route"):
@@ -803,12 +1026,14 @@ class TopKExperts(nn.Module):
                 norm_topk=self.norm_topk, scaling=self.routed_scaling,
                 score=self.score)
         y, load = ep_mod.experts_ffn(tokens, sel, weights, w1, w3, w2, first,
-                                     dtype=self.dtype)
+                                     dtype=self.dtype, act=self.act)
         self.sow("intermediates", "moe_counts", load)
         y = y.reshape(batch, seq, d_model)
         if self.shared:
-            y = y + SwiGLU(self.shared, self.dtype, name="shared")(x)
+            y = y + _FEED_FORWARD[self.act](self.shared, self.dtype,
+                                            name="shared")(x)
         return y
+
 
 
 class _RouterParams(nn.Module):
@@ -971,50 +1196,65 @@ class Block(nn.Module):
             self.num_heads, self.head_dim, mlp=self.mlp,
             mlp_ratio=self.mlp_ratio, num_experts=self.num_experts,
             capacity_factor=self.capacity_factor)
-        h = _norm(spec.norm, spec.norm_eps, self.dtype)(x)
+        if spec.op != "none":
+            x = x + self._op(spec, _norm(spec.norm, spec.norm_eps,
+                                         self.dtype)(x))
+        if spec.ff != "none":
+            x = x + self._ff(spec, _norm(spec.norm, spec.norm_eps,
+                                         self.dtype)(x))
+        return x
+
+    @nn.nowrap
+    def _op(self, spec, h):
         if spec.op == "conv":
-            h = ShortConv(spec.conv_kernel, self.dtype, name="short_conv")(h)
-        else:
-            # the fused form keeps flax's own name (Attention_0: checkpoints
-            # of the GPT-2 decoder), the grouped-query and latent forms are
-            # "attention"
-            latent = spec if spec.op == "mla" else None
-            h = Attention(
-                spec.num_heads, spec.head_dim, self.attention, self.mesh,
-                self.dtype, num_kv_heads=spec.num_kv_heads,
-                qk_norm=spec.qk_norm, norm_eps=spec.norm_eps,
-                rope_theta=(spec.rope_theta if spec.positions == "rope"
-                            else None),
-                rope_yarn=spec.rope_yarn, flash_block=spec.flash_block,
-                window=spec.window, latent=latent,
-                index_heads=spec.index_heads, index_dim=spec.index_dim,
-                index_topk=spec.index_topk,
-                name=None if spec.num_kv_heads is None and not latent
-                else "attention")(h)
-        x = x + h
-        h = _norm(spec.norm, spec.norm_eps, self.dtype)(x)
+            return ShortConv(spec.conv_kernel, self.dtype,
+                             name="short_conv")(h)
+        if spec.op == "mamba2":
+            return Mamba2(spec.ssm_heads, spec.ssm_head_dim, spec.ssm_state,
+                          spec.ssm_groups, spec.conv_kernel, spec.ssm_chunk,
+                          spec.norm_eps, self.dtype, name="mamba")(h)
+        # the fused form keeps flax's own name (Attention_0: checkpoints
+        # of the GPT-2 decoder), the grouped-query and latent forms are
+        # "attention"
+        latent = spec if spec.op == "mla" else None
+        return Attention(
+            spec.num_heads, spec.head_dim, self.attention, self.mesh,
+            self.dtype, num_kv_heads=spec.num_kv_heads,
+            qk_norm=spec.qk_norm, norm_eps=spec.norm_eps,
+            rope_theta=(spec.rope_theta if spec.positions == "rope"
+                        else None),
+            rope_yarn=spec.rope_yarn, flash_block=spec.flash_block,
+            window=spec.window, latent=latent,
+            index_heads=spec.index_heads, index_dim=spec.index_dim,
+            index_topk=spec.index_topk,
+            name=None if spec.num_kv_heads is None and not latent
+            else "attention")(h)
+
+    @nn.nowrap
+    def _ff(self, spec, h):
         if spec.ff == "switch":
-            h = MoEMlp(num_experts=spec.num_experts,
-                       mlp_ratio=spec.ff_size // x.shape[-1],
-                       capacity_factor=spec.capacity_factor,
-                       ep_mode=self.ep_mode, mesh=self.mesh,
-                       ep_batch_axes=self.ep_batch_axes,
-                       dtype=self.dtype, name="moe")(h)
-        elif spec.ff == "experts":
-            h = TopKExperts(
+            return MoEMlp(num_experts=spec.num_experts,
+                          mlp_ratio=spec.ff_size // h.shape[-1],
+                          capacity_factor=spec.capacity_factor,
+                          ep_mode=self.ep_mode, mesh=self.mesh,
+                          ep_batch_axes=self.ep_batch_axes,
+                          dtype=self.dtype, name="moe")(h)
+        if spec.ff == "experts":
+            return TopKExperts(
                 num_experts=spec.num_experts,
                 experts_per_token=spec.experts_per_token,
                 hidden=spec.expert_size, held=spec.held_experts,
                 norm_topk=spec.norm_topk, routed_scaling=spec.routed_scaling,
                 score=spec.router_score, selection_bias=spec.selection_bias,
-                shared=spec.shared_size, dtype=self.dtype, name="moe")(h)
-        elif spec.ff == "swiglu":
-            h = SwiGLU(spec.ff_size, self.dtype, name="mlp")(h)
-        else:
-            h = nn.Dense(spec.ff_size, dtype=self.dtype)(h)
-            h = nn.gelu(h)
-            h = nn.Dense(x.shape[-1], dtype=self.dtype)(h)
-        return x + h
+                shared=spec.shared_size, act=spec.expert_act,
+                dtype=self.dtype, name="moe")(h)
+        if spec.ff in _FEED_FORWARD:
+            return _FEED_FORWARD[spec.ff](spec.ff_size, self.dtype,
+                                          name="mlp")(h)
+        d_model = h.shape[-1]
+        h = nn.Dense(spec.ff_size, dtype=self.dtype)(h)
+        h = nn.gelu(h)
+        return nn.Dense(d_model, dtype=self.dtype)(h)
 
 
 class TransformerLM(nn.Module):
@@ -1061,15 +1301,18 @@ class TransformerLM(nn.Module):
         # kernel's output and logsumexp rows (B S H dv elements of the
         # compute dtype and B H S float32 a layer), and an indexed layer's
         # key bits and index logsumexp (S S / 8 bytes and S float32 a batch
-        # row).  Everything else in the block is recomputed.
+        # row), and a Mamba-2 layer's scan output and chunk states (B S H P
+        # elements of the compute dtype each, at chunks as long as the state
+        # is wide; its projections and convolution are made again).
+        # Everything else in the block is recomputed.
         block_cls = Block
         if self.remat:
-            from tensorflowonspark_tpu.ops import sparse_index
+            from tensorflowonspark_tpu.ops import sparse_index, ssd_scan
             from tensorflowonspark_tpu.ops.flash_attention import KEPT
 
             block_cls = nn.remat(
                 Block, policy=jax.checkpoint_policies.save_only_these_names(
-                    *KEPT, *sparse_index.KEPT))
+                    *KEPT, *sparse_index.KEPT, *ssd_scan.KEPT))
         for i, layer in enumerate(spec.layers):
             x = block_cls(attention=self.attention, ep_mode=self.ep_mode,
                           mesh=self.mesh, ep_batch_axes=self.ep_batch_axes,
@@ -1142,6 +1385,18 @@ def build_mellum2(config, attention="flash", mesh=None, remat=False,
     with the band as a mask; the sequence-parallel contractions refuse a
     window."""
     return TransformerLM(spec=mellum2_spec(config), attention=attention,
+                         mesh=mesh, remat=remat, dtype=jnp.dtype(dtype))
+
+
+@register_model("nemotron_h")
+def build_nemotron_h(config, attention="flash", mesh=None, remat=False,
+                     dtype="float32"):
+    """The one decoder under a Nemotron-H ``config.json`` (see
+    :func:`nemotron_h_spec`): layers that are a Mamba-2 mixer, an expert
+    layer or attention alone.  ``attention`` picks the attention layers'
+    contraction as for ``transformer_lm``; the scan's kernels run on a TPU
+    and its ``jax.numpy`` form elsewhere whatever it says."""
+    return TransformerLM(spec=nemotron_h_spec(config), attention=attention,
                          mesh=mesh, remat=remat, dtype=jnp.dtype(dtype))
 
 
@@ -1230,6 +1485,20 @@ def _sum_flash(tree):
             "flash_tiles_computed": sum(c["tiles_computed"] for c in found)}
 
 
+def _sum_ssd(tree):
+    """What the Mamba-2 layers sowed, under the names of ``train.Trainer``'s
+    counters: ``ssd_chunks`` the chunks their scans took over the rows,
+    ``ssd_state_bytes`` the bytes of the chunk states written (float32: a
+    step's can pass 2**31), ``ssd_layers`` the layer calls; None without such
+    layers."""
+    found = _sown(tree, "ssd_counts")
+    if not found:
+        return None
+    return {"ssd_chunks": sum(c["chunks"] for c in found),
+            "ssd_state_bytes": sum(c["state_bytes"] for c in found),
+            "ssd_layers": jnp.asarray(len(found), jnp.int32)}
+
+
 def loss_fn(model, moe_aux_weight=0.01):
     """Next-token cross-entropy with per-row masking.
 
@@ -1251,7 +1520,9 @@ def loss_fn(model, moe_aux_weight=0.01):
     with a window sow the tiles their kernels visit: ``aux["swa_counts"]``
     (the ``Trainer``'s ``swa_*`` counters), and every layer under
     ``attention="flash"`` its kernels' grid steps beside the tiles that
-    compute: ``aux["flash_counts"]`` (the ``Trainer``'s ``flash_*``).
+    compute: ``aux["flash_counts"]`` (the ``Trainer``'s ``flash_*``); every
+    Mamba-2 layer the chunks of its scan and the bytes of their states:
+    ``aux["ssd_counts"]`` (the ``Trainer``'s ``ssd_*``).
     """
     import optax
 
@@ -1283,6 +1554,9 @@ def loss_fn(model, moe_aux_weight=0.01):
         flash = _sum_flash(sown)
         if flash is not None:
             aux["flash_counts"] = flash
+        ssd = _sum_ssd(sown)
+        if ssd is not None:
+            aux["ssd_counts"] = ssd
         return ce, aux
 
     return loss

@@ -5,7 +5,10 @@ hand-scheduling VMEM traffic beats XLA's fusion — attention first
 (:mod:`~tensorflowonspark_tpu.ops.flash_attention`), then the grouped matrix
 product of an expert layer (:mod:`~tensorflowonspark_tpu.ops.grouped_matmul`)
 and the row movement around it, which stops at the rows routed here
-(:mod:`~tensorflowonspark_tpu.ops.routed_rows`).
+(:mod:`~tensorflowonspark_tpu.ops.routed_rows`), and the chunked
+state-space scan of a Mamba-2 layer with its backward
+(:mod:`~tensorflowonspark_tpu.ops.ssd_scan`; its function is
+``ops.ssd_scan.ssd_scan``: the name here stays the module's).
 Every kernel runs in pallas interpret mode off-TPU, so the suite validates
 them on the CPU mesh.
 """

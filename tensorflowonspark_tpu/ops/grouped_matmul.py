@@ -68,15 +68,51 @@ def _row_tile(m, most=512):
     return tile
 
 
-def _tiling(m, k, n):
-    return _row_tile(m), _tile(k, 1024), _tile(n, 1024)
+# what a kernel's tiles may take of VMEM (operands and result double
+# buffered, the float32 accumulator once): under the 16 MiB a kernel gets
+VMEM_BUDGET = 13 * 2 ** 20
+
+
+def _smaller(size, tile):
+    """The next multiple of 128 under ``tile`` that divides ``size``; None
+    where there is none (``tile`` is the whole of an odd dimension, or 128)."""
+    if tile % 128:
+        return None
+    return next((t for t in range(tile - 128, 0, -128) if size % t == 0),
+                None)
+
+
+def _tiling(m, k, n, itemsize=2, out_rows="m"):
+    """``(tm, tk, tn)``: 512 rows and the largest tiles of ``k`` and ``n`` up
+    to 1,024, shrunk where the kernel's buffers would pass ``VMEM_BUDGET``:
+    a width with no divisor that is a multiple of 128 (1,856) is one tile,
+    and the other dimensions make room for it, ``k`` first (more steps over
+    an accumulator that stays in VMEM), then ``n``, then the rows (the
+    weights are read once more a row tile).  ``out_rows="k"``: the
+    transposed product, ``[tk, tm] @ [tm, tn]`` summed over the rows."""
+    tiles = {"m": _row_tile(m), "k": _tile(k, 1024), "n": _tile(n, 1024)}
+
+    def need(m, k, n):
+        operands = k * m + m * n if out_rows == "k" else m * k + k * n
+        result = (k if out_rows == "k" else m) * n
+        return 2 * itemsize * (operands + result) + 4 * result
+
+    while need(**tiles) > VMEM_BUDGET:
+        smaller = {"k": _smaller(k, tiles["k"]), "n": _smaller(n, tiles["n"]),
+                   "m": tiles["m"] // 2 if tiles["m"] > 128 else None}
+        axis = next((a for a in "knm" if smaller[a]), None)
+        if axis is None:
+            break
+        tiles[axis] = smaller[axis]
+    return tiles["m"], tiles["k"], tiles["n"]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def _gmm(lhs, rhs, group_sizes, interpret):
     m, k = lhs.shape
     return _backend().gmm(lhs, rhs, group_sizes, lhs.dtype,
-                       _tiling(m, k, rhs.shape[2]), interpret=interpret)
+                          _tiling(m, k, rhs.shape[2], lhs.dtype.itemsize),
+                          interpret=interpret)
 
 
 def _gmm_fwd(lhs, rhs, group_sizes, interpret):
@@ -88,11 +124,14 @@ def _gmm_bwd(interpret, residual, grad):
     lhs, rhs, group_sizes = residual
     m, k = lhs.shape
     n = rhs.shape[2]
+    size = lhs.dtype.itemsize
     grad = grad.astype(lhs.dtype)
-    d_lhs = backend.gmm(grad, rhs, group_sizes, lhs.dtype, _tiling(m, n, k),
-                        transpose_rhs=True, interpret=interpret)
+    d_lhs = backend.gmm(grad, rhs, group_sizes, lhs.dtype,
+                        _tiling(m, n, k, size), transpose_rhs=True,
+                        interpret=interpret)
     d_rhs = backend.tgmm(lhs.swapaxes(0, 1), grad, group_sizes, rhs.dtype,
-                         _tiling(m, k, n), interpret=interpret)
+                         _tiling(m, k, n, size, out_rows="k"),
+                         interpret=interpret)
     return d_lhs, d_rhs, None
 
 

@@ -111,17 +111,18 @@ def _lane_row(column):
 
 def _slab(d, dtype):
     """``(sublanes, lanes, packed)`` of one row of ``d`` elements as a slab
-    of 32-bit words: 128 lanes wide where the words divide so (a whole
-    number of the TPU's (8, 128) tiles where ``sublanes`` is a multiple of
-    8), one sublane otherwise (sizes only the interpreter sees).  ``packed``:
-    a 16-bit dtype, two elements a word."""
+    of 32-bit words: 128 lanes wide where the words divide so, or where the
+    elements do (a 16-bit row of an odd number of 128s: the last slab row is
+    half used), one sublane otherwise (sizes only the interpreter sees).
+    ``packed``: a 16-bit dtype, two elements a word, columns ``sublanes *
+    lanes`` apart."""
     packed = jnp.dtype(dtype).itemsize == 2
     if jnp.dtype(dtype).itemsize not in (2, 4) or (packed and d % 2):
         raise ValueError("routed_rows: rows of {} x {} are not 32-bit "
                          "words".format(d, jnp.dtype(dtype).name))
     words = d // 2 if packed else d
-    lanes = 128 if words % 128 == 0 else words
-    return words // lanes, lanes, packed
+    lanes = 128 if d % 128 == 0 else words
+    return -(-words // lanes), lanes, packed
 
 
 def _to_words(lo, hi):
@@ -161,9 +162,11 @@ def _pack_kernel(n_ref, x_ref, out_ref, *, tile, sublanes, lanes, packed):
         for c in range(sublanes):
             cols = slice(c * lanes, (c + 1) * lanes)
             if packed:
+                lo = x_ref[:, cols]
                 words = _to_words(
-                    x_ref[:, cols],
-                    x_ref[:, slice(half + cols.start, half + cols.stop)])
+                    lo, x_ref[:, slice(half + cols.start, half + cols.stop)]
+                    if half + cols.start < x_ref.shape[1]
+                    else jnp.zeros_like(lo))
             else:
                 words = x_ref[:, cols]
             out_ref[pl.ds(c, tile, stride=sublanes), :] = words
@@ -248,9 +251,9 @@ def _wait_rows(source, buf, sem, sublanes, count):
           lambda _: _row_copy(source, 0, buf, 0, sem, sublanes).wait())
 
 
-def _chunks(buf, first, tile, sublanes, lanes, packed, dtype):
+def _chunks(buf, first, tile, sublanes, lanes, packed, dtype, d):
     """The ``tile`` slabs of ``buf`` from slab ``first`` on as ``(columns,
-    float32 [tile, lanes])`` pieces of the rows ``[tile, D]`` they hold: the
+    float32 [tile, lanes])`` pieces of the rows ``[tile, d]`` they hold: the
     inverse of :func:`_pack_kernel`, a slab row at a time."""
     pl, _ = _pallas()
     half = sublanes * lanes
@@ -260,7 +263,8 @@ def _chunks(buf, first, tile, sublanes, lanes, packed, dtype):
         if packed:
             lo, hi = _from_words(words, dtype)
             yield cols, lo
-            yield slice(half + cols.start, half + cols.stop), hi
+            if half + cols.start < d:
+                yield slice(half + cols.start, half + cols.stop), hi
         else:
             yield cols, words.astype(jnp.float32)
 
@@ -290,7 +294,7 @@ def _gather_kernel(n_ref, src_ref, *refs, tile, offset, sublanes, lanes,
         scale = _column(scale_ref[...]) if scaled else None
         dots = jnp.zeros((tile, 1), jnp.float32)
         for cols, value in _chunks(buf, 0, tile, sublanes, lanes, packed,
-                                   out_ref.dtype):
+                                   out_ref.dtype, out_ref.shape[1]):
             if dotted:
                 dots += (value * with_ref[:, cols].astype(jnp.float32)).sum(
                     axis=1, keepdims=True)
@@ -396,7 +400,8 @@ def _sum_kernel(counts_ref, rows_ref, slabs_ref, w_ref, source, out_ref, buf,
     # an absent slot's slab holds whatever was there: its weight, 0, selects
     columns = [_column(w_ref[j:j + 1, :]) for j in range(slots)]
     for pieces in zip(*[_chunks(buf, j * tile, tile, sublanes, lanes, packed,
-                                out_ref.dtype) for j in range(slots)]):
+                                out_ref.dtype, out_ref.shape[1])
+                        for j in range(slots)]):
         cols = pieces[0][0]
         acc = jnp.zeros((tile, cols.stop - cols.start), jnp.float32)
         for column, (_, value) in zip(columns, pieces):

@@ -13,7 +13,8 @@ routers of :mod:`~tensorflowonspark_tpu.models.transformer`.
   renormalised over the k chosen, or **by softmax scores without either**
   (the router's description says which), **no capacity and no token dropped
   at any imbalance**.  Dispatch is a sort of the (token, slot) pairs by expert, the
-  experts' SwiGLU products are grouped matrix products
+  experts' products (a SwiGLU's three, or the two of ``relu(.)**2``) are
+  grouped matrix products
   (:mod:`~tensorflowonspark_tpu.ops.grouped_matmul`) over the stacked weights
   of the experts **held here** (a contiguous range of the E the router
   knows), and the pairs whose expert lives elsewhere contribute nothing: the layer returns its own
@@ -237,14 +238,19 @@ def _combine(ys, weights, order, idx, n_local):
     return combine(ys, weights, order, idx, n_local)
 
 
-def experts_ffn(x, sel, weights, w1, w3, w2, first, dtype=None):
-    """The held experts' part of a top-k SwiGLU expert layer.
+def experts_ffn(x, sel, weights, w1, w3, w2, first, dtype=None,
+                act="swiglu"):
+    """The held experts' part of a top-k expert layer.
 
     ``x [T, D]`` tokens, ``sel``/``weights [T, k]`` from :func:`route_topk`,
     ``w1``/``w3 [H, D, F]`` and ``w2 [H, F, D]`` the stacked weights of the
     ``H`` experts held here, experts ``first .. first + H - 1`` of the
-    router's.  Returns ``(y [T, D], load)``: ``y = sum over the token's
-    slots whose expert is held of weight * SwiGLU_e(x)``, and ``load``, the
+    router's.  ``act="swiglu"``: an expert is ``(silu(x W_1) * (x W_3))
+    W_2``; ``"relu2"``: two matrices, ``relu(x W_1) ** 2 W_2``, and ``w3``
+    is None (two grouped products for three; the row movement, the sort and
+    the products themselves are the same).  Returns ``(y [T, D], load)``:
+    ``y = sum over the token's slots whose expert is held of weight *
+    expert_e(x)``, and ``load``, the
     token-slot counts of this call (int32 scalars ``slots_total``,
     ``slots_local``, ``expert_load_max``; float32 ``expert_load_mean``).
 
@@ -275,9 +281,15 @@ def experts_ffn(x, sel, weights, w1, w3, w2, first, dtype=None):
     idx = inverse.reshape(tokens, k)
     with jax.named_scope("dispatch"):
         xs = _dispatch(x.astype(dtype), order // k, idx, n_local)
+    if act not in ("swiglu", "relu2"):
+        raise ValueError("unknown expert form {!r}".format(act))
     with jax.named_scope("experts"):
-        h = jax.nn.silu(grouped_matmul(xs, w1.astype(dtype), group_sizes))
-        h = h * grouped_matmul(xs, w3.astype(dtype), group_sizes)
+        h = grouped_matmul(xs, w1.astype(dtype), group_sizes)
+        if act == "relu2":
+            h = jnp.square(jax.nn.relu(h))
+        else:
+            h = jax.nn.silu(h) * grouped_matmul(xs, w3.astype(dtype),
+                                                group_sizes)
         ys = grouped_matmul(h, w2.astype(dtype), group_sizes)
     with jax.named_scope("combine"):
         y = _combine(ys, weights, order, idx, n_local)

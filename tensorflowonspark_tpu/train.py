@@ -429,12 +429,14 @@ class Trainer(object):
     def _note_moe(self, aux):
         if not isinstance(aux, dict):
             return
-        # the index over the keys (dsa_*), the window layers (swa_*) and
-        # the flash kernels' grids (flash_*) count the same way
+        # the index over the keys (dsa_*), the window layers (swa_*), the
+        # flash kernels' grids (flash_*) and the state-space scans (ssd_*)
+        # count the same way
         counts = dict(aux.get("moe_counts") or {},
                       **(aux.get("dsa_counts") or {}),
                       **(aux.get("swa_counts") or {}),
-                      **(aux.get("flash_counts") or {}))
+                      **(aux.get("flash_counts") or {}),
+                      **(aux.get("ssd_counts") or {}))
         if not counts:
             return
         with self._moe_lock:
@@ -510,6 +512,11 @@ class Trainer(object):
         of 512, and 1.97 for the square grid over such rows, which a layer
         takes whose list SMEM would not hold).
 
+        The state-space scans, for a model with Mamba-2 layers (the same
+        way): ``ssd_chunks`` the chunks scanned (layers x rows x positions /
+        chunk a step), ``ssd_state_bytes`` the bytes of the chunk states the
+        forward kernels wrote, ``ssd_layers`` the layer calls counted.
+
         ``train_recompiles_total``: dispatches that made a step program
         executable under a name (``step``, ``multi_<k>``) that had run
         before: a batch of another shape, a state laid out anew.  Which
@@ -567,7 +574,8 @@ class Trainer(object):
             snap["train_recompiles_total"] = self._recompiles
         if self._moe_pending or self._moe_totals:
             self._fold_moe()
-            snap.update(self._moe_totals)   # moe_*, dsa_*, swa_*, flash_*
+            # moe_*, dsa_*, swa_*, flash_*, ssd_*
+            snap.update(self._moe_totals)
         return snap
 
     def counters_snapshot(self):

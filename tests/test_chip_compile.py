@@ -24,6 +24,7 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,  # noqa: E402
 
 fa = importlib.import_module("tensorflowonspark_tpu.ops.flash_attention")
 si = importlib.import_module("tensorflowonspark_tpu.ops.sparse_index")
+ssd = importlib.import_module("tensorflowonspark_tpu.ops.ssd_scan")
 
 
 @pytest.fixture(scope="module")
@@ -212,7 +213,7 @@ def test_sharded_flash_compiles_for_v5e_2x2(topo, monkeypatch):
     assert "tpu_custom_call" in text and "all-to-all" in text
 
 
-def _compiled_step(topo, monkeypatch, family, config_name):
+def _compiled_step(topo, monkeypatch, family, config_name, **overrides):
     """The whole training step of a benchmark configuration of a
     ``TransformerLM`` family (``benchmark/configs/<config_name>.json``: its
     widths, batch and rows, bf16 compute, remat per block, Adam) compiled
@@ -232,6 +233,7 @@ def _compiled_step(topo, monkeypatch, family, config_name):
 
     monkeypatch.setattr(fa, "_default_interpret", lambda: False)
     monkeypatch.setattr(si, "_default_interpret", lambda: False)
+    monkeypatch.setattr(ssd, "_default_impl", lambda: "pallas")
     monkeypatch.setattr(
         importlib.import_module("tensorflowonspark_tpu.ops.grouped_matmul"),
         "_default_impl", lambda: "pallas")
@@ -240,7 +242,7 @@ def _compiled_step(topo, monkeypatch, family, config_name):
         "_default_impl", lambda: ("pallas", False))
     with open(os.path.join(root, "benchmark", "configs",
                            config_name + ".json")) as f:
-        cfg = json.load(f)
+        cfg = dict(json.load(f), **overrides)
     config = adapter.program_config(cfg)
     model = get_model(family, config=config, attention=cfg["attention"],
                       remat=cfg["remat"], dtype=cfg["dtype"])
@@ -515,6 +517,67 @@ def test_mellum2_step_compiles_and_fits_v5e(topo, monkeypatch):
     assert not _one_lane_arrays("\n".join(
         line for line in calls if "/attention/flash" in line))
     assert sum("/moe/experts/" in line for line in calls) == 48
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bfloat16", "float32"])
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+def test_ssd_scan_kernels_compile_for_v5e(topo, direction, dtype):
+    """The chunked state-space scan at the benchmark's shape (2 rows of
+    8,192 positions, 64 heads of 64 in 8 groups, a state of 128, chunks of
+    128): the forward kernel with its carried state in VMEM and the reversed
+    backward kernel, two heads to a 128-lane slab, every slice on a tile."""
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def arg(shape, dt=dtype):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one)
+
+    x, dt = arg((2, 8192, 64, 64)), arg((2, 8192, 64), jnp.float32)
+    b = arg((2, 8192, 8, 128))
+
+    def scan(*operands):
+        return ssd.ssd_scan(*operands, chunk=128, impl="pallas")
+
+    if direction == "forward":
+        text = _compile(scan, x, dt, dt, b, b)
+    else:
+        text = _compile(
+            jax.grad(lambda *a: scan(*a).astype(jnp.float32).sum(),
+                     argnums=(0, 1, 2, 3, 4)), x, dt, dt, b, b)
+    calls = _kernel_lines(text)
+    assert len(calls) == (1 if direction == "forward" else 2)
+    # neither [T, T] nor a state a position
+    assert "8192,8192" not in text and "8192,64,64,128" not in text
+
+
+def test_nemotron3_nano_step_compiles_and_fits_v5e(topo, monkeypatch):
+    """The whole training step of ``nemotron3_nano_30b_a3b_ep16`` (published
+    widths; layers 35 to 43 of the pattern, ``MEMEMEM*E``: four Mamba-2
+    layers under the chunked scan, four expert layers of two-matrix relu2
+    experts, 8 of 128 held, beside a shared one, one attention layer of 32 /
+    2 heads without positions; an untied read-out over 16,384 rows; rows of
+    8,192 and the batch the file says) compiles for one described v5e chip
+    and fits its 15.75 GiB by XLA's memory analysis, which it may not
+    outgrow: 14.53 GiB at batch 3 (15.60 GB: 8.00 GB of parameters and Adam's
+    moments as arguments, 7.60 GB temporaries, gradients among them; batch 4
+    is refused at 16.29 GiB, batch 2 takes 13.31).  The scan kernels are in it once forward and once
+    backward a layer: the checkpoint keeps their output and chunk states,
+    so the recomputed pass holds none; hidden rows of 2,688 (1,344 words of
+    bfloat16, ten slab rows and a half) pass through the expert layer's row
+    movement, and an expert's width of 1,856, which no multiple of 128
+    divides, is one tile of the grouped products."""
+    compiled, parameters, needed = _compiled_step(
+        topo, monkeypatch, "nemotron_h", "nemotron3_nano_30b_a3b_ep16")
+    assert parameters == 666_963_456
+    assert needed <= 14.6 * 2 ** 30, needed
+    text = compiled.as_text()
+    assert "8192,8192" not in text
+    calls = _kernel_calls(compiled)
+    assert sum("/mamba/scan/" in line for line in calls) == 8
+    assert sum("/attention/flash/" in line for line in calls) == 3
+    # 4 expert layers x 2 grouped products x (forward, recomputed forward,
+    # two gradients)
+    assert sum("/moe/experts/" in line for line in calls) == 32
 
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],

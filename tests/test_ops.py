@@ -950,6 +950,42 @@ def test_gather_sum_rows_takes_a_slot_count_that_is_no_power_of_two(
                                rtol=1e-5)
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_routed_rows_take_rows_of_an_odd_number_of_lane_tiles(row_kernels,
+                                                              dtype):
+    """Rows of 384 (2,688 in small): 192 words of bfloat16 are a slab row and
+    a half, so the first 256 columns go to the low halves, the other 128 to
+    the high halves of the first slab row, and the second row's high halves
+    stay unused; gather (scaled, with its dot products) and gather-and-sum
+    against XLA's take."""
+    d, n = 384, 100
+    ks = jax.random.split(jax.random.PRNGKey(4), 6)
+    x = jax.random.normal(ks[0], (ROWS_T, d)).astype(dtype)
+    ys = jax.random.normal(ks[1], (ROWS_P, d)).astype(dtype)
+    src = jax.random.randint(ks[2], (ROWS_P,), 0, ROWS_T)
+    scale = jax.random.uniform(ks[3], (ROWS_P,))
+    idx = jax.random.permutation(ks[4], ROWS_P).reshape(ROWS_T, ROWS_K)
+    weights = jax.random.uniform(ks[5], (ROWS_T, ROWS_K))
+    assert row_kernels._slab(d, dtype)[:2] == (
+        (2, 128) if dtype == jnp.bfloat16 else (3, 128))
+    got, dots = row_kernels.gather_rows(x, src, n, scale=scale, dot_with=ys)
+    want, want_dots = row_kernels.gather_rows(x, src, n, scale=scale,
+                                              dot_with=ys, impl="xla")
+    np.testing.assert_array_equal(np.asarray(got[:n], np.float32),
+                                  np.asarray(want[:n], np.float32))
+    np.testing.assert_allclose(np.asarray(dots[:n]),
+                               np.asarray(want_dots[:n]), rtol=1e-5,
+                               atol=1e-5)
+    tol = 1e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(
+        np.asarray(row_kernels.gather_sum_rows(ys, idx, n, weights=weights),
+                   np.float32),
+        np.asarray(row_kernels.gather_sum_rows(ys, idx, n, weights=weights,
+                                               impl="xla"), np.float32),
+        atol=tol, rtol=tol)
+
+
 def test_routed_rows_refuse_an_unknown_impl():
     from tensorflowonspark_tpu.ops import gather_rows, gather_sum_rows
 

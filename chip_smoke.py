@@ -14,7 +14,8 @@ width of the models the repo supports, and checks what comes out:
 3. ``flash``   the transformer LM (8 layers, d_model 1024, 16x64 heads, seq
                1024, vocab 32,000, batch 8, bf16) with ``attention="flash"``:
                the kernel against a reference on a small input, the compiled
-               step's text, and the loss against ``attention="full"``.
+               step's text, and the loss against ``attention="full"`` held
+               to the plain contraction (on a TPU it takes the kernels too).
 4. ``serve``   the ResNet export behind one ``inference_cli --serve`` replica,
                answering ``gateway.ServingClient`` requests of mixed batch size.
 5. ``direct``  the same inputs through ``serving.ModelServer(...).predict_feed``
@@ -526,18 +527,32 @@ def flash_main(args, ctx):
         ctx.initialize_distributed()
         mesh = mesh_mod.build_mesh()
         report["kernel_parity"] = _kernel_parity(args.seed)
+        # the reference side is the plain contraction: on a TPU "full" takes
+        # the kernels too wherever the row tiles (the one rule of
+        # ops/flash_attention.py), which would hold kernels against kernels
+        import importlib
+
+        importlib.import_module(
+            "tensorflowonspark_tpu.ops.flash_attention"
+        ).full_attention_block = lambda q, k, v, mesh=None: None
         for attention in ("flash", "full"):
             trainer, batch, mask = _lm_trainer(args, mesh, attention)
-            if attention == "flash" and args.platform == "tpu":
-                # compiled, by the step's own text: not by trust in a default
+            if args.platform == "tpu":
+                # compiled or plain, by the step's own text: not by trust in
+                # a default or in the steering above
                 calls = _step_text(trainer, batch, mask).count(
                     "tpu_custom_call")
-                report["tpu_custom_calls"] = calls
-                if calls < 3 * args.layers:
+                if attention == "flash":
+                    report["tpu_custom_calls"] = calls
+                    right = calls >= 3 * args.layers
+                else:
+                    right = calls == 0
+                if not right:
                     raise SmokeError(
-                        "the compiled step holds {} tpu_custom_call, "
-                        "expected {} (3 kernels a layer)".format(
-                            calls, 3 * args.layers))
+                        "the compiled {} step holds {} tpu_custom_call: "
+                        "flash wants {} (3 kernels a layer), full none (the "
+                        "plain contraction)".format(attention, calls,
+                                                    3 * args.layers))
             losses, first, rate = _lm_run(trainer, batch, mask, args.steps)
             report[attention] = {"losses": losses, "first_step_secs": first,
                                  "steps_per_sec": rate}

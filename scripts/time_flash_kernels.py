@@ -4,6 +4,12 @@ microseconds a computed tile, with where XLA placed each operand.
     chiprun -- python3 scripts/time_flash_kernels.py --heads 32 --kv-heads 4 \\
         --rows 32768 --dk 128 --dv 128 --block 512 [--window 1024] [--key-bits]
 
+GPT-2 medium's layer under ``attention="full"`` (batch 4 x 16 heads of 64 over
+1,024 rows; the rule of ``full_attention_block`` gives it blocks of 512):
+
+    chiprun -- python3 scripts/time_flash_kernels.py --heads 64 --kv-heads 64 \\
+        --rows 1024 --dk 64 --dv 64 --block 512
+
 One jitted kernel a time (forward, dQ, dK/dV as ``ops/flash_attention.py``
 launches them, ``[heads, rows, width]`` bfloat16), ``--calls`` calls by the
 host's clock between two ``block_until_ready``.  A kernel timed alone is

@@ -3,10 +3,19 @@
 The reference framework predates attention entirely (SURVEY §5.7); this model
 is the long-context showcase of the TPU-native design: the same module runs
 
-- ``attention="full"``     — plain causal attention (single device / small S),
+- ``attention="full"``     — exact attention on one device (the default):
+  the flash kernels where the process holds a TPU and the row tiles (by
+  512, 256 or 128: ``ops.flash_attention.full_attention_block``, which
+  chooses the block from the row; on a mesh, where the kernels' per-shard
+  mapping fits), the plain contraction elsewhere (every CPU process, a row
+  of 64 or 1,000 tokens, a sequence-parallel mesh).  The same mathematics
+  either way; which one a layer took is said by the presence of
+  ``aux["flash_counts"]``,
 - ``attention="flash"``    — the pallas FlashAttention-2 kernels
-  (:mod:`tensorflowonspark_tpu.ops.flash_attention`): memory-linear in S,
-  hand-scheduled VMEM traffic on TPU, interpret mode elsewhere,
+  (:mod:`tensorflowonspark_tpu.ops.flash_attention`) always, at the
+  layer's ``flash_block``: memory-linear in S, hand-scheduled VMEM traffic
+  on TPU, interpret mode elsewhere, a ``ValueError`` that names the shapes
+  where the row does not tile,
 - ``attention="ring"``     — ring attention over the mesh's ``"seq"`` axis
   (sequence parallelism; see :mod:`tensorflowonspark_tpu.parallel.ring`),
 - ``attention="ulysses"``  — all-to-all head-parallel attention.
@@ -162,7 +171,9 @@ class LayerSpec:
     index_heads: int = 0
     index_dim: int = 0
     index_topk: int = 0
-    flash_block: int = 128         # q and k block of attention="flash"
+    # q and k block of attention="flash" ("full" reads its block from the
+    # row where it takes the kernels: ops.flash_attention.row_block)
+    flash_block: int = 128
     # a query reads its last ``window`` keys, its own among them (t - s <
     # window); 0 = every causal key, and then nothing of a layer changes.
     # One that covers the row is every causal key too
@@ -694,15 +705,15 @@ class Attention(nn.Module):
         return q, k, kv[..., nope:]
 
     @nn.nowrap
-    def _sow_grid(self, x, window=None):
+    def _sow_grid(self, x, block, window=None):
         """Sow what the flash kernels' forward grid takes for this call's
-        rows and heads (``flash_counts``): its steps, and the tiles among
-        them that compute (``ops.flash_attention.grid_tiles``; known when
-        the step is traced)."""
+        rows and heads at blocks of ``block`` (``flash_counts``): its steps,
+        and the tiles among them that compute
+        (``ops.flash_attention.grid_tiles``; known when the step is
+        traced)."""
         from tensorflowonspark_tpu.ops.flash_attention import grid_tiles
 
-        steps, computed = grid_tiles(x.shape[1], self.flash_block,
-                                     self.flash_block, window=window)
+        steps, computed = grid_tiles(x.shape[1], block, block, window=window)
         heads = x.shape[0] * self.num_heads
         self.sow("intermediates", "flash_counts", {
             "grid_steps": jnp.asarray(heads * steps, jnp.int32),
@@ -735,7 +746,7 @@ class Attention(nn.Module):
             out, lse = flash_attention_lse(
                 q, k, v, causal=True, block_q=block, block_k=block,
                 key_bits=bits)
-        self._sow_grid(x)
+        self._sow_grid(x, block)
         with jax.named_scope("index_loss"):
             loss = sparse_index.index_loss(iq, ik, iw, q, k, lse, index_lse,
                                            bits, block=block)
@@ -775,21 +786,30 @@ class Attention(nn.Module):
                                            self.rope_yarn)
             q, k = rope(q, inv, factor=factor), rope(k, inv, factor=factor)
         window = self.window or None
+        # the kernels' block: under "flash" the one the layer states; under
+        # "full" the one the row gives, where the kernels serve (None: the
+        # plain contraction)
+        block = None
+        if self.attention == "flash":
+            block = self.flash_block
+        elif self.attention == "full":
+            from tensorflowonspark_tpu.ops.flash_attention import (
+                full_attention_block)
+
+            block = full_attention_block(q, k, v, self.mesh)
         if self.index_heads:
             out = self._indexed(x, q, k, v)
-        elif self.attention == "flash":
+        elif block:
             from tensorflowonspark_tpu.ops import flash_attention
             from tensorflowonspark_tpu.ops.flash_attention import band_tiles
 
             with jax.named_scope("flash_window" if window else "flash"):
                 out = flash_attention(q, k, v, causal=True, mesh=self.mesh,
-                                      block_q=self.flash_block,
-                                      block_k=self.flash_block, scale=scale,
-                                      window=window)
-            self._sow_grid(x, window)
+                                      block_q=block, block_k=block,
+                                      scale=scale, window=window)
+            self._sow_grid(x, block, window)
             if window:      # what the band leaves of the causal tiles
-                computed, causal = band_tiles(x.shape[1], self.flash_block,
-                                              window)
+                computed, causal = band_tiles(x.shape[1], block, window)
                 self.sow("intermediates", "swa_counts", {
                     "tiles_computed": jnp.asarray(x.shape[0] * computed,
                                                   jnp.int32),
@@ -1474,10 +1494,12 @@ def _sum_swa(tree):
 
 
 def _sum_flash(tree):
-    """What the layers under ``attention="flash"`` sowed, under the names of
-    ``train.Trainer``'s counters: ``flash_grid_steps`` the steps of their
-    forward kernels' grids over the rows and heads, ``flash_tiles_computed``
-    the tiles among them that compute; None without such layers."""
+    """What the layers that took the flash kernels sowed (every layer under
+    ``attention="flash"``; under ``"full"`` those whose rows the kernels
+    serve), under the names of ``train.Trainer``'s counters:
+    ``flash_grid_steps`` the steps of their forward kernels' grids over the
+    rows and heads, ``flash_tiles_computed`` the tiles among them that
+    compute; None without such layers."""
     found = _sown(tree, "flash_counts")
     if not found:
         return None
@@ -1518,9 +1540,12 @@ def loss_fn(model, moe_aux_weight=0.01):
     ``aux["dsa_index_loss"]``, and with the tiles the picks touch goes out
     as ``aux["dsa_counts"]`` (the ``Trainer``'s ``dsa_*`` counters).  Layers
     with a window sow the tiles their kernels visit: ``aux["swa_counts"]``
-    (the ``Trainer``'s ``swa_*`` counters), and every layer under
-    ``attention="flash"`` its kernels' grid steps beside the tiles that
-    compute: ``aux["flash_counts"]`` (the ``Trainer``'s ``flash_*``); every
+    (the ``Trainer``'s ``swa_*`` counters), and every layer that takes the
+    flash kernels (under ``attention="flash"``, and under ``"full"`` where
+    the rule of ``ops.flash_attention.full_attention_block`` holds) its
+    kernels' grid steps beside the tiles that compute:
+    ``aux["flash_counts"]`` (the ``Trainer``'s ``flash_*``; present exactly
+    when some layer ran the kernels); every
     Mamba-2 layer the chunks of its scan and the bytes of their states:
     ``aux["ssd_counts"]`` (the ``Trainer``'s ``ssd_*``).
     """

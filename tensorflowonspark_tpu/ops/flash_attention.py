@@ -123,6 +123,16 @@ reads and the recomputed pass holds no forward kernel.  A name put on the
 op's result outside the rule would name a copy.  Without a policy a name is
 the identity.
 
+Who takes the kernels: ``TransformerLM(attention="flash")`` always, at the
+layer's block; ``attention="full"``, the default, by one rule
+(:func:`full_attention_block`): where they would run compiled and the row
+tiles, at the block the row gives (:func:`row_block`), else the plain
+contraction of :func:`~tensorflowonspark_tpu.parallel.ring
+.reference_attention`, which writes its ``[batch, heads, seq, seq]`` float32
+scores to HBM (GPT-2 medium at batch 4: 268 MB a layer, about ten passes over
+it forward and backward).  The three launchers are jitted functions
+(``_one_trace``), so the like layers of a model share one trace of each.
+
 Layout contract: ``[batch, seq, heads, dim]`` like
 :mod:`~tensorflowonspark_tpu.parallel.ring`; blocks default to 128 (MXU
 tile) and the sequence length must divide by the block size: a
@@ -479,6 +489,20 @@ def _row_step(steps_ref, block_q, block_k, n_k, window, listed):
     return qi, kk, step, n_k - 1
 
 
+# A launcher under ``jax.jit``, everything but its arrays static (``bits`` is
+# an operand or None): the like layers of a model share one trace and one
+# lowered function of each kernel, where a launcher called bare is traced and
+# lowered again in every layer (GPT-2 medium's 72 kernels: 13 s of a warm
+# set-up's tracing and lowering on the chip's host against 1.5 s; PERF.md,
+# PR 44).  XLA inlines the calls, so the compiled step is the same; a module
+# constant a launcher reads (``LISTED_STEPS``) is part of no trace's key.
+# Below the ``custom_vjp`` and below ``checkpoint_name``: the names a
+# checkpoint policy keeps stay on the kernel's own results.
+_one_trace = functools.partial(
+    jax.jit, static_argnames=("scale", "causal", "block_q", "block_k",
+                              "interpret", "group", "window"))
+
+
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
@@ -561,6 +585,7 @@ def _bits_spec(rows, block_q, block_k, q_block, k_block):
                              q_block(*ids), 0))
 
 
+@_one_trace
 def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret, group=1,
                bits=None, window=None):
     """Returns ``(out [bh, seq, dv], logsumexp [bh, 1, seq])``; ``q`` is
@@ -713,6 +738,7 @@ def _bwd_delta(out, g):
         -1)[:, None, :]
 
 
+@_one_trace
 def _flash_bwd_dq(q, k, v, g, lse, delta, scale, causal, block_q, block_k,
                   interpret, group=1, bits=None, window=None):
     from jax.experimental.pallas import tpu as pltpu
@@ -753,6 +779,7 @@ def _flash_bwd_dq(q, k, v, g, lse, delta, scale, causal, block_q, block_k,
         interpret=interpret, args=args)
 
 
+@_one_trace
 def _flash_bwd_dkv(q, k, v, g, lse, delta, scale, causal, block_q, block_k,
                    interpret, group=1, bits=None, window=None):
     from jax.experimental.pallas import tpu as pltpu
@@ -890,6 +917,49 @@ def _checked(q, k, v, scale, interpret):
                                  k.shape, v.shape))
     return (1.0 / (dim ** 0.5) if scale is None else scale,
             _default_interpret() if interpret is None else interpret)
+
+
+# the blocks the rule below chooses among, largest first, and the widest
+# ``[block, width]`` operand a kernel has been compiled with for a v5e
+# (tests/test_chip_compile.py)
+RULE_BLOCKS = (512, 256, 128)
+RULE_BLOCK_ELEMENTS = 512 * 256
+
+
+def row_block(seq, width):
+    """The q and k block that exact attention over rows of ``seq`` positions
+    takes where nobody states one: the largest of ``RULE_BLOCKS`` that
+    divides the row and whose ``[block, width]`` operands (``width`` the
+    wider of q/k's and v's) the kernels have been compiled with; None where
+    there is none (a row of 64 or 1,000 positions).  From the shapes alone."""
+    return next((block for block in RULE_BLOCKS
+                 if seq % block == 0
+                 and block * width <= RULE_BLOCK_ELEMENTS), None)
+
+
+def full_attention_block(q, k, v, mesh=None):
+    """The one rule by which ``attention="full"`` takes these kernels: the
+    block to run them with over ``q, k [batch, seq, heads, dk]`` and ``v [..,
+    dv]`` (:func:`row_block`), or None where the plain contraction runs.
+
+    The kernels where they would run compiled (the process's device is a
+    TPU: :func:`_default_interpret`) and the row tiles; on a mesh of more
+    than one device also only where :func:`flash_attention`'s per-shard
+    mapping fits as it is: the batch divides over ``data``/``fsdp``, both head
+    counts over ``tensor``, and no other axis (``seq``, ``expert``) has more
+    than one device.  Anywhere else (every CPU process, a row that does not
+    tile, a sequence-parallel mesh) the contraction that XLA partitions by
+    itself, never an error."""
+    if _default_interpret():
+        return None
+    if mesh is not None and mesh.size > 1:
+        sizes = dict(mesh.shape)
+        tensor = sizes.pop("tensor", 1)
+        rows = sizes.pop("data", 1) * sizes.pop("fsdp", 1)
+        if (any(size > 1 for size in sizes.values()) or q.shape[0] % rows
+                or q.shape[2] % tensor or k.shape[2] % tensor):
+            return None
+    return row_block(q.shape[1], max(q.shape[3], v.shape[3]))
 
 
 def flash_attention(q, k, v, causal=True, block_q=128, block_k=128,

@@ -132,6 +132,30 @@ def test_flash_kernel_compiles_for_v5e(topo, kernel, shape):
     assert not _one_lane_arrays("\n".join(_kernel_lines(text)))
 
 
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bfloat16", "float32"])
+@pytest.mark.parametrize("width", [256, 512, 1024])
+def test_the_rules_widest_operands_compile_for_v5e(topo, width, dtype):
+    """The block ``row_block`` gives a row of 1,024 at the widest heads it
+    serves (512 at 256, 256 at 512, 128 at 1,024: ``RULE_BLOCK_ELEMENTS``),
+    forward and backward, in bfloat16 and in float32 (the default dtype of
+    ``build_transformer``): what the rule hands the kernels, the chip's
+    compiler takes."""
+    block = fa.row_block(1024, width)
+    assert block * width == fa.RULE_BLOCK_ELEMENTS
+    assert fa.row_block(1024, 2 * width) == (block // 2 if block > 128
+                                             else None)
+    x = jax.ShapeDtypeStruct((2, 1024, 4, width), dtype,
+                             sharding=SingleDeviceSharding(topo.devices[0]))
+
+    def loss(q, k, v):
+        return fa.flash_attention(q, k, v, block_q=block, block_k=block,
+                                  interpret=False).astype(jnp.float32).sum()
+
+    text = _compile(jax.grad(loss, (0, 1, 2)), x, x, x)
+    assert text.count("tpu_custom_call") == 3
+
+
 def test_lm_block_with_flash_compiles_for_v5e(topo, monkeypatch):
     """One transformer block at chip_smoke's LM widths (d_model 1024, 16x64
     heads, seq 1024, batch 8, bf16), forward and backward: the kernel in
@@ -213,24 +237,57 @@ def test_sharded_flash_compiles_for_v5e_2x2(topo, monkeypatch):
     assert "tpu_custom_call" in text and "all-to-all" in text
 
 
-def _compiled_step(topo, monkeypatch, family, config_name, **overrides):
-    """The whole training step of a benchmark configuration of a
-    ``TransformerLM`` family (``benchmark/configs/<config_name>.json``: its
-    widths, batch and rows, bf16 compute, remat per block, Adam) compiled
-    for one described v5e chip with every pallas kernel of the program in
-    it: ``(compiled, parameter count, XLA's memory analysis in bytes:
-    arguments + outputs - aliased + temporaries)``."""
+@pytest.mark.parametrize("axes, kernels", [
+    ({"data": 2, "tensor": 2}, True), ({"data": 2, "seq": 2}, False)],
+    ids=["data2_tensor2", "data2_seq2"])
+def test_full_attention_on_a_mesh_compiles_for_v5e_2x2(topo, monkeypatch,
+                                                       axes, kernels):
+    """A GPT-2 block under ``attention="full"`` across the four chips,
+    forward and backward: where the kernels' per-shard mapping fits (batch
+    over ``data``, heads over ``tensor``) the rule hands it the three
+    kernels; on a mesh with a ``seq`` axis the plain contraction stays under
+    GSPMD, which compiles as it did."""
+    from tensorflowonspark_tpu.models import transformer
+
+    monkeypatch.setattr(fa, "_default_interpret", lambda: False)
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), tuple(axes))
+    block = transformer.Block(num_heads=16, head_dim=64, mesh=mesh,
+                              dtype=jnp.bfloat16)
+    x = jax.ShapeDtypeStruct(
+        (8, 1024, 1024), jnp.bfloat16,
+        sharding=NamedSharding(mesh, P("data", None, None)))
+    shapes = jax.eval_shape(
+        transformer.Block(num_heads=16, head_dim=64, dtype=jnp.bfloat16).init,
+        jax.random.PRNGKey(0), jnp.zeros((1, 128, 1024), jnp.bfloat16))
+    params = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                       sharding=NamedSharding(mesh, P())),
+        shapes)
+
+    def loss(p, x):
+        return block.apply(p, x).astype(jnp.float32).sum()
+
+    text = _compile(jax.grad(loss), params, x)
+    assert text.count("tpu_custom_call") == (3 if kernels else 0)
+
+
+def _benchmark_config(config_name, **overrides):
+    """``benchmark/configs/<config_name>.json`` as a dict, with the checkout
+    on the path for ``benchmark.adapters``."""
     import json
     import sys
-
-    import optax
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     if root not in sys.path:
         sys.path.insert(0, root)
-    adapter = importlib.import_module("benchmark.adapters." + family)
-    from tensorflowonspark_tpu.models import get_model, transformer
+    with open(os.path.join(root, "benchmark", "configs",
+                           config_name + ".json")) as f:
+        return dict(json.load(f), **overrides)
 
+
+def _steer_to_kernels(monkeypatch):
+    """Every op that picks its implementation from the process's platform
+    takes the one it takes on a TPU."""
     monkeypatch.setattr(fa, "_default_interpret", lambda: False)
     monkeypatch.setattr(si, "_default_interpret", lambda: False)
     monkeypatch.setattr(ssd, "_default_impl", lambda: "pallas")
@@ -240,12 +297,17 @@ def _compiled_step(topo, monkeypatch, family, config_name, **overrides):
     monkeypatch.setattr(
         importlib.import_module("tensorflowonspark_tpu.ops.routed_rows"),
         "_default_impl", lambda: ("pallas", False))
-    with open(os.path.join(root, "benchmark", "configs",
-                           config_name + ".json")) as f:
-        cfg = dict(json.load(f), **overrides)
-    config = adapter.program_config(cfg)
-    model = get_model(family, config=config, attention=cfg["attention"],
-                      remat=cfg["remat"], dtype=cfg["dtype"])
+
+
+def _lowered_step(topo, model, cfg, seq):
+    """The whole training step of ``model`` (the loss of
+    ``transformer.loss_fn``, Adam at the configuration's learning rate, the
+    configuration's batch of rows of ``seq`` tokens) lowered for one
+    described v5e chip: ``(lowered, parameter count)``."""
+    import optax
+
+    from tensorflowonspark_tpu.models import transformer
+
     # parameters never depend on the row's length: shape them on a short one
     shapes = jax.eval_shape(
         model.init, jax.random.PRNGKey(0),
@@ -268,16 +330,40 @@ def _compiled_step(topo, monkeypatch, family, config_name, **overrides):
             tree)
 
     batch = cfg["batch_size"]
-    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
+    lowered = jax.jit(step, donate_argnums=(0, 1)).lower(
         described(shapes), described(jax.eval_shape(optimizer.init, shapes)),
-        {"tokens": jax.ShapeDtypeStruct((batch, cfg["seq_len"]), jnp.int32,
+        {"tokens": jax.ShapeDtypeStruct((batch, seq), jnp.int32,
                                         sharding=one)},
-        jax.ShapeDtypeStruct((batch,), jnp.float32, sharding=one)).compile()
+        jax.ShapeDtypeStruct((batch,), jnp.float32, sharding=one))
+    return lowered, sum(x.size for x in jax.tree_util.tree_leaves(shapes))
+
+
+def _needed(compiled):
+    """XLA's memory analysis of a compiled program in bytes: arguments +
+    outputs - aliased + temporaries."""
     memory = compiled.memory_analysis()
-    needed = (memory.argument_size_in_bytes + memory.output_size_in_bytes
-              - memory.alias_size_in_bytes + memory.temp_size_in_bytes)
-    return (compiled,
-            sum(x.size for x in jax.tree_util.tree_leaves(shapes)), needed)
+    return (memory.argument_size_in_bytes + memory.output_size_in_bytes
+            - memory.alias_size_in_bytes + memory.temp_size_in_bytes)
+
+
+def _compiled_step(topo, monkeypatch, family, config_name, **overrides):
+    """The whole training step of a benchmark configuration of a
+    ``TransformerLM`` family (``benchmark/configs/<config_name>.json``: its
+    widths, batch and rows, bf16 compute, remat per block, Adam) compiled
+    for one described v5e chip with every pallas kernel of the program in
+    it: ``(compiled, parameter count, XLA's memory analysis in bytes:
+    arguments + outputs - aliased + temporaries)``."""
+    from tensorflowonspark_tpu.models import get_model
+
+    cfg = _benchmark_config(config_name, **overrides)
+    adapter = importlib.import_module("benchmark.adapters." + family)
+    _steer_to_kernels(monkeypatch)
+    model = get_model(family, config=adapter.program_config(cfg),
+                      attention=cfg["attention"], remat=cfg["remat"],
+                      dtype=cfg["dtype"])
+    lowered, parameters = _lowered_step(topo, model, cfg, cfg["seq_len"])
+    compiled = lowered.compile()
+    return compiled, parameters, _needed(compiled)
 
 
 def _kernel_calls(compiled):
@@ -286,6 +372,44 @@ def _kernel_calls(compiled):
     # pallas kernel that carries its scope
     assert "ragged-dot" not in text
     return _kernel_lines(text)
+
+
+def test_gpt2_medium_step_compiles_and_fits_v5e(topo, monkeypatch):
+    """The whole training step of ``gpt2_medium`` as the benchmark's cell 2
+    builds it (``attention="full"``, the default; 24 like layers, 16 heads
+    of 64, batch 4 of 1,024 tokens, bf16 compute, no remat, Adam) compiled
+    for one described v5e chip: under the rule of
+    ``flash_attention.full_attention_block`` every layer takes the three
+    flash kernels at blocks of 512 (72 calls, all under
+    ``block_i/Attention_0/flash``), no float32 ``[4, 16, 1024, 1024]`` score
+    tensor is left anywhere in the program (the plain contraction's step
+    mentions it 2,232 times and needs 8.38 GiB of temporaries), the 24
+    layers share one lowered function of each kernel (three calls in the
+    StableHLO where a launcher called bare lowers 72), and XLA's memory
+    analysis of it (arguments + outputs - aliased + temporaries) may not
+    outgrow the 8.16 GiB it is with Adam's state (8.77 GB, PR 44; 12.1 GB
+    with the scores in HBM, the configuration's ``assumed.batch_size``)."""
+    from tensorflowonspark_tpu.models import transformer
+
+    cfg = _benchmark_config("gpt2_medium")
+    _steer_to_kernels(monkeypatch)
+    model = transformer.build_transformer(
+        vocab_size=cfg["vocab_size"], num_layers=cfg["n_layer"],
+        num_heads=cfg["n_head"], head_dim=cfg["n_embd"] // cfg["n_head"],
+        max_seq_len=cfg["n_positions"], attention=cfg["attention"],
+        dtype=cfg["dtype"])
+    assert cfg["attention"] == "full"
+    lowered, parameters = _lowered_step(topo, model, cfg, cfg["n_positions"])
+    assert parameters == 354_823_168
+    assert lowered.as_text().count("tpu_custom_call") == 3
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    calls = _kernel_lines(text)
+    assert len(calls) == 72
+    assert sum("/Attention_0/flash/" in line for line in calls) == 72
+    assert not _one_lane_arrays("\n".join(calls))
+    assert "f32[4,16,1024,1024]" not in text
+    assert _needed(compiled) <= 8.25 * 2 ** 30, _needed(compiled)
 
 
 def test_lfm2_moe_step_compiles_and_fits_v5e(topo, monkeypatch):

@@ -589,14 +589,21 @@ def test_a_list_smem_would_not_hold_is_the_clamped_rectangle(
     want, grids = results()
     assert grids == sorted(["{}, {}".format(heads, tiles)] * 2 + [
         "{}, {}".format(heads // group, group * tiles)])
+    # the launchers are jitted: a trace made under one LISTED_STEPS (a
+    # constant outside this test) would serve the other
     monkeypatch.setattr(fa, "LISTED_STEPS", longest)
-    got, grids = results()
+    jax.clear_caches()
+    try:
+        got, grids = results()
+        assert fa.grid_tiles(seq, block, block) == (
+            (n * n if listed == "none" else tiles), tiles)
+    finally:
+        monkeypatch.undo()
+        jax.clear_caches()
     square = "{}, {}, {}".format(heads, n, n)
     assert grids == sorted(
         ([square] if listed == "none" else ["{}, {}".format(heads, tiles)])
         * 2 + ["{}, {}, {}".format(heads // group, n, group * n)])
-    assert fa.grid_tiles(seq, block, block) == (
-        (n * n if listed == "none" else tiles), tiles)
     for a, b in zip(got, want):
         np.testing.assert_array_equal(np.asarray(a, np.float32),
                                       np.asarray(b, np.float32))
@@ -741,6 +748,147 @@ def test_interpret_default_follows_the_platform(monkeypatch, platform,
     Device.platform = platform
     monkeypatch.setattr(jax, "devices", lambda *a: [Device()])
     assert fa._default_interpret() is interpret
+
+
+class _Shape:
+    """Stands in for an array where only ``shape`` is read."""
+
+    def __init__(self, *shape):
+        self.shape = shape
+
+
+@pytest.mark.parametrize("platform, seq, width, block", [
+    ("tpu", 1024, 64, 512), ("tpu", 8192, 192, 512), ("tpu", 32768, 128, 512),
+    ("tpu", 768, 64, 256), ("tpu", 384, 64, 128), ("tpu", 1000, 64, None),
+    ("tpu", 64, 64, None), ("tpu", 1024, 512, 256), ("tpu", 1024, 2048, None),
+    ("cpu", 1024, 64, None), ("cpu", 384, 64, None), ("cpu", 64, 64, None)])
+def test_full_attention_takes_the_kernels_where_a_row_tiles(
+        monkeypatch, platform, seq, width, block):
+    """The one rule: on a TPU the largest of 512, 256, 128 that divides the
+    row (and whose operands the kernels were compiled with); no block, so
+    the plain contraction, for a row that does not tile and for every row
+    off the TPU."""
+    import importlib
+
+    fa = importlib.import_module("tensorflowonspark_tpu.ops.flash_attention")
+    monkeypatch.setattr(fa, "_default_interpret", lambda: platform != "tpu")
+    q = _Shape(4, seq, 16, width)
+    assert fa.full_attention_block(q, q, _Shape(4, seq, 16, 64)) == block
+
+
+@pytest.mark.parametrize("axes, batch, heads, kv_heads, block", [
+    ({"data": 4}, 8, 16, 16, 512), ({"data": 2, "tensor": 2}, 8, 16, 4, 512),
+    ({"fsdp": 2, "tensor": 2}, 8, 16, 2, 512),
+    ({"data": 4}, 6, 16, 16, None),               # the batch does not divide
+    ({"data": 2, "tensor": 2}, 8, 16, 1, None),   # nor one KV head over two
+    ({"data": 2, "tensor": 2}, 8, 3, 3, None),
+    ({"data": 2, "seq": 2}, 8, 16, 16, None),     # sequence parallel: GSPMD
+    ({"data": 2, "expert": 2}, 8, 16, 16, None),
+    ({"data": 1, "seq": 1}, 3, 5, 5, 512)])       # one device: no mapping
+def test_full_attention_on_a_mesh_asks_what_the_mapping_asks(
+        monkeypatch, axes, batch, heads, kv_heads, block):
+    """On a mesh of more than one device the rule also asks what
+    ``flash_attention(mesh=)`` maps by: batch over data/fsdp, both head
+    counts over tensor, no other axis in use.  Never an error."""
+    import importlib
+
+    fa = importlib.import_module("tensorflowonspark_tpu.ops.flash_attention")
+    monkeypatch.setattr(fa, "_default_interpret", lambda: False)
+
+    class MeshLike:
+        shape = axes
+        size = int(np.prod(list(axes.values())))
+
+    q, k = _Shape(batch, 1024, heads, 64), _Shape(batch, 1024, kv_heads, 64)
+    assert fa.full_attention_block(q, k, k, MeshLike()) == block
+
+
+def _decoder(layer, mesh=None):
+    from tensorflowonspark_tpu.models import transformer
+
+    spec = transformer.DecoderSpec(
+        vocab_size=48, hidden_size=32, layers=(layer,) * 2,
+        learned_positions=384 if layer.positions == "learned" else 0,
+        norm=layer.norm)
+    return transformer.TransformerLM(spec=spec, mesh=mesh)   # "full"
+
+
+def _full_attention_layers():
+    from tensorflowonspark_tpu.models import transformer
+
+    grouped = dict(norm="rmsnorm", positions="rope", num_heads=4, head_dim=8,
+                   num_kv_heads=2, qk_norm=True, ff="swiglu", ff_size=64)
+    return {
+        "gpt2": transformer.gpt2_layer(4, 8),
+        "grouped_kv": transformer.LayerSpec(**grouped),
+        "window": transformer.LayerSpec(window=100, **grouped),
+        "latent": transformer.LayerSpec(
+            op="mla", norm="rmsnorm", positions="rope", num_heads=2,
+            head_dim=24, kv_rank=16, nope_dim=16, rope_dim=8, v_dim=12,
+            rope_pairing="interleaved", attn_scale=0.17, ff="swiglu",
+            ff_size=64),
+    }
+
+
+@pytest.mark.parametrize("form", ["gpt2", "grouped_kv", "window", "latent",
+                                  "gpt2_on_a_mesh"])
+def test_full_attention_through_the_kernels_is_the_plain_contraction(
+        monkeypatch, form):
+    """``attention="full"`` with the rule steered on (the kernels in
+    interpret mode, blocks of 128 over rows of 384: six tiles a head)
+    against the plain contraction: the loss and every gradient leaf, for the
+    fused GPT-2 form, grouped KV heads (handed over unrepeated), a window and
+    the latent form with its scale and its two widths, and the GPT-2 form
+    on a mesh (the kernels mapped per shard: batch over ``data``, heads over
+    ``tensor``); ``flash_counts`` (and a window's ``swa_counts``) come out
+    exactly when the kernels ran."""
+    import importlib
+
+    from tensorflowonspark_tpu.models import transformer
+    from tensorflowonspark_tpu.parallel import build_mesh
+
+    fa = importlib.import_module("tensorflowonspark_tpu.ops.flash_attention")
+    mesh = None
+    if form == "gpt2_on_a_mesh":
+        form, mesh = "gpt2", build_mesh({"data": 2, "tensor": 2},
+                                        devices=jax.devices()[:4])
+    model = _decoder(_full_attention_layers()[form], mesh)
+    tokens = jnp.asarray(
+        np.random.RandomState(3).randint(0, 48, (2, 384)), jnp.int32)
+    params = model.init(jax.random.PRNGKey(1), tokens)["params"]
+    loss = jax.value_and_grad(transformer.loss_fn(model), has_aux=True)
+    batch, mask = {"tokens": tokens}, jnp.ones((2,))
+
+    (want, plain_aux), want_grads = loss(params, batch, mask)
+    assert "flash_counts" not in plain_aux and "swa_counts" not in plain_aux
+
+    seen = []
+
+    def steered(q, k, v, mesh=None):
+        assert mesh is model.mesh
+        seen.append((q.shape, k.shape, v.shape))
+        return fa.row_block(q.shape[1], max(q.shape[3], v.shape[3]))
+
+    monkeypatch.setattr(fa, "full_attention_block", steered)
+    (got, aux), grads = loss(params, batch, mask)
+    heads, kv_heads = {"gpt2": (4, 4), "latent": (2, 2)}.get(form, (4, 2))
+    assert seen and all(q[2] == heads and k[2] == kv_heads == v[2]
+                        for q, k, v in seen)
+    # two layers x 2 rows x heads x the six causal tiles of three blocks (a
+    # window of 100 keys keeps five of them, in three runs of two steps)
+    tiles = 5 if form == "window" else 6
+    assert {k: int(v) for k, v in aux["flash_counts"].items()} == {
+        "flash_grid_steps": 2 * 2 * heads * 6,
+        "flash_tiles_computed": 2 * 2 * heads * tiles}
+    assert ("swa_counts" in aux) == (form == "window")
+    np.testing.assert_allclose(float(got), float(want), rtol=2e-5)
+    flat_want = dict(jax.tree_util.tree_leaves_with_path(want_grads))
+    for path, leaf in jax.tree_util.tree_leaves_with_path(grads):
+        ref = np.asarray(flat_want[path])
+        np.testing.assert_allclose(
+            np.asarray(leaf), ref, rtol=2e-3,
+            atol=2e-4 * max(float(np.abs(ref).max()), 1e-6),
+            err_msg=jax.tree_util.keystr(path))
 
 
 @pytest.mark.parametrize("sizes", [[40, 0, 100, 37], [0, 0, 0, 256],

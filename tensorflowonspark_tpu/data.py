@@ -32,7 +32,8 @@ import threading
 
 import numpy as np
 
-from tensorflowonspark_tpu import fsio
+from tensorflowonspark_tpu import fsio, telemetry
+from tensorflowonspark_tpu.datafeed import FEED_PHASES, _buffers_key
 
 logger = logging.getLogger(__name__)
 
@@ -152,6 +153,29 @@ def byte_lm_reader(seq_len, chunk_bytes=1 << 16):
     return reader
 
 
+def _fill(buf, values, asked):
+    """``values``, one a row, as a column: ``buf`` (``[rows, ...]`` of the
+    first row's kind, or of the dtype ``asked``) with each row copied into
+    its place, the only copy of a row; or, where a row is of another kind,
+    what ``np.asarray(values, asked)`` makes of them (python numbers that
+    change kind mid-batch widen, strings lengthen, a row of another shape
+    raises).  Scalars go a column at a time, arrays a row at a time."""
+    if buf.ndim == 1:
+        col = np.asarray(values, dtype=asked)
+        if col.shape != buf.shape or col.dtype != buf.dtype:
+            return col
+        buf[...] = col
+        return buf
+    shape, dtype = buf.shape[1:], buf.dtype
+    for i, v in enumerate(values):
+        if not isinstance(v, np.ndarray):
+            v = np.asarray(v)
+        if v.shape != shape or (asked is None and v.dtype != dtype):
+            return np.asarray(values, dtype=asked)
+        buf[i] = v
+    return buf
+
+
 class FileFeed(object):
     """Streaming columnar batches from record files (FILES mode).
 
@@ -192,6 +216,22 @@ class FileFeed(object):
         self._started = False
         self._threads = []
         self._errors = _queue.Queue()
+        # Batch buffers the caller handed back (see release): the columns
+        # of whole batches, all of one kind (``_free_key``).  Empty for a
+        # caller that never hands back: every batch is then new memory.
+        self._free = []
+        self._free_key = None
+        self.buffers_reused = 0
+        self.buffers_new = 0
+        self.items_consumed = 0
+        # Where the consumer's thread spends its wall time (always on;
+        # ``feed_<phase>_us`` in counters_snapshot), DataFeed's phases:
+        # ``wait`` blocked on the readers' queue, ``assemble`` the rest of
+        # a ``next_batch_arrays`` call (the reservoir, a row's one copy),
+        # ``away`` outside it.  ``read`` stays 0: the readers are threads
+        # of their own.  A caller that drives ``_next_rows`` itself (the
+        # data service's worker) reads no clock.
+        self._clock = telemetry.PhaseClock(FEED_PHASES)
 
     # -- reader side -------------------------------------------------------
 
@@ -258,10 +298,14 @@ class FileFeed(object):
                 return None
             if self._ends >= len(self._threads):
                 break  # every reader already finished (latched)
+            self._clock.switch("wait")
             try:
-                item = self._queue.get(timeout=0.5)
+                with telemetry.annotation("feed/wait"):
+                    item = self._queue.get(timeout=0.5)
             except _queue.Empty:
                 continue
+            finally:
+                self._clock.switch("assemble")
             if item is _END:
                 self._ends += 1
                 if self._ends >= len(self._threads):
@@ -295,22 +339,112 @@ class FileFeed(object):
     def next_batch_arrays(self, batch_size, dtypes=None):
         """Columnar ``(arrays, count)`` — same contract as
         ``DataFeed.next_batch_arrays`` (dict of columns for dict rows,
-        tuple of columns for tuple rows, single array otherwise)."""
-        self._ensure_started()
-        rows = self._pending
-        self._pending = []
-        while len(rows) < batch_size:
-            block = self._next_rows()
-            if block is None:
-                self._done = True
-                break
-            rows.extend(block)
-        if len(rows) > batch_size:
-            self._pending = rows[batch_size:]
-            rows = rows[:batch_size]
-        if not rows:
-            return np.empty((0,)), 0
-        return self._columnar(rows, dtypes), len(rows)
+        tuple of columns for tuple rows, single array otherwise).
+
+        A row is copied once, into its place in the batch's buffers (one
+        array a column, ``[count, ...]``, in the dtype asked for or the
+        rows' own): values, shapes and dtypes are what ``np.asarray`` over
+        the rows gives.
+
+        **Who owns a batch.**  The caller does, for as long as it keeps the
+        arrays: no later call writes to them.  A caller that is done with a
+        whole batch (its host-to-device transfer is over, nothing it keeps
+        refers to the memory) may hand the arrays back with :meth:`release`;
+        a later batch of the same kind is then built in that memory, which
+        is already mapped, instead of in new pages.  Never handing back is
+        fine and costs only that.
+        """
+        self._clock.switch("assemble")
+        try:
+            self._ensure_started()
+            rows = self._pending
+            self._pending = []
+            while len(rows) < batch_size:
+                block = self._next_rows()
+                if block is None:
+                    self._done = True
+                    break
+                rows.extend(block)
+            if len(rows) > batch_size:
+                self._pending = rows[batch_size:]
+                rows = rows[:batch_size]
+            if not rows:
+                return np.empty((0,)), 0
+            self.items_consumed += len(rows)
+            return self._batch(rows, dtypes), len(rows)
+        finally:
+            self._clock.switch("away")
+
+    def _batch(self, rows, dtypes):
+        """``rows`` as columns, what :meth:`_columnar` gives, built in
+        handed-back buffers of that kind if the feed holds any (a partial
+        last batch is of no kind in use: arrays of its own length)."""
+        first = rows[0]
+        names = None
+        if isinstance(first, dict):
+            names = list(first)
+            fields = [[r[k] for r in rows] for k in names]
+            asked = [dtypes.get(k) if dtypes else None for k in names]
+        elif isinstance(first, tuple):
+            arity = len(first)
+            if not arity or any(not isinstance(r, tuple) or len(r) != arity
+                                for r in rows):
+                return self._columnar(rows, dtypes)  # raises, or no fields
+            fields = [[r[f] for r in rows] for f in range(arity)]
+            asked = [dtypes[f] if dtypes else None for f in range(arity)]
+        else:
+            fields, asked = [rows], [dtypes if dtypes else None]
+        heads = [np.asarray(f[0]) for f in fields]
+        key = tuple(((len(rows),) + h.shape,
+                     (h.dtype if d is None else np.dtype(d)).str)
+                    for h, d in zip(heads, asked))
+        if self._free and key == self._free_key:
+            cols = self._free.pop()
+            self.buffers_reused += 1
+        else:
+            cols = [np.empty(shape, dtype) for shape, dtype in key]
+            self.buffers_new += 1
+        cols = [_fill(buf, values, d)
+                for buf, values, d in zip(cols, fields, asked)]
+        if names is not None:
+            return dict(zip(names, cols))
+        return tuple(cols) if isinstance(first, tuple) else cols[0]
+
+    def release(self, arrays):
+        """Hand a batch's arrays back: ``arrays`` as
+        :meth:`next_batch_arrays` returned them, from a caller that will
+        not touch that memory again (see "Who owns a batch" there).  A
+        later batch of the same kind is built in them.  Returns whether the
+        feed took them: only whole batches are taken (not views), only
+        once, and one kind at a time (``DataFeed.release``'s contract)."""
+        if isinstance(arrays, dict):
+            cols = list(arrays.values())
+        else:
+            cols = list(arrays) if isinstance(arrays, tuple) else [arrays]
+        key = _buffers_key(cols)
+        if key is None:
+            return False
+        if key != self._free_key:
+            # one kind at a time: what a changed batch size or row shape
+            # left behind goes, so the list never outgrows its reader
+            self._free, self._free_key = [], key
+        if any(held[0] is cols[0] for held in self._free):
+            return False
+        self._free.append(cols)
+        return True
+
+    def counters_snapshot(self):
+        """Flat telemetry counters, under ``DataFeed``'s names:
+        ``feed_items`` (rows delivered), ``feed_batch_buffers_reused`` /
+        ``feed_batch_buffers_new`` (batches built in handed-back buffers
+        against in new memory) and ``feed_<phase>_us`` for each of
+        ``datafeed.FEED_PHASES`` (they sum to this feed's age).  Safe from
+        any thread at any moment of a running feed."""
+        snap = {"feed_items": self.items_consumed,
+                "feed_batch_buffers_reused": self.buffers_reused,
+                "feed_batch_buffers_new": self.buffers_new}
+        snap.update(self._clock.snapshot("feed_"))
+        return snap
 
     @staticmethod
     def _columnar(rows, dtypes):

@@ -401,8 +401,9 @@ class ShardedFeed(object):
         return batch, mask
 
     def _hand_back(self):
-        """Give the feed its batch buffers back (``DataFeed.release``; a
-        feed without the method keeps today's contract), oldest first, as
+        """Give the feed its batch buffers back (``DataFeed.release``,
+        ``FileFeed.release``; a feed without the method keeps its arrays
+        its own), oldest first, as
         far as nothing on the device side reads them any more: the
         transfer that was made of them is complete, and no device array is
         the host memory itself.  A device with memory of its own copied;
@@ -420,14 +421,21 @@ class ShardedFeed(object):
         while self._lent:
             lent, leaves = self._lent[0]
             try:
-                if not all(leaf.is_ready() for leaf in leaves):
-                    return
-                spans = [(a.ctypes.data, a.ctypes.data + a.nbytes)
-                         for a in jax.tree_util.tree_leaves(lent)]
-                mine = any(
-                    lo <= shard.data.unsafe_buffer_pointer() < hi
-                    for leaf in leaves for shard in leaf.addressable_shards
-                    if shard.device.platform == "cpu" for lo, hi in spans)
+                # a donating step deleted them: it cannot be told (and
+                # is_ready() on a deleted array does not raise, it takes
+                # the process down: jax 0.9.0, CPU client)
+                mine = any(leaf.is_deleted() for leaf in leaves)
+                if not mine:
+                    if not all(leaf.is_ready() for leaf in leaves):
+                        return
+                    spans = [(a.ctypes.data, a.ctypes.data + a.nbytes)
+                             for a in jax.tree_util.tree_leaves(lent)]
+                    mine = any(
+                        lo <= shard.data.unsafe_buffer_pointer() < hi
+                        for leaf in leaves
+                        for shard in leaf.addressable_shards
+                        if shard.device.platform == "cpu"
+                        for lo, hi in spans)
             except Exception:  # noqa: BLE001 — cannot tell
                 mine = True
             self._lent.popleft()

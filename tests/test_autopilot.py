@@ -495,6 +495,12 @@ class TestKnobCoordinator:
 
 
 class TestNodeRegistry:
+    @pytest.fixture(autouse=True)
+    def _registry_of_its_own(self, monkeypatch):
+        # feeds that earlier tests of this process left alive would claim
+        # the knobs too
+        monkeypatch.setattr(node_mod, "_feeds", [])
+
     def test_apply_knobs_duck_types_claimed_names(self):
         class _Feed:
             def __init__(self):

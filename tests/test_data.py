@@ -288,3 +288,235 @@ class TestProcessPoolFeed:
             p.join(timeout=30)
             assert not p.is_alive(), "surviving worker not stopped on error"
         feed.terminate()
+
+
+# -- whose memory a batch is (ISSUE 45) ---------------------------------------
+
+def _kind_rows(kind, n=150):
+    """``n`` rows of one of the row shapes FileFeed takes."""
+    def x(i):
+        return np.full((3, 2), i, np.float32)
+
+    if kind == "dict":
+        return [{"x": x(i), "id": i, "w": i * 0.5} for i in range(n)]
+    if kind == "tuple":
+        return [(x(i), i) for i in range(n)]
+    if kind == "single":
+        return [x(i) for i in range(n)]
+    if kind == "scalar":
+        return list(range(n))
+    if kind == "lists":     # a list row is one vector, not fields
+        return [[float(i), i + 0.5] for i in range(n)]
+    assert kind == "widening"   # python numbers change kind mid-batch
+    return [{"id": i if i % 16 < 11 else i + 0.5,
+             "s": "r" * (1 + i % 7), "x": x(i)} for i in range(n)]
+
+
+def _kind_dtypes(kind):
+    return {"dict": {"x": np.float16, "id": np.int32},
+            "tuple": (np.float64, np.int16), "single": np.float16,
+            "scalar": np.float32, "lists": np.float32,
+            "widening": {"id": np.float32, "x": np.int32}}[kind]
+
+
+def _kind_feed(cls, kind, shuffle, n=150, **kw):
+    files = ["a", "b", "c"]
+
+    def reader(path):       # a closure: the pool's workers get it by value
+        rows = _kind_rows(kind, n)
+        at = files.index(path)
+        return iter(rows[at * n // 3:(at + 1) * n // 3])
+
+    if cls is data_mod.ProcessPoolFeed:
+        kw.update(num_procs=1, block_rows=16)
+    else:
+        kw.update(reader_threads=1)
+    return cls(files, row_reader=reader, shard=False, shuffle_buffer=shuffle,
+               seed=11, **kw)
+
+
+def _parent_batches(feed, batch_size, dtypes):
+    """What the parent's ``next_batch_arrays`` gave, its loop to the letter:
+    the blocks of ``_next_rows`` cut at ``batch_size``, each through
+    ``_columnar``."""
+    feed._ensure_started()
+    rows, out = [], []
+    while True:
+        block = feed._next_rows()
+        rows.extend(block or [])
+        while len(rows) >= batch_size or (block is None and rows):
+            out.append(data_mod.FileFeed._columnar(rows[:batch_size], dtypes))
+            rows = rows[batch_size:]
+        if block is None:
+            return out
+
+
+def _leaves(arrays):
+    if isinstance(arrays, dict):
+        return list(arrays.values())
+    return list(arrays) if isinstance(arrays, tuple) else [arrays]
+
+
+def _same(got, want):
+    assert type(got) is type(want)
+    if isinstance(want, dict):
+        assert list(got) == list(want)
+    assert len(_leaves(got)) == len(_leaves(want))
+    for g, w in zip(_leaves(got), _leaves(want)):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        assert (g == w).all()
+
+
+_POOL = data_mod.ProcessPoolFeed
+_CONTRACT = [(data_mod.FileFeed, kind, cast, shuffle)
+             for kind in ("dict", "tuple", "single", "scalar", "lists",
+                          "widening")
+             for cast in (False, True) for shuffle in (0, 24)]
+_CONTRACT += [(_POOL, "dict", False, 0), (_POOL, "tuple", True, 24)]
+
+
+@pytest.mark.parametrize(
+    "cls,kind,cast,shuffle", _CONTRACT,
+    ids=["{}-{}-{}-shuffle{}".format(c.__name__, k, "cast" if d else "asis", s)
+         for c, k, d, s in _CONTRACT])
+def test_batches_are_what_columnar_gives_in_the_parents_order(
+        cls, kind, cast, shuffle):
+    """Values, shapes, dtypes and row order of every batch are those of the
+    parent's ``_columnar`` over the rows ``_next_rows`` emits for the seed,
+    whether or not buffers go round, and a batch the caller keeps is never
+    written by a later call."""
+    dtypes = _kind_dtypes(kind) if cast else None
+    oracle = _kind_feed(cls, kind, shuffle)
+    want = _parent_batches(oracle, 16, dtypes)
+    oracle.terminate()
+    assert len(want) == 10 and len(_leaves(want[-1])[0]) == 150 - 9 * 16
+    for hand_back in (False, True):
+        feed = _kind_feed(cls, kind, shuffle)
+        kept = []
+        for b, w in enumerate(want):
+            assert not feed.should_stop()
+            arrays, count = feed.next_batch_arrays(16, dtypes)
+            assert count == len(_leaves(w)[0])
+            _same(arrays, w)
+            if hand_back and b % 2:
+                assert feed.release(arrays)
+            else:
+                kept.append((arrays, w))
+        assert feed.should_stop()
+        empty, count = feed.next_batch_arrays(16, dtypes)
+        assert count == 0 and empty.shape == (0,)
+        for arrays, w in kept:      # many calls later
+            _same(arrays, w)
+        snap = feed.counters_snapshot()
+        assert snap["feed_items"] == 150
+        assert snap["feed_batch_buffers_new"] + \
+            snap["feed_batch_buffers_reused"] == 10
+        if not hand_back:
+            assert snap["feed_batch_buffers_reused"] == 0
+        elif kind != "widening":
+            # every second batch went back and held the one after it (a
+            # column that widens or lengthens is new memory every time)
+            assert snap["feed_batch_buffers_reused"] == 4
+        feed.terminate()
+
+
+@pytest.mark.parametrize("cls", [data_mod.FileFeed, _POOL],
+                         ids=["FileFeed", "ProcessPoolFeed"])
+def test_a_released_buffer_is_the_next_batchs_memory(cls):
+    feed = _kind_feed(cls, "dict", 0, n=16 * 7 + 5)
+    kept = [feed.next_batch_arrays(16)[0] for _ in range(2)]   # never back
+    lent = feed.next_batch_arrays(16)[0]
+    where = {k: v.ctypes.data for k, v in lent.items()}
+    for b in range(3, 7):
+        assert feed.release(lent)
+        assert not feed.release(lent)       # a batch goes back once
+        lent, count = feed.next_batch_arrays(16)
+        assert count == 16
+        assert {k: v.ctypes.data for k, v in lent.items()} == where
+        assert lent["id"].tolist() == list(range(16 * b, 16 * b + 16))
+    for b, arrays in enumerate(kept):       # four batches later
+        assert arrays["id"].tolist() == list(range(16 * b, 16 * b + 16))
+        assert arrays["x"][:, 0, 0].tolist() == arrays["id"].tolist()
+    assert feed.release(lent)
+    # the partial last batch: arrays of its own length, in no one's buffers
+    tail, count = feed.next_batch_arrays(16)
+    assert count == 5 and feed.should_stop()
+    assert all(v.base is None and len(v) == 5 for v in tail.values())
+    assert tail["x"].ctypes.data != where["x"]
+    snap = feed.counters_snapshot()
+    assert snap["feed_batch_buffers_new"] == 4      # three kept, the tail
+    assert snap["feed_batch_buffers_reused"] == 4
+    feed.terminate()
+
+
+def test_only_whole_batches_of_the_kind_in_use_are_taken_back():
+    from tensorflowonspark_tpu import datafeed
+
+    feed = _kind_feed(data_mod.FileFeed, "tuple", 0)
+    (x, y), _ = feed.next_batch_arrays(16)
+    assert not feed.release((x[:8], y[:8]))          # views
+    assert not feed.release((x, y[:8].copy()))       # not one batch
+    assert not feed.release(None)
+    assert not feed.release(np.empty((0,)))
+    assert feed.release((x, y))
+    (small, _), count = feed.next_batch_arrays(8)    # another batch size
+    assert count == 8 and not np.shares_memory(small, x)
+    assert len(feed._free) == 1                      # nothing asked for it
+    other = (np.empty((8, 3, 2), np.float32), np.empty(8, np.int64))
+    assert feed.release(other)                       # replaces the old kind
+    assert feed._free_key != datafeed._buffers_key([x, y])
+    assert len(feed._free) == 1
+    (again, _), count = feed.next_batch_arrays(8)
+    assert again is other[0]
+    # another dtype is another kind: new memory, and its hand-back drops
+    # what the list held
+    assert feed.release(other)
+    (cast, _), count = feed.next_batch_arrays(8, (np.float16, np.int64))
+    assert cast.dtype == np.float16 and len(feed._free) == 1
+    assert feed.release((cast, _)) and feed._free[0][0] is cast
+    # and so is another row shape
+    assert feed.release((np.empty((8, 5), np.float16),
+                         np.empty(8, np.int64)))
+    assert len(feed._free) == 1 and feed._free[0][0].shape == (8, 5)
+    (x2, y2), count = feed.next_batch_arrays(8, (np.float16, np.int64))
+    assert x2.shape == (8, 3, 2) and y2.tolist() == list(range(40, 48))
+    feed.terminate()
+
+
+def test_a_row_of_another_shape_raises():
+    def reader(path):
+        for i in range(8):
+            yield {"x": np.zeros((3 if i < 5 else 4,), np.float32)}
+
+    feed = data_mod.FileFeed(["a"], row_reader=reader, shard=False)
+    with pytest.raises(ValueError):
+        feed.next_batch_arrays(8)
+    feed.terminate()
+
+
+def test_the_consumers_phases_sum_to_the_feeds_age():
+    import time
+
+    from tensorflowonspark_tpu import datafeed
+
+    def slow(path):
+        for i in range(32):
+            if i == 16:
+                time.sleep(0.2)     # the consumer waits on the queue here
+            yield (np.full(4, i, np.float32), i)
+
+    born = time.monotonic()
+    feed = data_mod.FileFeed(["a"], row_reader=slow, shard=False)
+    feed.BLOCK = 16
+    for _ in range(2):
+        feed.next_batch_arrays(16)
+        time.sleep(0.05)            # away
+    snap = feed.counters_snapshot()
+    age_us = (time.monotonic() - born) * 1e6
+    phases = [snap["feed_{}_us".format(p)] for p in datafeed.FEED_PHASES]
+    assert abs(sum(phases) - age_us) < 50e3
+    assert snap["feed_read_us"] == 0        # the readers are threads
+    assert snap["feed_wait_us"] >= 100e3
+    assert snap["feed_away_us"] >= 100e3
+    assert snap["feed_items"] == 32
+    feed.terminate()

@@ -543,15 +543,27 @@ def _aliased(device_array, host_arrays):
                for s in device_array.addressable_shards for lo, hi in spans)
 
 
+def _file_feed(rows, **kw):
+    """The same tuple rows under FILES: a FileFeed over one 'file'."""
+    from tensorflowonspark_tpu import data as data_mod
+
+    return data_mod.FileFeed(["rows"], row_reader=lambda path: iter(rows),
+                             shard=False, **kw)
+
+
 @pytest.mark.parametrize("prefetch", [0, 2])
 @pytest.mark.parametrize("transform", ["views", "copies"])
+@pytest.mark.parametrize("source", ["DataFeed", "FileFeed"])
 def test_delivered_batches_keep_their_rows_while_buffers_go_round(
-        mgr, prefetch, transform):
+        mgr, prefetch, transform, source):
     """On the CPU a device array may BE the host buffer: such a buffer is
     never handed back, any other is, and either way a batch delivered
     earlier still holds its own rows many batches later."""
-    _fill(mgr, _rows(8 * 9))
-    feed = DataFeed(mgr)
+    if source == "DataFeed":
+        _fill(mgr, _rows(8 * 9))
+        feed = DataFeed(mgr)
+    else:
+        feed = _file_feed(_rows(8 * 9))
     back = []           # the first column of every batch the feed took back
     release = feed.release
 
@@ -600,6 +612,52 @@ def test_delivered_batches_keep_their_rows_while_buffers_go_round(
         assert len(back) == 9
         assert snap["feed_batch_buffers_new"] == 1
         assert snap["feed_batch_buffers_reused"] == 8
+
+
+def test_a_donated_batch_is_not_handed_back():
+    """A step that donates its batch deletes the device arrays: whether the
+    transfer out of the host buffers is over can no longer be told, and
+    the buffers stay the batch's."""
+    feed = _file_feed(_rows(8 * 4))
+    back = []
+    release = feed.release
+    feed.release = lambda arrays: back.append(arrays) or release(arrays)
+    sf = ShardedFeed(feed, build_mesh(), global_batch_size=8, prefetch=0,
+                     transform=lambda c: {"x": c[0] * 2.0, "y": c[1] + 0})
+    hand_back = sf._hand_back
+    sf._hand_back = lambda: None        # as if the transfer were under way
+    gen = sf.batches()
+    batch, _ = next(gen)
+    assert len(sf._lent) == 1 and not back
+    for leaf in batch.values():
+        leaf.delete()                   # what donation leaves behind
+    sf._hand_back = hand_back
+    later, _ = next(gen)                # looks at the lent batch again
+    assert not sf._lent and len(back) == 1      # the second one alone
+    assert np.asarray(later["y"]).tolist() == list(range(8, 16))
+    assert feed.counters_snapshot()["feed_batch_buffers_reused"] == 0
+    gen.close()
+    feed.terminate()
+
+
+def test_terminate_with_buffers_lent_out_does_not_hang():
+    import time
+
+    feed = _file_feed(_rows(8 * 64), num_epochs=1000, shuffle_buffer=16)
+    sf = ShardedFeed(feed, build_mesh(), global_batch_size=8, prefetch=2,
+                     transform=lambda c: {"x": c[0][:, :2], "y": c[1]})
+    gen = sf.batches()
+    held = [next(gen) for _ in range(3)]    # views: their buffers stay lent
+    t0 = time.time()
+    sf.terminate()
+    assert time.time() - t0 < 10
+    t = sf._prefetch_thread
+    assert t is not None and not t.is_alive()
+    assert feed.should_stop() and all(not r.is_alive() for r in feed._threads)
+    for batch, _ in held:                   # and still hold their rows
+        y = np.asarray(batch["y"])
+        assert np.asarray(batch["x"])[:, 0].tolist() == y.tolist()
+    gen.close()
 
 
 def test_a_feed_without_the_method_is_skipped():

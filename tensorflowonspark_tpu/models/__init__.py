@@ -12,7 +12,9 @@ makes natural):
 - :mod:`~tensorflowonspark_tpu.models.unet`        — U-Net segmentation
   (reference ``examples/segmentation/segmentation_spark.py:70-122``)
 - :mod:`~tensorflowonspark_tpu.models.transformer` — decoder-only LM with
-  full/ring/ulysses attention (sequence parallelism over the mesh)
+  full/ring/ulysses attention (sequence parallelism over the mesh), built
+  from a description; :mod:`~tensorflowonspark_tpu.models.families` holds
+  one module a published decoder family, each a description's author
 
 The registry maps exported model names (checkpoint descriptors,
 ``checkpoint.export_model``) back to constructors so pipeline-transform
@@ -42,3 +44,4 @@ def get_model(name, **config):
 # Import for registration side effects.
 from tensorflowonspark_tpu.models import (  # noqa: E402,F401
     linear, mnist, resnet, transformer, twotower, unet)
+from tensorflowonspark_tpu.models import families  # noqa: E402,F401

@@ -9,8 +9,8 @@ is the long-context showcase of the TPU-native design: the same module runs
   chooses the block from the row; on a mesh, where the kernels' per-shard
   mapping fits), the plain contraction elsewhere (every CPU process, a row
   of 64 or 1,000 tokens, a sequence-parallel mesh).  The same mathematics
-  either way; which one a layer took is said by the presence of
-  ``aux["flash_counts"]``,
+  either way; which one a layer took is said by the presence of the
+  ``flash_*`` keys in ``aux["counters"]``,
 - ``attention="flash"``    — the pallas FlashAttention-2 kernels
   (:mod:`tensorflowonspark_tpu.ops.flash_attention`) always, at the
   layer's ``flash_block``: memory-linear in S, hand-scheduled VMEM traffic
@@ -25,68 +25,32 @@ swaps the core contraction, so checkpoints are interchangeable between modes
 (e.g. train with ring on a pod, serve with full on one chip).
 
 **One decoder, a pattern of layers.**  ``TransformerLM`` is built from a
-:class:`DecoderSpec`: the vocabulary, the position table, the final norm and
-one :class:`LayerSpec` a layer (norm kind and epsilon, operator kind,
-feed-forward kind, position kind, and their widths), read from a
-configuration:
+:class:`DecoderSpec`, its only form: the vocabulary, the position table, the
+final norm and one :class:`LayerSpec` a layer (norm kind and epsilon,
+operator kind, feed-forward kind, position kind, and their widths).  Beside
+the description it takes the run-time choices alone (``attention``,
+``ep_mode``, ``mesh``, ``ep_batch_axes``, ``remat``, ``dtype``).  Who writes
+a description:
 
-- :func:`gpt2_spec`: what the constructor's own fields describe when no spec
-  is given: LayerNorm, learned positions, fused-qkv multi-head attention and
-  a GELU MLP (or the top-1 Switch ``MoEMlp``) in every layer; its parameter
-  tree (``block_i/LayerNorm_0``, ``Attention_0/qkv``, ``Dense_0``,
-  ``Dense_1``, ``pos_embed``, ``embed``) and arithmetic are the GPT-2
-  decoder's as they always were;
-- :func:`lfm2_moe_spec` (registered as ``lfm2_moe``): the LFM2 mixture
-  family's ``config.json``: RMSNorm, no position table, ``layer_types``
-  choosing a gated short convolution (:class:`ShortConv`) or grouped-query
-  attention with per-head q/k RMSNorm and RoPE for each layer,
-  ``num_dense_layers`` leading SwiGLU feed-forwards and then
-  :class:`TopKExperts` (top-k of E by sigmoid scores with a selection bias,
-  nothing dropped, told which experts it holds);
-- :func:`deepseek_v2_spec` (registered as ``deepseek_v2``): the DeepSeek-V2
-  family's ``config.json`` without ``q_lora_rank``: RMSNorm, latent
-  attention (``op="mla"``: a down-projection to a ``kv_lora_rank`` latent
-  and one shared rotary key, an RMSNorm on the latent, an up-projection to
-  per-head keys and values narrower than the scores' 192 dimensions, YaRN
-  frequencies with interleaved pairing on the rotary part only, the
-  family's softmax scale), ``first_k_dense_replace`` leading SwiGLU
-  feed-forwards and then :class:`TopKExperts` by softmax scores without a
-  bias leaf and without renormalisation, beside a shared SwiGLU that every
-  token passes through, and a read-out of its own (``tied_readout=False``);
-- :func:`keye_vl2_spec` (registered as ``keye_vl2``): the language model of
-  the Keye-VL-2.0 mixture family's ``config.json``: RMSNorm, grouped-query
-  attention with per-head q/k RMSNorm and RoPE **over the keys a learned
-  index picks** (``sa_config``: ``index_heads`` index query heads of
-  ``index_dim`` and one index key head score every causal pair on a detached
-  copy of the layer's input, each query keeps its ``index_topk`` best keys,
-  exactly; :mod:`tensorflowonspark_tpu.ops.sparse_index`), an expert layer
-  in every layer (softmax scores, top-k renormalised, no bias leaf, no
-  shared expert) and an untied read-out.  The index is trained by a loss of
-  its own, sown a layer (``dsa_index_loss``) and added by :func:`loss_fn`:
-  its leaves (``index_q``, ``index_k``, ``index_k_norm``, ``index_w``) get
-  that loss's gradient alone and every other leaf the cross-entropy's alone;
-- :func:`mellum2_spec` (registered as ``mellum2``): the Mellum 2 mixture
-  family's ``config.json``: RMSNorm, grouped-query attention with per-head
-  q/k RMSNorm in every layer, of two kinds by ``layer_types``: a
-  ``sliding_attention`` layer's query reads its last ``sliding_window`` keys
-  (``LayerSpec.window``: the flash kernels' grid follows the band, the other
-  contractions mask it) under plain RoPE, a ``full_attention`` layer's every
-  causal key under YaRN frequencies (``rope_parameters`` has a table for
-  each kind); softmax top-k experts renormalised, no bias leaf, no shared
-  expert, and an untied read-out;
-- :func:`nemotron_h_spec` (registered as ``nemotron_h``): the Nemotron-H
-  family's ``config.json``: **every layer is one part alone**, ``x +
-  part(RMSNorm(x))``, by ``hybrid_override_pattern``: ``M`` a Mamba-2 layer
-  (:class:`Mamba2`: one input projection to a gate, ``x``, ``B``, ``C`` and
-  a step size a head, a causal depthwise convolution with a bias, the
-  chunked state-space scan of
-  :mod:`~tensorflowonspark_tpu.ops.ssd_scan`, a gated RMSNorm by groups,
-  the output projection), ``E`` an expert layer (sigmoid scores with a
-  selection bias, top-k renormalised and scaled, experts of **two**
-  matrices with ``relu(.)**2`` between, a shared expert of the same form),
-  ``*`` grouped-query attention with no positions at all (no RoPE, no
-  table), ``-`` a dense feed-forward of the experts' form; an untied
-  read-out.
+- :func:`gpt2_spec`, for :func:`build_transformer` (registered as
+  ``transformer_lm``): LayerNorm, learned positions, fused-qkv multi-head
+  attention and a GELU MLP (or the top-1 Switch ``MoEMlp``) in every layer;
+  its parameter tree (``block_i/LayerNorm_0``, ``Attention_0/qkv``,
+  ``Dense_0``, ``Dense_1``, ``pos_embed``, ``embed``) and arithmetic are the
+  GPT-2 decoder's as they always were;
+- a family's spec function, one module a family under
+  :mod:`tensorflowonspark_tpu.models.families` (``lfm2_moe``,
+  ``deepseek_v2``, ``keye_vl2``, ``mellum2``, ``nemotron_h``), which reads
+  the family's ``config.json`` and which :func:`register_decoder` registers
+  with ``get_model``.  A new family is a file there; a layer kind it brings
+  is a ``LayerSpec`` value and a branch of ``Block._op`` / ``Block._ff``
+  here, its kernels a module under ``ops/``.
+
+**What a layer counts** it sows as one dict of device scalars,
+``self.sow("intermediates", "counters", {...})``, under the names
+``train.Trainer.counters_snapshot()`` publishes; :func:`loss_fn` adds the
+dicts up key by key into ``aux["counters"]`` and the ``Trainer`` adds that up
+over the steps.  Neither knows a key.
 
 Scopes a device trace can be read by (``jax.named_scope`` under the flax
 module names): ``block_i/short_conv``, ``block_i/attention/flash`` (a layer
@@ -247,49 +211,18 @@ def gpt2_layer(num_heads, head_dim, mlp="dense", mlp_ratio=4, num_experts=8,
 
 def gpt2_spec(vocab_size, num_layers, num_heads, head_dim, max_seq_len,
               **layer):
-    """The GPT-2 decoder that ``TransformerLM``'s own fields describe
-    (``layer``: :func:`gpt2_layer`'s options)."""
+    """The GPT-2 decoder of :func:`build_transformer`'s sizes (``layer``:
+    :func:`gpt2_layer`'s options)."""
     return DecoderSpec(
         vocab_size=vocab_size, hidden_size=num_heads * head_dim,
         layers=(gpt2_layer(num_heads, head_dim, **layer),) * num_layers,
         learned_positions=max_seq_len)
 
 
-def lfm2_moe_spec(config):
-    """:class:`DecoderSpec` of an LFM2 mixture ``config.json`` (a dict with
-    the source's keys: ``layer_types``, ``num_dense_layers``, ``conv_L_cache``,
-    ``num_experts_per_tok``, ...).  ``num_experts`` is the router's width;
-    ``held_experts`` (``[first, count]``, optional) the experts this program
-    holds of each expert layer; ``flash_block`` (optional) the attention
-    kernel's block."""
-    held = config.get("held_experts")
-    common = dict(
-        norm="rmsnorm", norm_eps=config["norm_eps"], positions="rope",
-        num_heads=config["num_attention_heads"],
-        head_dim=config["hidden_size"] // config["num_attention_heads"],
-        num_kv_heads=config["num_key_value_heads"], qk_norm=True,
-        rope_theta=float(config["rope_theta"]),
-        flash_block=config.get("flash_block", 512),
-        conv_kernel=config["conv_L_cache"],
-        ff_size=config["intermediate_size"],
-        num_experts=config["num_experts"],
-        experts_per_token=config["num_experts_per_tok"],
-        expert_size=config["moe_intermediate_size"],
-        held_experts=tuple(held) if held else None,
-        norm_topk=config.get("norm_topk_prob", True),
-        routed_scaling=float(config.get("routed_scaling_factor", 1.0)))
-    kinds = {"conv": "conv", "full_attention": "attention"}
-    layers = tuple(
-        LayerSpec(op=kinds[kind],
-                  ff="swiglu" if i < config["num_dense_layers"] else "experts",
-                  **common)
-        for i, kind in enumerate(config["layer_types"]))
-    if len(layers) != config["num_hidden_layers"]:
-        raise ValueError("{} layer_types for num_hidden_layers {}".format(
-            len(layers), config["num_hidden_layers"]))
-    return DecoderSpec(vocab_size=config["vocab_size"],
-                       hidden_size=config["hidden_size"], layers=layers,
-                       norm="rmsnorm", norm_eps=config["norm_eps"])
+def _norm(kind, eps, dtype):
+    if kind == "rmsnorm":
+        return nn.RMSNorm(epsilon=eps, dtype=dtype)
+    return nn.LayerNorm(epsilon=eps, dtype=dtype)
 
 
 def yarn_mscale(scale, mscale):
@@ -298,270 +231,6 @@ def yarn_mscale(scale, mscale):
     import math
 
     return 1.0 if scale <= 1 else 0.1 * mscale * math.log(scale) + 1.0
-
-
-def deepseek_v2_spec(config):
-    """:class:`DecoderSpec` of a DeepSeek-V2 ``config.json`` without a query
-    latent (a dict with the source's keys: ``kv_lora_rank``,
-    ``qk_nope_head_dim``, ``qk_rope_head_dim``, ``v_head_dim``,
-    ``rope_scaling``, ``first_k_dense_replace``, ``n_shared_experts``,
-    ``scoring_func``, ...).  ``n_routed_experts`` is the router's width;
-    ``held_experts`` (``[first, count]``, optional) the experts this program
-    holds of each expert layer; ``flash_block`` (optional) the attention
-    kernel's block.  What the family's modelling code does and no key says:
-    interleaved RoPE pairing; a softmax scale of ``(nope + rope) ** -0.5``
-    times YaRN's factor of ``mscale_all_dim``, squared; cos and sin times
-    the ratio of the factors of ``mscale`` and ``mscale_all_dim``."""
-    unsupported = {
-        "q_lora_rank": config.get("q_lora_rank") is not None,
-        "topk_method": config.get("topk_method", "greedy") != "greedy",
-        "n_group": config.get("n_group", 1) != 1,
-        "moe_layer_freq": config.get("moe_layer_freq", 1) != 1,
-        "scoring_func": config.get("scoring_func", "softmax")
-        not in ("softmax", "sigmoid"),
-        "rope_scaling": (config.get("rope_scaling") or {"type": "yarn"})[
-            "type"] != "yarn"}
-    if any(unsupported.values()):
-        raise ValueError("deepseek_v2: no support for this config's {}"
-                         .format(sorted(k for k, v in unsupported.items()
-                                        if v)))
-    held = config.get("held_experts")
-    nope, rot = config["qk_nope_head_dim"], config["qk_rope_head_dim"]
-    scale, yarn = (nope + rot) ** -0.5, None
-    scaling = config.get("rope_scaling")
-    if scaling:
-        yarn = tuple(float(scaling[k]) for k in (
-            "factor", "original_max_position_embeddings", "beta_fast",
-            "beta_slow", "mscale", "mscale_all_dim"))
-        scale *= yarn_mscale(yarn[0], yarn[5]) ** 2
-    common = dict(
-        op="mla", norm="rmsnorm", norm_eps=config["rms_norm_eps"],
-        positions="rope", num_heads=config["num_attention_heads"],
-        head_dim=nope + rot, kv_rank=config["kv_lora_rank"], nope_dim=nope,
-        rope_dim=rot, v_dim=config["v_head_dim"],
-        rope_theta=float(config["rope_theta"]), rope_pairing="interleaved",
-        rope_yarn=yarn, attn_scale=scale,
-        flash_block=config.get("flash_block", 512),
-        ff_size=config["intermediate_size"],
-        num_experts=config["n_routed_experts"],
-        experts_per_token=config["num_experts_per_tok"],
-        expert_size=config["moe_intermediate_size"],
-        held_experts=tuple(held) if held else None,
-        router_score=config.get("scoring_func", "softmax"),
-        selection_bias=False,
-        norm_topk=config.get("norm_topk_prob", False),
-        routed_scaling=float(config.get("routed_scaling_factor", 1.0)),
-        shared_size=(config.get("n_shared_experts") or 0)
-        * config["moe_intermediate_size"])
-    layers = tuple(
-        LayerSpec(ff="swiglu" if i < config["first_k_dense_replace"]
-                  else "experts", **common)
-        for i in range(config["num_hidden_layers"]))
-    return DecoderSpec(vocab_size=config["vocab_size"],
-                       hidden_size=config["hidden_size"], layers=layers,
-                       norm="rmsnorm", norm_eps=config["rms_norm_eps"],
-                       tied_readout=config.get("tie_word_embeddings", False))
-
-
-def keye_vl2_spec(config):
-    """:class:`DecoderSpec` of the language model under a Keye-VL-2.0
-    mixture ``config.json`` (a dict with the source's keys: ``head_dim``,
-    ``num_key_value_heads``, ``num_experts_per_tok``, ``norm_topk_prob``,
-    ``sa_config`` with ``indexer_num_heads``, ``indexer_head_dim``,
-    ``indexer_num_kv_heads`` and ``topk``, ...).  ``num_experts`` is the
-    router's width; ``held_experts`` (``[first, count]``, optional) the
-    experts this program holds of each layer; ``flash_block`` (optional) the
-    attention kernel's block.  Text rows only: the three sections of
-    ``mrope_section`` all carry the token's position, which is plain RoPE.
-    What the family's modelling code does and no key says: per-head RMSNorm
-    on q and k, rotate-half pairing."""
-    sparse = config.get("sa_config") or {}
-    unsupported = {
-        "decoder_sparse_step": config.get("decoder_sparse_step", 1) != 1,
-        "mlp_only_layers": bool(config.get("mlp_only_layers")),
-        "use_sliding_window": bool(config.get("use_sliding_window")),
-        "attention_bias": bool(config.get("attention_bias")),
-        "sa_config.indexer_num_kv_heads":
-            sparse.get("indexer_num_kv_heads", 1) != 1}
-    if any(unsupported.values()):
-        raise ValueError("keye_vl2: no support for this config's {}".format(
-            sorted(k for k, v in unsupported.items() if v)))
-    held = config.get("held_experts")
-    layer = LayerSpec(
-        op="attention", ff="experts", norm="rmsnorm",
-        norm_eps=config["rms_norm_eps"], positions="rope",
-        num_heads=config["num_attention_heads"], head_dim=config["head_dim"],
-        num_kv_heads=config["num_key_value_heads"], qk_norm=True,
-        rope_theta=float(config["rope_theta"]),
-        index_heads=sparse.get("indexer_num_heads", 0),
-        index_dim=sparse.get("indexer_head_dim", 0),
-        index_topk=sparse.get("topk", 0),
-        flash_block=config.get("flash_block", 512),
-        num_experts=config["num_experts"],
-        experts_per_token=config["num_experts_per_tok"],
-        expert_size=config["moe_intermediate_size"],
-        held_experts=tuple(held) if held else None,
-        router_score="softmax", selection_bias=False,
-        norm_topk=config.get("norm_topk_prob", True))
-    return DecoderSpec(vocab_size=config["vocab_size"],
-                       hidden_size=config["hidden_size"],
-                       layers=(layer,) * config["num_hidden_layers"],
-                       norm="rmsnorm", norm_eps=config["rms_norm_eps"],
-                       tied_readout=config.get("tie_word_embeddings", False))
-
-
-def mellum2_spec(config):
-    """:class:`DecoderSpec` of a Mellum 2 mixture ``config.json`` (a dict
-    with the source's keys: ``layer_types`` of ``sliding_attention`` and
-    ``full_attention``, ``sliding_window``, ``rope_parameters`` with a table
-    for each kind of layer, ``mlp_layer_types``, ``num_experts_per_tok``,
-    ``norm_topk_prob``, ...).  ``num_experts`` is the router's width;
-    ``held_experts`` (``[first, count]``, optional) the experts this program
-    holds of each layer; ``flash_block`` (optional) the attention kernels'
-    block, in both kinds of layer.
-    A ``yarn`` table's ``attention_factor`` is what cos and sin are
-    multiplied by (absent: ``0.1 ln(factor) + 1``).  What the family's
-    modelling code does and no key says: per-head RMSNorm on q and k,
-    rotate-half pairing, the window ``t - s < sliding_window``."""
-    import math
-
-    kinds = set(config["layer_types"])
-    tables = config["rope_parameters"]
-    unsupported = {
-        "attention_bias": bool(config.get("attention_bias")),
-        "layer_types": not kinds <= {"sliding_attention", "full_attention"},
-        "mlp_layer_types": set(config.get("mlp_layer_types") or ["sparse"])
-        != {"sparse"},
-        "use_sliding_window": "sliding_attention" in kinds
-        and not config.get("use_sliding_window", True),
-        "rope_parameters": any(
-            kind not in tables or tables[kind].get("rope_type", "default")
-            not in ("default", "yarn") for kind in kinds)}
-    if any(unsupported.values()):
-        raise ValueError("mellum2: no support for this config's {}".format(
-            sorted(k for k, v in unsupported.items() if v)))
-    if len(config["layer_types"]) != config["num_hidden_layers"]:
-        raise ValueError("{} layer_types for num_hidden_layers {}".format(
-            len(config["layer_types"]), config["num_hidden_layers"]))
-    held = config.get("held_experts")
-    block = config.get("flash_block", 512)
-
-    def layer(kind):
-        table = tables[kind]
-        yarn = None
-        if table.get("rope_type", "default") == "yarn":
-            factor = float(table["factor"])
-            # rope_frequencies multiplies cos and sin by yarn_mscale(factor,
-            # mscale) / yarn_mscale(factor, mscale_all_dim): the table's
-            # attention_factor with mscale_all_dim 0
-            mscale = ((float(table["attention_factor"]) - 1.0)
-                      / (0.1 * math.log(factor))
-                      if "attention_factor" in table else 1.0)
-            yarn = (factor, float(table["original_max_position_embeddings"]),
-                    float(table["beta_fast"]), float(table["beta_slow"]),
-                    mscale, 0.0)
-        sliding = kind == "sliding_attention"
-        return LayerSpec(
-            op="attention", ff="experts", norm="rmsnorm",
-            norm_eps=config["rms_norm_eps"], positions="rope",
-            num_heads=config["num_attention_heads"],
-            head_dim=config["head_dim"],
-            num_kv_heads=config["num_key_value_heads"], qk_norm=True,
-            rope_theta=float(table["rope_theta"]), rope_yarn=yarn,
-            window=config["sliding_window"] if sliding else 0,
-            flash_block=block,
-            num_experts=config["num_experts"],
-            experts_per_token=config["num_experts_per_tok"],
-            expert_size=config["moe_intermediate_size"],
-            held_experts=tuple(held) if held else None,
-            router_score="softmax", selection_bias=False,
-            norm_topk=config.get("norm_topk_prob", True))
-
-    of_kind = {kind: layer(kind) for kind in kinds}
-    return DecoderSpec(vocab_size=config["vocab_size"],
-                       hidden_size=config["hidden_size"],
-                       layers=tuple(of_kind[kind]
-                                    for kind in config["layer_types"]),
-                       norm="rmsnorm", norm_eps=config["rms_norm_eps"],
-                       tied_readout=config.get("tie_word_embeddings", False))
-
-
-def nemotron_h_spec(config):
-    """:class:`DecoderSpec` of a Nemotron-H ``config.json`` (a dict with the
-    source's keys: ``hybrid_override_pattern``, ``mamba_num_heads``,
-    ``mamba_head_dim``, ``ssm_state_size``, ``n_groups``, ``conv_kernel``,
-    ``chunk_size``, ``n_routed_experts``, ``num_experts_per_tok``,
-    ``moe_intermediate_size``, ``moe_shared_expert_intermediate_size``,
-    ``routed_scaling_factor``, ``mlp_hidden_act``, ...).  One character of
-    the pattern a layer, each one part alone: ``M`` Mamba-2, ``E`` experts,
-    ``*`` attention, ``-`` a dense feed-forward of ``intermediate_size``.
-    ``n_routed_experts`` is the router's width; ``held_experts`` (``[first,
-    count]``, optional) the experts this program holds of each expert layer;
-    ``flash_block`` (optional) the attention kernels' block.
-    ``n_groups`` is the scan's (groups of B and C) and ``n_group`` /
-    ``topk_group`` the router's.  What the family's modelling code does and
-    no key says: the inner width is ``mamba_num_heads * mamba_head_dim``
-    (``expand`` is not read), one convolution over x, B and C together, the
-    gate before the grouped norm, no clamp on the step size, ``relu2(x) =
-    relu(x) ** 2``, and **no rotary embedding** in the attention layers
-    (``rope_theta`` and ``partial_rotary_factor`` are not read)."""
-    pattern = config["hybrid_override_pattern"]
-    unsupported = {
-        "hybrid_override_pattern": not set(pattern) <= set("ME*-"),
-        "n_group": config.get("n_group", 1) != 1
-        or config.get("topk_group", 1) != 1,
-        "mlp_hidden_act": config.get("mlp_hidden_act", "relu2") != "relu2",
-        "mamba_hidden_act": config.get("mamba_hidden_act", "silu") != "silu",
-        "bias": any(config.get(k) for k in (
-            "attention_bias", "mlp_bias", "mamba_proj_bias", "use_bias")),
-        "use_conv_bias": not config.get("use_conv_bias", True),
-        "sliding_window": config.get("sliding_window") is not None}
-    if any(unsupported.values()):
-        raise ValueError("nemotron_h: no support for this config's {}".format(
-            sorted(k for k, v in unsupported.items() if v)))
-    if len(pattern) != config["num_hidden_layers"]:
-        raise ValueError(
-            "a hybrid_override_pattern of {} for num_hidden_layers {}".format(
-                len(pattern), config["num_hidden_layers"]))
-    held = config.get("held_experts")
-    eps = config.get("layer_norm_epsilon", config.get("norm_eps", 1e-5))
-    common = dict(
-        norm="rmsnorm", norm_eps=eps, positions="none",
-        num_heads=config["num_attention_heads"], head_dim=config["head_dim"],
-        num_kv_heads=config["num_key_value_heads"],
-        flash_block=config.get("flash_block", 512),
-        conv_kernel=config["conv_kernel"],
-        ssm_heads=config["mamba_num_heads"],
-        ssm_head_dim=config["mamba_head_dim"],
-        ssm_state=config["ssm_state_size"], ssm_groups=config["n_groups"],
-        ssm_chunk=config["chunk_size"],
-        ff_size=config["intermediate_size"], expert_act="relu2",
-        num_experts=config["n_routed_experts"],
-        experts_per_token=config["num_experts_per_tok"],
-        expert_size=config["moe_intermediate_size"],
-        held_experts=tuple(held) if held else None,
-        router_score="sigmoid", selection_bias=True,
-        norm_topk=config.get("norm_topk_prob", True),
-        routed_scaling=float(config.get("routed_scaling_factor", 1.0)),
-        shared_size=(config.get("n_shared_experts") or 0)
-        * config.get("moe_shared_expert_intermediate_size", 0))
-    kinds = {"M": dict(op="mamba2", ff="none"),
-             "*": dict(op="attention", ff="none"),
-             "E": dict(op="none", ff="experts"),
-             "-": dict(op="none", ff="relu2")}
-    of_kind = {kind: LayerSpec(**kinds[kind], **common)
-               for kind in set(pattern)}
-    return DecoderSpec(vocab_size=config["vocab_size"],
-                       hidden_size=config["hidden_size"],
-                       layers=tuple(of_kind[kind] for kind in pattern),
-                       norm="rmsnorm", norm_eps=eps,
-                       tied_readout=config.get("tie_word_embeddings", False))
-
-
-def _norm(kind, eps, dtype):
-    if kind == "rmsnorm":
-        return nn.RMSNorm(epsilon=eps, dtype=dtype)
-    return nn.LayerNorm(epsilon=eps, dtype=dtype)
 
 
 def rope_frequencies(dim, theta, yarn=None):
@@ -707,23 +376,24 @@ class Attention(nn.Module):
     @nn.nowrap
     def _sow_grid(self, x, block, window=None):
         """Sow what the flash kernels' forward grid takes for this call's
-        rows and heads at blocks of ``block`` (``flash_counts``): its steps,
-        and the tiles among them that compute
-        (``ops.flash_attention.grid_tiles``; known when the step is
-        traced)."""
+        rows and heads at blocks of ``block``: its steps
+        (``flash_grid_steps``), and the tiles among them that compute
+        (``flash_tiles_computed``; ``ops.flash_attention.grid_tiles``, known
+        when the step is traced)."""
         from tensorflowonspark_tpu.ops.flash_attention import grid_tiles
 
         steps, computed = grid_tiles(x.shape[1], block, block, window=window)
         heads = x.shape[0] * self.num_heads
-        self.sow("intermediates", "flash_counts", {
-            "grid_steps": jnp.asarray(heads * steps, jnp.int32),
-            "tiles_computed": jnp.asarray(heads * computed, jnp.int32)})
+        self.sow("intermediates", "counters", {
+            "flash_grid_steps": jnp.asarray(heads * steps, jnp.int32),
+            "flash_tiles_computed": jnp.asarray(heads * computed, jnp.int32)})
 
     @nn.nowrap
     def _indexed(self, x, q, k, v):
         """Attention over the keys the index picks; sows the index's loss
-        (``dsa_index_loss [B]``) and the tiles its picks touch
-        (``dsa_counts``)."""
+        (``dsa_index_loss [B]``) and, as counters, the causal ``[block,
+        block]`` tiles of (queries, keys) and those of them that hold a
+        picked key (``dsa_tiles_causal``, ``dsa_tiles_touched``)."""
         from tensorflowonspark_tpu.ops import (flash_attention_lse,
                                                sparse_index)
 
@@ -751,9 +421,10 @@ class Attention(nn.Module):
             loss = sparse_index.index_loss(iq, ik, iw, q, k, lse, index_lse,
                                            bits, block=block)
         self.sow("intermediates", "dsa_index_loss", loss)
-        self.sow("intermediates", "dsa_counts",
-                 {"tiles_touched": touched,
-                  "tiles_causal": jnp.asarray(causal, jnp.int32)})
+        self.sow("intermediates", "counters", {
+            "dsa_tiles_touched": touched,
+            "dsa_tiles_causal": jnp.asarray(causal, jnp.int32),
+            "dsa_layers_steps": jnp.asarray(1, jnp.int32)})
         return out
 
     @nn.compact
@@ -810,11 +481,12 @@ class Attention(nn.Module):
             self._sow_grid(x, block, window)
             if window:      # what the band leaves of the causal tiles
                 computed, causal = band_tiles(x.shape[1], block, window)
-                self.sow("intermediates", "swa_counts", {
-                    "tiles_computed": jnp.asarray(x.shape[0] * computed,
-                                                  jnp.int32),
-                    "tiles_causal": jnp.asarray(x.shape[0] * causal,
-                                                jnp.int32)})
+                self.sow("intermediates", "counters", {
+                    "swa_tiles_computed": jnp.asarray(x.shape[0] * computed,
+                                                      jnp.int32),
+                    "swa_tiles_causal": jnp.asarray(x.shape[0] * causal,
+                                                    jnp.int32),
+                    "swa_layers_steps": jnp.asarray(1, jnp.int32)})
         else:
             group = self.num_heads // k.shape[2]
             if group > 1:   # the contractions below want a KV head each
@@ -896,7 +568,8 @@ class Mamba2(nn.Module):
     ``A_log``, ``dt_bias`` and ``D`` float32 leaves.
 
     The chunks scanned and the bytes of the chunk states written are sown
-    under ``intermediates/ssd_counts``."""
+    as counters (``ssd_chunks``; ``ssd_state_bytes``, float32: a step's can
+    pass 2**31; ``ssd_layers`` the layer calls)."""
 
     heads: int
     head_dim: int
@@ -941,9 +614,10 @@ class Mamba2(nn.Module):
             y = ssd.ssd_scan(x, dt, dt * -jnp.exp(a_log.astype(f32)), b, c,
                              chunk=self.chunk)
         chunks, state_bytes = ssd.chunk_counts(x, b, self.chunk)
-        self.sow("intermediates", "ssd_counts", {
-            "chunks": jnp.asarray(chunks, jnp.int32),
-            "state_bytes": jnp.asarray(state_bytes, f32)})
+        self.sow("intermediates", "counters", {
+            "ssd_chunks": jnp.asarray(chunks, jnp.int32),
+            "ssd_state_bytes": jnp.asarray(state_bytes, f32),
+            "ssd_layers": jnp.asarray(1, jnp.int32)})
         with jax.named_scope("gate_norm"):
             y = y.astype(f32) + skip.astype(f32)[:, None] * x.astype(f32)
             y = y.reshape(batch, seq, inner) * nn.silu(z.astype(f32))
@@ -1008,10 +682,10 @@ class TopKExperts(nn.Module):
     The shared expert is computed whole by every holder: when the shares of
     a layer are added up it counts once.
 
-    The token-slot counts of the call are sown under
-    ``intermediates/moe_counts`` (``slots_total``, ``slots_local``,
-    ``expert_load_max``, ``expert_load_mean``); ``loss_fn`` adds them up
-    over the expert layers into ``aux["moe_counts"]``."""
+    The token-slot counts of the call are sown as counters
+    (``moe_slots_total``, ``moe_slots_local``, the heaviest and the mean
+    held expert's count as ``moe_expert_load_max_sum`` and
+    ``moe_expert_load_mean_sum``, ``moe_layers_steps`` the layer calls)."""
 
     num_experts: int
     experts_per_token: int
@@ -1047,7 +721,12 @@ class TopKExperts(nn.Module):
                 score=self.score)
         y, load = ep_mod.experts_ffn(tokens, sel, weights, w1, w3, w2, first,
                                      dtype=self.dtype, act=self.act)
-        self.sow("intermediates", "moe_counts", load)
+        self.sow("intermediates", "counters", {
+            "moe_slots_total": load["slots_total"],
+            "moe_slots_local": load["slots_local"],
+            "moe_expert_load_max_sum": load["expert_load_max"],
+            "moe_expert_load_mean_sum": load["expert_load_mean"],
+            "moe_layers_steps": jnp.asarray(1, jnp.int32)})
         y = y.reshape(batch, seq, d_model)
         if self.shared:
             y = y + _FEED_FORWARD[self.act](self.shared, self.dtype,
@@ -1196,26 +875,16 @@ class MoEMlp(nn.Module):
 
 
 class Block(nn.Module):
-    num_heads: int = 8
-    head_dim: int = 64
-    mlp_ratio: int = 4
+    spec: LayerSpec           # the layer's description
     attention: str = "full"
-    mlp: str = "dense"        # dense | moe
-    num_experts: int = 8
-    capacity_factor: float = 1.25
     ep_mode: str = "gspmd"    # gspmd | shard_map (see MoEMlp)
     mesh: Optional[object] = None
     ep_batch_axes: Optional[tuple] = None
     dtype: jnp.dtype = jnp.float32
-    # the layer's description; None: a GPT-2 layer of the fields above
-    spec: Optional[LayerSpec] = None
 
     @nn.compact
     def __call__(self, x):
-        spec = self.spec or gpt2_layer(
-            self.num_heads, self.head_dim, mlp=self.mlp,
-            mlp_ratio=self.mlp_ratio, num_experts=self.num_experts,
-            capacity_factor=self.capacity_factor)
+        spec = self.spec
         if spec.op != "none":
             x = x + self._op(spec, _norm(spec.norm, spec.norm_eps,
                                          self.dtype)(x))
@@ -1278,31 +947,17 @@ class Block(nn.Module):
 
 
 class TransformerLM(nn.Module):
-    vocab_size: int = 32000
-    num_layers: int = 4
-    num_heads: int = 8
-    head_dim: int = 64
-    max_seq_len: int = 2048
+    spec: DecoderSpec         # the decoder's description
     attention: str = "full"
-    mlp: str = "dense"        # dense | moe
-    num_experts: int = 8
-    capacity_factor: float = 1.25
     ep_mode: str = "gspmd"    # gspmd | shard_map (see MoEMlp)
     mesh: Optional[object] = None
     ep_batch_axes: Optional[tuple] = None
     remat: bool = False
     dtype: jnp.dtype = jnp.float32
-    # the decoder's description; None: the GPT-2 decoder that vocab_size,
-    # num_layers, num_heads, head_dim, max_seq_len, mlp, num_experts and
-    # capacity_factor describe (gpt2_spec)
-    spec: Optional[DecoderSpec] = None
 
     @nn.compact
     def __call__(self, tokens):
-        spec = self.spec or gpt2_spec(
-            self.vocab_size, self.num_layers, self.num_heads, self.head_dim,
-            self.max_seq_len, mlp=self.mlp, num_experts=self.num_experts,
-            capacity_factor=self.capacity_factor)
+        spec = self.spec
         x = nn.Embed(spec.vocab_size, spec.hidden_size, dtype=self.dtype,
                      name="embed")(tokens)
         if spec.learned_positions:
@@ -1327,16 +982,15 @@ class TransformerLM(nn.Module):
         # Everything else in the block is recomputed.
         block_cls = Block
         if self.remat:
-            from tensorflowonspark_tpu.ops import sparse_index, ssd_scan
-            from tensorflowonspark_tpu.ops.flash_attention import KEPT
+            from tensorflowonspark_tpu.ops import KEPT
 
             block_cls = nn.remat(
                 Block, policy=jax.checkpoint_policies.save_only_these_names(
-                    *KEPT, *sparse_index.KEPT, *ssd_scan.KEPT))
+                    *KEPT))
         for i, layer in enumerate(spec.layers):
-            x = block_cls(attention=self.attention, ep_mode=self.ep_mode,
-                          mesh=self.mesh, ep_batch_axes=self.ep_batch_axes,
-                          dtype=self.dtype, spec=layer,
+            x = block_cls(spec=layer, attention=self.attention,
+                          ep_mode=self.ep_mode, mesh=self.mesh,
+                          ep_batch_axes=self.ep_batch_axes, dtype=self.dtype,
                           name="block_%d" % i)(x)
         x = _norm(spec.norm, spec.norm_eps, self.dtype)(x)
         if not spec.tied_readout:
@@ -1354,70 +1008,30 @@ def build_transformer(vocab_size=32000, num_layers=4, num_heads=8,
                       mlp="dense", num_experts=8, capacity_factor=1.25,
                       ep_mode="gspmd", mesh=None, ep_batch_axes=None,
                       remat=False, dtype="float32"):
-    return TransformerLM(vocab_size=vocab_size, num_layers=num_layers,
-                         num_heads=num_heads, head_dim=head_dim,
-                         max_seq_len=max_seq_len, attention=attention,
-                         mlp=mlp, num_experts=num_experts,
-                         capacity_factor=capacity_factor, ep_mode=ep_mode,
-                         mesh=mesh, ep_batch_axes=ep_batch_axes,
-                         remat=remat, dtype=jnp.dtype(dtype))
+    spec = gpt2_spec(vocab_size, num_layers, num_heads, head_dim, max_seq_len,
+                     mlp=mlp, num_experts=num_experts,
+                     capacity_factor=capacity_factor)
+    return TransformerLM(spec=spec, attention=attention, ep_mode=ep_mode,
+                         mesh=mesh, ep_batch_axes=ep_batch_axes, remat=remat,
+                         dtype=jnp.dtype(dtype))
 
 
-@register_model("lfm2_moe")
-def build_lfm2_moe(config, attention="flash", mesh=None, remat=False,
-                   dtype="float32"):
-    """The one decoder under an LFM2 mixture ``config.json`` (see
-    :func:`lfm2_moe_spec`); ``attention`` picks the contraction as for
-    ``transformer_lm`` (grouped KV heads reach ``flash`` as they are and are
-    repeated for the others)."""
-    return TransformerLM(spec=lfm2_moe_spec(config), attention=attention,
-                         mesh=mesh, remat=remat, dtype=jnp.dtype(dtype))
-
-
-@register_model("deepseek_v2")
-def build_deepseek_v2(config, attention="flash", mesh=None, remat=False,
-                      dtype="float32"):
-    """The one decoder under a DeepSeek-V2 ``config.json`` (see
-    :func:`deepseek_v2_spec`); ``attention`` picks the contraction as for
-    ``transformer_lm`` (``flash`` takes values narrower than the scores'
-    width as they are; ``full`` is the same mathematics without a kernel)."""
-    return TransformerLM(spec=deepseek_v2_spec(config), attention=attention,
-                         mesh=mesh, remat=remat, dtype=jnp.dtype(dtype))
-
-
-@register_model("keye_vl2")
-def build_keye_vl2(config, attention="flash", mesh=None, remat=False,
-                   dtype="float32"):
-    """The one decoder under a Keye-VL-2.0 mixture ``config.json``'s language
-    model (see :func:`keye_vl2_spec`).  The index over the keys runs under
-    ``attention="flash"`` (its kernels, and the flash kernels reading a key
-    set a query) alone."""
-    return TransformerLM(spec=keye_vl2_spec(config), attention=attention,
-                         mesh=mesh, remat=remat, dtype=jnp.dtype(dtype))
-
-
-@register_model("mellum2")
-def build_mellum2(config, attention="flash", mesh=None, remat=False,
+def register_decoder(name):
+    """Decorator of a family's spec function (``config`` -> a
+    :class:`DecoderSpec`; ``models/families/``): registers with ``get_model``
+    under ``name`` the one decoder under ``spec_fn(config)``, at the
+    run-time choices of ``transformer_lm`` (``attention`` picks the
+    contraction).  Returns the spec function as it came."""
+    def deco(spec_fn):
+        def build(config, attention="flash", mesh=None, remat=False,
                   dtype="float32"):
-    """The one decoder under a Mellum 2 mixture ``config.json`` (see
-    :func:`mellum2_spec`).  Under ``attention="flash"`` a sliding layer's
-    kernels visit the band's tiles alone; ``"full"`` is the same mathematics
-    with the band as a mask; the sequence-parallel contractions refuse a
-    window."""
-    return TransformerLM(spec=mellum2_spec(config), attention=attention,
-                         mesh=mesh, remat=remat, dtype=jnp.dtype(dtype))
+            return TransformerLM(spec=spec_fn(config), attention=attention,
+                                 mesh=mesh, remat=remat,
+                                 dtype=jnp.dtype(dtype))
 
-
-@register_model("nemotron_h")
-def build_nemotron_h(config, attention="flash", mesh=None, remat=False,
-                     dtype="float32"):
-    """The one decoder under a Nemotron-H ``config.json`` (see
-    :func:`nemotron_h_spec`): layers that are a Mamba-2 mixer, an expert
-    layer or attention alone.  ``attention`` picks the attention layers'
-    contraction as for ``transformer_lm``; the scan's kernels run on a TPU
-    and its ``jax.numpy`` form elsewhere whatever it says."""
-    return TransformerLM(spec=nemotron_h_spec(config), attention=attention,
-                         mesh=mesh, remat=remat, dtype=jnp.dtype(dtype))
+        register_model(name)(build)
+        return spec_fn
+    return deco
 
 
 def _sown(tree, name):
@@ -1439,86 +1053,15 @@ def _sum_moe_aux(tree):
     return sum(found) if found else None
 
 
-def _sum_moe_counts(tree):
-    """Every ``moe_counts`` sown anywhere in the intermediates tree (one dict
-    an expert layer), added up under the names of ``train.Trainer``'s
-    counters: ``moe_slots_total`` and ``moe_slots_local`` as they are, the
-    heaviest and the mean held expert's count as ``*_sum`` over the layers,
-    and ``moe_layers_steps`` how many layers there were; None without expert
-    layers."""
-    found = _sown(tree, "moe_counts")
-    if not found:
-        return None
-    return {"moe_slots_total": sum(c["slots_total"] for c in found),
-            "moe_slots_local": sum(c["slots_local"] for c in found),
-            "moe_expert_load_max_sum": sum(c["expert_load_max"]
-                                           for c in found),
-            "moe_expert_load_mean_sum": sum(c["expert_load_mean"]
-                                            for c in found),
-            "moe_layers_steps": jnp.asarray(len(found), jnp.int32)}
-
-
-def _sum_dsa(tree, mask):
-    """What the layers with an index over the keys sowed: ``(loss, counts)``
-    with ``loss`` the layers' ``dsa_index_loss [B]`` added up and averaged
-    over the rows as ``mask`` weighs them, and ``counts`` under the names of
-    ``train.Trainer``'s counters (``dsa_tiles_causal``, ``dsa_tiles_touched``
-    of the causal ``[flash_block, flash_block]`` tiles and of those that
-    hold a picked key, ``dsa_index_loss``, ``dsa_layers_steps``); None
-    without such layers."""
-    losses = _sown(tree, "dsa_index_loss")
-    if not losses:
-        return None
-    loss = (sum(losses) * mask).sum() / jnp.maximum(mask.sum(), 1.0)
-    tiles = _sown(tree, "dsa_counts")
-    return loss, {
-        "dsa_tiles_causal": sum(c["tiles_causal"] for c in tiles),
-        "dsa_tiles_touched": sum(c["tiles_touched"] for c in tiles),
-        "dsa_index_loss": jax.lax.stop_gradient(loss),
-        "dsa_layers_steps": jnp.asarray(len(losses), jnp.int32)}
-
-
-def _sum_swa(tree):
-    """What the layers with a window sowed under ``attention="flash"``,
-    under the names of ``train.Trainer``'s counters: ``swa_tiles_causal``
-    the causal ``[flash_block, flash_block]`` tiles of their (queries,
-    keys), ``swa_tiles_computed`` those that the forward kernel's grid
-    computes (the band's; known when the step is traced),
-    ``swa_layers_steps`` the layer calls; None without such layers."""
-    found = _sown(tree, "swa_counts")
-    if not found:
-        return None
-    return {"swa_tiles_causal": sum(c["tiles_causal"] for c in found),
-            "swa_tiles_computed": sum(c["tiles_computed"] for c in found),
-            "swa_layers_steps": jnp.asarray(len(found), jnp.int32)}
-
-
-def _sum_flash(tree):
-    """What the layers that took the flash kernels sowed (every layer under
-    ``attention="flash"``; under ``"full"`` those whose rows the kernels
-    serve), under the names of ``train.Trainer``'s counters:
-    ``flash_grid_steps`` the steps of their forward kernels' grids over the
-    rows and heads, ``flash_tiles_computed`` the tiles among them that
-    compute; None without such layers."""
-    found = _sown(tree, "flash_counts")
-    if not found:
-        return None
-    return {"flash_grid_steps": sum(c["grid_steps"] for c in found),
-            "flash_tiles_computed": sum(c["tiles_computed"] for c in found)}
-
-
-def _sum_ssd(tree):
-    """What the Mamba-2 layers sowed, under the names of ``train.Trainer``'s
-    counters: ``ssd_chunks`` the chunks their scans took over the rows,
-    ``ssd_state_bytes`` the bytes of the chunk states written (float32: a
-    step's can pass 2**31), ``ssd_layers`` the layer calls; None without such
-    layers."""
-    found = _sown(tree, "ssd_counts")
-    if not found:
-        return None
-    return {"ssd_chunks": sum(c["chunks"] for c in found),
-            "ssd_state_bytes": sum(c["state_bytes"] for c in found),
-            "ssd_layers": jnp.asarray(len(found), jnp.int32)}
+def _sum_counters(tree):
+    """Every ``counters`` dict sown anywhere in the intermediates tree (one
+    a layer call that counts), added up key by key, each key in the dtype it
+    was sown in; empty when no layer counts."""
+    total = {}
+    for counts in _sown(tree, "counters"):
+        for key, val in counts.items():
+            total[key] = total[key] + val if key in total else val
+    return total
 
 
 def loss_fn(model, moe_aux_weight=0.01):
@@ -1531,23 +1074,17 @@ def loss_fn(model, moe_aux_weight=0.01):
 
     MoE models' sown load-balance auxiliaries are folded in with weight
     ``moe_aux_weight`` (Switch Transformer's alpha=0.01 default) and
-    reported via ``aux["moe_aux_loss"]``.  ``TopKExperts`` layers have no
-    auxiliary loss; their token-slot counts of the step come out as
-    ``aux["moe_counts"]`` (device scalars; ``train.Trainer`` adds them up
-    into its ``moe_*`` counters without a host sync).  Layers with an index
-    over the keys sow that index's loss: it is added to the step's loss as
-    it is (its gradient reaches the index's leaves alone), reported as
-    ``aux["dsa_index_loss"]``, and with the tiles the picks touch goes out
-    as ``aux["dsa_counts"]`` (the ``Trainer``'s ``dsa_*`` counters).  Layers
-    with a window sow the tiles their kernels visit: ``aux["swa_counts"]``
-    (the ``Trainer``'s ``swa_*`` counters), and every layer that takes the
-    flash kernels (under ``attention="flash"``, and under ``"full"`` where
-    the rule of ``ops.flash_attention.full_attention_block`` holds) its
-    kernels' grid steps beside the tiles that compute:
-    ``aux["flash_counts"]`` (the ``Trainer``'s ``flash_*``; present exactly
-    when some layer ran the kernels); every
-    Mamba-2 layer the chunks of its scan and the bytes of their states:
-    ``aux["ssd_counts"]`` (the ``Trainer``'s ``ssd_*``).
+    reported via ``aux["moe_aux_loss"]``.  Layers with an index over the
+    keys sow that index's loss: it is added to the step's loss as it is (its
+    gradient reaches the index's leaves alone) and reported as
+    ``aux["dsa_index_loss"]``.
+
+    ``aux["counters"]``: what the layers that count sowed as ``counters``
+    (device scalars under the names ``train.Trainer`` publishes them by,
+    added up over the layers; the ``Trainer`` adds them up over the steps
+    without a host sync), absent when no layer counts.  A layer states its
+    own keys where it sows them; the one key added here is the index's loss,
+    detached, as ``dsa_index_loss``.
     """
     import optax
 
@@ -1566,22 +1103,16 @@ def loss_fn(model, moe_aux_weight=0.01):
         if lb is not None:
             aux["moe_aux_loss"] = lb
             ce = ce + moe_aux_weight * lb
-        counts = _sum_moe_counts(sown)
-        if counts is not None:
-            aux["moe_counts"] = counts
-        dsa = _sum_dsa(sown, mask)
-        if dsa is not None:
-            aux["dsa_index_loss"], aux["dsa_counts"] = dsa[0], dsa[1]
-            ce = ce + dsa[0]
-        swa = _sum_swa(sown)
-        if swa is not None:
-            aux["swa_counts"] = swa
-        flash = _sum_flash(sown)
-        if flash is not None:
-            aux["flash_counts"] = flash
-        ssd = _sum_ssd(sown)
-        if ssd is not None:
-            aux["ssd_counts"] = ssd
+        counters = _sum_counters(sown)
+        index_losses = _sown(sown, "dsa_index_loss")    # [B] a layer
+        if index_losses:
+            index_loss = (sum(index_losses) * mask).sum() / jnp.maximum(
+                mask.sum(), 1.0)
+            aux["dsa_index_loss"] = index_loss
+            counters["dsa_index_loss"] = jax.lax.stop_gradient(index_loss)
+            ce = ce + index_loss
+        if counters:
+            aux["counters"] = counters
         return ce, aux
 
     return loss

@@ -11,10 +11,18 @@ state-space scan of a Mamba-2 layer with its backward
 ``ops.ssd_scan.ssd_scan``: the name here stays the module's).
 Every kernel runs in pallas interpret mode off-TPU, so the suite validates
 them on the CPU mesh.
+
+``KEPT``: the names (``jax.ad_checkpoint.checkpoint_name``) of the kernels'
+residuals that cost a kernel run to make again, all modules' together: what a
+rematerialised block keeps (``save_only_these_names(*KEPT)``).  A kernel with
+such a residual adds its names to its module's ``KEPT`` and its module here.
 """
 
+from tensorflowonspark_tpu.ops import sparse_index, ssd_scan
 from tensorflowonspark_tpu.ops.flash_attention import (  # noqa: F401
-    flash_attention, flash_attention_lse)
+    KEPT as _FLASH_KEPT, flash_attention, flash_attention_lse)
 from tensorflowonspark_tpu.ops.grouped_matmul import grouped_matmul  # noqa: F401
 from tensorflowonspark_tpu.ops.routed_rows import (  # noqa: F401
     gather_rows, gather_sum_rows)
+
+KEPT = _FLASH_KEPT + sparse_index.KEPT + ssd_scan.KEPT

@@ -105,7 +105,10 @@ class Trainer(object):
       loss_fn: ``fn(params, batch, mask) -> (loss, aux)`` — or, when
         ``extra_state`` is given, ``fn(params, extra, batch, mask)`` where
         ``extra`` carries non-trainable collections (BatchNorm stats); the
-        updated collections are returned in ``aux["extra_state"]``.  ``mask``
+        updated collections are returned in ``aux["extra_state"]``.  The
+        one other key of ``aux`` the trainer knows is ``aux["counters"]``, a
+        flat dict of device scalars, added up over the steps and published
+        by :meth:`counters_snapshot` under their keys.  ``mask``
         is the per-row validity mask from the infeed (1.0 = real row) and
         must be applied by the loss so padded rows contribute nothing.
       init_params: parameter pytree (replicated over the mesh).
@@ -386,14 +389,14 @@ class Trainer(object):
         self._steps_per_call_gauge = 0
         self._steps_per_call_hwm = 0
         self._steps_per_call_req = None
-        # The router's load (models with TopKExperts layers): each step's
-        # ``aux["moe_counts"]`` waits here as device scalars until the device
-        # has produced them; ``_fold_moe`` adds the finished ones into host
-        # totals and never waits, so neither the step loop nor a heartbeat's
+        # What the model counts: each step's ``aux["counters"]`` waits here
+        # as device scalars until the device has produced them;
+        # ``_fold_aux_counters`` adds the finished ones into host totals and
+        # never waits, so neither the step loop nor a heartbeat's
         # counters_snapshot syncs on a step in flight.
-        self._moe_pending = []
-        self._moe_totals = {}
-        self._moe_lock = threading.Lock()
+        self._aux_pending = []
+        self._aux_totals = {}
+        self._aux_lock = threading.Lock()
         # Which program was made, at which step (see _note_compile): the
         # compile plane's tallies as of the last program seen, the names of
         # the step programs that have run, the programs made again under a
@@ -426,33 +429,24 @@ class Trainer(object):
                 "another shape, or a state laid out anew", name,
                 self._steps_total, cost)
 
-    def _note_moe(self, aux):
-        if not isinstance(aux, dict):
-            return
-        # the index over the keys (dsa_*), the window layers (swa_*), the
-        # flash kernels' grids (flash_*) and the state-space scans (ssd_*)
-        # count the same way
-        counts = dict(aux.get("moe_counts") or {},
-                      **(aux.get("dsa_counts") or {}),
-                      **(aux.get("swa_counts") or {}),
-                      **(aux.get("flash_counts") or {}),
-                      **(aux.get("ssd_counts") or {}))
+    def _note_aux_counters(self, aux):
+        counts = aux.get("counters") if isinstance(aux, dict) else None
         if not counts:
             return
-        with self._moe_lock:
-            self._moe_pending.append(counts)
-        if len(self._moe_pending) > 8:
-            self._fold_moe()
+        with self._aux_lock:
+            self._aux_pending.append(counts)
+        if len(self._aux_pending) > 8:
+            self._fold_aux_counters()
 
-    def _fold_moe(self):
+    def _fold_aux_counters(self):
         """Add the counts of every step the device has finished to the host
         totals, oldest first; stops at the first step still in flight."""
-        with self._moe_lock:
-            while self._moe_pending and all(
-                    v.is_ready() for v in self._moe_pending[0].values()):
+        with self._aux_lock:
+            while self._aux_pending and all(
+                    v.is_ready() for v in self._aux_pending[0].values()):
                 for key, val in jax.device_get(
-                        self._moe_pending.pop(0)).items():
-                    self._moe_totals[key] = self._moe_totals.get(
+                        self._aux_pending.pop(0)).items():
+                    self._aux_totals[key] = self._aux_totals.get(
                         key, 0) + val.item()
 
     def _own_counters(self):
@@ -476,46 +470,12 @@ class Trainer(object):
         Prometheus gauges): the stated ``step_flops_override`` over the
         window's device-synced step time, absent when no count was stated.
 
-        The router's load, for a model with ``TopKExperts`` layers only
-        (summed over the steps the device has finished; a step in flight
-        is counted by a later snapshot): ``moe_slots_total`` (token, slot)
-        pairs routed, ``moe_slots_local`` of them to experts held here
-        (their ratio is the share of the sorted buffers' rows that the expert
-        layer's kernels fetch, 1 minus it the share they skip),
-        ``moe_expert_load_max_sum`` / ``moe_expert_load_mean_sum`` the
-        heaviest and the mean held expert's pairs summed over layers and
-        steps (their ratio is the imbalance the grouped products see),
-        ``moe_layers_steps`` the expert-layer calls counted.
-
-        The index over the keys, for a model whose attention has one (the
-        same way): ``dsa_tiles_causal`` the causal ``[flash_block,
-        flash_block]`` tiles of (queries, keys) and ``dsa_tiles_touched``
-        those of them that hold a picked key (what a kernel that skipped
-        empty tiles could save on this data), ``dsa_index_loss`` the index's
-        loss summed over the steps, ``dsa_layers_steps`` the layer calls
-        counted.
-
-        The window layers, for a model that has them under
-        ``attention="flash"`` (the same way): ``swa_tiles_causal`` the causal
-        ``[flash_block, flash_block]`` tiles of their (queries, keys),
-        ``swa_tiles_computed`` those of them that the forward kernel's grid
-        computes (the band's: their ratio is what the window leaves of a
-        full layer's work, 100% for a kernel that masks),
-        ``swa_layers_steps`` the layer calls counted.
-
-        The flash kernels' grids, for a model with layers under
-        ``attention="flash"`` (the same way): ``flash_grid_steps`` the steps
-        of the forward kernels' grids over rows, heads and layers,
-        ``flash_tiles_computed`` the tiles among them that compute (their
-        ratio is 1 for a causal layer, whose grid lists the triangle's tiles,
-        192 / 189 for a window of 1,024 keys in 32,768-token rows at blocks
-        of 512, and 1.97 for the square grid over such rows, which a layer
-        takes whose list SMEM would not hold).
-
-        The state-space scans, for a model with Mamba-2 layers (the same
-        way): ``ssd_chunks`` the chunks scanned (layers x rows x positions /
-        chunk a step), ``ssd_state_bytes`` the bytes of the chunk states the
-        forward kernels wrote, ``ssd_layers`` the layer calls counted.
+        What the model counts, where its loss returns ``aux["counters"]``
+        (a flat dict of device scalars a step): each key as it came, summed
+        over the steps the device has finished (a step in flight is counted
+        by a later snapshot).  The keys and their meanings are the model's
+        (``models/transformer.py``, where a layer sows them;
+        ``docs/OBSERVABILITY.md``).
 
         ``train_recompiles_total``: dispatches that made a step program
         executable under a name (``step``, ``multi_<k>``) that had run
@@ -572,16 +532,14 @@ class Trainer(object):
                 snap["train_grad_norm_max"] = round(self._health_grad, 6)
         if self._recompiles:
             snap["train_recompiles_total"] = self._recompiles
-        if self._moe_pending or self._moe_totals:
-            self._fold_moe()
-            # moe_*, dsa_*, swa_*, flash_*, ssd_*
-            snap.update(self._moe_totals)
+        if self._aux_pending or self._aux_totals:
+            self._fold_aux_counters()
+            snap.update(self._aux_totals)
         return snap
 
     def counters_snapshot(self):
-        """:meth:`_own_counters` (see there: the step loop's, and a model's
-        ``moe_*``, ``dsa_*`` and ``swa_*`` where it has expert layers, an
-        index over the keys or window layers) and what the process keeps
+        """:meth:`_own_counters` (see there: the step loop's, and what the
+        model counts in ``aux["counters"]``) and what the process keeps
         for all its trainers: the compile plane's tallies
         (``compilecache.stats.tallies()``: ``compile_trace_us``,
         ``compile_lower_us``, ``compile_backend_us``,
@@ -949,7 +907,7 @@ class Trainer(object):
         # (multi_step buffers its scan's on-device grad-norm mean the same
         # way).
         aux, self._health_grad_norm = packed
-        self._note_moe(aux)
+        self._note_aux_counters(aux)
         self._steps_per_call_gauge = 1
         self._steps_per_call_hwm = max(self._steps_per_call_hwm, 1)
         self._steps_total += 1

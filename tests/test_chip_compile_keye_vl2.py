@@ -1,0 +1,48 @@
+"""The whole training step of the benchmark's ``keye_vl2_30b_a3b_ep8``
+configuration compiled for one described TPU v5e chip (see
+``tests/chip_compile.py``)."""
+
+from chip_compile import (  # noqa: F401  (fixtures)
+    _compiled_step, _kernel_calls, _one_lane_arrays, _flash_calls,
+    no_compile_cache, topo)
+
+
+def test_keye_vl2_step_compiles_and_fits_v5e(topo, monkeypatch):
+    """The whole training step of ``keye_vl2_30b_a3b_ep8`` (published
+    widths; four layers; grouped-query attention over the 2,048 keys a
+    learned index picks of a 32,768-token row; 16 of 128 experts by softmax
+    top-8; an untied read-out over 18,992 rows; batch 1, as the file says)
+    compiles for one described v5e chip with every kernel of the index in it
+    and fits its 15.75 GiB by XLA's memory analysis: 13.66 GiB with four
+    layers' kernel outputs, logsumexp rows, key bits and index logsumexp
+    kept across their recomputed blocks, which it may not outgrow; no array
+    of it is ``[T, T]``.  PR 40: 14.25 before it; 14.47 with the flash
+    kernels' statistics as dense rows (the most that is live at once fell
+    0.53 GB with the ``[.., seq, 1]`` arrays, and the block the compiler
+    packs the temporaries into grew: a 0.39 GB hole in the expert layer's
+    backward pass that its 0.40 GB buffers do not fit, and the analysis
+    counts such a hole twice); 13.66 with the index's backward kernels run
+    in their own layer's backward pass (``transformer._backward_together``:
+    two layers' folded ``q``, ``k`` and index queries no longer lie over the
+    third's expert layer).  The numbers of PR 37 are in the configuration's
+    ``assumed.batch_size``."""
+    compiled, parameters, needed = _compiled_step(
+        topo, monkeypatch, "keye_vl2", "keye_vl2_30b_a3b_ep8")
+    assert parameters == 465_391_104
+    assert needed <= 13.7 * 2 ** 30, needed
+    assert "32768,32768" not in compiled.as_text()
+    calls = _kernel_calls(compiled)
+
+    def count(scope, kernel):
+        return sum("/attention/{}/".format(scope) in line and kernel in line
+                   for line in calls)
+
+    # four layers x (forward, dQ, dK/dV) under attention/flash and one
+    # selection each: the recomputed pass holds neither; the index's loss
+    # once alone (forward) and once with its gradients (backward)
+    assert count("flash", "pallas_call") == 12
+    assert not _one_lane_arrays(_flash_calls(calls))
+    assert count("select", "dsa_select/") == 4
+    assert count("index_loss", "dsa_index_loss/") == 4
+    assert count("index_loss", "dsa_index_loss_grads/") == 4
+    assert sum("/moe/experts/" in line for line in calls) == 48

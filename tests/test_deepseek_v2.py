@@ -26,6 +26,7 @@ if ROOT not in sys.path:
 
 from benchmark.references import deepseek_v2 as ref  # noqa: E402
 from tensorflowonspark_tpu.models import get_model, transformer  # noqa: E402
+from tensorflowonspark_tpu.models.families import deepseek_v2 as family  # noqa: E402
 
 LITE_ROPE = {"beta_fast": 32, "beta_slow": 1, "factor": 40, "mscale": 0.707,
              "mscale_all_dim": 0.707,
@@ -99,7 +100,7 @@ def test_softmax_scale_by_hand():
     want = 192 ** -0.5 * m * m
     assert want == pytest.approx(0.114722, abs=1e-6)
     lite = dict(TINY, qk_nope_head_dim=128, qk_rope_head_dim=64)
-    spec = transformer.deepseek_v2_spec(lite)
+    spec = family.deepseek_v2_spec(lite)
     assert spec.layers[0].attn_scale == pytest.approx(want, rel=1e-12)
     assert ref.softmax_scale(lite) == pytest.approx(want, rel=1e-12)
     assert spec.layers[0].rope_yarn == (40.0, 4096.0, 32.0, 1.0, 0.707, 0.707)
@@ -166,7 +167,7 @@ def _program_share(w, x, first, count, shared=True):
         params["shared"] = {k: {"kernel": w["L0.s" + k]}
                             for k in ("w1", "w3", "w2")}
     y, state = layer.apply({"params": params}, x, mutable=["intermediates"])
-    return y, state["intermediates"]["moe_counts"][0]
+    return y, state["intermediates"]["counters"][0]
 
 
 def _reference_layer(w, x, held=(0, 8), shared=True):
@@ -192,8 +193,8 @@ def test_the_shares_with_the_shared_expert_once_add_up(row_path):
     np.testing.assert_allclose(
         np.asarray(sum(y for y, _ in routed) + shared_alone),
         np.asarray(whole), atol=2e-5, rtol=2e-5)
-    assert sum(int(c["slots_local"]) for _, c in routed) == 3 * 40 * 3
-    assert all(int(c["slots_total"]) == 3 * 40 * 3 for _, c in routed)
+    assert sum(int(c["moe_slots_local"]) for _, c in routed) == 3 * 40 * 3
+    assert all(int(c["moe_slots_total"]) == 3 * 40 * 3 for _, c in routed)
     # what a chip really computes: its routed part and the shared expert
     # whole; four of those hold the shared expert four times
     chips = [_program_share(w, x, first, 2)[0] for first in (0, 2, 4, 6)]
@@ -228,8 +229,8 @@ def test_nothing_is_dropped_when_the_router_sends_all_to_one_expert(row_path):
     x = x + 6.0 * u
     w["L0.router"] = w["L0.router"].at[:, 3].set(4.0 * u)
     y, counts = _program_share(w, x, 3, 2)
-    assert int(counts["expert_load_max"]) == 3 * 40
-    assert int(counts["slots_local"]) >= 3 * 40
+    assert int(counts["moe_expert_load_max_sum"]) == 3 * 40
+    assert int(counts["moe_slots_local"]) >= 3 * 40
     want = _reference_layer(w, x, (3, 2))
     np.testing.assert_allclose(np.asarray(y), np.asarray(want),
                                atol=2e-5, rtol=2e-5)

@@ -28,6 +28,7 @@ if ROOT not in sys.path:
 from benchmark.adapters import keye_vl2 as adapter  # noqa: E402
 from benchmark.references import keye_vl2 as ref  # noqa: E402
 from tensorflowonspark_tpu.models import get_model, transformer  # noqa: E402
+from tensorflowonspark_tpu.models.families import keye_vl2 as family  # noqa: E402
 from tensorflowonspark_tpu.ops import (  # noqa: E402
     flash_attention, flash_attention_lse)
 
@@ -297,9 +298,9 @@ def test_the_shares_of_the_eight_chips_add_up_to_the_uncut_layer(row_path):
                      for k in ("w1", "w3", "w2")}}
         y, state = layer.apply({"params": params}, x,
                                mutable=["intermediates"])
-        counts = state["intermediates"]["moe_counts"][0]
-        assert int(counts["slots_total"]) == 2 * 40 * 3
-        total, local = total + y, local + int(counts["slots_local"])
+        counts = state["intermediates"]["counters"][0]
+        assert int(counts["moe_slots_total"]) == 2 * 40 * 3
+        total, local = total + y, local + int(counts["moe_slots_local"])
         if first == 6:      # one share alone is the reference's same share
             mine = dict(w, **{k: w[k][first:first + 2]
                               for k in ("L0.ew1", "L0.ew3", "L0.ew2")})
@@ -315,7 +316,7 @@ def test_the_shares_of_the_eight_chips_add_up_to_the_uncut_layer(row_path):
 # -- the description, its tree, its counters ----------------------------------
 
 def test_keye_vl2_is_registered_and_follows_the_description():
-    spec = transformer.keye_vl2_spec(adapter.program_config(TINY))
+    spec = family.keye_vl2_spec(adapter.program_config(TINY))
     assert len(spec.layers) == 2 and not spec.tied_readout
     layer = spec.layers[0]
     assert (layer.op, layer.ff, layer.qk_norm) == ("attention", "experts",
@@ -328,7 +329,7 @@ def test_keye_vl2_is_registered_and_follows_the_description():
     assert layer.held_experts == (2, 4) and layer.num_experts == 8
     assert transformer.LayerSpec().index_heads == 0     # none: as before
     with pytest.raises(ValueError, match="mlp_only_layers"):
-        transformer.keye_vl2_spec(dict(TINY, mlp_only_layers=[0]))
+        family.keye_vl2_spec(dict(TINY, mlp_only_layers=[0]))
 
 
 def test_the_description_yields_exactly_the_paths_its_adapter_names():

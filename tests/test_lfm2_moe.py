@@ -54,7 +54,7 @@ def _program_share(w, x, first, count):
               **{k: w["L0.e" + k][first:first + count]
                  for k in ("w1", "w3", "w2")}}
     y, state = layer.apply({"params": params}, x, mutable=["intermediates"])
-    return y, state["intermediates"]["moe_counts"][0]
+    return y, state["intermediates"]["counters"][0]
 
 
 def _reference_layer(w, x, held=(0, 8)):
@@ -75,8 +75,8 @@ def test_the_shares_add_up_to_the_whole_layer(row_path):
     parts = [_program_share(w, x, first, 2) for first in (0, 2, 4, 6)]
     np.testing.assert_allclose(np.asarray(sum(y for y, _ in parts)),
                                np.asarray(whole), atol=2e-5, rtol=2e-5)
-    assert sum(int(c["slots_local"]) for _, c in parts) == 3 * 40 * 2
-    assert all(int(c["slots_total"]) == 3 * 40 * 2 for _, c in parts)
+    assert sum(int(c["moe_slots_local"]) for _, c in parts) == 3 * 40 * 2
+    assert all(int(c["moe_slots_total"]) == 3 * 40 * 2 for _, c in parts)
     # one share alone is the reference's same share, not a rescaled whole
     np.testing.assert_allclose(
         np.asarray(parts[1][0]), np.asarray(_reference_layer(w, x, (2, 2))),
@@ -92,8 +92,8 @@ def test_nothing_is_dropped_when_every_token_goes_to_one_expert(row_path):
     bias[3] = 10.0
     w, x = _layer_weights(1, bias)
     y, counts = _program_share(w, x, 3, 2)
-    assert int(counts["expert_load_max"]) == 3 * 40
-    assert int(counts["slots_local"]) >= 3 * 40
+    assert int(counts["moe_expert_load_max_sum"]) == 3 * 40
+    assert int(counts["moe_slots_local"]) >= 3 * 40
     want = _reference_layer(w, x, (3, 2))
     np.testing.assert_allclose(np.asarray(y), np.asarray(want),
                                atol=2e-5, rtol=2e-5)
@@ -108,30 +108,36 @@ def test_a_share_that_nothing_is_routed_to_gives_zero(row_path):
     bias[:2] = 10.0        # both slots of every token go to experts 0 and 1
     w, x = _layer_weights(2, bias)
     y, counts = _program_share(w, x, 4, 4)
-    assert int(counts["slots_local"]) == 0
+    assert int(counts["moe_slots_local"]) == 0
     assert not np.asarray(y).any()
 
 
 def test_gpt2_description_keeps_the_parameter_tree():
-    """The decoder under ``gpt2_spec`` (what ``TransformerLM``'s own fields
-    describe) has exactly the parameter paths ``benchmark/adapters/gpt2.py``
-    names, with or without an explicit spec, and the same logits."""
+    """The decoder under ``gpt2_spec`` has exactly the parameter paths
+    ``benchmark/adapters/gpt2.py`` names, and ``build_transformer(...)`` and
+    ``TransformerLM(spec=gpt2_spec(...))`` make the same parameters and the
+    same logits."""
     from benchmark.adapters import gpt2 as adapter
 
     cfg = {"n_layer": 2, "n_head": 4, "n_embd": 64, "n_positions": 32,
            "vocab_size": 97}
     tokens = jnp.asarray(np.arange(64).reshape(2, 32) % 97, jnp.int32)
-    legacy = transformer.build_transformer(
+    built = transformer.build_transformer(
         vocab_size=97, num_layers=2, num_heads=4, head_dim=16, max_seq_len=32)
-    params = legacy.init(jax.random.PRNGKey(0), tokens)["params"]
+    params = built.init(jax.random.PRNGKey(0), tokens)["params"]
     paths = set(traverse_util.flatten_dict(params, sep="/"))
     assert paths == set(adapter.reference_names(cfg))
     explicit = transformer.TransformerLM(
         spec=transformer.gpt2_spec(97, 2, 4, 16, 32))
+    assert explicit == built
     again = explicit.init(jax.random.PRNGKey(0), tokens)["params"]
     assert set(traverse_util.flatten_dict(again, sep="/")) == paths
+    for leaf, leaf_again in zip(jax.tree_util.tree_leaves(params),
+                                jax.tree_util.tree_leaves(again)):
+        np.testing.assert_array_equal(np.asarray(leaf),
+                                      np.asarray(leaf_again))
     np.testing.assert_array_equal(
-        np.asarray(legacy.apply({"params": params}, tokens)),
+        np.asarray(built.apply({"params": params}, tokens)),
         np.asarray(explicit.apply({"params": params}, tokens)))
 
 
@@ -236,6 +242,36 @@ def test_a_model_without_experts_has_no_moe_counters():
         vocab_size=61, num_layers=1, num_heads=2, head_dim=8, max_seq_len=16))
     assert not [k for k in snap if k.startswith(("moe_", "flash_"))]
     assert snap["dispatch_count"] == 3
+
+
+def test_a_layer_kind_of_its_own_counts_without_an_edit_elsewhere():
+    """A layer sows ``counters`` under keys that neither ``loss_fn`` nor the
+    ``Trainer`` has heard of: they come out of ``counters_snapshot()`` under
+    those keys, added up over the layers and the steps, an integer an
+    integer and a float a float."""
+    import flax.linen as nn
+
+    class Counting(nn.Module):
+        @nn.compact
+        def __call__(self, x):
+            self.sow("intermediates", "counters", {
+                "toy_rows": jnp.asarray(x.shape[0], jnp.int32),
+                "toy_halves": jnp.asarray(0.5, jnp.float32)})
+            return nn.Dense(x.shape[-1])(x)
+
+    class ToyLM(nn.Module):
+        @nn.compact
+        def __call__(self, tokens):
+            x = nn.Embed(61, 8)(tokens)
+            for _ in range(2):
+                x = Counting()(x)
+            return nn.Dense(61)(x)
+
+    snap = _fit(ToyLM())
+    assert snap["toy_rows"] == 3 * 2 * 2        # steps x layers x rows
+    assert isinstance(snap["toy_rows"], int)
+    assert snap["toy_halves"] == 3 * 2 * 0.5
+    assert isinstance(snap["toy_halves"], float)
 
 
 @pytest.mark.parametrize("seq, tiles, longest, steps", [

@@ -27,6 +27,7 @@ if ROOT not in sys.path:
 from benchmark.adapters import mellum2 as adapter  # noqa: E402
 from benchmark.references import mellum2 as ref  # noqa: E402
 from tensorflowonspark_tpu.models import get_model, transformer  # noqa: E402
+from tensorflowonspark_tpu.models.families import mellum2 as family  # noqa: E402
 from tensorflowonspark_tpu.ops.flash_attention import band_tiles  # noqa: E402
 
 YARN = {"rope_type": "yarn", "rope_theta": 500000, "factor": 16,
@@ -81,7 +82,8 @@ def test_logits_loss_and_every_gradient_leaf_against_the_reference(attention):
         built["params"], {"tokens": tokens}, jnp.ones((2,)))
     assert float(loss) == pytest.approx(want_loss, rel=2e-5)
     # the band's tiles are counted where the kernels run, and there alone
-    assert ("swa_counts" in aux) == (attention == "flash")
+    assert ("swa_tiles_computed" in aux.get("counters", {})) == (
+        attention == "flash")
     got = traverse_util.flatten_dict(grads, sep="/")
     assert set(got) == set(built["names"])
     for path, name in built["names"].items():
@@ -108,7 +110,7 @@ def test_the_window_changes_the_model():
 # -- the two kinds of layer ---------------------------------------------------
 
 def test_a_full_and_a_sliding_layer_get_their_own_rotary_tables():
-    spec = transformer.mellum2_spec(adapter.program_config(TINY))
+    spec = family.mellum2_spec(adapter.program_config(TINY))
     sliding, full = spec.layers[0], spec.layers[3]
     assert [layer.window for layer in spec.layers] == [40, 40, 40, 0]
     assert sliding.rope_yarn is None and full.rope_yarn is not None
@@ -135,7 +137,7 @@ def test_a_full_and_a_sliding_layer_get_their_own_rotary_tables():
         rtol=1e-6)
     # a table without attention_factor: YaRN's own 0.1 ln(factor) + 1
     bare = {k: v for k, v in YARN.items() if k != "attention_factor"}
-    again = transformer.mellum2_spec(dict(
+    again = family.mellum2_spec(dict(
         adapter.program_config(TINY),
         rope_parameters=dict(TINY["rope_parameters"], full_attention=bare)))
     assert again.layers[3].rope_yarn == full.rope_yarn[:4] + (1.0, 0.0)
@@ -207,9 +209,9 @@ def test_the_shares_of_the_eight_chips_add_up_to_the_uncut_layer(row_path):
                      for k in ("w1", "w3", "w2")}}
         y, state = layer.apply({"params": params}, x,
                                mutable=["intermediates"])
-        counts = state["intermediates"]["moe_counts"][0]
-        assert int(counts["slots_total"]) == 2 * 40 * 3
-        total, local = total + y, local + int(counts["slots_local"])
+        counts = state["intermediates"]["counters"][0]
+        assert int(counts["moe_slots_total"]) == 2 * 40 * 3
+        total, local = total + y, local + int(counts["moe_slots_local"])
         if first == 6:      # one share alone is the reference's same share
             mine = dict(w, **{k: w[k][first:first + 2]
                               for k in ("L0.ew1", "L0.ew3", "L0.ew2")})
@@ -225,7 +227,7 @@ def test_the_shares_of_the_eight_chips_add_up_to_the_uncut_layer(row_path):
 # -- the description, its tree, its counters ----------------------------------
 
 def test_mellum2_is_registered_and_follows_the_description():
-    spec = transformer.mellum2_spec(adapter.program_config(TINY))
+    spec = family.mellum2_spec(adapter.program_config(TINY))
     assert len(spec.layers) == 4 and not spec.tied_readout
     for layer in spec.layers:
         assert (layer.op, layer.ff, layer.qk_norm) == ("attention",
@@ -242,10 +244,10 @@ def test_mellum2_is_registered_and_follows_the_description():
                        ("mlp_layer_types", ["dense"] * 4),
                        ("use_sliding_window", False)):
         with pytest.raises(ValueError, match=key):
-            transformer.mellum2_spec(dict(adapter.program_config(TINY),
+            family.mellum2_spec(dict(adapter.program_config(TINY),
                                           **{key: value}))
     with pytest.raises(ValueError, match="num_hidden_layers"):
-        transformer.mellum2_spec(dict(adapter.program_config(TINY),
+        family.mellum2_spec(dict(adapter.program_config(TINY),
                                       num_hidden_layers=5))
 
 

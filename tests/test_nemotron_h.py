@@ -30,6 +30,7 @@ if ROOT not in sys.path:
 from benchmark.adapters import nemotron_h as adapter  # noqa: E402
 from benchmark.references import nemotron_h as ref  # noqa: E402
 from tensorflowonspark_tpu.models import get_model, transformer  # noqa: E402
+from tensorflowonspark_tpu.models.families import nemotron_h as family  # noqa: E402
 from tensorflowonspark_tpu.ops import ssd_scan as ssd  # noqa: E402
 
 TINY = {"attention_bias": False, "chunk_size": 16, "conv_kernel": 4,
@@ -88,8 +89,8 @@ def test_logits_loss_and_every_gradient_leaf_against_the_reference(
     (loss, aux), grads = jax.value_and_grad(built["loss"], has_aux=True)(
         built["params"], {"tokens": tokens}, jnp.ones((2,)))
     assert float(loss) == pytest.approx(want_loss, rel=2e-5)
-    assert int(aux["ssd_counts"]["ssd_layers"]) == 2
-    assert int(aux["ssd_counts"]["ssd_chunks"]) == 2 * 2 * 64 // 16
+    assert int(aux["counters"]["ssd_layers"]) == 2
+    assert int(aux["counters"]["ssd_chunks"]) == 2 * 2 * 64 // 16
     got = traverse_util.flatten_dict(grads, sep="/")
     assert set(got) == set(built["names"])
     for path, name in built["names"].items():
@@ -245,9 +246,9 @@ def test_the_shares_of_the_sixteen_chips_add_up_to_the_uncut_layer(row_path):
                   "w2": w["L0.ew2"][first:first + 2]}
         y, state = layer.apply({"params": params}, x,
                                mutable=["intermediates"])
-        counts = state["intermediates"]["moe_counts"][0]
-        assert int(counts["slots_total"]) == 2 * 40 * 3
-        total, local = total + y, local + int(counts["slots_local"])
+        counts = state["intermediates"]["counters"][0]
+        assert int(counts["moe_slots_total"]) == 2 * 40 * 3
+        total, local = total + y, local + int(counts["moe_slots_local"])
         if first == 6:      # one share alone is the reference's same share
             mine = dict(w, **{k: w[k][first:first + 2]
                               for k in ("L0.ew1", "L0.ew2")})
@@ -267,7 +268,7 @@ def test_the_shares_of_the_sixteen_chips_add_up_to_the_uncut_layer(row_path):
 # -- the description, its tree, its counters ----------------------------------
 
 def test_nemotron_h_is_registered_and_follows_the_description():
-    spec = transformer.nemotron_h_spec(adapter.program_config(TINY))
+    spec = family.nemotron_h_spec(adapter.program_config(TINY))
     assert len(spec.layers) == 5 and not spec.tied_readout
     assert [(layer.op, layer.ff) for layer in spec.layers] == [
         ("mamba2", "none"), ("none", "experts"), ("mamba2", "none"),
@@ -283,7 +284,7 @@ def test_nemotron_h_is_registered_and_follows_the_description():
             == ("sigmoid", True, True, 2.5, 40, "relu2")
         assert layer.held_experts == (2, 4) and layer.num_experts == 8
     assert spec.layers[0] is spec.layers[2]
-    dense = transformer.nemotron_h_spec(dict(
+    dense = family.nemotron_h_spec(dict(
         adapter.program_config(TINY), hybrid_override_pattern="M-M*E"))
     assert (dense.layers[1].op, dense.layers[1].ff) == ("none", "relu2")
     for key, value in (("hybrid_override_pattern", "MEMXE"),
@@ -292,10 +293,10 @@ def test_nemotron_h_is_registered_and_follows_the_description():
                        ("sliding_window", 64)):
         wrong = {"mlp_bias": True} if key == "bias" else {key: value}
         with pytest.raises(ValueError, match=key):
-            transformer.nemotron_h_spec(dict(adapter.program_config(TINY),
+            family.nemotron_h_spec(dict(adapter.program_config(TINY),
                                              **wrong))
     with pytest.raises(ValueError, match="num_hidden_layers"):
-        transformer.nemotron_h_spec(dict(adapter.program_config(TINY),
+        family.nemotron_h_spec(dict(adapter.program_config(TINY),
                                          num_hidden_layers=6))
 
 

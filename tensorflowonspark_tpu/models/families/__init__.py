@@ -5,4 +5,4 @@ with ``get_model`` by
 family is a file here and an import below."""
 
 from tensorflowonspark_tpu.models.families import (  # noqa: F401
-    deepseek_v2, keye_vl2, lfm2_moe, mellum2, nemotron_h)
+    deepseek_v2, keye_vl2, lfm2_moe, mellum2, nemotron_h, olmo_hybrid)

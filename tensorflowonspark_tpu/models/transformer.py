@@ -40,7 +40,8 @@ a description:
   GPT-2 decoder's as they always were;
 - a family's spec function, one module a family under
   :mod:`tensorflowonspark_tpu.models.families` (``lfm2_moe``,
-  ``deepseek_v2``, ``keye_vl2``, ``mellum2``, ``nemotron_h``), which reads
+  ``deepseek_v2``, ``keye_vl2``, ``mellum2``, ``nemotron_h``,
+  ``olmo_hybrid``), which reads
   the family's ``config.json`` and which :func:`register_decoder` registers
   with ``get_model``.  A new family is a file there; a layer kind it brings
   is a ``LayerSpec`` value and a branch of ``Block._op`` / ``Block._ff``
@@ -71,7 +72,11 @@ the index's loss),
 ``block_i/mamba/in_proj``, ``mamba/conv`` (taps, bias, silu), ``mamba/scan``
 (the scan's kernels and the decays' sums they read, nothing else),
 ``mamba/gate_norm`` (the skip, the gate, the grouped norm) and
-``mamba/out_proj``.
+``mamba/out_proj``; of a Gated DeltaNet layer ``block_i/delta/in_proj``,
+``delta/conv`` (taps, silu), ``delta/scan`` (the L2 norms of q and k, the
+delta rule's kernels and the decays' sums they read, nothing else),
+``delta/gate_norm`` (a head's norm, the gate) and ``delta/out_proj``; its
+counters are ``delta_chunks``, ``delta_state_bytes`` and ``delta_layers``.
 """
 
 import dataclasses
@@ -87,13 +92,15 @@ from tensorflowonspark_tpu.parallel import ring
 
 @dataclasses.dataclass(frozen=True)
 class LayerSpec:
-    """One decoder layer, ``x + op(norm(x))`` then ``x + ff(norm(x))``: its
-    kinds, and the widths they need.  A layer may be one half alone
+    """One decoder layer, ``x + op(norm(x))`` then ``x + ff(norm(x))`` (or,
+    ``norm_place="output"``, ``x + norm(op(x))`` then ``x + norm(ff(x))``):
+    its kinds, and the widths they need.  A layer may be one half alone
     (``op="none"`` or ``ff="none"``): one norm and one residual add."""
 
     op: str = "attention"          # attention | conv (gated short convolution)
     #                                | mla (latent attention, see below)
     #                                | mamba2 (state-space scan: Mamba2)
+    #                                | gated_delta (delta rule: GatedDelta)
     #                                | none (a feed-forward layer)
     ff: str = "gelu"               # gelu | switch (top-1, capacity: MoEMlp)
     #                                | swiglu | experts (top-k: TopKExperts)
@@ -101,6 +108,9 @@ class LayerSpec:
     #                                | none (a mixer layer)
     norm: str = "layernorm"        # layernorm | rmsnorm
     norm_eps: float = 1e-6
+    norm_place: str = "input"      # input: a part reads the normed stream
+    #                                | output: the part's result is normed
+    #                                before it is added to the stream
     positions: str = "learned"     # learned (a table added to the embedding:
     #                                nothing in the layer) | rope (on q and k)
     #                                | none (nothing anywhere)
@@ -110,7 +120,10 @@ class LayerSpec:
     # (GPT-2's); a number: separate q/k/v/o projections without biases,
     # query head i reading KV head i // (num_heads // num_kv_heads)
     num_kv_heads: Optional[int] = None
-    qk_norm: bool = False          # per-head RMSNorm on q and on k
+    # RMSNorm on q and on k: False | "head" (or True: over a head's width,
+    # one weight [head_dim] for all heads) | "whole" (over the whole
+    # projection before the split into heads, a weight [heads * head_dim])
+    qk_norm: object = False
     rope_theta: float = 10000.0
     rope_pairing: str = "half"     # half: dimension i turns with i + D/2
     #                                | interleaved: 2i with 2i + 1
@@ -169,12 +182,26 @@ class LayerSpec:
     ssm_state: int = 0
     ssm_groups: int = 1
     ssm_chunk: int = 128
+    # op="gated_delta": delta_heads heads with keys (and queries) of
+    # delta_key_dim and values of delta_value_dim, conv_kernel taps, chunks
+    # of delta_chunk positions; delta_neg_eigval: the write strength b in
+    # (0, 2) for (0, 1), so that I - b k k^T may turn a key's part around
+    delta_heads: int = 0
+    delta_key_dim: int = 0
+    delta_value_dim: int = 0
+    delta_neg_eigval: bool = False
+    delta_chunk: int = 64
 
     def __post_init__(self):
         if self.op == "none" and self.ff == "none":
             raise ValueError("a layer with neither op nor ff")
+        if self.norm_place not in ("input", "output"):
+            raise ValueError("norm_place={!r}".format(self.norm_place))
+        if self.qk_norm not in (False, True, "head", "whole"):
+            raise ValueError("qk_norm={!r}".format(self.qk_norm))
         if self.window < 0 or self.window and (
-                self.op in ("conv", "mamba2", "none") or self.index_topk > 0):
+                self.op in ("conv", "mamba2", "gated_delta", "none")
+                or self.index_topk > 0):
             raise ValueError(
                 "window={} wants an attention layer without an index over "
                 "the keys (op={!r}, index_topk={})".format(
@@ -312,7 +339,7 @@ class Attention(nn.Module):
     dtype: jnp.dtype = jnp.float32
     # grouped-query form (see LayerSpec.num_kv_heads); None = GPT-2's
     num_kv_heads: Optional[int] = None
-    qk_norm: bool = False
+    qk_norm: object = False   # LayerSpec.qk_norm
     norm_eps: float = 1e-6
     rope_theta: Optional[float] = None
     rope_yarn: Optional[Tuple[float, ...]] = None   # LayerSpec.rope_yarn
@@ -447,7 +474,12 @@ class Attention(nn.Module):
                 for name, heads in (("q", self.num_heads),
                                     ("k", self.num_kv_heads),
                                     ("v", self.num_kv_heads)))
-        if self.qk_norm:
+        if self.qk_norm == "whole":     # the statistic over all the heads
+            q, k = (
+                nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype, name=name)(
+                    t.reshape(t.shape[:2] + (-1,))).reshape(t.shape)
+                for name, t in (("q_norm", q), ("k_norm", k)))
+        elif self.qk_norm:
             q = nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype,
                            name="q_norm")(q)
             k = nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype,
@@ -625,6 +657,87 @@ class Mamba2(nn.Module):
             y = (by_group * jax.lax.rsqrt(
                 jnp.square(by_group).mean(-1, keepdims=True) + self.norm_eps)
                  ).reshape(batch, seq, inner) * scale.astype(f32)
+        return nn.Dense(d_model, use_bias=False, dtype=self.dtype,
+                        name="out_proj")(y.astype(self.dtype))
+
+
+class GatedDelta(nn.Module):
+    """A Gated DeltaNet mixer (arXiv:2412.06464): ``[q | k | v | z | a | b]
+    = x W_in`` (``heads * key_dim`` twice, ``heads * value_dim`` twice,
+    ``heads`` twice; no bias); ``q, k, v = silu(conv(.))``, the convolution
+    depthwise and causal, ``conv_kernel`` taps a channel, no bias, zeros
+    before the row; a head's ``q`` and ``k`` L2-normalised (``x / sqrt(sum
+    x^2 + 1e-6)``), ``q`` times ``key_dim ** -0.5``; ``b = sigmoid(b)``
+    (times 2 where ``neg_eigval``), ``g = -exp(A_log) softplus(a +
+    dt_bias)`` a head; the delta rule ``S_t = exp(g_t) S_{t-1} + b_t k_t^T
+    (v_t - exp(g_t) k_t S_{t-1})``, ``o_t = q_t S_t``
+    (:func:`~tensorflowonspark_tpu.ops.gated_delta.gated_delta_rule`, chunks
+    of ``chunk``); ``y = RMSNorm_head(o) * w * silu(z)``, one weight
+    ``[value_dim]`` for all heads; ``y W_out``.  Products in ``dtype``; the
+    L2 norms, ``b``, ``g``, the decays' sums, the chunk's inverse, the
+    carried state and the norm's statistics float32; ``A_log`` and
+    ``dt_bias`` float32 leaves.
+
+    The chunks scanned and the bytes of the chunk states written are sown
+    as counters (``delta_chunks``; ``delta_state_bytes``, float32: a step's
+    can pass 2**31; ``delta_layers`` the layer calls)."""
+
+    heads: int
+    key_dim: int
+    value_dim: int
+    conv_kernel: int = 4
+    neg_eigval: bool = False
+    chunk: int = 64
+    norm_eps: float = 1e-6
+    dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        from tensorflowonspark_tpu.ops import gated_delta
+
+        batch, seq, d_model = x.shape
+        f32 = jnp.float32
+        keys, values = self.heads * self.key_dim, self.heads * self.value_dim
+        qkv, z, a, b = jnp.split(
+            nn.Dense(2 * keys + 2 * values + 2 * self.heads, use_bias=False,
+                     dtype=self.dtype, name="in_proj")(x),
+            [2 * keys + values, 2 * keys + 2 * values,
+             2 * keys + 2 * values + self.heads], axis=-1)
+        taps = self.param("conv", nn.initializers.normal(0.02),
+                          (self.conv_kernel, 2 * keys + values))
+        a_log = self.param(
+            "A_log", lambda key, shape: jnp.log(jax.random.uniform(
+                key, shape, f32, 1.0, 16.0)), (self.heads,))
+        dt_bias = self.param("dt_bias", _inverse_softplus_steps(),
+                             (self.heads,))
+        scale = self.param("norm", nn.initializers.ones, (self.value_dim,))
+        with jax.named_scope("conv"):
+            qkv = nn.silu(_causal_taps(qkv, taps.astype(self.dtype)))
+        q, k, v = jnp.split(qkv, [keys, 2 * keys], axis=-1)
+        q = q.reshape(batch, seq, self.heads, self.key_dim)
+        k = k.reshape(batch, seq, self.heads, self.key_dim)
+        v = v.reshape(batch, seq, self.heads, self.value_dim)
+        beta = jax.nn.sigmoid(b.astype(f32)) * (2.0 if self.neg_eigval
+                                                else 1.0)
+        g = -jnp.exp(a_log.astype(f32)) * jax.nn.softplus(
+            a.astype(f32) + dt_bias.astype(f32))
+        with jax.named_scope("scan"):
+            unit = lambda t: t.astype(f32) * jax.lax.rsqrt(  # noqa: E731
+                jnp.square(t.astype(f32)).sum(-1, keepdims=True) + 1e-6)
+            o = gated_delta.gated_delta_rule(
+                (unit(q) * self.key_dim ** -0.5).astype(self.dtype),
+                unit(k).astype(self.dtype), v, g, beta, chunk=self.chunk)
+        chunks, state_bytes = gated_delta.chunk_counts(k, v, self.chunk)
+        self.sow("intermediates", "counters", {
+            "delta_chunks": jnp.asarray(chunks, jnp.int32),
+            "delta_state_bytes": jnp.asarray(state_bytes, f32),
+            "delta_layers": jnp.asarray(1, jnp.int32)})
+        with jax.named_scope("gate_norm"):
+            o = o.astype(f32)
+            o = o * jax.lax.rsqrt(
+                jnp.square(o).mean(-1, keepdims=True) + self.norm_eps)
+            y = (o * scale.astype(f32)).reshape(batch, seq, values) \
+                * nn.silu(z.astype(f32))
         return nn.Dense(d_model, use_bias=False, dtype=self.dtype,
                         name="out_proj")(y.astype(self.dtype))
 
@@ -885,12 +998,14 @@ class Block(nn.Module):
     @nn.compact
     def __call__(self, x):
         spec = self.spec
-        if spec.op != "none":
-            x = x + self._op(spec, _norm(spec.norm, spec.norm_eps,
-                                         self.dtype)(x))
-        if spec.ff != "none":
-            x = x + self._ff(spec, _norm(spec.norm, spec.norm_eps,
-                                         self.dtype)(x))
+        for kind, part in ((spec.op, self._op), (spec.ff, self._ff)):
+            if kind == "none":
+                continue
+            norm = _norm(spec.norm, spec.norm_eps, self.dtype)
+            if spec.norm_place == "output":
+                x = x + norm(part(spec, x))
+            else:
+                x = x + part(spec, norm(x))
         return x
 
     @nn.nowrap
@@ -902,6 +1017,11 @@ class Block(nn.Module):
             return Mamba2(spec.ssm_heads, spec.ssm_head_dim, spec.ssm_state,
                           spec.ssm_groups, spec.conv_kernel, spec.ssm_chunk,
                           spec.norm_eps, self.dtype, name="mamba")(h)
+        if spec.op == "gated_delta":
+            return GatedDelta(spec.delta_heads, spec.delta_key_dim,
+                              spec.delta_value_dim, spec.conv_kernel,
+                              spec.delta_neg_eigval, spec.delta_chunk,
+                              spec.norm_eps, self.dtype, name="delta")(h)
         # the fused form keeps flax's own name (Attention_0: checkpoints
         # of the GPT-2 decoder), the grouped-query and latent forms are
         # "attention"

@@ -8,7 +8,9 @@ and the row movement around it, which stops at the rows routed here
 (:mod:`~tensorflowonspark_tpu.ops.routed_rows`), and the chunked
 state-space scan of a Mamba-2 layer with its backward
 (:mod:`~tensorflowonspark_tpu.ops.ssd_scan`; its function is
-``ops.ssd_scan.ssd_scan``: the name here stays the module's).
+``ops.ssd_scan.ssd_scan``: the name here stays the module's), and the
+chunked gated delta rule of a Gated DeltaNet layer with its backward
+(:mod:`~tensorflowonspark_tpu.ops.gated_delta`, ``gated_delta_rule``).
 Every kernel runs in pallas interpret mode off-TPU, so the suite validates
 them on the CPU mesh.
 
@@ -18,11 +20,11 @@ rematerialised block keeps (``save_only_these_names(*KEPT)``).  A kernel with
 such a residual adds its names to its module's ``KEPT`` and its module here.
 """
 
-from tensorflowonspark_tpu.ops import sparse_index, ssd_scan
+from tensorflowonspark_tpu.ops import gated_delta, sparse_index, ssd_scan
 from tensorflowonspark_tpu.ops.flash_attention import (  # noqa: F401
     KEPT as _FLASH_KEPT, flash_attention, flash_attention_lse)
 from tensorflowonspark_tpu.ops.grouped_matmul import grouped_matmul  # noqa: F401
 from tensorflowonspark_tpu.ops.routed_rows import (  # noqa: F401
     gather_rows, gather_sum_rows)
 
-KEPT = _FLASH_KEPT + sparse_index.KEPT + ssd_scan.KEPT
+KEPT = _FLASH_KEPT + sparse_index.KEPT + ssd_scan.KEPT + gated_delta.KEPT

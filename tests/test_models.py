@@ -24,7 +24,8 @@ def test_registry():
     ("lfm2_moe", "lfm2_8b_a1b_ep4"), ("deepseek_v2", "deepseek_v2_lite_ep8"),
     ("keye_vl2", "keye_vl2_30b_a3b_ep8"),
     ("mellum2", "mellum2_12b_a2p5b_ep8"),
-    ("nemotron_h", "nemotron3_nano_30b_a3b_ep16")])
+    ("nemotron_h", "nemotron3_nano_30b_a3b_ep16"),
+    ("olmo_hybrid", "olmo_hybrid_7b_tp2")])
 def test_a_registered_family_is_the_one_decoder_under_its_description(
         family, config_name):
     """``get_model(<family>, config=...)`` is a ``TransformerLM`` whose

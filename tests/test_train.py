@@ -850,3 +850,50 @@ def test_counters_snapshot_tells_the_bringup_only_once_it_is_whole(
     assert [w for _, w in seen] == [snap["bringup_wall_us"]] * 3
     assert not [k for k in tr._own_counters()
                 if k.startswith(("bringup_", "compile_"))]
+
+
+def _pointers(tree):
+    return {shard.data.unsafe_buffer_pointer()
+            for leaf in jax.tree_util.tree_leaves(tree)
+            for shard in leaf.addressable_shards}
+
+
+def test_the_trainer_owns_its_buffers_without_holding_adams_moments_twice():
+    """What the caller hands over is copied (the donated step must not
+    delete the caller's arrays); what ``optimizer.init`` made here is not:
+    a copy of the whole state held Adam's two moments twice for a moment."""
+    from tensorflowonspark_tpu import train as train_mod
+
+    params = {"w": jnp.ones((8, 4)), "b": jnp.zeros((4,))}
+    optimizer = optax.adam(1e-3)
+    made = optimizer.init(params)
+    state = train_mod._own(train_mod.TrainState(
+        step=jnp.zeros((), jnp.int32), params=params, opt_state=made))
+    assert not _pointers(state.params) & _pointers(params)
+    assert _pointers(state.opt_state) == _pointers(made)
+    trainer = Trainer(lambda p, batch, mask: (jnp.sum(p["w"]) * 0.0
+                                              + jnp.sum(batch["x"] @ p["w"]
+                                                        + p["b"]), {}),
+                      params, optimizer, batch_size=2)
+    trainer.step({"x": jnp.ones((2, 8))}, jnp.ones((2,)))
+    assert float(params["w"].sum()) == 32.0      # the caller's, still there
+
+
+def test_an_optimizer_state_that_is_the_parameters_is_copied():
+    """Leaves of the optimizer's state that share a buffer with the
+    parameters, or with each other, get buffers of their own: donating one
+    buffer twice is an error, and the caller's would be deleted."""
+    from tensorflowonspark_tpu import train as train_mod
+
+    params = {"w": jnp.ones((8, 4))}
+    fresh = jnp.zeros((8, 4))
+    state = train_mod._own(train_mod.TrainState(
+        step=jnp.zeros((), jnp.int32), params=params,
+        opt_state={"slow": params["w"], "mu": fresh, "again": fresh}))
+    taken = [_pointers(leaf) for leaf in (
+        state.params, state.opt_state["slow"], state.opt_state["mu"],
+        state.opt_state["again"])]
+    assert len(set.union(*taken)) == sum(len(t) for t in taken) == 4
+    assert not set.union(*taken) & _pointers(params)
+    # the first of two that share one keeps it (a dict's leaves go by key)
+    assert _pointers(state.opt_state["again"]) == _pointers(fresh)

@@ -798,7 +798,11 @@ class TopKExperts(nn.Module):
     The token-slot counts of the call are sown as counters
     (``moe_slots_total``, ``moe_slots_local``, the heaviest and the mean
     held expert's count as ``moe_expert_load_max_sum`` and
-    ``moe_expert_load_mean_sum``, ``moe_layers_steps`` the layer calls)."""
+    ``moe_expert_load_mean_sum``, ``moe_layers_steps`` the layer calls),
+    and beside them the row tiles of a sorted buffer that the passes between
+    the grouped products visit of those there are (``moe_gate_tiles_live``,
+    ``moe_gate_tiles_total``: ``moe_slots_local / moe_slots_total`` rounded
+    up to a tile)."""
 
     num_experts: int
     experts_per_token: int
@@ -839,6 +843,8 @@ class TopKExperts(nn.Module):
             "moe_slots_local": load["slots_local"],
             "moe_expert_load_max_sum": load["expert_load_max"],
             "moe_expert_load_mean_sum": load["expert_load_mean"],
+            "moe_gate_tiles_live": load["gate_tiles_live"],
+            "moe_gate_tiles_total": load["gate_tiles_total"],
             "moe_layers_steps": jnp.asarray(1, jnp.int32)})
         y = y.reshape(batch, seq, d_model)
         if self.shared:

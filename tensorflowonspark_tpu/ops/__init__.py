@@ -4,8 +4,10 @@ The compute path is jax/XLA; these kernels cover the few ops where
 hand-scheduling VMEM traffic beats XLA's fusion — attention first
 (:mod:`~tensorflowonspark_tpu.ops.flash_attention`), then the grouped matrix
 product of an expert layer (:mod:`~tensorflowonspark_tpu.ops.grouped_matmul`)
-and the row movement around it, which stops at the rows routed here
-(:mod:`~tensorflowonspark_tpu.ops.routed_rows`), and the chunked
+and the row movement around it and the row-wise passes between its
+products, which stop at the rows routed here
+(:mod:`~tensorflowonspark_tpu.ops.routed_rows`,
+:mod:`~tensorflowonspark_tpu.ops.expert_gate`), and the chunked
 state-space scan of a Mamba-2 layer with its backward
 (:mod:`~tensorflowonspark_tpu.ops.ssd_scan`; its function is
 ``ops.ssd_scan.ssd_scan``: the name here stays the module's), and the

@@ -10,7 +10,10 @@ that lie inside a group (a scalar-prefetched tile -> group map; a tile that
 straddles a boundary is visited once a group, masked), so the work follows
 ``sum(group_sizes)``, not the buffer.  **Rows behind the last group are not
 written** and, where a product sums over rows (the gradient of ``rhs``), not
-read: the expert layer leaves them as they are and reads none of them.
+read: the expert layer leaves them as they are and reads none of them (what
+it does to a product's rows between two products, the gate and the sum of two
+input gradients, visits the row tiles in front of the last group only:
+:mod:`~tensorflowonspark_tpu.ops.expert_gate`).
 
 The kernels are the ``megablox`` grouped products that ship with jax
 (``jax.experimental.pallas.ops.tpu.megablox``: ``gmm`` for the forward
@@ -119,9 +122,8 @@ def _gmm_fwd(lhs, rhs, group_sizes, interpret):
     return _gmm(lhs, rhs, group_sizes, interpret), (lhs, rhs, group_sizes)
 
 
-def _gmm_bwd(interpret, residual, grad):
+def _gmm_grads(lhs, rhs, group_sizes, grad, interpret):
     backend = _backend()
-    lhs, rhs, group_sizes = residual
     m, k = lhs.shape
     n = rhs.shape[2]
     size = lhs.dtype.itemsize
@@ -132,7 +134,11 @@ def _gmm_bwd(interpret, residual, grad):
     d_rhs = backend.tgmm(lhs.swapaxes(0, 1), grad, group_sizes, rhs.dtype,
                          _tiling(m, k, n, size, out_rows="k"),
                          interpret=interpret)
-    return d_lhs, d_rhs, None
+    return d_lhs, d_rhs
+
+
+def _gmm_bwd(interpret, residual, grad):
+    return _gmm_grads(*residual, grad, interpret) + (None,)
 
 
 _gmm.defvjp(_gmm_fwd, _gmm_bwd)
@@ -156,3 +162,25 @@ def grouped_matmul(lhs, rhs, group_sizes, impl=None, interpret=False):
         raise ValueError("unknown grouped_matmul impl {!r}".format(impl))
     return _gmm(lhs, rhs.astype(lhs.dtype), group_sizes.astype(jnp.int32),
                 interpret)
+
+
+def grouped_matmul_grads(lhs, rhs, group_sizes, grad, impl=None,
+                         interpret=False):
+    """The cotangents ``(d_lhs [m, k], d_rhs [groups, k, n])`` of
+    :func:`grouped_matmul`'s operands from the result's, ``grad [m, n]``: what
+    differentiating it gives, for a caller that writes the backward of
+    several products itself (an expert's two "up" products share their
+    ``lhs``, and
+    :func:`~tensorflowonspark_tpu.parallel.ep.experts_ffn` sums their two
+    ``d_lhs`` in front of the last group only).  Rows of ``d_lhs`` behind
+    the last group are unspecified and rows of ``grad`` there are not read;
+    ``impl`` as :func:`grouped_matmul`."""
+    if impl is None:
+        impl = _default_impl()
+    if impl == "xla":
+        return jax.vjp(lambda lhs, rhs: jax.lax.ragged_dot(
+            lhs, rhs, group_sizes), lhs, rhs)[1](grad)
+    if impl != "pallas":
+        raise ValueError("unknown grouped_matmul impl {!r}".format(impl))
+    return _gmm_grads(lhs, rhs.astype(lhs.dtype),
+                      group_sizes.astype(jnp.int32), grad, interpret)

@@ -10,7 +10,9 @@ every row of the buffer whatever ``n_rows`` says.  These kernels fetch a row
 only where its sorted position lies in front of ``n_rows`` (one DMA a row,
 a tile's DMAs all in flight together), so their work follows the routed
 pairs as the grouped products' does
-(:mod:`~tensorflowonspark_tpu.ops.grouped_matmul`):
+(:mod:`~tensorflowonspark_tpu.ops.grouped_matmul`) and that of the row-wise
+passes between those (:mod:`~tensorflowonspark_tpu.ops.expert_gate`, which
+takes its row tiles through this module's ``_front_tile``):
 
 - :func:`gather_rows`: ``out[i] = scale[i] * x[src[i]]`` for ``i < n_rows``
   (dispatch; the gradient of combine with respect to the experts' output,

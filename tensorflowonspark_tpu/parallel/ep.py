@@ -20,10 +20,11 @@ routers of :mod:`~tensorflowonspark_tpu.models.transformer`.
   knows), and the pairs whose expert lives elsewhere contribute nothing: the layer returns its own
   experts' part of the sum, which is what one chip of an expert-parallel
   deployment computes before the exchange.  Buffers have the static
-  worst-case size (every pair routed here); the work of the grouped products
-  and of the row movement around them
-  (:mod:`~tensorflowonspark_tpu.ops.routed_rows`) follows the pairs that
-  are.  ``TopKExperts`` uses it.  Nothing here stands
+  worst-case size (every pair routed here); the work of the grouped
+  products, of the row-wise passes between them
+  (:mod:`~tensorflowonspark_tpu.ops.expert_gate`) and of the row movement
+  around them (:mod:`~tensorflowonspark_tpu.ops.routed_rows`) follows the
+  pairs that are.  ``TopKExperts`` uses it.  Nothing here stands
   in for the absent chips: on one chip there is no exchange.
 
 **Two ways over the mesh** for the capacity router's layer (the last of the
@@ -238,6 +239,47 @@ def _combine(ys, weights, order, idx, n_local):
     return combine(ys, weights, order, idx, n_local)
 
 
+def _up(xs, w1, w3, group_sizes, n_local, act):
+    """The "up" half of the held experts on the sorted rows: ``silu(xs W_1)
+    * (xs W_3)``, or ``relu(xs W_1) ** 2`` (``w3`` None), rows from
+    ``n_local`` on unspecified.  Both directions are written here so that
+    every pass over a sorted buffer stops at ``n_local``
+    (:mod:`~tensorflowonspark_tpu.ops.expert_gate`): differentiating the
+    plain form leaves the gate, its backward and the sum of the two products'
+    input gradients to fusions that pass over the whole buffer.  Kept for
+    the backward are ``xs`` and the two products, as differentiating
+    keeps them."""
+    import jax
+
+    from tensorflowonspark_tpu.ops.expert_gate import (add_rows, gate,
+                                                        gate_grad)
+    from tensorflowonspark_tpu.ops.grouped_matmul import (
+        grouped_matmul, grouped_matmul_grads)
+
+    def fwd(xs, w1, w3, group_sizes, n_local):
+        h1 = grouped_matmul(xs, w1, group_sizes)
+        h3 = None if w3 is None else grouped_matmul(xs, w3, group_sizes)
+        return (gate(h1, h3, n_local, act),
+                (xs, w1, w3, h1, h3, group_sizes, n_local))
+
+    @jax.custom_vjp
+    def up(xs, w1, w3, group_sizes, n_local):
+        return fwd(xs, w1, w3, group_sizes, n_local)[0]
+
+    def bwd(residual, d_h):
+        xs, w1, w3, h1, h3, group_sizes, n_local = residual
+        d_h1, d_h3 = gate_grad(h1, h3, d_h, n_local, act)
+        d_xs, d_w1 = grouped_matmul_grads(xs, w1, group_sizes, d_h1)
+        d_w3 = None
+        if w3 is not None:
+            d_xs3, d_w3 = grouped_matmul_grads(xs, w3, group_sizes, d_h3)
+            d_xs = add_rows(d_xs, d_xs3, n_local)
+        return d_xs, d_w1, d_w3, None, None
+
+    up.defvjp(fwd, bwd)
+    return up(xs, w1, w3, group_sizes, n_local)
+
+
 def experts_ffn(x, sel, weights, w1, w3, w2, first, dtype=None,
                 act="swiglu"):
     """The held experts' part of a top-k expert layer.
@@ -252,7 +294,9 @@ def experts_ffn(x, sel, weights, w1, w3, w2, first, dtype=None,
     ``y = sum over the token's slots whose expert is held of weight *
     expert_e(x)``, and ``load``, the
     token-slot counts of this call (int32 scalars ``slots_total``,
-    ``slots_local``, ``expert_load_max``; float32 ``expert_load_mean``).
+    ``slots_local``, ``expert_load_max``; float32 ``expert_load_mean``)
+    with the row tiles of a sorted buffer that the gate's passes visit of
+    those there are (int32 ``gate_tiles_live``, ``gate_tiles_total``).
 
     No pair is dropped: the sorted buffers hold all ``T * k`` pairs (the
     worst case, every pair routed here), the ``n_local`` pairs of held
@@ -265,12 +309,17 @@ def experts_ffn(x, sel, weights, w1, w3, w2, first, dtype=None,
     construction, the three grouped products
     (:func:`~tensorflowonspark_tpu.ops.grouped_matmul.grouped_matmul`: pallas
     kernels on a TPU, ``jax.lax.ragged_dot`` elsewhere) by their tile map,
-    and what lies between them works row by row.  ``slots_local /
+    and the row-wise passes between them (the gate, its backward and the sum
+    of the two "up" products' input gradients,
+    :mod:`~tensorflowonspark_tpu.ops.expert_gate`: pallas kernels on a TPU,
+    the plain ``jax.numpy`` form elsewhere) by theirs.  ``slots_local /
     slots_total`` is therefore the share of the rows that is fetched, and 1
-    minus it the share that is skipped."""
+    minus it the share that is skipped; ``gate_tiles_live /
+    gate_tiles_total`` is that share rounded up to a row tile."""
     import jax
     import jax.numpy as jnp
 
+    from tensorflowonspark_tpu.ops.expert_gate import row_tile
     from tensorflowonspark_tpu.ops.grouped_matmul import grouped_matmul
 
     dtype = dtype or x.dtype
@@ -284,19 +333,19 @@ def experts_ffn(x, sel, weights, w1, w3, w2, first, dtype=None,
     if act not in ("swiglu", "relu2"):
         raise ValueError("unknown expert form {!r}".format(act))
     with jax.named_scope("experts"):
-        h = grouped_matmul(xs, w1.astype(dtype), group_sizes)
-        if act == "relu2":
-            h = jnp.square(jax.nn.relu(h))
-        else:
-            h = jax.nn.silu(h) * grouped_matmul(xs, w3.astype(dtype),
-                                                group_sizes)
+        h = _up(xs, w1.astype(dtype),
+                None if act == "relu2" else w3.astype(dtype), group_sizes,
+                n_local, act)
         ys = grouped_matmul(h, w2.astype(dtype), group_sizes)
     with jax.named_scope("combine"):
         y = _combine(ys, weights, order, idx, n_local)
+    tile = row_tile(tokens * k, w1.shape[2], dtype)
     load = {"slots_total": jnp.asarray(tokens * k, jnp.int32),
             "slots_local": n_local,
             "expert_load_max": group_sizes.max(),
-            "expert_load_mean": n_local.astype(jnp.float32) / held}
+            "expert_load_mean": n_local.astype(jnp.float32) / held,
+            "gate_tiles_live": (n_local + tile - 1) // tile,
+            "gate_tiles_total": jnp.asarray(tokens * k // tile, jnp.int32)}
     return y, load
 
 

@@ -96,9 +96,11 @@ def pytest_runtest_call(item):
 
 @pytest.fixture(params=["xla", "kernels"])
 def row_path(request, monkeypatch):
-    """Both paths of an expert layer's row movement (``ops/routed_rows``):
-    XLA's take, which is what a CPU process gets, and the kernels a TPU
-    process gets, here in interpret mode."""
+    """Both paths of an expert layer's row movement (``ops/routed_rows``)
+    and of the row-wise passes between its products (``ops/expert_gate``,
+    which asks the same question): XLA's take and the plain form, which is
+    what a CPU process gets, and the kernels a TPU process gets, here in
+    interpret mode."""
     import importlib
 
     if request.param == "kernels":

@@ -375,3 +375,43 @@ def test_routed_rows_kernels_compile_v5e(topo, dtype):
         arg((pairs, d), dtype), arg((tokens, slots), jnp.int32),
         arg((), jnp.int32), arg((tokens, slots), jnp.float32))
     assert text.count("tpu_custom_call") >= 2       # pack, gather-and-sum
+
+
+# (P, F, D, act) of the benchmark's five expert cells, in their order
+EXPERT_CELLS = [(131072, 1792, 2048, "swiglu"), (196608, 1408, 2048, "swiglu"),
+                (262144, 768, 2048, "swiglu"), (262144, 896, 2304, "swiglu"),
+                (147456, 1856, 2688, "relu2")]
+
+
+@pytest.mark.parametrize("rows,f,d,act", EXPERT_CELLS,
+                         ids=["lfm2moe", "dsv2lite", "keyevl2", "mellum2",
+                              "nemotron3nano"])
+def test_expert_gate_kernels_compile_v5e(topo, rows, f, d, act):
+    """The expert layer's row-wise passes at the sorted buffers of the
+    benchmark's cells: the gate over ``[P, F]``, its backward (five arrays a
+    tile, the gate's cotangent overwritten) and the sum of the two input
+    gradients over ``[P, D]`` (one of them overwritten).  1,856 is no
+    multiple of 128 lanes: its blocks are whole rows and its last piece half
+    a lane tile."""
+    from tensorflowonspark_tpu.ops import expert_gate as eg
+
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def arg(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one)
+
+    h, n = arg((rows, f)), arg((), jnp.int32)
+    h3 = None if act == "relu2" else h
+    assert eg.row_tile(rows, f, jnp.bfloat16) == 256
+    text = _compile(lambda h1, h3, n: eg.gate(h1, h3, n, act, impl="pallas"),
+                    h, h3, n)
+    assert len(_kernel_lines(text)) == 1
+    text = _compile(
+        lambda h1, h3, g, n: eg.gate_grad(h1, h3, g, n, act, impl="pallas"),
+        h, h3, h, n)
+    (line,) = _kernel_lines(text)
+    assert "output_to_operand_aliasing" in line
+    text = _compile(lambda a, b, n: eg.add_rows(a, b, n, impl="pallas"),
+                    arg((rows, d)), arg((rows, d)), n)
+    (line,) = _kernel_lines(text)
+    assert "output_to_operand_aliasing" in line

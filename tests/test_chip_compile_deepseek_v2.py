@@ -28,5 +28,13 @@ def test_deepseek_v2_lite_step_compiles_and_fits_v5e(topo, monkeypatch):
     # layers x 3 grouped products x 4 passes, and their row movement
     assert sum("/attention/flash/" in line for line in calls) == 15
     assert not _one_lane_arrays(_flash_calls(calls))
-    assert sum("/moe/experts/" in line for line in calls) == 48
+    assert sum("/moe/experts/" in line for line in calls) == 48 + 16
+    # ... and between them the row-wise passes that stop at n_local: the
+    # gate (forward, recomputed forward), its backward and the sum of the two
+    # input gradients, an expert layer
+    for kernel, count in (("expert_gate", 8), ("expert_gate_grad", 4),
+                          ("expert_gate_sum", 4)):
+        assert sum("/moe/experts/" in line
+                   and "/{}/pallas_call".format(kernel) in line
+                   for line in calls) == count, kernel
     assert len(calls) >= 15 + 48 + 40

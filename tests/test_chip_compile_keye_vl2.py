@@ -45,4 +45,12 @@ def test_keye_vl2_step_compiles_and_fits_v5e(topo, monkeypatch):
     assert count("select", "dsa_select/") == 4
     assert count("index_loss", "dsa_index_loss/") == 4
     assert count("index_loss", "dsa_index_loss_grads/") == 4
-    assert sum("/moe/experts/" in line for line in calls) == 48
+    assert sum("/moe/experts/" in line for line in calls) == 48 + 16
+    # ... and between them the row-wise passes that stop at n_local: the
+    # gate (forward, recomputed forward), its backward and the sum of the two
+    # input gradients, an expert layer
+    for kernel, count in (("expert_gate", 8), ("expert_gate_grad", 4),
+                          ("expert_gate_sum", 4)):
+        assert sum("/moe/experts/" in line
+                   and "/{}/pallas_call".format(kernel) in line
+                   for line in calls) == count, kernel

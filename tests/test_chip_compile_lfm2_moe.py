@@ -12,11 +12,12 @@ def test_lfm2_moe_step_compiles_and_fits_v5e(topo, monkeypatch):
     configuration: published widths, the layer pattern, 8 of 32 experts,
     batch and 8,192-token rows as the file says, bf16 compute, remat per
     block, Adam) compiles for one described v5e chip, with the grouped-query
-    flash kernels, the grouped expert products and the expert layer's row
-    movement (pallas kernels all) in it, and XLA's memory analysis of it
-    (arguments + outputs - aliased + temporaries) is no larger than the
-    11.73 GiB it is with the attention layer's kernel output and logsumexp
-    rows kept across the recomputed block and the flash kernels' statistics
+    flash kernels, the grouped expert products, the row-wise passes between
+    them and the expert layer's row movement (pallas kernels all) in it, and
+    XLA's memory analysis of it (arguments + outputs - aliased +
+    temporaries) is no larger than the 11.73 GiB it is with the attention
+    layer's kernel output and logsumexp rows kept across the recomputed
+    block and the flash kernels' statistics
     as dense rows (PR 40; 12.01 while they were ``[.., seq, 1]``; a v5e
     offers 15.75).  The numbers of PR 28 are in the configuration's
     ``assumed.batch_size``."""
@@ -36,7 +37,15 @@ def test_lfm2_moe_step_compiles_and_fits_v5e(topo, monkeypatch):
     # gathers them (forward and recomputed forward) and its gradient packs
     # and gather-sums; combine packs and gather-sums once (its recomputed
     # forward is dead code) and its gradient packs and gathers
-    assert len(calls) >= 48 + 3 + 40
+    assert len(calls) >= 48 + 3 + 40 + 16
+    # ... and between them the row-wise passes that stop at n_local: the
+    # gate (forward, recomputed forward), its backward and the sum of the two
+    # input gradients, an expert layer
+    for kernel, count in (("expert_gate", 8), ("expert_gate_grad", 4),
+                          ("expert_gate_sum", 4)):
+        assert sum("/moe/experts/" in line
+                   and "/{}/pallas_call".format(kernel) in line
+                   for line in calls) == count, kernel
     for scope, kernel, count in (("dispatch", "gather", 8),
                                  ("dispatch", "sum", 4),
                                  ("combine", "sum", 4),

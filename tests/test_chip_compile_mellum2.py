@@ -29,4 +29,12 @@ def test_mellum2_step_compiles_and_fits_v5e(topo, monkeypatch):
     assert sum("/attention/flash/" in line for line in calls) == 3
     assert not _one_lane_arrays("\n".join(
         line for line in calls if "/attention/flash" in line))
-    assert sum("/moe/experts/" in line for line in calls) == 48
+    assert sum("/moe/experts/" in line for line in calls) == 48 + 16
+    # ... and between them the row-wise passes that stop at n_local: the
+    # gate (forward, recomputed forward), its backward and the sum of the two
+    # input gradients, an expert layer
+    for kernel, count in (("expert_gate", 8), ("expert_gate_grad", 4),
+                          ("expert_gate_sum", 4)):
+        assert sum("/moe/experts/" in line
+                   and "/{}/pallas_call".format(kernel) in line
+                   for line in calls) == count, kernel

@@ -32,5 +32,10 @@ def test_nemotron3_nano_step_compiles_and_fits_v5e(topo, monkeypatch):
     assert sum("/mamba/scan/" in line for line in calls) == 8
     assert sum("/attention/flash/" in line for line in calls) == 3
     # 4 expert layers x 2 grouped products x (forward, recomputed forward,
-    # two gradients)
-    assert sum("/moe/experts/" in line for line in calls) == 32
+    # two gradients), and between them the gate (forward, recomputed) and
+    # its backward, which stop at n_local (relu2: one "up" product, no sum)
+    assert sum("/moe/experts/" in line for line in calls) == 32 + 12
+    for kernel, count in (("expert_gate", 8), ("expert_gate_grad", 4),
+                          ("expert_gate_sum", 0)):
+        assert sum("/{}/pallas_call".format(kernel) in line
+                   for line in calls) == count, kernel

@@ -77,6 +77,13 @@ def test_the_shares_add_up_to_the_whole_layer(row_path):
                                np.asarray(whole), atol=2e-5, rtol=2e-5)
     assert sum(int(c["moe_slots_local"]) for _, c in parts) == 3 * 40 * 2
     assert all(int(c["moe_slots_total"]) == 3 * 40 * 2 for _, c in parts)
+    # the gate's passes visit a share's pairs rounded up to a row tile (240
+    # rows are 15 tiles of 16), and every tile where every expert is held
+    for _, c in parts + [_program_share(w, x, 0, 8)]:
+        assert int(c["moe_gate_tiles_total"]) == 15
+        assert int(c["moe_gate_tiles_live"]) == -(
+            -int(c["moe_slots_local"]) // 16)
+    assert all(int(c["moe_gate_tiles_live"]) < 15 for _, c in parts)
     # one share alone is the reference's same share, not a rescaled whole
     np.testing.assert_allclose(
         np.asarray(parts[1][0]), np.asarray(_reference_layer(w, x, (2, 2))),
@@ -109,6 +116,7 @@ def test_a_share_that_nothing_is_routed_to_gives_zero(row_path):
     w, x = _layer_weights(2, bias)
     y, counts = _program_share(w, x, 4, 4)
     assert int(counts["moe_slots_local"]) == 0
+    assert int(counts["moe_gate_tiles_live"]) == 0
     assert not np.asarray(y).any()
 
 

@@ -383,12 +383,45 @@ def test_grouped_matmul_kernels_match_ragged_dot(sizes):
                                    rtol=1e-3, err_msg="d" + name)
 
 
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+@pytest.mark.parametrize("sizes", [[40, 0, 100, 37], [0, 0, 0, 256],
+                                   [64, 64, 64, 64]],
+                         ids=["uneven", "one_group", "even"])
+def test_grouped_matmul_grads_are_what_differentiating_gives(sizes, impl):
+    """``grouped_matmul_grads`` against ``jax.vjp`` of ``grouped_matmul``
+    under the same ``impl`` (the kernels in interpret mode), on the rows in
+    front of the last group: the same two cotangents, to the last bit."""
+    import importlib
+
+    gm = importlib.import_module("tensorflowonspark_tpu.ops.grouped_matmul")
+    keys = jax.random.split(jax.random.PRNGKey(9), 3)
+    lhs = jax.random.normal(keys[0], (256, 128))
+    rhs = 0.1 * jax.random.normal(keys[1], (4, 128, 256))
+    group_sizes = jnp.asarray(sizes, jnp.int32)
+    valid = (jnp.arange(256) < group_sizes.sum())[:, None]
+    grad = jnp.where(valid, jax.random.normal(keys[2], (256, 256)), 0.0)
+    lhs = jnp.where(valid, lhs, 0.0)
+    want = jax.vjp(lambda lhs, rhs: gm.grouped_matmul(
+        lhs, rhs, group_sizes, impl=impl, interpret=True), lhs, rhs)[1](grad)
+    got = gm.grouped_matmul_grads(lhs, rhs, group_sizes, grad, impl=impl,
+                                  interpret=True)
+    assert [g.shape for g in got] == [lhs.shape, rhs.shape]
+    np.testing.assert_array_equal(np.asarray(jnp.where(valid, got[0], 0.0)),
+                                  np.asarray(jnp.where(valid, want[0], 0.0)))
+    np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
+
+
 def test_grouped_matmul_refuses_an_unknown_impl():
     from tensorflowonspark_tpu.ops import grouped_matmul
+    from tensorflowonspark_tpu.ops.grouped_matmul import grouped_matmul_grads
 
     with pytest.raises(ValueError, match="impl"):
         grouped_matmul(jnp.zeros((8, 8)), jnp.zeros((1, 8, 8)),
                        jnp.asarray([8], jnp.int32), impl="cuda")
+    with pytest.raises(ValueError, match="impl"):
+        grouped_matmul_grads(jnp.zeros((8, 8)), jnp.zeros((1, 8, 8)),
+                             jnp.asarray([8], jnp.int32), jnp.zeros((8, 8)),
+                             impl="cuda")
 
 
 # the row movement of the expert layer: 64 tokens, 4 slots, rows of 256
@@ -487,7 +520,8 @@ def test_nothing_reads_a_sorted_buffer_behind_n_local(row_kernels,
     """``experts_ffn`` with every kernel in interpret mode and the rows
     behind ``n_local`` of every sorted buffer set to NaN, forward and
     backward (the tokens in expert order, the grouped products' inputs and
-    outputs, and their cotangents): the layer's output and the gradients of
+    outputs, and their cotangents, the "up" products' own backward among
+    them): the layer's output and the gradients of
     tokens, weights and expert weights are finite and equal to the XLA
     path's, which is never poisoned."""
     import importlib
@@ -495,7 +529,7 @@ def test_nothing_reads_a_sorted_buffer_behind_n_local(row_kernels,
     from tensorflowonspark_tpu.parallel import ep
 
     gm = importlib.import_module("tensorflowonspark_tpu.ops.grouped_matmul")
-    real = gm.grouped_matmul
+    real, real_grads = gm.grouped_matmul, gm.grouped_matmul_grads
 
     @jax.custom_vjp
     def poison(rows, n):
@@ -513,6 +547,13 @@ def test_nothing_reads_a_sorted_buffer_behind_n_local(row_kernels,
         return poison(real(poison(lhs, n), rhs, group_sizes, impl="pallas",
                            interpret=True), n)
 
+    def poisoned_grads(lhs, rhs, group_sizes, grad):
+        n = group_sizes.sum()
+        d_lhs, d_rhs = real_grads(poison(lhs, n), rhs, group_sizes,
+                                  poison(grad, n), impl="pallas",
+                                  interpret=True)
+        return poison(d_lhs, n), d_rhs
+
     tokens, k, d, f, held = 128, 2, 128, 128, 3
     ks = jax.random.split(jax.random.PRNGKey(5), 6)
     x = jax.random.normal(ks[0], (tokens, d))
@@ -527,6 +568,7 @@ def test_nothing_reads_a_sorted_buffer_behind_n_local(row_kernels,
 
     run = jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4), has_aux=True)
     monkeypatch.setattr(gm, "grouped_matmul", poisoned)
+    monkeypatch.setattr(gm, "grouped_matmul_grads", poisoned_grads)
     (_, (y, n_local)), grads = run(x, weights, *ws)
     monkeypatch.undo()
     assert len(calls) == 3
@@ -538,6 +580,177 @@ def test_nothing_reads_a_sorted_buffer_behind_n_local(row_kernels,
         assert np.isfinite(np.asarray(got)).all(), name
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=1e-3, rtol=1e-3, err_msg=name)
+
+
+# the row-wise passes between the grouped products: four row tiles of 32
+GATE_P, GATE_TILE = 128, 32
+
+
+@pytest.fixture
+def gate_kernels(row_kernels, monkeypatch):
+    """``ops/expert_gate`` with four row tiles in ``GATE_P`` rows; its
+    kernels are the default path wherever the row movement's are."""
+    from tensorflowonspark_tpu.ops import expert_gate as eg
+
+    monkeypatch.setattr(eg, "TILE", GATE_TILE)
+    return eg
+
+
+@pytest.mark.parametrize("width", [1792, 1856])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("act", ["swiglu", "relu2"])
+@pytest.mark.parametrize("n_local", [0, 1, 40, 64, GATE_P],
+                         ids=["none", "one_row", "mid_tile", "tile_edge",
+                              "all"])
+def test_expert_gate_kernels_match_the_plain_formulation(gate_kernels,
+                                                         n_local, act, dtype,
+                                                         width):
+    """The gate, its backward and the sum through the front-tile kernels
+    (interpret mode) against the plain ``jax.numpy`` form and jax's
+    derivative of it, on the rows in front of ``n_local``: no row, one, a
+    tile and a part, two tiles exactly, every row; at the widths of the
+    benchmark's cells 3 and 7 (1,856 is 14 lane tiles and a half).  The
+    kernels agree to the last bit with the plain form computed in float32
+    and rounded once, in float32 and in bfloat16; the plain form in
+    bfloat16, which off the TPU rounds after every operation, lies within
+    2 ** -6 of the largest value of them (terms of the gate's derivative
+    cancel, so an element's own relative distance can be anything).  A tile
+    wholly behind ``n_local`` keeps what the buffer held (NaN here, where a
+    result is written over an operand)."""
+    eg = gate_kernels
+    ks = jax.random.split(jax.random.PRNGKey(3), 5)
+    h1, h3, d_h, a, b = (jax.random.normal(key, (GATE_P, width)).astype(dtype)
+                         for key in ks)
+    h3 = None if act == "relu2" else h3
+    n = jnp.int32(n_local)
+    front = (jnp.arange(GATE_P) < n_local)[:, None]
+    # rows behind the last tile in front of n_local, which no kernel touches
+    behind = jnp.arange(GATE_P) >= max(-(-n_local // GATE_TILE), 1) * GATE_TILE
+
+    def poisoned(v):
+        return jnp.where(front, v, jnp.nan)
+
+    def plain(fn, *arrays):
+        """``fn`` by the plain form in float32, rounded once, and (bfloat16)
+        in the arrays' dtype, rounded after every operation."""
+        wide = fn(*[None if v is None else v.astype(jnp.float32)
+                    for v in arrays])
+        wide = jax.tree_util.tree_map(lambda v: v.astype(dtype), wide)
+        return wide, fn(*arrays)
+
+    def same(got, want):
+        got, (once, each) = (jax.tree_util.tree_map(
+            lambda v: np.asarray(jnp.where(front, v, 0), np.float32), tree)
+            for tree in (got, want))
+        for g, o, e in zip(*map(jax.tree_util.tree_leaves,
+                                (got, once, each))):
+            np.testing.assert_array_equal(g, o)
+            np.testing.assert_allclose(g, e, rtol=0,
+                                       atol=2 ** -6 * np.abs(e).max())
+
+    same(eg.gate(h1, h3, n, act),
+         plain(lambda h1, h3: eg.gate(h1, h3, n, act, impl="xla"), h1, h3))
+    got = eg.gate_grad(h1, h3, poisoned(d_h), n, act)
+    same(got, plain(lambda *v: eg.gate_grad(*v, n, act, impl="xla"),
+                    h1, h3, d_h))
+    assert (got[1] is None) == (act == "relu2")
+    assert np.isnan(np.asarray(got[0], np.float32)[behind]).all()
+    got = eg.add_rows(poisoned(a), poisoned(b), n)
+    same(got, plain(lambda a, b: eg.add_rows(a, b, n, impl="xla"), a, b))
+    assert np.isnan(np.asarray(got, np.float32)[behind]).all()
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("act", ["swiglu", "relu2"])
+@pytest.mark.parametrize("first,held", [(8, 2), (2, 3), (0, 8)],
+                         ids=["none_held", "some_held", "all_held"])
+def test_experts_ffn_matches_the_products_differentiated_by_jax(
+        gate_kernels, monkeypatch, first, held, act, dtype):
+    """``experts_ffn`` against the formulation it had before its "up" half
+    wrote its own backward (the plain gate between the grouped products,
+    everything differentiated by jax), the same kernels under both in
+    interpret mode: the output and the gradients in ``x``, ``weights``,
+    ``w1``, ``w3`` and ``w2``, where the held experts get no pair, some
+    (``n_local`` inside a row tile) and every one (every tile live)."""
+    import functools
+    import importlib
+
+    from tensorflowonspark_tpu.parallel import ep
+
+    gm = importlib.import_module("tensorflowonspark_tpu.ops.grouped_matmul")
+    for name in ("grouped_matmul", "grouped_matmul_grads"):
+        monkeypatch.setattr(gm, name, functools.partial(
+            getattr(gm, name), impl="pallas", interpret=True))
+    tokens, k, d, f = 64, 2, 128, 192
+    ks = jax.random.split(jax.random.PRNGKey(7), 6)
+    x = jax.random.normal(ks[0], (tokens, d))
+    sel = jax.random.randint(ks[1], (tokens, k), 0, 8, jnp.int32)
+    weights = jax.random.uniform(ks[2], (tokens, k))
+    w1, w3, w2 = (0.1 * jax.random.normal(key, shape) for key, shape in zip(
+        ks[3:], [(held, d, f), (held, d, f), (held, f, d)]))
+    params = (x, weights, w1, w2) + ((w3,) if act == "swiglu" else ())
+
+    def before(x, weights, w1, w2, w3=None):
+        order, inverse, group_sizes, n_local = ep.sort_pairs(sel, first, held)
+        idx = inverse.reshape(tokens, k)
+        xs = ep._dispatch(x.astype(dtype), order // k, idx, n_local)
+        h = gm.grouped_matmul(xs, w1.astype(dtype), group_sizes)
+        if act == "relu2":
+            h = jnp.square(jax.nn.relu(h))
+        else:
+            h = jax.nn.silu(h) * gm.grouped_matmul(xs, w3.astype(dtype),
+                                                   group_sizes)
+        ys = gm.grouped_matmul(h, w2.astype(dtype), group_sizes)
+        return ep._combine(ys, weights, order, idx, n_local), n_local
+
+    def now(x, weights, w1, w2, w3=None):
+        y, load = ep.experts_ffn(x, sel, weights, w1, w3, w2, first,
+                                 dtype=dtype, act=act)
+        assert set(load) >= {"gate_tiles_live", "gate_tiles_total"}
+        return y, (load["slots_local"], load["gate_tiles_live"],
+                   load["gate_tiles_total"])
+
+    def run(fn):
+        def loss(*params):
+            y, aux = fn(*params)
+            return (y.astype(jnp.float32) ** 2).sum(), (y, aux)
+
+        (_, (y, aux)), grads = jax.value_and_grad(
+            loss, argnums=tuple(range(len(params))), has_aux=True)(*params)
+        return (y,) + grads, aux
+
+    got, (n_local, live, total) = run(now)
+    want, n_want = run(before)
+    assert int(n_local) == int(n_want)
+    assert (int(n_local) == 0) == (held == 2)
+    assert int(total) == tokens * k // GATE_TILE
+    assert int(live) == -(-int(n_local) // GATE_TILE)
+    if held == 8:
+        assert int(live) == int(total)
+    elif held == 3:
+        assert int(n_local) % GATE_TILE and int(live) < int(total)
+    for a, b, name in zip(got, want, ("y", "d_x", "d_weights", "d_w1", "d_w2",
+                                      "d_w3")):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.isfinite(a).all(), name
+        if dtype == jnp.float32:
+            np.testing.assert_array_equal(a, b, err_msg=name)
+        else:
+            np.testing.assert_allclose(a, b, rtol=3e-2,
+                                       atol=3e-2 * np.abs(b).max(),
+                                       err_msg=name)
+
+
+def test_expert_gate_refuses_an_unknown_impl_and_form():
+    from tensorflowonspark_tpu.ops import expert_gate
+
+    h = jnp.zeros((8, 8))
+    with pytest.raises(ValueError, match="impl"):
+        expert_gate.gate(h, h, 8, impl="cuda")
+    with pytest.raises(ValueError, match="form"):
+        expert_gate.gate(h, h, 8, act="gelu", impl="xla")
 
 
 def test_gather_sum_rows_takes_a_slot_count_that_is_no_power_of_two(

@@ -65,7 +65,7 @@ RoPE), ``block_i/attention/select`` (the one kernel that scores every causal
 pair and picks each query's keys: the scores never leave VMEM, so they have
 no scope apart from the search), ``block_i/attention/tiles`` (the count of
 the tiles the picks touch), ``block_i/attention/index_loss`` (the kernel of
-the index's loss),
+the index's loss, in a training step the form with its gradients),
 ``block_i/moe/route`` (router, top-k, sort), ``moe/dispatch`` (gather),
 ``moe/experts`` (the grouped products), ``moe/combine`` (scale, gather back),
 ``moe/shared`` (the shared expert); of a Mamba-2 layer
@@ -319,10 +319,10 @@ def _backward_together(main, side):
     For a branch whose gradient ends in parameters alone (the index of
     :meth:`Attention._indexed`, which reads a detached ``x``): nothing of the
     layers below waits for it, so the compiler is free to put its backward
-    kernels behind theirs, and holds what they read (a layer's folded ``q``
-    and ``k``, the index's queries) all the while.  At 32,768-token rows two
-    layers' worth of that, 0.9 GB, lay over the third's expert layer, the
-    step's peak (``tests/test_chip_compile.py``, the keye step)."""
+    pass behind theirs, and to hold what that reads all the while (the
+    gradients the index's loss kept in the forward pass, 159 MB a layer at
+    32,768-token rows) over their expert layers, the step's peak
+    (``tests/test_chip_compile_keye_vl2.py``)."""
     return main, side
 
 
@@ -431,7 +431,7 @@ class Attention(nn.Module):
         block = self.flash_block
         with jax.named_scope("indexer"):
             iq, ik, iw = self._index(x)
-        # the index's backward kernels run in this layer's backward pass
+        # the index's backward runs in this layer's backward pass
         q, (iq, ik, iw) = _backward_together(q, (iq, ik, iw))
         with jax.named_scope("select"):
             bits, index_lse = sparse_index.select_keys(

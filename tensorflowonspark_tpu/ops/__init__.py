@@ -18,8 +18,12 @@ them on the CPU mesh.
 
 ``KEPT``: the names (``jax.ad_checkpoint.checkpoint_name``) of the kernels'
 residuals that cost a kernel run to make again, all modules' together: what a
-rematerialised block keeps (``save_only_these_names(*KEPT)``).  A kernel with
-such a residual adds its names to its module's ``KEPT`` and its module here.
+rematerialised block keeps (``save_only_these_names(*KEPT)``): the flash
+kernel's output and logsumexp rows, the learned index's key bits and index
+logsumexp and the three gradients its loss's kernel makes in the forward pass
+(``dsa_index_loss_dq``, ``_dk``, ``_dw``: all its backward rule reads), the
+scans' outputs and states.  A kernel with such a residual adds its names to
+its module's ``KEPT`` and its module here.
 """
 
 from tensorflowonspark_tpu.ops import gated_delta, sparse_index, ssd_scan

@@ -22,10 +22,19 @@ layer; nothing here ever holds one in HBM:
   S_t} p[t, s] (log p[t, s] - log softmax_{S_t}(I[t, .])[s])`` with ``p``
   the attention probabilities over the kept keys averaged over the query
   heads (a constant).  One kernel over the causal tiles recomputes the
-  scores and the heads' probabilities from the saved logsumexp rows; in the
-  backward pass the same kernel also accumulates the gradient to ``qI``,
-  ``kI`` and ``w`` (``dL/dI = (softmax(I) - p) / T`` on the kept pairs).
-  Nothing else gets a gradient from it.
+  scores and the heads' probabilities from the saved logsumexp rows
+  (``dsa_index_loss``: the value alone, which is all an undifferentiated
+  call runs).  Under differentiation the forward pass runs the form that
+  also accumulates the gradient of the loss's sum to ``qI``, ``kI`` and
+  ``w`` (``dsa_index_loss_grads``: ``dL/dI = softmax(I) - p`` on the kept
+  pairs) and no other: the value is that kernel's, the heads' scores are
+  taken once a step, and the backward pass runs no kernel, it scales the
+  three gradients by the cotangent over ``T``.  They are float32, as the
+  kernel accumulates them, ``qI``'s a dense row a position (``[B, T, J
+  E]``: 134 MB a layer at 32k, where ``[B J, T, E]`` would lie on half its
+  lanes), and carry names (``KEPT_GRADS``) that a checkpoint policy keeps,
+  so that a recomputed block does not run the kernel again.  Nothing else
+  gets a gradient from it.
 
 Off-TPU the kernels run in pallas interpret mode.  Precision: the index
 products take bf16 (the inputs') operands and accumulate in float32; scores,
@@ -46,9 +55,11 @@ _INT_MIN = -2 ** 31
 # the kernels keep a block's scores (select) or a row block of every head
 # (loss) in VMEM: more than the compiler's default share of the 128 MiB
 _VMEM_LIMIT = 100 * 1024 * 1024
-# select_keys' results, by the names a checkpoint policy may keep them under
+# select_keys' results and the loss kernel's three gradients (to index_q,
+# index_k, index_w), by the names a checkpoint policy may keep them under
 KEPT_BITS, KEPT_INDEX_LSE = "dsa_key_bits", "dsa_index_lse"
-KEPT = (KEPT_BITS, KEPT_INDEX_LSE)
+KEPT_GRADS = ("dsa_index_loss_dq", "dsa_index_loss_dk", "dsa_index_loss_dw")
+KEPT = (KEPT_BITS, KEPT_INDEX_LSE) + KEPT_GRADS
 
 
 def key_groups(seq):
@@ -322,13 +333,17 @@ def _loss_kernel(*refs, scale, block, n_k, heads, group, index_heads,
     def _emit():
         loss_ref[0] = loss_scr[:]
         if with_grads:
-            diq_ref[...] = diq_scr[:]
+            # the heads side by side on the lanes: a dense row a position
+            dim = diq_scr.shape[2]
+            for j in range(index_heads):
+                diq_ref[0, :, j * dim:(j + 1) * dim] = diq_scr[j]
             diw_ref[0] = diw_scr[:, :index_heads]
 
 
 def _loss_call(q, k, lse, index_q, index_k, index_w, index_lse, bits, scale,
                block, interpret, with_grads):
-    """Per-position loss ``[B, T]`` (and the three gradients of its sum)."""
+    """Per-position loss ``[B, T]`` (and the three gradients of its sum, as
+    the kernel lays them out: :func:`_unfold_grads`)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -361,12 +376,12 @@ def _loss_call(q, k, lse, index_q, index_k, index_w, index_lse, bits, scale,
     scratch = [pltpu.VMEM((block, 1), jnp.float32)]
     if with_grads:
         out_specs += [
-            pl.BlockSpec((index_heads, block, index_dim), at_q),
+            pl.BlockSpec((1, block, index_heads * index_dim), at_q),
             pl.BlockSpec((1, n, index_dim, block),
                          lambda b, i, kk: (b, 0, 0, 0)),
             pl.BlockSpec((1, block, index_heads), at_q)]
         out_shape += [
-            jax.ShapeDtypeStruct((batch * index_heads, seq, index_dim),
+            jax.ShapeDtypeStruct((batch, seq, index_heads * index_dim),
                                  jnp.float32),
             jax.ShapeDtypeStruct((batch, n, index_dim, block), jnp.float32),
             jax.ShapeDtypeStruct((batch, seq, index_heads), jnp.float32)]
@@ -386,41 +401,49 @@ def _loss_call(q, k, lse, index_q, index_k, index_w, index_lse, bits, scale,
         name="dsa_index_loss_grads" if with_grads else "dsa_index_loss",
     )(_fold_heads(q), _fold_heads(k), lse, _fold_heads(index_q), index_k,
       index_w, index_lse[..., None], bits)
-    if not with_grads:
-        return outs[0][..., 0]
-    loss, diq, dik, diw = outs
-    diq = diq.reshape(batch, index_heads, seq, index_dim).transpose(
-        0, 2, 1, 3)
-    dik = dik.transpose(0, 1, 3, 2).reshape(batch, seq, index_dim)
-    return loss[..., 0], (diq, dik, diw)
+    loss = outs[0][..., 0]
+    return (loss, tuple(outs[1:])) if with_grads else loss
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10))
+def _unfold_grads(grads):
+    """The kernel's three gradients in their inputs' shapes: ``index_q``'s
+    ``[B, T, J E] -> [B, T, J, E]``, ``index_k``'s ``[B, T / block, E,
+    block] -> [B, T, E]``, ``index_w``'s ``[B, T, J]`` as it is."""
+    diq, dik, diw = grads
+    batch, seq, index_heads = diw.shape
+    return (diq.reshape(batch, seq, index_heads, -1),
+            dik.transpose(0, 1, 3, 2).reshape(batch, seq, -1), diw)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10, 11))
 def _index_loss(index_q, index_k, index_w, q, k, lse, index_lse, bits, scale,
-                block, interpret):
+                block, interpret, dtypes):
+    # undifferentiated, the value alone
     return _loss_call(q, k, lse, index_q, index_k, index_w, index_lse, bits,
                       scale, block, interpret, False).mean(axis=1)
 
 
 def _index_loss_fwd(index_q, index_k, index_w, q, k, lse, index_lse, bits,
-                    scale, block, interpret):
-    # the value alone: under a recomputed block its second run has no reader
-    # and goes, and the gradients are made once, in the backward pass
-    loss = _index_loss(index_q, index_k, index_w, q, k, lse, index_lse, bits,
-                       scale, block, interpret)
-    return loss, (index_q, index_k, index_w, q, k, lse, index_lse, bits)
+                    scale, block, interpret, dtypes):
+    # the value and the gradients of its sum from one pass over the heads'
+    # scores; the names on the kernel's own results (one put on them outside
+    # the rule would name a copy), so that a recomputed block keeps them and
+    # its second run of the kernel has no reader and goes
+    loss, grads = _loss_call(q, k, lse, index_q, index_k, index_w, index_lse,
+                             bits, scale, block, interpret, True)
+    return loss.mean(axis=1), tuple(
+        checkpoint_name(d, name) for d, name in zip(grads, KEPT_GRADS))
 
 
-def _index_loss_bwd(scale, block, interpret, res, g):
-    index_q, index_k, index_w, q, k, lse, index_lse, bits = res
-    _, grads = _loss_call(q, k, lse, index_q, index_k, index_w, index_lse,
-                          bits, scale, block, interpret, True)
-    seq = q.shape[1]
-    scaled = [(d * (g / seq).reshape((-1,) + (1,) * (d.ndim - 1))).astype(
-        x.dtype) for d, x in zip(grads, (index_q, index_k, index_w))]
-    zeros = [np.zeros(x.shape, jax.dtypes.float0) if x.dtype == jnp.int32
-             else jnp.zeros_like(x) for x in (q, k, lse, index_lse, bits)]
-    return tuple(scaled) + tuple(zeros)
+def _index_loss_bwd(scale, block, interpret, dtypes, grads, g):
+    # no kernel: the kept gradients times the cotangent, in the types of
+    # what they are gradients to (``dtypes``)
+    grads = _unfold_grads(grads)
+    seq = grads[0].shape[1]
+    scaled = tuple(
+        (d * (g / seq).reshape((-1,) + (1,) * (d.ndim - 1))).astype(dtype)
+        for d, dtype in zip(grads, dtypes))
+    return scaled + (None,) * 5      # q, k, lse, index_lse, bits: constants
 
 
 _index_loss.defvjp(_index_loss_fwd, _index_loss_bwd)
@@ -443,5 +466,7 @@ def index_loss(index_q, index_k, index_w, q, k, lse, index_lse, key_bits,
     if scale is None:
         scale = dim ** -0.5
     q, k, lse, index_lse = jax.lax.stop_gradient((q, k, lse, index_lse))
-    return _index_loss(index_q, index_k, index_w.astype(jnp.float32), q, k,
-                       lse, index_lse, key_bits, scale, block, interpret)
+    index_w = index_w.astype(jnp.float32)
+    return _index_loss(index_q, index_k, index_w, q, k, lse, index_lse,
+                       key_bits, scale, block, interpret,
+                       (index_q.dtype, index_k.dtype, index_w.dtype))

@@ -60,12 +60,13 @@ def remat_keeps_the_attention_kernels_results(case):
     """Under ``attention="flash"`` a recomputed block does not run its
     forward kernel again: the checkpoint keeps the kernel's output and
     logsumexp rows (and an indexed layer's key bits and index logsumexp,
-    so the selection runs once too).  Loss and gradients are those of the
-    stored-activation model, and the gradient's jaxpr holds three flash
-    kernels a layer (forward, dQ, dK/dV), not four.  Cases: GPT-2's fused
-    heads, grouped-query heads, the latent form (values narrower than
-    keys), a learned index, and the kernel mapped over a mesh's shards
-    (the names sit inside the ``shard_map``)."""
+    so the selection runs once too, and the three gradients of the index's
+    loss, whose kernel runs once, with them, and never for the value alone).
+    Loss and gradients are those of the stored-activation model, and the
+    gradient's jaxpr holds three flash kernels a layer (forward, dQ, dK/dV),
+    not four.  Cases: GPT-2's fused heads, grouped-query heads, the latent
+    form (values narrower than keys), a learned index, and the kernel mapped
+    over a mesh's shards (the names sit inside the ``shard_map``)."""
     (remat, tokens), (base, _) = (
         _flash_lm(case, flag) for flag in (True, False))
     params = base.init(jax.random.PRNGKey(0), tokens)["params"]
@@ -87,8 +88,15 @@ def remat_keeps_the_attention_kernels_results(case):
     counts = [[_kernels(jax.make_jaxpr(value_and_grad(model))(
         params).jaxpr).count(name)
         for name in ("_fwd_kernel", "_bwd_dq_kernel", "_bwd_dkv_kernel",
-                     "dsa_select")] for model in (remat, base)]
+                     "dsa_select", "dsa_index_loss_grads",
+                     "dsa_index_loss")] for model in (remat, base)]
     assert counts[0] == counts[1], counts
     layers = counts[1][0]
-    assert layers > 0 and counts[1] == [
-        layers] * 3 + [layers if case == "indexed" else 0]
+    assert layers > 0 and counts[1] == [layers] * 3 + [
+        layers if case == "indexed" else 0] * 2 + [0]
+    if case == "indexed":
+        # ... and without differentiation the kernel of the value alone
+        value = _kernels(jax.make_jaxpr(lambda p: transformer.loss_fn(remat)(
+            p, {"tokens": tokens}, mask)[0])(params).jaxpr)
+        assert (value.count("dsa_index_loss"),
+                value.count("dsa_index_loss_grads")) == (layers, 0)

@@ -137,8 +137,8 @@ def test_each_loss_reaches_its_own_leaves_alone():
 def test_the_indexs_gradient_is_made_before_its_layers_is_handed_on():
     """``_backward_together`` is the identity both ways, and its backward
     pass holds the two gradients behind one barrier (so that the index's
-    backward kernels, which nothing below waits for, run in their own
-    layer's backward pass and free what they read)."""
+    backward, which nothing below waits for, runs in its own layer's
+    backward pass and frees what it reads)."""
     main, side = jnp.arange(6.0).reshape(2, 3), (jnp.ones(4), jnp.ones(5))
 
     def loss(main, side):

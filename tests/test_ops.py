@@ -350,6 +350,129 @@ def test_a_single_kept_key_has_probability_one():
     np.testing.assert_allclose(
         np.asarray(lse), np.asarray((q * k).sum(-1) * 16 ** -0.5),
         atol=1e-6, rtol=1e-6)
+
+
+# -- the index's loss ---------------------------------------------------------
+
+# (batch, rows, query heads, KV heads, head width, index heads, index width,
+# dtype, the key sets): the model's form at test size (bfloat16 operands,
+# ``select_keys``' own sets of 40), and rows of three blocks in float32 over
+# a random causal set, index heads as wide as the cell's
+INDEX_LOSS_CASES = {
+    "selected_bf16": (2, 256, 4, 2, 16, 4, 8, jnp.bfloat16, 40),
+    "random_f32": (1, 384, 2, 1, 32, 2, 64, jnp.float32, None),
+}
+
+
+def _index_loss_inputs(case):
+    """``index_loss``'s eight operands as a layer makes them: the key sets,
+    the logsumexp of the index scores over them, the attention kernel's
+    logsumexp rows under them."""
+    from tensorflowonspark_tpu.ops import sparse_index
+
+    batch, seq, heads, kv, dim, j, e, dtype, topk = INDEX_LOSS_CASES[case]
+    ks = jax.random.split(jax.random.PRNGKey(29), 7)
+    q = jax.random.normal(ks[0], (batch, seq, heads, dim)).astype(dtype)
+    k, v = (jax.random.normal(key, (batch, seq, kv, dim)).astype(dtype)
+            for key in ks[1:3])
+    iq = jax.random.normal(ks[3], (batch, seq, j, e)).astype(dtype)
+    ik = jax.random.normal(ks[4], (batch, seq, e)).astype(dtype)
+    iw = 0.2 * jax.random.normal(ks[5], (batch, seq, j))
+    if topk:
+        bits, index_lse = sparse_index.select_keys(iq, ik, iw, topk,
+                                                   block_q=128, chunk=128)
+    else:
+        kept = (np.asarray(jax.random.uniform(ks[6], (batch, seq, seq)))
+                < 0.3) & np.tril(np.ones((seq, seq), bool)) | np.eye(
+                    seq, dtype=bool)
+        bits = _key_bits(kept)
+        scores = (iw.transpose(0, 2, 1)[..., None] * jax.nn.relu(
+            jnp.einsum("btje,bse->bjts", iq, ik))).sum(axis=1)
+        index_lse = jax.nn.logsumexp(jnp.where(kept, scores, -jnp.inf),
+                                     axis=-1)
+    _, lse = flash_attention_lse(q, k, v, causal=True, block_q=128,
+                                 block_k=128, key_bits=bits)
+    return iq, ik, iw, q, k, lse, index_lse, bits
+
+
+@pytest.mark.parametrize("case", list(INDEX_LOSS_CASES))
+def test_index_loss_takes_the_heads_scores_once(case):
+    """Under differentiation ``index_loss`` runs its kernel once, with the
+    gradients, and its backward rule none: value and the three gradients
+    are, bit for bit, those of the two-kernel form (the value from the
+    kernel without gradients, the gradients from the one with them, scaled
+    by the cotangent over the rows' length and cast, as the backward rule
+    once did itself)."""
+    from tensorflowonspark_tpu.ops import sparse_index
+
+    operands = _index_loss_inputs(case)
+    iq, ik, iw, q, k, lse, index_lse, bits = operands
+    batch, seq, j, e = iq.shape
+    weight = 0.37 * jnp.arange(1.0, 1.0 + batch)      # the cotangent, a row
+
+    def weighted(iq, ik, iw):
+        return (sparse_index.index_loss(iq, ik, iw, *operands[3:],
+                                        block=128) * weight).sum()
+
+    got, got_grads = jax.value_and_grad(weighted, (0, 1, 2))(iq, ik, iw)
+
+    def kernel(with_grads):
+        return sparse_index._loss_call(
+            q, k, lse, iq, ik, iw, index_lse, bits, q.shape[3] ** -0.5, 128,
+            True, with_grads)
+
+    want = (kernel(False).mean(axis=1) * weight).sum()
+    _, (diq, dik, diw) = kernel(True)
+    assert diq.shape == (batch, seq, j * e)           # a dense row a position
+    unit = (diq.reshape(batch, seq, j, e),
+            dik.transpose(0, 1, 3, 2).reshape(batch, seq, e), diw)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    for mine, d, x in zip(got_grads, unit, (iq, ik, iw)):
+        assert mine.dtype == x.dtype and mine.shape == x.shape
+        scaled = (d * (weight / seq).reshape((-1,) + (1,) * (d.ndim - 1))
+                  ).astype(x.dtype)
+        assert np.asarray(scaled, np.float32).any()
+        np.testing.assert_array_equal(np.asarray(mine, np.float32),
+                                      np.asarray(scaled, np.float32))
+    # ... and the value alone, undifferentiated, is the same number
+    np.testing.assert_array_equal(np.asarray(weighted(iq, ik, iw)),
+                                  np.asarray(want))
+
+
+def test_index_loss_keeps_its_gradients_and_no_one_lane_array(capsys):
+    """What ``save_only_these_names(*KEPT)`` keeps of the op across a
+    checkpoint is its kernel's three gradients, float32 and under their
+    names, the index queries' as a dense row a position: none of the
+    operands (the backward rule runs no kernel), and no array whose last
+    dimension is 1.  Traced only, at the cell's widths."""
+    from jax.ad_checkpoint import print_saved_residuals
+
+    from tensorflowonspark_tpu.ops import sparse_index
+
+    batch, seq, heads, kv, dim, j, e = 1, 1024, 32, 4, 128, 16, 64
+
+    def arg(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct((batch, seq) + shape, dtype)
+
+    def op(*operands):
+        return sparse_index.index_loss(*operands, block=512,
+                                       interpret=True).sum()
+
+    print_saved_residuals(
+        jax.checkpoint(op, policy=jax.checkpoint_policies
+                       .save_only_these_names(*sparse_index.KEPT)),
+        arg(j, e), arg(e), arg(j, dtype=jnp.float32), arg(heads, dim),
+        arg(kv, dim), arg(heads, dtype=jnp.float32),
+        jax.ShapeDtypeStruct((batch, seq), jnp.float32),
+        jax.ShapeDtypeStruct((batch, 1, seq, 128), jnp.int32))
+    kept = capsys.readouterr().out.splitlines()
+    assert [line.split(" from ")[0] for line in kept] == [
+        "f32[1,1024,1024] named 'dsa_index_loss_dq'",
+        "f32[1,2,64,512] named 'dsa_index_loss_dk'",
+        "f32[1,1024,16] named 'dsa_index_loss_dw'"], kept
+    assert not any(line.split(" ")[0].endswith(",1]") for line in kept)
+
+
 @pytest.mark.parametrize("sizes", [[40, 0, 100, 37], [0, 0, 0, 256],
                                    [64, 64, 64, 64]],
                          ids=["uneven", "one_group", "even"])
